@@ -1,26 +1,27 @@
 """Persistent-compilation-cache wiring (restart-latency fast path).
 
-Every supervisor restart and every chaos trial used to pay the full
-XLA compile of the train step on top of process boot — the dominant
-self-inflicted straggler in the recovery path (ROADMAP item 5). jax
-ships a persistent compilation cache keyed on the lowered program +
-compile options; this module is the single place its knobs are applied
-so the CLI entry points, the driver hooks, and the cluster backends
-cannot drift on how the cache is enabled:
+Every supervisor restart, chaos trial and chip run pays the full XLA
+compile of the train step on top of process boot unless a previous
+process left its compiles behind. jax ships a persistent compilation
+cache keyed on the lowered program + compile options + the cache
+path; this module is the single place its knobs are applied so the CLI
+entry points, ``chip_smoke.py`` and the cluster backends cannot drift
+on where the cache lives:
 
-* :func:`enable_persistent_cache` — apply a :class:`~.config.
-  CompileConfig`'s knobs to ``jax.config``. The cache dir resolves
-  config → ``DMT_COMPILE_CACHE_DIR`` env (how ``LocalProcessCluster``
-  threads one SHARED dir into every worker it spawns, so a restarted
-  worker hits warm compiles from its predecessor's run) → disabled.
-* :func:`cache_stats` — entries/bytes on disk plus this process's
-  hit/miss counters (from jax's monitoring events), so compile-cache
-  regressions are visible in bench artifacts and worker journals
-  instead of only as mysteriously slower restarts.
+* where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own cache lives
+  there — jax reads the variable itself, worker processes inherit it,
+  and the program sets no other directory in code;
+* where it is not set, the cache goes to ONE fixed path inside the
+  checkout (:data:`DEFAULT_CACHE_DIR`). The path is part of the cache
+  key, so a directory derived from a temporary name, a pid or the time
+  would never hit;
+* ``compile.persistent_cache=false`` turns the cache off for the
+  process (the explicit cold arm of the restart-latency bench).
 
-Measured on this repo's chaos train payload (2-device simulated mesh,
-ZeRO-1 on): spawn→first-logged-step drops ~10 s → ~5 s when the cache
-is warm — the compile simply disappears from the boot path.
+:func:`cache_stats` reports entries/bytes on disk plus this process's
+hit/miss counters (from jax's monitoring events), so compile-cache
+regressions are visible in bench artifacts and worker journals instead
+of only as mysteriously slower restarts.
 """
 
 from __future__ import annotations
@@ -34,130 +35,87 @@ from .log import get_logger
 
 logger = get_logger("compile_cache")
 
-#: the env var LocalProcessCluster threads into worker processes
-CACHE_DIR_ENV = "DMT_COMPILE_CACHE_DIR"
+#: jax's own variable: where it is set, that directory is the cache
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: the one fixed in-checkout location used when the variable is unset
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 # this process's persistent-cache hit/miss counters, fed by jax's
 # monitoring events (registered once, on first enable)
 _counters = {"hits": 0, "misses": 0}
 _listener_installed = False
+_applied = False  # enable_persistent_cache ran in this process
 _enabled_dir: Path | None = None
 
 
 def resolve_cache_dir(cfg: CompileConfig | None = None) -> Path | None:
-    """The cache dir a config resolves to: ``cfg.cache_dir`` when set,
-    else ``DMT_COMPILE_CACHE_DIR``, else None (cache disabled)."""
+    """The cache dir in force: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else :data:`DEFAULT_CACHE_DIR`; None when ``compile.
+    persistent_cache`` is off."""
     cfg = cfg or CompileConfig()
     if not cfg.persistent_cache:
         return None
-    raw = cfg.cache_dir or os.environ.get(CACHE_DIR_ENV, "")
-    return Path(raw) if raw else None
-
-
-#: jax releases whose serialized executables are UNSAFE to load in a
-#: different process than the one that compiled them. Measured on this
-#: container's 0.4.37: a restarted worker reading its predecessor's
-#: persistent-cache (or AOT) entries computes wrong numerics at its
-#: first resumed step and segfaults within a few more — dense and
-#: ZeRO-1 programs alike, graceful-drain and SIGKILL handoffs alike
-#: (13/13 corrupt with the cache on, 0/4 without). This is the
-#: cross-process face of the same-process reload corruption the AOT
-#: cache already refuses via its pid stamp. Newer jax releases fall
-#: outside the tuple and re-enable automatically.
-_CROSS_PROCESS_UNSAFE_MAX = (0, 4, 37)
-
-
-def cross_process_reuse_quarantined() -> str | None:
-    """Reason string when loading compile-cache entries written by a
-    DIFFERENT process is known to corrupt this jax, else None. Version
-    check only — no backend touch, so entry points may call this
-    before the mesh is forced."""
-    import jax
-    try:
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:3])
-    except ValueError:
-        return None  # dev/dirty version string: assume current = fixed
-    if ver <= _CROSS_PROCESS_UNSAFE_MAX:
-        return (f"jax {jax.__version__} deserializes corrupt "
-                "executables cross-process (wrong numerics then "
-                "SIGSEGV on restarted workers — measured)")
-    return None
+    raw = os.environ.get(CACHE_DIR_ENV, "")
+    return Path(raw) if raw else DEFAULT_CACHE_DIR
 
 
 def _install_listener() -> None:
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        from jax._src import monitoring
+    from jax import monitoring
 
-        def _on_event(name: str, **kw: Any) -> None:
-            if name == "/jax/compilation_cache/cache_hits":
-                _counters["hits"] += 1
-            elif name == "/jax/compilation_cache/cache_misses":
-                _counters["misses"] += 1
+    def _on_event(name: str, **kw: Any) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            _counters["hits"] += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            _counters["misses"] += 1
 
-        monitoring.register_event_listener(_on_event)
-        _listener_installed = True
-    except Exception as e:  # private API — stats degrade, cache doesn't
-        logger.debug("no cache hit/miss monitoring on this jax: %s", e)
+    monitoring.register_event_listener(_on_event)
+    _listener_installed = True
 
 
 def enable_persistent_cache(cfg: CompileConfig | None = None) -> Path | None:
     """Apply the persistent-cache knobs to ``jax.config``; returns the
-    active cache dir (None = disabled/unsupported). Safe to call more
-    than once and before or after backend init — jax reads the config
-    at each compile. Unknown knobs on older jax are skipped, never
-    fatal: a worker must train with a cold cache rather than not at
-    all."""
-    global _enabled_dir
+    active cache dir (None = off). An entry-point action (launch CLI,
+    ``chip_smoke.py``): call it once, before the first compile. Safe to
+    call again — jax reads the config at each compile."""
+    global _applied, _enabled_dir
     import jax
 
     cfg = cfg or CompileConfig()
     cache_dir = resolve_cache_dir(cfg)
-    if cache_dir is None:
-        return None
-    reason = cross_process_reuse_quarantined()
-    if reason is not None and not cfg.trust_cache_cross_process:
-        # The persistent cache's ONLY value is cross-process reuse
-        # (in-process recompiles hit jax's in-memory caches first), so
-        # a quarantined jax disables it outright: a restart must train
-        # with a cold compile rather than resume on corrupt numerics.
-        # compile.trust_cache_cross_process=true overrides for
-        # platforms someone has actually validated.
-        logger.warning("persistent compile cache QUARANTINED: %s — "
-                       "compiles stay cold (override: "
-                       "compile.trust_cache_cross_process)", reason)
-        return None
-    try:
-        cache_dir.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except Exception as e:
-        logger.warning("persistent compile cache unavailable (%s) — "
-                       "compiles stay cold", e)
-        return None
-    for knob, value in (
-            ("jax_persistent_cache_min_entry_size_bytes",
-             cfg.min_entry_size_bytes),
-            ("jax_persistent_cache_min_compile_time_secs",
-             cfg.min_compile_time_secs)):
+    if cache_dir is not None and not os.environ.get(CACHE_DIR_ENV):
         try:
-            jax.config.update(knob, value)
-        except Exception as e:  # older jax: knob absent
-            logger.debug("compile-cache knob %s unsupported: %s", knob, e)
-    _install_listener()
-    if _enabled_dir != cache_dir:
-        # jax latches "no cache" at the first compile that runs with
-        # the dir unset (measured on 0.4.37: enabling afterwards
-        # silently writes nothing) — reset the latch so enabling works
-        # whenever it happens, not only in a pristine process
-        try:
-            from jax._src import compilation_cache as _ccache
-            _ccache.reset_cache()
-        except Exception as e:
-            logger.debug("compilation-cache reset unavailable: %s", e)
-        logger.info("persistent compile cache: %s", cache_dir)
-        _enabled_dir = cache_dir
+            cache_dir.mkdir(parents=True, exist_ok=True)
+        except OSError as e:
+            # a worker must train with a cold cache rather than not at all
+            logger.warning("persistent compile cache unavailable (%s) — "
+                           "compiles stay cold", e)
+            cache_dir = None
+        else:
+            jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    # off must mean cold even under an inherited
+    # JAX_COMPILATION_CACHE_DIR, which jax honours on its own
+    jax.config.update("jax_enable_compilation_cache", cache_dir is not None)
+    if cache_dir is not None:
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          cfg.min_entry_size_bytes)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          cfg.min_compile_time_secs)
+        _install_listener()
+    if not _applied or _enabled_dir != cache_dir:
+        # jax 0.9.0 builds its cache object once, for the directory in
+        # force then, and latches "cache in use" at the first compile
+        # (jax/_src/compilation_cache.py): a change of directory or an
+        # on/off flip in a live process takes effect only after a reset.
+        # Merely setting the directory after a first compile needs none
+        # (measured: entries are written either way).
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
+        logger.info("persistent compile cache: %s", cache_dir or "off")
+        _applied, _enabled_dir = True, cache_dir
     return cache_dir
 
 

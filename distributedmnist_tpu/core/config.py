@@ -438,58 +438,34 @@ class PrecisionConfig:
 
 @dataclass(frozen=True)
 class CompileConfig:
-    """Restart-latency fast path (ROADMAP item 5): persistent XLA
-    compilation cache + ahead-of-time train-step compilation.
+    """Restart-latency fast path: persistent XLA compilation cache +
+    ahead-of-time train-step compilation.
 
     Every supervisor restart and chaos trial used to pay the full XLA
-    compile (~10 s) on top of process boot; these knobs let a restarted
-    worker reuse its predecessor's compiles.
+    compile (~10 s) on top of process boot; the persistent cache lets a
+    restarted worker reuse its predecessor's compiles.
 
-    ``cache_dir``: where jax's persistent compilation cache lives. ""
-    resolves the ``DMT_COMPILE_CACHE_DIR`` env var (how
-    ``LocalProcessCluster`` threads ONE shared cache dir into every
-    worker it spawns) and disables the cache when that is unset too —
-    so plain library use is unchanged unless a dir is provided.
-    The global jax cache is only ENABLED at process entry points
-    (launch CLI, ``__graft_entry__``) — never from inside the Trainer:
-    on jaxlib 0.4.37 a process that builds several Trainers against an
-    enabled cache corrupts itself (measured). Library callers wanting
-    it call ``core.compile_cache.enable_persistent_cache`` once at
-    startup; the Trainer itself only uses the dir for the AOT
-    executable cache below.
+    ``persistent_cache``: use jax's persistent compilation cache. Its
+    directory is not a knob: ``JAX_COMPILATION_CACHE_DIR`` where set,
+    else one fixed path inside the checkout
+    (``core.compile_cache.DEFAULT_CACHE_DIR``). The global jax cache is
+    ENABLED at process entry points (launch CLI, ``chip_smoke.py``);
+    library callers wanting it call
+    ``core.compile_cache.enable_persistent_cache`` once at startup.
+    ``false`` turns the cache off for the process even under an
+    inherited variable — the explicit cold arm.
 
     ``precompile``: Trainer AOT-compiles the train step
     (``jit(...).lower(...).compile()``) BEFORE the first batch, so
     compile time is journaled separately from step time (the
     ``event: "compile"`` record in train_log.jsonl) and a warm standby
     can park fully compiled.
-
-    ``aot_executable_cache``: additionally serialize the compiled
-    train-step executable into ``<cache_dir>/aot`` keyed on
-    (model, config, topology) where the installed jax/backend supports
-    cross-process executable serialization. Platforms that don't (the
-    CPU backend raises "Symbols not found" on a foreign executable)
-    discover it on first load, journal the fallback, and lean on the
-    persistent compilation cache instead — measured, not assumed.
     """
 
     persistent_cache: bool = True
-    cache_dir: str = ""
     min_entry_size_bytes: int = 0
     min_compile_time_secs: float = 0.0
     precompile: bool = True
-    aot_executable_cache: bool = True
-    # Cross-process cache reuse is QUARANTINED on jaxlib <= 0.4.37: a
-    # restarted worker that loads executables serialized by its dead
-    # predecessor computes wrong numerics and then segfaults (measured
-    # on this container — dense and ZeRO-1 alike, graceful or SIGKILL
-    # handoff; the cross-process face of the same-process reload
-    # corruption the AOT cache already refuses via its pid stamp).
-    # enable_persistent_cache and the AOT disk cache both refuse on a
-    # quarantined jax unless this override asserts the platform has
-    # been validated (e.g. a real TPU backend where serialization is
-    # known good).
-    trust_cache_cross_process: bool = False
 
 
 @dataclass(frozen=True)

@@ -37,8 +37,8 @@ def _load_cfg_and_bringup(args):
         simulate_devices(cfg.mesh.simulate_devices)
     else:
         initialize_distributed()  # multi-host bring-up before backend init
-    # persistent compile cache (cfg.compile / DMT_COMPILE_CACHE_DIR):
-    # a restarted worker reuses its predecessor's compiles instead of
+    # persistent compile cache (core/compile_cache.py's one rule): a
+    # restarted worker reuses its predecessor's compiles instead of
     # paying the full XLA compile again on every recovery
     from ..core.compile_cache import enable_persistent_cache
     enable_persistent_cache(cfg.compile)
@@ -156,6 +156,10 @@ def _serve(args) -> None:
     from ..servesvc.server import ServingReplica, wait_for_run_config
 
     cfg = wait_for_run_config(args.train_dir)
+    # same cache rule as `launch train`: a restarted replica reads its
+    # predecessor's prefill-bucket and decode-step compiles
+    from ..core.compile_cache import enable_persistent_cache
+    enable_persistent_cache(cfg.compile)
     activation = os.environ.get("DMT_STANDBY_ACTIVATION")
     if activation:
         args.serve_dir = _park_serve_standby(activation)

@@ -203,12 +203,15 @@ def start_evaluator(run_dir: Path) -> subprocess.Popen:
 
     The child's env is scrubbed of the parent's forced-mesh settings
     (simulate_devices mutates XLA_FLAGS/JAX_PLATFORMS process-wide) so
-    the evaluator boots the true AMBIENT backend — one real device,
-    not N virtual CPU devices it would immediately discard."""
+    the evaluator boots one device, not N virtual CPU devices it would
+    immediately discard — and it is pinned to the CPU platform: a chip
+    belongs to one process, the training parent holds it, and the
+    evaluator is an accuracy oracle, not a device measurement."""
     from ..core.mesh import strip_forced_platform_env
     run_dir.mkdir(parents=True, exist_ok=True)
     eval_dir = run_dir / "eval"
     env = strip_forced_platform_env(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     with open(run_dir / "evaluator_stdout.log", "w") as log:
         proc = subprocess.Popen(
             ["nice", "-n", "5",

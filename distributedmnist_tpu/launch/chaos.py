@@ -778,12 +778,6 @@ class ChaosConfig:
     stall_timeout_s: float | None = None  # None = per-payload default
     standby_workers: int = 0              # pre-booted spares per trial
     poll_secs: float | None = None        # None = per-payload default
-    # One persistent compile cache shared by the reference run and
-    # every trial (<campaign root>/compile_cache): the reference pays
-    # the cold compile once and every later worker boot — including
-    # every restart the faults force — is warm. What makes the
-    # boot-derived stall timeout below safe.
-    share_compile_cache: bool = True
     # Adaptive stall timeout (train payload): once a run has MEASURED
     # its spawn→first-log boot cost, trials stop paying the hardcoded
     # 90 s worst case — detection drops to
@@ -1176,12 +1170,7 @@ class ChaosCampaign:
             # its jax boot already paid
             standby_command=(cfg.resolved_serve_command()
                              if brokered and cfg.broker_standbys > 0
-                             else ""),
-            # ONE cache for the whole campaign, not per-trial: the
-            # reference's cold compile warms every later boot
-            compile_cache=cfg.share_compile_cache,
-            compile_cache_dir=(str(cfg.root / "compile_cache")
-                               if cfg.share_compile_cache else ""))
+                             else ""))
         executor = CommandExecutor(
             journal=lcfg.root / "command_journal.jsonl",
             retry=RetryPolicy(max_attempts=1, seed=seed),

@@ -402,14 +402,6 @@ class LocalClusterConfig:
     # activation file appears, then adopt the assigned logdir). "" =
     # train_command, which `launch train` realizes natively.
     standby_command: str = ""
-    # One SHARED persistent compile cache threaded into every worker's
-    # env (DMT_COMPILE_CACHE_DIR): a restarted worker hits warm
-    # compiles from its predecessor's run instead of paying the full
-    # XLA compile again. "" = <root>/compile_cache; disable with
-    # compile_cache=false. An explicit DMT_COMPILE_CACHE_DIR in
-    # cfg.env still wins.
-    compile_cache: bool = True
-    compile_cache_dir: str = ""
     env: dict[str, str] = dataclasses.field(default_factory=dict)
 
     @classmethod
@@ -430,12 +422,6 @@ class LocalClusterConfig:
 
     def standby_dir(self, j: int) -> Path:
         return self.root / f"standby{j}"
-
-    def resolved_compile_cache_dir(self) -> Path | None:
-        if not self.compile_cache:
-            return None
-        return (Path(self.compile_cache_dir) if self.compile_cache_dir
-                else self.root / "compile_cache")
 
     def resolved_standby_command(self) -> str:
         return self.standby_command or self.train_command
@@ -545,19 +531,12 @@ class LocalProcessCluster(ClusterBackend):
         env["PYTHONPATH"] = os.pathsep.join(
             [repo_root] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH")
                            else []))
-        cache = self.cfg.resolved_compile_cache_dir()
-        if cache is not None:
-            # the shared warm-compile seam: every worker (and standby)
-            # of this cluster reads/writes ONE persistent compile cache
-            env["DMT_COMPILE_CACHE_DIR"] = str(cache)
-        else:
-            # compile_cache=false must mean COLD: an inherited ambient
-            # cache dir (the bench's cold arm runs in the same shell
-            # that exported it) would silently warm every "cold"
-            # worker. jax reads its own env var at import, with no
-            # enable_persistent_cache call needed, so it must go too.
-            env.pop("DMT_COMPILE_CACHE_DIR", None)
-            env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        # the shared warm-compile seam is core/compile_cache.py's one
+        # rule: workers inherit JAX_COMPILATION_CACHE_DIR where it is
+        # set and otherwise all resolve the same fixed in-checkout
+        # path, so a restarted worker hits its predecessor's compiles.
+        # A cold worker is a payload decision
+        # (compile.persistent_cache=false), not an env edit here.
         env.update(self.cfg.env)
         env.update({"DMT_WORKER_INDEX": str(k),
                     "DMT_NUM_WORKERS": str(self.cfg.num_workers),

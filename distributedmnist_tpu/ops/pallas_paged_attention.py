@@ -49,10 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # pre-rename spelling (jax <= 0.4.x) of the same dataclass
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30  # finite: matches decode_step's mask, exp -> exact 0.0
 
 _LANE = 128

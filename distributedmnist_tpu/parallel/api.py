@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from functools import partial
 from typing import Any, Callable
 
@@ -1127,8 +1128,19 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             mb_batch = jax.tree.map(
                 lambda x: x.reshape((accum, x.shape[0] // accum)
                                     + x.shape[1:]), batch)
-            g_zero = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), fwd_params)
+            # the scan carry must enter with the varying mesh axes the
+            # body's outputs have (per-replica loss/grads), so the
+            # zeros take them from the abstract per-microbatch result
+            def zeros_as(sds):
+                z = jnp.zeros(sds.shape, jnp.float32)
+                vma = tuple(sds.vma)
+                return lax.pcast(z, vma, to="varying") if vma else z
+
+            l_zero, a_zero, g_zero = jax.tree.map(
+                zeros_as, jax.eval_shape(
+                    compute_grads,
+                    jax.tree.map(lambda x: x[0], mb_batch),
+                    prng.replica_key(state.root_key, "dropout", step, me)))
 
             def mb_body(carry, xs):
                 g_acc, l_acc, a_acc = carry
@@ -1141,8 +1153,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
                 return (g_acc, l_acc + l, a_acc + a), None
 
             (g_sum, l_sum, a_sum), _ = lax.scan(
-                mb_body, (g_zero, jnp.zeros((), jnp.float32),
-                          jnp.zeros((), jnp.float32)),
+                mb_body, (g_zero, l_zero, a_zero),
                 (mb_batch, jnp.arange(accum)))
             grads = jax.tree.map(lambda g: g / accum, g_sum)
             loss = l_sum / accum
@@ -1291,7 +1302,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
 
     zeros_ms: list[jax.Array] = []  # lazily built + cached default
     disc_default: list[jax.Array] = []  # static-cfg discipline vector
-    # AOT fast path (parallel/aot.py): precompile() fills this with the
+    # AOT fast path: precompile() fills this with the
     # ahead-of-time compiled executable + the argument signature it was
     # lowered for; step_fn then dispatches matching concrete calls
     # through it — the first training step after a precompile (or a
@@ -1345,29 +1356,30 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
 
     def precompile(state: TrainState, batch: dict,
                    measured_ms: jax.Array | None = None,
-                   discipline: jax.Array | None = None,
-                   cache_dir=None, cache_key: str | None = None,
-                   trust_cross_process: bool = False) -> dict[str, Any]:
+                   discipline: jax.Array | None = None) -> dict[str, Any]:
         """AOT-compile the step for these exact avals (no execution, no
-        donation — lowering only reads shapes) and arm the fast path.
-        With a cache_dir+key, the executable round-trips the disk cache
-        where the platform supports it AND the jax release is outside
-        the cross-process corruption quarantine (parallel/aot.py)."""
-        from . import aot as aot_lib
+        donation — lowering only reads shapes) and arm the fast path."""
         if measured_ms is None:
             measured_ms = _default_measured()
         if discipline is None:
             discipline = _default_discipline()
-        compiled, info = aot_lib.aot_compile(
-            jitted, (state, batch, measured_ms, discipline),
-            cache_dir=cache_dir, key=cache_key,
-            trust_cross_process=trust_cross_process)
-        aot_box["exe"] = compiled
-        aot_box["sig"] = _args_sig((state, batch, measured_ms, discipline))
-        return info
+        args = (state, batch, measured_ms, discipline)
+        t0 = time.perf_counter()
+        # through jax's persistent compilation cache when an entry
+        # point enabled it (core/compile_cache.py): the one warm path
+        # across processes
+        aot_box["exe"] = jitted.lower(*args).compile()
+        aot_box["sig"] = _args_sig(args)
+        # the fields Trainer journals as the event:"compile" record
+        # (its inline-compile fallback writes source "inline")
+        return {"compile_s": round(time.perf_counter() - t0, 3),
+                "source": "compiled"}
 
     step_fn.precompile = precompile
     step_fn.jitted = jitted
+    # the AOT executable the fast path runs (None before precompile) —
+    # chip_smoke.py reads its text for the Mosaic custom calls
+    step_fn.executable = lambda: aot_box.get("exe")
     step_fn.default_discipline = _default_discipline
     return step_fn
 

@@ -134,9 +134,11 @@ class ServingReplica:
                 # checkpoint is sharded-loaded through the model's TP
                 # partition rules (restore_for_topology below) and the
                 # jitted predict/decode runs GSPMD-partitioned over
-                # the serving mesh. On hosts with fewer devices than
-                # ranks the mesh is simulated (virtual CPU devices) —
-                # the sharded-load/swap/verify contract is identical.
+                # the serving mesh. A CPU host with fewer devices than
+                # ranks simulates the mesh (virtual CPU devices) — the
+                # sharded-load/swap/verify contract is identical. An
+                # accelerator host with too few chips is refused by
+                # make_topology: it never trades a chip for a CPU mesh.
                 self.topo = make_topology(MeshConfig(
                     num_replicas=1, model_parallelism=self.tp_ranks,
                     simulate_devices=(0 if len(jax.devices())

@@ -225,30 +225,21 @@ def default_spawn_fn(base_argv: list[str], serve_dir: str | Path,
         argv.append(tok)
 
     def _child_env() -> dict:
-        """On a CPU host with fewer ambient devices than ranks, rank 0
-        needs its virtual-device count forced BEFORE its XLA backend
-        initializes (post-hoc re-forcing needs jax >= 0.4.38), so the
-        supervisor plants the flag in the child env; on an accelerator
-        pod the ranks see real chips and the env passes through."""
+        """The supervisor stays off jax: a parent that initialised an
+        accelerator backend would hold the chip against rank 0. Where
+        the env pins the CPU platform, rank 0 needs its virtual-device
+        count forced BEFORE its XLA backend initializes, so the flag
+        is planted here; otherwise the ranks see the ambient chips and
+        the env passes through (too few chips is refused in rank 0 by
+        make_topology, never papered over with a CPU mesh)."""
+        from ..core.mesh import (force_device_count_flag,
+                                 forced_device_count)
         env = dict(os.environ)
-        try:
-            import re
-
-            import jax
-            if (jax.default_backend() == "cpu"
-                    and len(jax.devices()) < ranks):
-                flag = (f"--xla_force_host_platform_device_count="
-                        f"{ranks}")
-                flags = env.get("XLA_FLAGS", "")
-                if "xla_force_host_platform_device_count" in flags:
-                    flags = re.sub(
-                        r"--xla_force_host_platform_device_count=\d+",
-                        flag, flags)
-                else:
-                    flags = (flags + " " + flag).strip()
-                env["XLA_FLAGS"] = flags
-        except Exception:
-            pass
+        if env.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+            return env  # e.g. unset, or the chip host's "tpu,cpu"
+        flags = env.get("XLA_FLAGS", "")
+        if (forced_device_count(flags) or 1) < ranks:
+            env["XLA_FLAGS"] = force_device_count_flag(flags, ranks)
         return env
 
     def spawn(rank: int, attempt: int) -> subprocess.Popen:

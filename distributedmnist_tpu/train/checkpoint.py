@@ -223,7 +223,8 @@ class _LeafViews:
     single-device shard is a host view (no copy), and PJRT's
     copy-on-donate protects any buffer with a live external reference,
     so the views keep their pre-donation values after the next step
-    donates the state (verified on jaxlib 0.4.37). Crucially the
+    donates the state (pinned by tests/test_async_checkpoint.py).
+    Crucially the
     CROSS-SHARD ASSEMBLY of replica-split (ZeRO-1) leaves — the real
     per-save cost — is deferred to :meth:`materialize` on the
     checkpoint worker thread instead of the train loop."""

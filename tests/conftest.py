@@ -17,6 +17,16 @@ import os
 # (hot path); per-call toggling only affects schema.maybe_check_event.
 os.environ.setdefault("DMT_VALIDATE_EVENTS", "1")
 
+# The jaxlib 0.9.0 XLA:CPU fusion emitters contract mul+add into FMA
+# per fusion, so two programs with the SAME arithmetic but a different
+# fusion structure differ in the last bit (measured: 2/32 elements of a
+# bias leaf, 7e-9, between the ZeRO-1 layouts; gone with the emitters
+# off or with --xla_cpu_max_isa=SSE4_2). The suite's bitwise-parity
+# tests compare exactly such pairs, so the CPU test backend runs the
+# classic emitters. Before the backend initializes, like the mesh.
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_cpu_use_fusion_emitters=false").strip()
+
 from distributedmnist_tpu.core.mesh import simulate_devices  # noqa: E402
 
 simulate_devices(8)
@@ -43,33 +53,14 @@ def synthetic_datasets():
     return make_synthetic(num_train=2048, num_test=512)
 
 
-# ---- jax-0.4.37 check_rep shim vs the gold-parity tests ----------------
-#
-# On jax < 0.4.38, core/mesh.py installs its check_rep=False shard_map
-# shim (mesh.CHECK_REP_SHIM): the replication checker is off and
-# jax.lax.pcast degrades to an identity. Two measured consequences for
-# the sharded-vs-dense parity tests (moe/pp/tp):
-#   * cross-shard reductions REASSOCIATE relative to the dense
-#     single-device program — float32 forward/loss parity holds only
-#     to ~1e-4, hence the shim-conditional 2e-4 loss tolerance;
-#   * pcast's transpose (a psum) is DROPPED from backward passes, so
-#     parameter-update parity is structurally broken (measured up to
-#     ~1e-2 of param scale — a missing reduction, not noise). No
-#     tolerance can honestly cover that, so under the shim
-#     assert_update_parity skips the param comparison; loss/forward
-#     parity still gates, and jax >= 0.4.38 runs the full check.
-from distributedmnist_tpu.core.mesh import CHECK_REP_SHIM  # noqa: E402
+# ---- the gold-parity tests' tolerances (moe/pp/tp sharded vs dense) ----
 
-LOSS_TOL = (dict(rtol=2e-4, atol=2e-4) if CHECK_REP_SHIM
-            else dict(rtol=2e-5, atol=2e-5))
+LOSS_TOL = dict(rtol=2e-5, atol=2e-5)
 
 
 def assert_update_parity(got, want, rtol=3e-4, atol=3e-5):
-    """Leaf-wise sharded-vs-dense post-update parameter comparison —
-    skipped under the check_rep=False shim (see the note above)."""
+    """Leaf-wise sharded-vs-dense post-update parameter comparison."""
     import numpy as np
-    if CHECK_REP_SHIM:
-        return
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=rtol, atol=atol)

@@ -193,6 +193,22 @@ def test_exec_all_runs_in_each_worker_dir(tmp_path):
     assert not (c.cfg.worker_dir(1) / "touched.txt").exists()
 
 
+def test_worker_env_passes_the_compile_cache_variable_through(
+        tmp_path, monkeypatch):
+    """core/compile_cache.py's one rule on the worker side: a set
+    JAX_COMPILATION_CACHE_DIR reaches every worker untouched (jax reads
+    it itself), nothing else is planted over it, and unset stays unset
+    (each worker then resolves the same fixed in-checkout path)."""
+    c = _local(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    env = c._worker_env(0)
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/placed/from/outside"
+    assert "DMT_COMPILE_CACHE_DIR" not in env
+    assert env["JAX_PLATFORMS"] == "cpu"  # the cluster tier stays off chips
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert "JAX_COMPILATION_CACHE_DIR" not in c._worker_env(1)
+
+
 def test_poll_reads_worker0_structured_log(tmp_path):
     c = _local(tmp_path)
     c.create()

@@ -1,8 +1,7 @@
-"""Restart-latency fast path: persistent-cache wiring, AOT cache-key
-correctness (same config ⇒ hit, different config ⇒ miss), bitwise
-parity of the precompiled step vs the cold-compiled one, and the disk
-cache's degrade-don't-crash contract (corrupt entry, unsupported
-platform)."""
+"""Restart-latency fast path: the one rule for where the persistent
+compile cache lives (JAX_COMPILATION_CACHE_DIR where set, one fixed
+in-checkout path otherwise, off on request) and bitwise parity of the
+precompiled step vs the cold-compiled one."""
 
 import json
 
@@ -11,9 +10,6 @@ import pytest
 
 from distributedmnist_tpu.core import compile_cache as cc
 from distributedmnist_tpu.core.config import CompileConfig, ExperimentConfig
-from distributedmnist_tpu.core.mesh import make_topology
-from distributedmnist_tpu.models.registry import get_model
-from distributedmnist_tpu.parallel import aot
 
 pytestmark = pytest.mark.tier1
 
@@ -24,129 +20,113 @@ pytestmark = pytest.mark.tier1
 
 def test_compile_config_roundtrip_and_unknown_key():
     cfg = ExperimentConfig.from_dict(
-        {"compile": {"persistent_cache": False, "cache_dir": "/x",
-                     "precompile": False}})
-    assert cfg.compile.cache_dir == "/x"
+        {"compile": {"persistent_cache": False, "precompile": False}})
     assert not cfg.compile.persistent_cache
     assert ExperimentConfig.from_dict(cfg.to_dict()).compile == cfg.compile
     from distributedmnist_tpu.core.config import ConfigError
     with pytest.raises(ConfigError, match="min_entry"):
         ExperimentConfig.from_dict({"compile": {"min_entry": 1}})
+    # the directory is not a config knob (core/compile_cache.py's rule)
+    with pytest.raises(ConfigError, match="cache_dir"):
+        ExperimentConfig.from_dict({"compile": {"cache_dir": "/x"}})
 
 
-def test_resolve_cache_dir_precedence(monkeypatch, tmp_path):
+def test_resolve_cache_dir_rule(monkeypatch, tmp_path):
+    # unset → the ONE fixed path inside the checkout
     monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
-    assert cc.resolve_cache_dir(CompileConfig()) is None
+    from pathlib import Path
+    repo = Path(__file__).resolve().parents[1]
+    assert cc.resolve_cache_dir(CompileConfig()) == repo / ".jax_cache"
+    assert cc.resolve_cache_dir() == cc.DEFAULT_CACHE_DIR
+    # set → that directory
     monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "env"))
     assert cc.resolve_cache_dir(CompileConfig()) == tmp_path / "env"
-    # explicit config wins over env; the enable flag wins over both
-    got = cc.resolve_cache_dir(CompileConfig(cache_dir=str(tmp_path / "c")))
-    assert got == tmp_path / "c"
-    assert cc.resolve_cache_dir(
-        CompileConfig(persistent_cache=False,
-                      cache_dir=str(tmp_path / "c"))) is None
+    # the enable flag wins over both
+    assert cc.resolve_cache_dir(CompileConfig(persistent_cache=False)) is None
+    monkeypatch.delenv(cc.CACHE_DIR_ENV)
+    assert cc.resolve_cache_dir(CompileConfig(persistent_cache=False)) is None
 
 
-def test_enable_persistent_cache_sets_jax_config_and_stats(tmp_path):
-    d = tmp_path / "cache"
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        # this container's jax is inside the cross-process corruption
-        # quarantine — the wiring is exercised through the validated-
-        # platform override (the quarantine itself is pinned below)
-        got = cc.enable_persistent_cache(
-            CompileConfig(cache_dir=str(d), trust_cache_cross_process=True))
-        assert got == d and d.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(d)
-        import jax.numpy as jnp
-        # a program no earlier test can have compiled: jax's in-memory
-        # compilation LRU sits ABOVE the persistent cache, and an
-        # aliased HLO would never reach the disk layer this test is
-        # about (hash() is process-salted, so the constant is unique
-        # per run and the HLO unique in this process)
-        k = float(hash(str(d)) % 9973 + 2)
-        jax.jit(lambda x: (x * k).sum())(jnp.ones((4,))).block_until_ready()
-        stats = cc.cache_stats(d)
-        assert stats["entries"] >= 1 and stats["bytes"] > 0
-        # the monitoring listener fed the counters (this jax has them)
-        assert stats["hits"] + stats["misses"] >= 1
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        # drop the now-stale cache object too: it holds the tmp dir
-        # pytest is about to delete, and later multi-threaded compiles
-        # against a stale cache have been observed to corrupt the
-        # process on jax 0.4.37
-        from jax._src import compilation_cache as _ccache
-        _ccache.reset_cache()
-        cc._enabled_dir = None
+@pytest.fixture()
+def jax_cache_config():
+    """Snapshot/restore the jax cache config a test flips, and drop the
+    cache object jax built for a tmp dir pytest is about to delete."""
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_on = jax.config.jax_enable_compilation_cache
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+    jax.config.update("jax_enable_compilation_cache", prev_on)
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
+    cc._applied, cc._enabled_dir = False, None
 
 
-@pytest.mark.skipif(cc.cross_process_reuse_quarantined() is None,
-                    reason="this jax is outside the corruption quarantine")
-def test_cache_quarantine_on_known_bad_jax(tmp_path):
-    """jax <= 0.4.37 deserializes corrupt executables cross-process
-    (wrong numerics then SIGSEGV on restarted workers — measured 13/13
-    on this container): by DEFAULT both cache layers refuse, and only
-    the explicit validated-platform override re-enables them."""
-    d = tmp_path / "q"
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        assert cc.enable_persistent_cache(
-            CompileConfig(cache_dir=str(d))) is None
-        assert jax.config.jax_compilation_cache_dir == prev
-        assert not d.exists()  # refused before any side effect
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-    # the AOT disk cache refuses BOTH directions untrusted...
-    fn, args = _jit_and_args()
-    _, info = aot.aot_compile(fn, args, cache_dir=tmp_path, key="kq")
-    assert info["source"] == "compiled" and info["serialized"] is False
-    assert not (tmp_path / "aot" / "kq.exe").exists()
-    # ...and a pre-existing foreign entry is never loaded untrusted
-    _, info_t = aot.aot_compile(fn, args, cache_dir=tmp_path, key="kq",
-                                trust_cross_process=True)
-    if info_t["serialized"]:  # platform can serialize: plant foreign pid
-        import os
-        import pickle
-        entry = tmp_path / "aot" / "kq.exe"
-        pid, *rest = pickle.loads(entry.read_bytes())
-        entry.write_bytes(pickle.dumps((pid + 1, *rest)))
-        fn2, _ = _jit_and_args()
-        _, info2 = aot.aot_compile(fn2, args, cache_dir=tmp_path, key="kq")
-        assert info2["source"] == "compiled"  # quarantined: not aot_disk
-    # config surface: the override round-trips
-    cfg = ExperimentConfig.from_dict(
-        {"compile": {"trust_cache_cross_process": True}})
-    assert cfg.compile.trust_cache_cross_process is True
+def _unique_compile(tag: str) -> None:
+    """Compile a program no earlier test can have compiled: jax's
+    in-memory compilation LRU sits ABOVE the persistent cache, and an
+    aliased HLO would never reach the disk layer (hash() is
+    process-salted, so the constant is unique per run)."""
+    import jax.numpy as jnp
+    k = float(hash(tag) % 9973 + 2)
+    jax.jit(lambda x: (x * k).sum())(jnp.ones((4,))).block_until_ready()
 
 
-# ---------------------------------------------------------------------------
-# AOT cache key: hit on identity, miss on any topology/config change
-# ---------------------------------------------------------------------------
+def test_variable_set_is_the_cache_and_no_other_dir_is_set(
+        monkeypatch, tmp_path, jax_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: jax's own cache lives there and
+    the program issues no jax.config.update to any other directory."""
+    d = tmp_path / "from_env"
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(d))
+    # what jax itself does with the variable at import
+    jax.config.update("jax_compilation_cache_dir", str(d))
+    dir_updates = []
+    real_update = jax.config.update
 
-def test_aot_cache_key_same_triple_hits_different_misses(topo8):
-    cfg = ExperimentConfig.from_dict({"model": {"compute_dtype": "float32"}})
-    model = get_model(cfg.model)
-    k1 = aot.aot_cache_key(model, cfg, topo8)
-    k2 = aot.aot_cache_key(get_model(cfg.model), ExperimentConfig.from_dict(
-        {"model": {"compute_dtype": "float32"}}), topo8)
-    assert k1 == k2  # same (model, cfg, topo) ⇒ same key
-    # any config change ⇒ different executable ⇒ different key
-    assert aot.aot_cache_key(
-        model, cfg.override({"data.batch_size": 64}), topo8) != k1
-    assert aot.aot_cache_key(
-        model, cfg.override({"sync.mode": "quorum"}), topo8) != k1
-    # a different topology must never reuse a stale executable
-    from distributedmnist_tpu.core.config import MeshConfig
-    topo_tp = make_topology(MeshConfig(num_replicas=4, model_parallelism=2))
-    assert aot.aot_cache_key(model, cfg, topo_tp) != k1
-    assert aot.aot_cache_key(model, cfg, topo8, what="eval") != k1
-    # host-side knobs (run length, cadence, dirs) never enter the
-    # lowered program — bumping them must HIT, not recompile cold
-    assert aot.aot_cache_key(
-        model, cfg.override({"train.max_steps": 999}), topo8) == k1
-    assert aot.aot_cache_key(
-        model, cfg.override({"train.log_every_steps": 7}), topo8) == k1
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            dir_updates.append(value)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    assert cc.enable_persistent_cache(CompileConfig()) == d
+    assert dir_updates == []
+    assert jax.config.jax_compilation_cache_dir == str(d)
+    _unique_compile(str(d))
+    stats = cc.cache_stats()
+    assert stats["dir"] == str(d)
+    assert stats["entries"] >= 1 and stats["bytes"] > 0
+    # the monitoring listener fed the counters
+    assert stats["hits"] + stats["misses"] >= 1
+    assert not cc.DEFAULT_CACHE_DIR.joinpath("from_env").exists()
+
+
+def test_variable_unset_uses_the_fixed_checkout_path(
+        monkeypatch, tmp_path, jax_cache_config):
+    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+    # point the constant at a scratch dir: the test must not write the
+    # real checkout's cache, only show that the constant is what is used
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", tmp_path / ".jax_cache")
+    got = cc.enable_persistent_cache(CompileConfig())
+    assert got == tmp_path / ".jax_cache" and got.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(got)
+    _unique_compile(str(got))
+    assert cc.cache_stats()["entries"] >= 1
+
+
+def test_persistent_cache_false_is_off_even_under_the_variable(
+        monkeypatch, tmp_path, jax_cache_config):
+    d = tmp_path / "inherited"
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(d))
+    jax.config.update("jax_compilation_cache_dir", str(d))
+    assert cc.enable_persistent_cache(
+        CompileConfig(persistent_cache=False)) is None
+    assert jax.config.jax_enable_compilation_cache is False
+    _unique_compile(str(d))
+    assert cc.cache_stats(d)["entries"] == 0  # cold means cold
+    # and on again in the same process works (the reset in enable)
+    assert cc.enable_persistent_cache(CompileConfig()) == d
+    _unique_compile(str(d) + "again")
+    assert cc.cache_stats(d)["entries"] >= 1
 
 
 # ---------------------------------------------------------------------------
@@ -193,98 +173,3 @@ def test_precompile_first_step_bitwise_equals_cold(tmp_path):
     assert compile_events[0]["compile_s"] == info["compile_s"]
     assert s_pre["compile"]["source"] == "compiled"
     assert s_cold["compile"] is None
-
-
-# ---------------------------------------------------------------------------
-# executable disk cache: roundtrip, corruption, unsupported platform
-# ---------------------------------------------------------------------------
-
-def _jit_and_args():
-    import jax.numpy as jnp
-    fn = jax.jit(lambda x: (x * 3.0).sum())
-    return fn, (jnp.arange(8, dtype=jnp.float32),)
-
-
-def test_aot_disk_cache_roundtrip_and_corruption(tmp_path):
-    # trust override: the roundtrip mechanics under test are what the
-    # quarantine (tested above) would otherwise short-circuit
-    def compile_trusted(fn, args, **kw):
-        return aot.aot_compile(fn, args, trust_cross_process=True, **kw)
-
-    fn, args = _jit_and_args()
-    compiled, info = compile_trusted(fn, args, cache_dir=tmp_path, key="k1")
-    assert info["source"] == "compiled"
-    assert float(compiled(*args)) == float(fn(*args))
-    if not info["serialized"]:
-        pytest.skip("platform cannot serialize executables — the "
-                    "unsupported-marker path is covered below")
-    # an entry THIS process stored is refused (measured 0.4.37 hazard:
-    # same-process deserialize of a real train step corrupts the
-    # runtime) — the load quietly falls back to a compile
-    fn2, _ = _jit_and_args()
-    _, info_same = compile_trusted(fn2, args, cache_dir=tmp_path, key="k1")
-    assert info_same["source"] == "compiled"
-    # a FOREIGN process's entry (different stored pid) is served from
-    # disk with a bitwise-identical result — the restart fast path
-    import os
-    import pickle
-    entry = tmp_path / "aot" / "k1.exe"
-    pid, *rest = pickle.loads(entry.read_bytes())
-    assert pid == os.getpid()
-    entry.write_bytes(pickle.dumps((pid + 1, *rest)))
-    compiled2, info2 = compile_trusted(fn2, args, cache_dir=tmp_path,
-                                       key="k1")
-    assert info2["source"] == "aot_disk"
-    assert float(compiled2(*args)) == float(compiled(*args))
-    # a DIFFERENT key is a miss, never a stale reuse
-    _, info3 = compile_trusted(fn2, args, cache_dir=tmp_path, key="k-other")
-    assert info3["source"] == "compiled"
-    # corrupt the entry: logged fallback to cold compile, entry healed
-    # (deleted), never a crash
-    entry = tmp_path / "aot" / "k1.exe"
-    entry.write_bytes(b"torn garbage, not a pickle")
-    import logging
-    msgs: list[str] = []
-    handler = logging.Handler()
-    handler.emit = lambda rec: msgs.append(rec.getMessage())
-    logging.getLogger("distributedmnist_tpu.aot").addHandler(handler)
-    try:
-        compiled4, info4 = compile_trusted(fn2, args, cache_dir=tmp_path,
-                                           key="k1")
-    finally:
-        logging.getLogger("distributedmnist_tpu.aot").removeHandler(handler)
-    assert info4["source"] == "compiled"
-    assert float(compiled4(*args)) == float(compiled(*args))
-    # the fallback is LOGGED and the torn entry healed (deleted, then
-    # re-serialized by the recompile) — never a crash
-    assert any("corrupt AOT cache entry" in m for m in msgs)
-    assert not entry.exists() or info4["serialized"]
-
-
-def test_aot_unsupported_platform_marker_short_circuits(tmp_path):
-    """A backend deserialize failure (the cross-process CPU case) marks
-    the cache dir unsupported; later processes skip the probe and go
-    straight to the compile (persistent-cache-warm) path."""
-    fn, args = _jit_and_args()
-    cache = aot.ExecutableCache(tmp_path, trust_cross_process=True)
-    assert not cache.serialization_known_unsupported()
-    cache._mark_unsupported(RuntimeError("Symbols not found"))
-    assert cache.serialization_known_unsupported()
-    # load AND store now short-circuit without touching the backend
-    assert cache.load("k1") is None
-    compiled, info = aot.aot_compile(fn, args, cache_dir=tmp_path, key="k1",
-                                     trust_cross_process=True)
-    assert info["source"] == "compiled" and info["serialized"] is False
-    assert not (tmp_path / "aot" / "k1.exe").exists()
-    assert float(compiled(*args)) == float(fn(*args))
-    # the verdict is about ONE (platform, device_kind, jax) triple: a
-    # marker left behind by a different runtime (jaxlib upgrade, cache
-    # dir moved across backends) must re-probe, not disable forever
-    marker = tmp_path / "aot" / "SERIALIZATION_UNSUPPORTED"
-    rec = json.loads(marker.read_text())
-    rec["runtime"]["jax"] = "0.0.0"
-    marker.write_text(json.dumps(rec))
-    assert not cache.serialization_known_unsupported()
-    # a legacy/torn (non-JSON) marker also reads as "probe again"
-    marker.write_text("RuntimeError: Symbols not found\n")
-    assert not cache.serialization_known_unsupported()

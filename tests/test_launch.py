@@ -117,22 +117,6 @@ def test_repo_sweep_configs_all_parse():
     assert "mnist_99" in names  # the one-command 99% repro config
 
 
-def _jax_can_resize_cpu_mesh() -> bool:
-    """Post-init CPU-device-count changes need the jax_num_cpu_devices
-    knob (jax ≥ 0.4.38); older jax degrades gracefully to the ambient
-    mesh (simulate_devices documents this), so the strict resize
-    assertion below is version-gated."""
-    import jax
-    try:
-        jax.config.jax_num_cpu_devices  # noqa: B018
-        return True
-    except AttributeError:
-        return False
-
-
-@pytest.mark.skipif(not _jax_can_resize_cpu_mesh(),
-                    reason="this jax cannot resize the CPU mesh post-init "
-                           "(no jax_num_cpu_devices)")
 def test_sweep_restores_ambient_mesh(tmp_path):
     """A sweep mixing a simulated-mesh config with ambient-mesh ones
     must run each on ITS mesh: the 4-device config forces 4 virtual
@@ -189,6 +173,32 @@ def test_ensure_mesh_noop_and_nonrestorable():
             mesh_mod.ensure_mesh(0)
     finally:
         mesh_mod._ambient_mesh = saved
+
+
+def test_simulated_mesh_refuses_to_replace_a_live_accelerator(monkeypatch):
+    """A config asking for MORE virtual devices than are visible tears
+    the backend down and forces a CPU mesh — only when the live backend
+    IS the CPU. On a live accelerator both entry points refuse loudly:
+    a run that believes it is on the chip never lands on a CPU mesh."""
+    import jax
+    from distributedmnist_tpu.core import mesh as mesh_mod
+    from distributedmnist_tpu.core.config import MeshConfig
+
+    devs_before = jax.devices()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="live backend is 'tpu'"):
+        mesh_mod.make_topology(MeshConfig(simulate_devices=16))
+    saved = mesh_mod._ambient_mesh
+    try:
+        mesh_mod._ambient_mesh = (8, "tpu")
+        with pytest.raises(RuntimeError, match="live backend is 'tpu'"):
+            mesh_mod.ensure_mesh(16)
+    finally:
+        mesh_mod._ambient_mesh = saved
+    assert jax.devices() == devs_before  # nothing was torn down
+    # a count the visible devices already cover never reaches the branch
+    assert mesh_mod.make_topology(
+        MeshConfig(simulate_devices=8)).num_replicas == 8
 
 
 def test_campaign_groups_resolve_to_configs():

@@ -142,7 +142,7 @@ def _launch(tmp_path, cfg_dicts=None, sleep_ms=(0.0, 0.0),
     return results
 
 
-@pytest.mark.slow  # boots 2 real gloo worker processes; ~100 s on the tier-1 box (and crashes in jaxlib-0.4.37 gloo: EnforceNotMet pair.cc)
+@pytest.mark.slow  # boots 2 real gloo worker processes
 def test_two_process_training_matches_single_process(tmp_path):
     r0, r1 = _launch(tmp_path)
     for r in (r0, r1):
@@ -176,10 +176,7 @@ def test_two_process_training_matches_single_process(tmp_path):
     assert ev["num_examples"] == 96
 
 
-@pytest.mark.slow  # boots 2 real gloo worker processes; passes standalone
-# but under full-suite load reliably hits the known jaxlib-0.4.37 gloo
-# SIGABRT (gloo::EnforceNotMet pair.cc) — same crash its 3 slow-marked
-# siblings were quarantined for
+@pytest.mark.slow  # boots 2 real gloo worker processes
 def test_two_process_quorum_gathers_on_every_host(tmp_path):
     """Quorum mode across two live processes: the k-of-n mask, the
     replicated [n] timing vector and the flags gather — the exact paths
@@ -222,7 +219,10 @@ def test_two_process_quorum_gathers_on_every_host(tmp_path):
     assert records[-1]["flags"] == r0["flags"]
 
 
-@pytest.mark.slow  # boots 2 real gloo worker processes (jaxlib-0.4.37 gloo crash)
+@pytest.mark.slow  # boots 2 real gloo worker processes. Open on jax 0.9.0
+# (8-core box): runs to the end, but the sleeping process's measured
+# step time reads 9.1 ms against 7.4 ms, so the 10x ratio assertion
+# fails — the stall is not reaching the measured vector
 def test_slow_process_loses_quorum_by_measured_time(tmp_path):
     """A REALLY slow process — its host loop stalled by an actual
     sleep, not a configured delay — must lose quorum membership through
@@ -352,7 +352,7 @@ def _tp_cfg_dict(train_dir: str, max_steps: int) -> dict:
     }
 
 
-@pytest.mark.slow  # boots real gloo worker processes (jaxlib-0.4.37 gloo crash)
+@pytest.mark.slow  # boots real gloo worker processes
 def test_two_process_tp_sharded_save_kill_resume_and_eval(tmp_path):
     """The round-5 per-host checkpoint proof (SURVEY §2.3 'per-host
     array serialization'): a live 2-process cluster with params
