@@ -39,6 +39,8 @@ import queue
 import threading
 from typing import Any, Callable, Iterator
 
+from ..obsv import spans
+
 _ITEM, _DONE, _ERR = "item", "done", "err"
 
 
@@ -92,14 +94,16 @@ class DevicePrefetcher:
         try:
             while not self._stop.is_set():
                 try:
-                    batch = next(self._it)
+                    with spans.span(spans.PREFETCH_ASSEMBLE):
+                        batch = next(self._it)
                 except StopIteration:
                     self._q_put(_DONE, None)
                     return
                 # cursor AFTER producing this batch == "this batch
                 # consumed" once the consumer takes it
                 snap = self._it.state() if self.has_state else None
-                staged = self._put(batch)
+                with spans.span(spans.PREFETCH_PUT):
+                    staged = self._put(batch)
                 if not self._q_put(_ITEM, (staged, snap)):
                     return  # stopping; stop() re-syncs the cursor
         except BaseException as e:  # surface in the consumer thread

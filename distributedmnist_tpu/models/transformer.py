@@ -151,8 +151,8 @@ def apply(params: Params, tokens: jax.Array, *, num_heads: int = 4,
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
-    x = p["embed"][tokens] + p["pos"][positions]
+    p = _cast(params, compute_dtype)
+    x = _embed(p, tokens, positions)
     d = x.shape[-1]
     hd = d // num_heads
     m = lax.axis_size(model_axis) if model_axis else 1
@@ -201,11 +201,33 @@ def apply(params: Params, tokens: jax.Array, *, num_heads: int = 4,
     for blk in p["blocks"]:
         x, aux = block(x, blk)
         aux_total = aux_total + aux
-    x = _rms_norm(x, p["final_norm"])
-    logits = (x @ p["embed"].T).astype(jnp.float32)  # tied head
+    logits = _head(p, x)
     return (logits, aux_total) if return_aux else logits
 
 
+# Device scopes (obsv/spans.py SCOPES): every HLO operation carries the
+# scope it was traced under in its op_name, backward and recomputed
+# operations included, so a profiler trace splits a step by layer.
+
+@jax.named_scope("cast")
+def _cast(params: Params, compute_dtype) -> Params:
+    """The stored weights in the compute dtype, once per program."""
+    return jax.tree.map(lambda a: a.astype(compute_dtype), params)
+
+
+@jax.named_scope("embed")
+def _embed(p: Params, tokens: jax.Array, positions: jax.Array) -> jax.Array:
+    return p["embed"][tokens] + p["pos"][positions]
+
+
+@jax.named_scope("head")
+def _head(p: Params, x: jax.Array) -> jax.Array:
+    """Final norm and the tied head: [..., d] → float32 logits."""
+    x = _rms_norm(x, p["final_norm"])
+    return (x @ p["embed"].T).astype(jnp.float32)
+
+
+@jax.named_scope("attention")
 def _attn_sublayer(x: jax.Array, blk: Params, *, h_local: int, hd: int,
                    attn: Callable,
                    model_axis: str | None,
@@ -244,6 +266,7 @@ def _attn_sublayer(x: jax.Array, blk: Params, *, h_local: int, hd: int,
     return out
 
 
+@jax.named_scope("ffn")
 def _ffn_sublayer(x: jax.Array, blk: Params, *, model_axis: str | None,
                   expert_axis: str | None = None, num_experts: int = 0,
                   capacity_factor: float = 1.25, moe_num_groups: int = 0,
@@ -319,8 +342,8 @@ def prefill_with_kv(params: Params, tokens: jax.Array, *,
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
-    x = p["embed"][tokens] + p["pos"][positions]
+    p = _cast(params, compute_dtype)
+    x = _embed(p, tokens, positions)
     d = x.shape[-1]
     hd = d // num_heads
     ks, vs = [], []
@@ -331,9 +354,7 @@ def prefill_with_kv(params: Params, tokens: jax.Array, *,
         ks.append(k)
         vs.append(v)
         x, _ = _ffn_sublayer(x, blk, model_axis=None)
-    x = _rms_norm(x, p["final_norm"])
-    logits = (x @ p["embed"].T).astype(jnp.float32)
-    return logits, jnp.stack(ks), jnp.stack(vs)
+    return _head(p, x), jnp.stack(ks), jnp.stack(vs)
 
 
 def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
@@ -374,9 +395,9 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
         raise ValueError(
             f"decode.attention_kernel must be 'dense' or 'paged', "
             f"got {attention_kernel!r}")
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    p = _cast(params, compute_dtype)
     num_slots = tokens.shape[0]
-    x = p["embed"][tokens] + p["pos"][positions]  # [S, d]
+    x = _embed(p, tokens, positions)  # [S, d]
     d = x.shape[-1]
     hd = d // num_heads
     scale = 1.0 / (hd ** 0.5)
@@ -387,41 +408,58 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     offs = positions % block_size
     live = ctx_pos[None, :] < lengths[:, None]  # [S, ctx]
     for li, blk in enumerate(p["blocks"]):
-        h = _rms_norm(x, blk["ln1"])
-        qkv = jnp.einsum("sd,dte->ste", h, blk["wqkv"])
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, d]
+        x, k_cache, v_cache = _decode_attn(
+            x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
+            offs, live, num_heads=num_heads, scale=scale,
+            attention_kernel=attention_kernel)
+        x, _ = _ffn_sublayer(x, blk, model_axis=None)
+    return _head(p, x), k_cache, v_cache
+
+
+@jax.named_scope("attention")
+def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
+                 blk_ids, offs, live, *, num_heads, scale,
+                 attention_kernel):
+    """One layer's attention sublayer of :func:`decode_step`: this
+    token's K/V written through the block table (scope ``cache_write``),
+    the context read back (``cache_gather`` on the dense arm; the paged
+    kernel walks the table itself), x + wo(attn)."""
+    num_slots, d = x.shape
+    hd = d // num_heads
+    ctx = live.shape[1]
+    h = _rms_norm(x, blk["ln1"])
+    qkv = jnp.einsum("sd,dte->ste", h, blk["wqkv"])
+    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, d]
+    with jax.named_scope("cache_write"):
         kh = k.reshape(num_slots, num_heads, hd)
         vh = v.reshape(num_slots, num_heads, hd)
         k_cache = k_cache.at[li, blk_ids, offs].set(
             kh.astype(k_cache.dtype))
         v_cache = v_cache.at[li, blk_ids, offs].set(
             vh.astype(v_cache.dtype))
-        qh = q.reshape(num_slots, num_heads, hd)
-        if attention_kernel == "paged":
-            # fused path: the kernel walks the block table itself, so
-            # per-token traffic is O(actual context) — no dense view
-            from ..ops.pallas_paged_attention import paged_attention
-            o = paged_attention(qh, k_cache[li], v_cache[li],
-                                block_tables, lengths, scale=scale)
-        else:
-            # gather the slot's pages into one dense context view: the
-            # block table IS the indirection, so this read is identical
-            # for a 3-token and a 90-token sequence — one compiled shape
+    qh = q.reshape(num_slots, num_heads, hd)
+    if attention_kernel == "paged":
+        # fused path: the kernel walks the block table itself, so
+        # per-token traffic is O(actual context) — no dense view
+        from ..ops.pallas_paged_attention import paged_attention
+        o = paged_attention(qh, k_cache[li], v_cache[li],
+                            block_tables, lengths, scale=scale)
+    else:
+        # gather the slot's pages into one dense context view: the
+        # block table IS the indirection, so this read is identical
+        # for a 3-token and a 90-token sequence — one compiled shape
+        with jax.named_scope("cache_gather"):
             kp = k_cache[li][block_tables].reshape(
                 num_slots, ctx, num_heads, hd)
             vp = v_cache[li][block_tables].reshape(
                 num_slots, ctx, num_heads, hd)
-            scores = jnp.einsum("shd,skhd->shk", qh.astype(jnp.float32),
-                                kp.astype(jnp.float32)) * scale
-            scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("shk,skhd->shd", w, vp.astype(jnp.float32))
-        o = o.astype(compute_dtype).reshape(num_slots, d)
-        x = x + o @ blk["wo"]
-        x, _ = _ffn_sublayer(x, blk, model_axis=None)
-    x = _rms_norm(x, p["final_norm"])
-    logits = (x @ p["embed"].T).astype(jnp.float32)
-    return logits, k_cache, v_cache
+        scores = jnp.einsum("shd,skhd->shk", qh.astype(jnp.float32),
+                            kp.astype(jnp.float32)) * scale
+        scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
+        w = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("shk,skhd->shd", w, vp.astype(jnp.float32))
+    o = o.astype(x.dtype).reshape(num_slots, d)
+    return x + o @ blk["wo"], k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------
@@ -866,6 +904,7 @@ def sp_partial_token_loss(logits: jax.Array, tgt: jax.Array,
     return jnp.sum(nll * w) / total, jnp.sum(correct * w) / total
 
 
+@jax.named_scope("loss")
 def loss_fn(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Next-token mean xent. ``labels`` are the input tokens; targets
     are labels shifted left (last position dropped)."""
@@ -875,6 +914,7 @@ def loss_fn(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return jnp.mean(nll)
 
 
+@jax.named_scope("loss")
 def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     pred = jnp.argmax(logits[:, :-1], axis=-1)
     return jnp.mean((pred == labels[:, 1:]).astype(jnp.float32))

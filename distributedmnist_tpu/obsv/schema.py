@@ -218,9 +218,13 @@ _declare(EventSchema(
         "decode_start": _act(("slots", "block_size", "num_blocks",
                               "max_prompt_len", "max_new_tokens",
                               "swap_policy", "model_step")),
+        # prefill_ms: the start of `_prefill` to its streamed token;
+        # ttft_ms: the same value under its first name (kept for the
+        # readers that ask for it); queue_ms: admission to the start of
+        # `_prefill`, absent on a restart's re-prefill
         "prefill": _act(("id", "prompt_len", "bucket", "blocks",
                          "model_step", "ttft_ms"),
-                        ("restart",)),
+                        ("restart", "queue_ms", "prefill_ms")),
         "decode_finish": _act(("id", "reason", "tokens_streamed",
                                "model_step", "started_step",
                                "latency_ms"),
@@ -296,7 +300,7 @@ _declare(EventSchema(
     required=("step",),
     optional=("tp_rank", "queue_depth", "queue_limit", "kv_blocks_free",
               "kv_blocks_total", "kv_blocks_reserved",
-              "decode_waiting"),
+              "decode_waiting", "slots_live", "decode_steps"),
 ))
 
 # Load-generator journal (servesvc/loadgen.py loadgen.jsonl): every

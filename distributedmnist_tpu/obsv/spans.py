@@ -1,0 +1,124 @@
+"""The program's own names in a ``jax.profiler`` trace: host spans,
+device scopes, and the one way a trace is started.
+
+One mechanism, the profiler's own, and no store beside it. A host span
+is a ``jax.profiler.TraceAnnotation``: it lands on the thread's line of
+plane ``/host:CPU`` in the same ``.xplane.pb`` as the device planes, on
+the same clock, so a reader can say what the host was doing while the
+chip ran nothing. With no profiler session it costs about 1 µs
+(0.5 µs without keyword facts; measured, jax 0.9.0). A device scope is
+a ``jax.named_scope``: it becomes part of every HLO operation's
+``op_name`` metadata and changes nothing else of the compiled program.
+Kernels (``pallas_call(name=...)``) and jitted programs (a named
+function in place of a ``functools.partial``) carry their names the
+same way.
+
+Every host span starts with :data:`PREFIX`, so a reader can tell the
+program's spans from the runtime's (``PjitFunction(...)``,
+``np.asarray(jax.Array)``). The leaves tile the loop they sit in: there
+is no outer per-iteration span, because a reader that attributes an
+idle gap to the span overlapping it most (``benchmark/lib/
+trace_reduce._host_label``) would give every gap that straddles two
+phases to the outer one. Iterations are counted from the dispatch span.
+Spans of one request share its ``id``; a count rides on the span at
+whose boundary it is true.
+
+Readers: ``benchmark/lib/program_trace.py`` (whose ``__main__`` prints
+the span table and the scope table of any trace directory) and the
+per-layer metrics named beside each span below; PERF.md §3 has the
+same list from the metrics' side.
+"""
+
+from __future__ import annotations
+
+import jax
+
+PREFIX = "dml."
+
+# -- the decode replica's batcher thread (servesvc/decode.py) --------------
+#: the park on the admission queue while no slot is live and nothing
+#: waits (`_admit_new`); read by: serve_idle_unattributed_share (an idle
+#: replica's gaps are the queue's, not the loop's)
+SERVE_IDLE = "dml.serve.idle"
+#: queue drain and slot assignment in `_admit_new`, less the prefill it
+#: calls; read by: the span table, serve_idle_unattributed_share
+SERVE_ADMIT = "dml.serve.admit"
+#: `_maybe_swap`, only when a publish was staged; read by: the span table
+#: (a swap is the one stall of the loop that is not a request's)
+SERVE_SWAP = "dml.serve.swap"
+#: all of `_prefill` for one request (id, prompt_len, bucket, queue_ms);
+#: parent of the next two; read by: the span table (prefill_ms_p50 reads
+#: the journal's copy of its duration)
+SERVE_PREFILL = "dml.serve.prefill"
+#: the padded prompt's upload and the prefill program's dispatch
+SERVE_PREFILL_FORWARD = "dml.serve.prefill.forward"
+#: the scatter of the prompt's K/V into the paged cache
+#: (`jit_write_prompt_kv`; prefill_cache_write_share_of_busy reads the
+#: program, this span says where the host waits for it)
+SERVE_PREFILL_CACHE_WRITE = "dml.serve.prefill.cache_write"
+#: `_step_active` up to the dispatch: the numpy vectors, their three
+#: uploads and `_tables_for`
+SERVE_STEP_INPUTS = "dml.serve.step.inputs"
+#: the call of the jitted decode step (live, waiting, version): one per
+#: iteration and params version, so it counts iterations; read by:
+#: decode_slots_live_p50 (`live`), decode_sample_ms_per_iter and
+#: decode_stream_ms_per_iter (the count)
+SERVE_STEP_DISPATCH = "dml.serve.step.dispatch"
+#: the blocking fetch of the step's [slots, vocab] logits
+SERVE_STEP_FETCH = "dml.serve.step.fetch"
+#: `_sample` for one slot (id, slot): a row's upload, an eager argmax or
+#: draw, a blocking fetch; read by: decode_sample_ms_per_iter,
+#: serve_idle_sample_share
+SERVE_SAMPLE = "dml.serve.sample"
+#: `_stream_token` (id): one JSON line and one `sendall`; read by:
+#: decode_stream_ms_per_iter
+SERVE_STREAM = "dml.serve.stream"
+#: `_finish_seq` (id, reason): journal, terminal line, blocks freed
+SERVE_FINISH = "dml.serve.finish"
+
+# -- the trainer's loop thread (train/loop.py) ------------------------------
+#: `next(feed)` from the prefetcher, or the inline `device_put_batch`
+TRAIN_FEED = "dml.train.feed"
+#: the call of the train step (step); one per step
+TRAIN_DISPATCH = "dml.train.dispatch"
+#: the device probe's drain poll, only when the probe is on
+TRAIN_PROBE = "dml.train.probe"
+#: the log-window flush, which fetches the window's losses
+TRAIN_FLUSH = "dml.train.flush"
+#: `_save`: snapshot, write or hand-off to the async writer
+TRAIN_SAVE = "dml.train.save"
+# -- the prefetcher's producer thread (data/device_prefetch.py) -------------
+#: `next()` of the host iterator: one global batch assembled
+PREFETCH_ASSEMBLE = "dml.prefetch.assemble"
+#: the staged batch's host-to-device copy
+PREFETCH_PUT = "dml.prefetch.put"
+# The train spans have no per-layer reader yet (PERF.md §7: the train
+# cells' exact metric sets are pinned by a test of the benchmark); they
+# are read with `python3 benchmark/lib/program_trace.py <dir>`, which is
+# how PERF.md §5's train tables are made.
+
+#: device scopes (``jax.named_scope``), by where they are opened:
+#: models/transformer.py (cast: the stored weights to the compute dtype;
+#: embed, attention, ffn, head; in the decode step cache_write and
+#: cache_gather inside attention; loss), servesvc/kv_cache.py
+#: (cache_write), parallel/api.py and ops/masked_psum.py (aggregate,
+#: update, timing)
+SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
+          "ffn", "head", "loss", "aggregate", "update", "timing")
+
+#: a host span: ``with span(SERVE_SAMPLE, id=..., slot=...):``
+span = jax.profiler.TraceAnnotation
+
+
+def start_profile(log_dir) -> None:
+    """Start a profiler session as the benchmark's traced runs do
+    (``benchmark/lib/runtime.py``): the Python tracer off. It hooks
+    every call and return of the loop it is meant to measure, so with
+    it on (the profiler's default) a trace measures the tracer."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=options)
+
+
+def stop_profile() -> None:
+    jax.profiler.stop_trace()

@@ -220,13 +220,6 @@ class StepTimeCollector:
             out["snapshot_stall_ms"] = self.snapshot_stall_stats().to_dict()
         return out
 
-    def reset(self) -> None:
-        self._raw.clear()
-        self._materialized = 0
-        self._host_steps.clear()
-        self._prefetch_depths.clear()
-        self._snapshot_stalls.clear()
-
 
 class ReplicaDeviceProbe:
     """Per-replica DEVICE-side completion probes.

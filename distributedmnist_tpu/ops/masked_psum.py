@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 
+@jax.named_scope("aggregate")
 def contribution_scale(flag: jax.Array,
                        axis_name: str) -> tuple[jax.Array, jax.Array]:
     """(scale, num_contributors): pre-multiplying each replica's
@@ -41,6 +42,7 @@ def contribution_scale(flag: jax.Array,
     return flag / jnp.maximum(num, 1.0), num
 
 
+@jax.named_scope("aggregate")
 def masked_mean_psum(tree: Any, flag: jax.Array, axis_name: str) -> tuple[Any, jax.Array]:
     """Cross-replica masked mean of a pytree.
 

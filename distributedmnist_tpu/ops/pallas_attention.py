@@ -240,6 +240,7 @@ def _forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     out = res[0].reshape(bb, sp, hh, dp)[:, :s, :, :d]
     return out, (res[1] if save_lse else None)
@@ -439,6 +440,7 @@ def _backward(q, k, v, out, lse, dout, causal: bool, scale: float,
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
+            name="flash_bwd_fused",
         )(qp, kp, vp, dop, op, lse)
         return (unpad(dq, q.dtype), unpad(dk, k.dtype), unpad(dv, v.dtype))
 
@@ -469,6 +471,7 @@ def _backward(q, k, v, out, lse, dout, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, op, lse)
 
     dk, dv = pl.pallas_call(
@@ -485,6 +488,7 @@ def _backward(q, k, v, out, lse, dout, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, op, lse)
 
     return unpad(dq, q.dtype), unpad(dk, k.dtype), unpad(dv, v.dtype)

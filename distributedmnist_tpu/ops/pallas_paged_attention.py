@@ -194,6 +194,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qp, kp, vp)
     return out[:, :num_heads, :hd]

@@ -495,6 +495,7 @@ def _spec_norm_axes(spec) -> tuple[str, ...]:
     return tuple(axes)
 
 
+@jax.named_scope("update")
 def _apply_tree_update(opt, params: Any, grads: Any, opt_state: Any,
                        lr: jax.Array, t: jax.Array,
                        param_specs: Any) -> tuple[Any, Any]:
@@ -541,6 +542,7 @@ def _pad_flat(x: jax.Array, lp) -> jax.Array:
         [flat, jnp.zeros((lp.pad - lp.size,), flat.dtype)])
 
 
+@jax.named_scope("update")
 def _zero1_update(params: Any, grads: Any, opt_state: Any,
                   flag: jax.Array, lr: jax.Array, t: jax.Array,
                   axis: str, plan: Zero1Plan, opt, param_specs: Any
@@ -611,17 +613,24 @@ def _zero1_update(params: Any, grads: Any, opt_state: Any,
     def guard(new, old):
         return new if stateless else jnp.where(applied > 0, new, old)
 
-    gm_leaves = [g * scale.astype(g.dtype) for g in g_leaves]
+    # device scopes (obsv/spans.py): inside this function's `update`,
+    # the mask and every reduction of a gradient are `aggregate`, as
+    # masked_mean_psum is on the replicated path
+    with jax.named_scope("aggregate"):
+        gm_leaves = [g * scale.astype(g.dtype) for g in g_leaves]
 
     # bucketed reduce-scatter: one collective per layer-ordered bucket,
     # issued as soon as that bucket's gradients exist in the dataflow
     gsh_by_leaf: dict[int, jax.Array] = {}
     if bucketed:
         for bucket in buckets:
-            rows = [_pad_flat(gm_leaves[i], lp_leaves[i])
-                    .reshape(plan.n, lp_leaves[i].chunk) for i in bucket]
-            scat = lax.psum_scatter(jnp.concatenate(rows, axis=1), axis,
-                                    scatter_dimension=0, tiled=True)[0]
+            with jax.named_scope("aggregate"):
+                rows = [_pad_flat(gm_leaves[i], lp_leaves[i])
+                        .reshape(plan.n, lp_leaves[i].chunk)
+                        for i in bucket]
+                scat = lax.psum_scatter(
+                    jnp.concatenate(rows, axis=1), axis,
+                    scatter_dimension=0, tiled=True)[0]
             off = 0
             for i in bucket:
                 c = lp_leaves[i].chunk
@@ -642,8 +651,9 @@ def _zero1_update(params: Any, grads: Any, opt_state: Any,
                 # monolithic discipline: reduce-scatter per leaf —
                 # [pad] masked grads → this replica's summed [chunk]
                 # slice (already the mean via the pre-scale)
-                gsh = lax.psum_scatter(_pad_flat(gm, lp), axis,
-                                       scatter_dimension=0, tiled=True)
+                with jax.named_scope("aggregate"):
+                    gsh = lax.psum_scatter(_pad_flat(gm, lp), axis,
+                                           scatter_dimension=0, tiled=True)
             psh = (p if resident
                    else lax.dynamic_slice(_pad_flat(p, lp),
                                           (me * lp.chunk,), (lp.chunk,)))
@@ -663,7 +673,8 @@ def _zero1_update(params: Any, grads: Any, opt_state: Any,
                     nps, axis, lp.pad, me * lp.chunk)
                 new_p.append(full[:lp.size].reshape(lp.shape))
         else:
-            mean = lax.psum(gm, axis)
+            with jax.named_scope("aggregate"):
+                mean = lax.psum(gm, axis)
             axes = _spec_norm_axes(spec)
             nr = ((lambda x, a=axes: lax.psum(x, a)) if axes
                   else (lambda x: x))
@@ -862,10 +873,11 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
     def fwd_view(params):
         if not fwd_cast:
             return params
-        return jax.tree.map(
-            lambda p: (p.astype(param_dtype)
-                       if jnp.issubdtype(p.dtype, jnp.floating) else p),
-            params)
+        with jax.named_scope("cast"):
+            return jax.tree.map(
+                lambda p: (p.astype(param_dtype)
+                           if jnp.issubdtype(p.dtype, jnp.floating) else p),
+                params)
 
     # Sequence parallelism: when the mesh spends devices on the seq
     # axis, the model must provide a sequence-sharded apply (the
@@ -1166,16 +1178,17 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             grads = drop_connect_grads(grads, dckey, sync.drop_connect_probability)
 
         # --- step-time model & contribution mask ---------------------
-        t_ms = policies.sample_step_time_ms(sync, state.root_key, step, me,
-                                            my_measured_ms)
-        if mode in ("sync", "cdf"):
-            flag = jnp.ones((), jnp.float32)
-        elif mode == "quorum":
-            flag = policies.quorum_flag(t_ms, disc_k, axis)
-        elif mode == "timeout":
-            flag = policies.timeout_flag(t_ms, disc_timeout_ms)
-        else:  # interval: stale if slower than a whole window
-            flag = policies.timeout_flag(t_ms, disc_interval_ms)
+        with jax.named_scope("timing"):
+            t_ms = policies.sample_step_time_ms(sync, state.root_key, step,
+                                                me, my_measured_ms)
+            if mode in ("sync", "cdf"):
+                flag = jnp.ones((), jnp.float32)
+            elif mode == "quorum":
+                flag = policies.quorum_flag(t_ms, disc_k, axis)
+            elif mode == "timeout":
+                flag = policies.timeout_flag(t_ms, disc_timeout_ms)
+            else:  # interval: stale if slower than a whole window
+                flag = policies.timeout_flag(t_ms, disc_interval_ms)
 
         # --- apply discipline ----------------------------------------
         t_next = state.updates_applied.astype(jnp.float32) + 1.0
@@ -1214,12 +1227,13 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
                     t_next, pspec_tree)
                 # moment slots decay even on zero gradients, so a true
                 # no-op needs the select
-                new_params = jax.tree.map(
-                    lambda new, old: jnp.where(applied > 0, new, old),
-                    new_params, state.params)
-                new_opt = jax.tree.map(
-                    lambda new, old: jnp.where(applied > 0, new, old),
-                    new_opt, state.momentum)
+                with jax.named_scope("update"):
+                    new_params = jax.tree.map(
+                        lambda new, old: jnp.where(applied > 0, new, old),
+                        new_params, state.params)
+                    new_opt = jax.tree.map(
+                        lambda new, old: jnp.where(applied > 0, new, old),
+                        new_opt, state.momentum)
             new_state = state.replace(
                 params=new_params, momentum=new_opt,
                 updates_applied=state.updates_applied + applied)
@@ -1232,18 +1246,20 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
         # materialize its own copy without touching non-addressable
         # shards (≙ the CDF timing gossip, src/timeout_manager.py:48-61,
         # with no RPC mesh at all) ------------------------------------
-        metrics = {
-            "loss": lax.pmean(loss, axis),
-            "train_acc": lax.pmean(train_acc, axis),
-            "lr": schedule(state.updates_applied),
-            "num_contributors": num_contrib,
-            "updates_applied": new_state.updates_applied,
-            "step_times_ms": _gather_replicated(t_ms, axis, n),  # [n]
-            "flags": _gather_replicated(flag, axis, n),          # [n]
-            "applied": applied,
-        }
+        with jax.named_scope("timing"):
+            metrics = {
+                "loss": lax.pmean(loss, axis),
+                "train_acc": lax.pmean(train_acc, axis),
+                "lr": schedule(state.updates_applied),
+                "num_contributors": num_contrib,
+                "updates_applied": new_state.updates_applied,
+                "step_times_ms": _gather_replicated(t_ms, axis, n),  # [n]
+                "flags": _gather_replicated(flag, axis, n),          # [n]
+                "applied": applied,
+            }
         return new_state, metrics
 
+    @jax.named_scope("update")
     def _interval_apply(state: TrainState, mean_grads: Any,
                         t_ms: jax.Array,
                         interval_ms: jax.Array) -> tuple[TrainState, jax.Array]:

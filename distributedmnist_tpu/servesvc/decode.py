@@ -66,7 +66,6 @@ Wire protocol (one connection per request, line-delimited JSON):
 from __future__ import annotations
 
 import collections
-import functools
 import json
 import queue
 import time
@@ -77,6 +76,7 @@ import numpy as np
 
 from ..core.config import ConfigError
 from ..models.registry import sample_token
+from ..obsv import spans
 from .kv_cache import PagedKVCache
 from .server import ServingReplica, _Pending
 
@@ -141,14 +141,23 @@ class DecodeReplica(ServingReplica):
             layers, self.dcfg.num_blocks, self.dcfg.block_size,
             heads, head_dim, self.dcfg.max_blocks_per_seq(), dtype=dtype)
         self._prefill_jit = jax.jit(self.model.decode_prefill)
+        model_step = self.model.decode_step
+        block_size = self.dcfg.block_size
+        attention_kernel = self.dcfg.attention_kernel
+
+        # a named function, not a functools.partial: a profiler trace
+        # calls the program `jit_decode_step`, a partial `jit__unknown`
+        def decode_step(params, tokens, positions, k_cache, v_cache,
+                        block_tables, lengths):
+            return model_step(
+                params, tokens, positions, k_cache, v_cache, block_tables,
+                lengths, block_size=block_size,
+                attention_kernel=attention_kernel)
+
         # the cache arrays are rebound to the step's outputs at every
         # call site — donate them so XLA updates in place instead of
         # copying the whole [L, N, B, h, hd] pair per generated token
-        self._decode_jit = jax.jit(
-            functools.partial(self.model.decode_step,
-                              block_size=self.dcfg.block_size,
-                              attention_kernel=self.dcfg.attention_kernel),
-            donate_argnums=(3, 4))
+        self._decode_jit = jax.jit(decode_step, donate_argnums=(3, 4))
         # decode-loop-owned state (single writer: the batcher thread)
         self._slots: list[_DecodeSeq | None] = (
             [None] * self.dcfg.decode_slots)
@@ -156,6 +165,7 @@ class DecodeReplica(ServingReplica):
         self._versions: dict[int, object] = {}  # pinned old params
         self._seq_counter = 0
         self.tokens_streamed = 0
+        self.decode_steps = 0      # dispatches of the jitted decode step
         self.sequences_finished = 0
         # block-table upload cache: slot→block assignments only change
         # on admit/finish/restart, so the [slots, width] tables array a
@@ -230,7 +240,10 @@ class DecodeReplica(ServingReplica):
             staged, self._staged = self._staged, None
         if staged is None:
             return
-        install, t0 = staged
+        with spans.span(spans.SERVE_SWAP):
+            self._swap(*staged)
+
+    def _swap(self, install: dict, t0: float) -> None:
         if install["step"] <= self.model_step:
             return  # monotone: never swap backwards
         in_flight = [s for s in self._slots if s is not None]
@@ -286,7 +299,9 @@ class DecodeReplica(ServingReplica):
                 "kv_blocks_free": alloc.available,
                 "kv_blocks_total": alloc.num_blocks - 1,
                 "kv_blocks_reserved": len(alloc.in_use),
-                "decode_waiting": len(self._waiting)}
+                "decode_waiting": len(self._waiting),
+                "slots_live": sum(s is not None for s in self._slots),
+                "decode_steps": self.decode_steps}
 
     # -- the decode loop ------------------------------------------------
 
@@ -324,24 +339,39 @@ class DecodeReplica(ServingReplica):
         than evicting a running generation."""
         idle = (not self._waiting
                 and all(s is None for s in self._slots))
-        try:
-            # idle: park briefly on the queue instead of spinning.
-            # _waiting is capped at the slot count — anything beyond
-            # stays in the BOUNDED socket queue, so sustained block
-            # pressure still sheds typed `overloaded` rejects at
-            # admission instead of growing an unbounded staging line
-            while len(self._waiting) < self.dcfg.decode_slots:
-                self._waiting.append(
-                    self._queue.get(timeout=0.05) if idle
-                    else self._queue.get_nowait())
-                idle = False
-        except queue.Empty:
-            pass
+        with spans.span(spans.SERVE_IDLE if idle else spans.SERVE_ADMIT):
+            try:
+                # idle: park briefly on the queue instead of spinning.
+                # _waiting is capped at the slot count — anything beyond
+                # stays in the BOUNDED socket queue, so sustained block
+                # pressure still sheds typed `overloaded` rejects at
+                # admission instead of growing an unbounded staging line
+                while len(self._waiting) < self.dcfg.decode_slots:
+                    self._waiting.append(
+                        self._queue.get(timeout=0.05) if idle
+                        else self._queue.get_nowait())
+                    idle = False
+            except queue.Empty:
+                pass
+        while True:
+            # the prefill is a sibling of the admit span, not its child:
+            # the leaves tile the loop (obsv/spans.py)
+            with spans.span(spans.SERVE_ADMIT):
+                s = self._place_next()
+            if s is None:
+                return
+            self._prefill(s)
+
+    def _place_next(self) -> _DecodeSeq | None:
+        """Move the head of the deferred line into a free slot with its
+        blocks reserved; None when nothing more can be admitted now (no
+        one waits, no free slot, or block pressure: retried next
+        iteration)."""
         while self._waiting:
             free = next((i for i, s in enumerate(self._slots)
                          if s is None), None)
             if free is None:
-                return
+                return None
             s = self._waiting[0]
             if time.time() >= s.deadline_at:
                 self._waiting.popleft()
@@ -351,7 +381,7 @@ class DecodeReplica(ServingReplica):
             table = self.cache.alloc_sequence(
                 int(s.inputs.size) + s.max_tokens)
             if table is None:
-                return  # block pressure: retry next iteration
+                return None
             self._waiting.popleft()
             s.block_table = table
             s.params_step = s.started_step = self.model_step
@@ -359,33 +389,51 @@ class DecodeReplica(ServingReplica):
             self._seq_counter += 1
             self._slots[free] = s
             self._bump_tables_epoch()
-            self._prefill(s)
+            return s
+        return None
 
     def _prefill(self, s: _DecodeSeq, restart: bool = False) -> None:
         """Run the prompt through the model's prefill export (the
         configured attention kernel), seed the paged cache, and sample
         + stream the first token."""
         t0 = time.time()
+        queue_ms = round((t0 - s.admitted_at) * 1e3, 3)
+        slot = self._slots.index(s)
         plen = int(s.inputs.size)
         bucket = self._bucket(plen, self.dcfg.max_prompt_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :plen] = s.inputs
-        logits, ks, vs = self._prefill_jit(
-            self._params_for(s.params_step), jnp.asarray(toks))
-        self.cache.write_prompt(s.block_table, ks[:, 0], vs[:, 0], plen)
-        s.length = plen
-        tok = self._sample(s, logits[0, plen - 1])
-        s.tokens.append(tok)
-        self._stream_token(s, tok)
-        rec = {"action": "prefill", "id": s.req_id, "prompt_len": plen,
-               "bucket": bucket,
-               "blocks": int(np.count_nonzero(s.block_table)),
-               "model_step": s.params_step,
-               "ttft_ms": round((time.time() - t0) * 1e3, 3)}
-        if restart:
-            rec["restart"] = True
-        self._journal(rec)
-        self._maybe_finish(self._slots.index(s), s)
+        with spans.span(spans.SERVE_PREFILL, id=s.req_id, prompt_len=plen,
+                        bucket=bucket, queue_ms=queue_ms):
+            with spans.span(spans.SERVE_PREFILL_FORWARD):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :plen] = s.inputs
+                logits, ks, vs = self._prefill_jit(
+                    self._params_for(s.params_step), jnp.asarray(toks))
+            with spans.span(spans.SERVE_PREFILL_CACHE_WRITE):
+                self.cache.write_prompt(s.block_table, ks[:, 0], vs[:, 0],
+                                        plen)
+            s.length = plen
+            tok = self._sample(slot, s, logits[0, plen - 1])
+            s.tokens.append(tok)
+            self._stream_token(s, tok)
+            prefill_ms = round((time.time() - t0) * 1e3, 3)
+            rec = {"action": "prefill", "id": s.req_id, "prompt_len": plen,
+                   "bucket": bucket,
+                   "blocks": int(np.count_nonzero(s.block_table)),
+                   "model_step": s.params_step,
+                   "prefill_ms": prefill_ms,
+                   # the same value under its first name, which said
+                   # "time to first token" and never was: readers of
+                   # the journal (benchmark/lib/serving.py) still ask
+                   # for it
+                   "ttft_ms": prefill_ms}
+            if restart:
+                rec["restart"] = True
+            else:
+                # admitted_at → the start of this prefill: the wait in
+                # the queue and the deferred line for the running step
+                rec["queue_ms"] = queue_ms
+            self._journal(rec)
+        self._maybe_finish(slot, s)
 
     def _bump_tables_epoch(self) -> None:
         """Invalidate cached block-table uploads — called by every
@@ -440,36 +488,43 @@ class DecodeReplica(ServingReplica):
         # step per version, idle-for-this-version slots masked via the
         # null block table + zero length
         for ver in sorted({s.params_step for _, s in active}):
-            mine = [(i, s) for i, s in active if s.params_step == ver]
-            tokens = np.zeros((num_slots,), np.int32)
-            positions = np.zeros((num_slots,), np.int32)
-            lengths = np.zeros((num_slots,), np.int32)
-            for i, s in mine:
-                tokens[i] = s.tokens[-1]
-                positions[i] = s.length
-                lengths[i] = s.length + 1
-            logits, self.cache.k, self.cache.v = self._decode_jit(
-                self._params_for(ver), jnp.asarray(tokens),
-                jnp.asarray(positions), self.cache.k, self.cache.v,
-                self._tables_for(ver, mine, num_slots, width),
-                jnp.asarray(lengths))
-            logits = np.asarray(jax.device_get(logits))
+            with spans.span(spans.SERVE_STEP_INPUTS):
+                mine = [(i, s) for i, s in active if s.params_step == ver]
+                tokens = np.zeros((num_slots,), np.int32)
+                positions = np.zeros((num_slots,), np.int32)
+                lengths = np.zeros((num_slots,), np.int32)
+                for i, s in mine:
+                    tokens[i] = s.tokens[-1]
+                    positions[i] = s.length
+                    lengths[i] = s.length + 1
+                tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
+                tables = self._tables_for(ver, mine, num_slots, width)
+                lengths = jnp.asarray(lengths)
+            with spans.span(spans.SERVE_STEP_DISPATCH, live=len(active),
+                            waiting=len(self._waiting), version=ver):
+                logits, self.cache.k, self.cache.v = self._decode_jit(
+                    self._params_for(ver), tokens, positions, self.cache.k,
+                    self.cache.v, tables, lengths)
+                self.decode_steps += 1
+            with spans.span(spans.SERVE_STEP_FETCH):
+                logits = np.asarray(jax.device_get(logits))
             for i, s in mine:
                 s.length += 1  # the fed token's K/V is now cached
-                tok = self._sample(s, logits[i])
+                tok = self._sample(i, s, logits[i])
                 s.tokens.append(tok)
                 self._stream_token(s, tok)
                 self._maybe_finish(i, s)
 
-    def _sample(self, s: _DecodeSeq, logits_row) -> int:
-        if s.temperature <= 0.0:
-            return int(sample_token(jnp.asarray(logits_row)))
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(s.sample_seed),
-            len(s.tokens) + 1000 * s.restarts)
-        return int(sample_token(jnp.asarray(logits_row), key,
-                                temperature=s.temperature,
-                                top_k=s.top_k))
+    def _sample(self, slot: int, s: _DecodeSeq, logits_row) -> int:
+        with spans.span(spans.SERVE_SAMPLE, id=s.req_id, slot=slot):
+            if s.temperature <= 0.0:
+                return int(sample_token(jnp.asarray(logits_row)))
+            key = jax.random.fold_in(
+                jax.random.PRNGKey(s.sample_seed),
+                len(s.tokens) + 1000 * s.restarts)
+            return int(sample_token(jnp.asarray(logits_row), key,
+                                    temperature=s.temperature,
+                                    top_k=s.top_k))
 
     # -- streaming + termination ----------------------------------------
 
@@ -482,13 +537,14 @@ class DecodeReplica(ServingReplica):
             s.conn_dead = True  # finish early at the next check
 
     def _stream_token(self, s: _DecodeSeq, tok: int) -> None:
-        if s.first_token_at is None:
-            s.first_token_at = time.time()
-        self.tokens_streamed += 1
-        self._send_line(s, {"id": s.req_id, "stream": "token",
-                            "token": int(tok),
-                            "index": len(s.tokens) - 1,
-                            "model_step": s.params_step})
+        with spans.span(spans.SERVE_STREAM, id=s.req_id):
+            if s.first_token_at is None:
+                s.first_token_at = time.time()
+            self.tokens_streamed += 1
+            self._send_line(s, {"id": s.req_id, "stream": "token",
+                                "token": int(tok),
+                                "index": len(s.tokens) - 1,
+                                "model_step": s.params_step})
 
     def _maybe_finish(self, i: int, s: _DecodeSeq) -> None:
         eos = self.dcfg.eos_token
@@ -506,32 +562,33 @@ class DecodeReplica(ServingReplica):
         line, free the blocks, release the slot (refillable this very
         iteration) and drop the param version if this was its last
         pinned sequence."""
-        now = time.time()
-        fields = {"reason": reason, "tokens_streamed": len(s.tokens),
-                  "model_step": s.params_step,
-                  "started_step": s.started_step,
-                  "latency_ms": round((now - s.admitted_at) * 1e3, 3)}
-        if s.first_token_at is not None:
-            fields["ttft_ms"] = round(
-                (s.first_token_at - s.admitted_at) * 1e3, 3)
-        if s.restarts:
-            fields["restarts"] = s.restarts
-        self._terminal("decode_finish", s.req_id, **fields)
-        payload = {
-            "id": s.req_id, "status": "ok",
-            "tokens": [int(t) for t in s.tokens],
-            "finish_reason": reason, "model_step": s.params_step,
-            "started_step": s.started_step}
-        # idempotency: a mid-stream reset that ate this terminal makes
-        # the retry a dedup hit carrying the SAME completed tokens —
-        # the generation never runs twice for one request id
-        self._dedup_put(s.req_id, payload)
-        self._respond(s.conn, payload)
-        self._slots[i] = None
-        self.cache.free_sequence(s.block_table)
-        self._bump_tables_epoch()
-        self._release_version(s.params_step)
-        self.sequences_finished += 1
+        with spans.span(spans.SERVE_FINISH, id=s.req_id, reason=reason):
+            now = time.time()
+            fields = {"reason": reason, "tokens_streamed": len(s.tokens),
+                      "model_step": s.params_step,
+                      "started_step": s.started_step,
+                      "latency_ms": round((now - s.admitted_at) * 1e3, 3)}
+            if s.first_token_at is not None:
+                fields["ttft_ms"] = round(
+                    (s.first_token_at - s.admitted_at) * 1e3, 3)
+            if s.restarts:
+                fields["restarts"] = s.restarts
+            self._terminal("decode_finish", s.req_id, **fields)
+            payload = {
+                "id": s.req_id, "status": "ok",
+                "tokens": [int(t) for t in s.tokens],
+                "finish_reason": reason, "model_step": s.params_step,
+                "started_step": s.started_step}
+            # idempotency: a mid-stream reset that ate this terminal makes
+            # the retry a dedup hit carrying the SAME completed tokens —
+            # the generation never runs twice for one request id
+            self._dedup_put(s.req_id, payload)
+            self._respond(s.conn, payload)
+            self._slots[i] = None
+            self.cache.free_sequence(s.block_table)
+            self._bump_tables_epoch()
+            self._release_version(s.params_step)
+            self.sequences_finished += 1
 
     # -- metadata / lifecycle -------------------------------------------
 

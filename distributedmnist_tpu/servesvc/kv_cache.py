@@ -82,6 +82,7 @@ class BlockAllocator:
             self._free.append(b)
 
 
+@jax.named_scope("cache_write")
 def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
                     ks: jax.Array, vs: jax.Array,
                     block_table: jax.Array, length: jax.Array, *,
@@ -119,12 +120,12 @@ class PagedKVCache:
         shape = (num_layers, num_blocks, block_size, num_heads, head_dim)
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
-        import functools
         # write_prompt's caller rebinds self.k/self.v to the outputs —
-        # donate the cache operands so the scatter updates in place
-        self._write = jax.jit(functools.partial(
-            write_prompt_kv, block_size=block_size),
-            donate_argnums=(0, 1))
+        # donate the cache operands so the scatter updates in place.
+        # The function itself with a static argument, not a partial of
+        # it: a profiler trace calls the program `jit_write_prompt_kv`
+        self._write = jax.jit(write_prompt_kv, static_argnames="block_size",
+                              donate_argnums=(0, 1))
 
     def alloc_sequence(self, total_len: int) -> np.ndarray | None:
         """Reserve blocks for a sequence of up to ``total_len`` tokens;
@@ -153,7 +154,8 @@ class PagedKVCache:
         [L, s_pad, h, hd])."""
         self.k, self.v = self._write(self.k, self.v, ks, vs,
                                      jnp.asarray(block_table),
-                                     jnp.asarray(length))
+                                     jnp.asarray(length),
+                                     block_size=self.block_size)
 
     def gather_dense(self, block_table: np.ndarray,
                      length: int) -> tuple[np.ndarray, np.ndarray]:

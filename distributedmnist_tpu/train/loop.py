@@ -29,6 +29,7 @@ from ..data.device_prefetch import DevicePrefetcher
 from ..data.pipeline import device_prefetch_pays, make_train_iterator
 from .evaluation import run_full_eval
 from ..models.registry import Model, get_model
+from ..obsv import spans
 from ..obsv.timing import StepTimeCollector
 from ..parallel.api import (TrainState, build_eval_step, build_train_step,
                             canonical_save_state, init_train_state,
@@ -398,6 +399,10 @@ class Trainer:
         # writes the classic single file alone.
         if not self.is_writer and not ckpt.state_needs_sharded_save(self.state):
             return
+        with spans.span(spans.TRAIN_SAVE, step=step):
+            self._save_now()
+
+    def _save_now(self) -> None:
         t0 = time.perf_counter()
         # the world the artifact is saved under: what lets a restore
         # tell "same world" from "resized world, reshard" and the
@@ -902,27 +907,30 @@ class Trainer:
                 feed = self.train_feed
                 in_window = profile_stop > profile_start and profile_start <= step < profile_stop
                 if in_window and not profiling and self.is_writer:
-                    jax.profiler.start_trace(str(self.train_dir / "profile"))
+                    spans.start_profile(self.train_dir / "profile")
                     profiling = True
                 if (trace_every and self.is_writer and tracing_step is None
                         and step % trace_every == 0):
-                    jax.profiler.start_trace(
-                        str(self.train_dir / "profile" / f"step_{step}"))
+                    spans.start_profile(
+                        self.train_dir / "profile" / f"step_{step}")
                     tracing_step = step
                 t0 = time.time()
-                if prefetching:
-                    gbatch = next(feed)
-                    # gauge AT dequeue: sampled any later, the producer
-                    # has refilled and a producer-bound pipeline (the
-                    # "pinned at 0" reading) would look healthy
-                    queue_depth = feed.qsize
-                else:
-                    gbatch = self.topo.device_put_batch(
-                        next(feed), seq_sharded=self.seq_sharded)
-                self.state, metrics = self.step_fn(
-                    self.state, gbatch, measured_vector(),
-                    None if self._discipline is None
-                    else self._discipline.vector)
+                with spans.span(spans.TRAIN_FEED):
+                    if prefetching:
+                        gbatch = next(feed)
+                        # gauge AT dequeue: sampled any later, the
+                        # producer has refilled and a producer-bound
+                        # pipeline (the "pinned at 0" reading) would
+                        # look healthy
+                        queue_depth = feed.qsize
+                    else:
+                        gbatch = self.topo.device_put_batch(
+                            next(feed), seq_sharded=self.seq_sharded)
+                with spans.span(spans.TRAIN_DISPATCH, step=step):
+                    self.state, metrics = self.step_fn(
+                        self.state, gbatch, measured_vector(),
+                        None if self._discipline is None
+                        else self._discipline.vector)
                 # host_dt is the per-HOST base time and must be captured
                 # BEFORE the probe's drain poll — otherwise one slow device
                 # would inflate every local replica's base (and the slow
@@ -936,7 +944,9 @@ class Trainer:
                             # delay is attributed to the right replica
                             # even on backends without per-device FIFO
                             self._device_probe.note(_r, fn(arg))
-                    self._last_device_skew = self._device_probe.measure_skew_ms()
+                    with spans.span(spans.TRAIN_PROBE):
+                        self._last_device_skew = (
+                            self._device_probe.measure_skew_ms())
                 step += 1
                 self.collector.add(
                     metrics["step_times_ms"], host_dt,
@@ -947,7 +957,7 @@ class Trainer:
                     # one full step per window; fetch a scalar first so the
                     # trace covers the device work, not just the dispatch
                     float(metrics["loss"])
-                    jax.profiler.stop_trace()
+                    spans.stop_profile()
                     tracing_step = None
 
                 if cfg.step_pace_ms > 0:
@@ -956,10 +966,11 @@ class Trainer:
                     time.sleep(cfg.step_pace_ms / 1e3)
 
                 if step % log_every == 0:
-                    flush(time.time())
+                    with spans.span(spans.TRAIN_FLUSH):
+                        flush(time.time())
 
                 if profiling and step >= profile_stop:
-                    jax.profiler.stop_trace()
+                    spans.stop_profile()
                     profiling = False
 
                 if cfg.save_interval_secs > 0:
@@ -969,7 +980,8 @@ class Trainer:
                     self._save(step)
                 if cfg.save_results_period > 0 and step % cfg.save_results_period == 0:
                     self._dump_series()
-              flush(time.time())  # records past the last log boundary
+              with spans.span(spans.TRAIN_FLUSH):
+                  flush(time.time())  # records past the last log boundary
               break
             except _NonFiniteLoss as e:
                 # NaN/Inf guard: discard the poisoned window, stop any
@@ -981,10 +993,10 @@ class Trainer:
                 # is ever written, per the flush pre-scan.)
                 pending.clear()
                 if tracing_step is not None:
-                    jax.profiler.stop_trace()
+                    spans.stop_profile()
                     tracing_step = None
                 if profiling:
-                    jax.profiler.stop_trace()
+                    spans.stop_profile()
                     profiling = False
                 rollbacks += 1
                 if rollbacks > self.cfg.train.nan_guard_max_rollbacks:
@@ -1009,7 +1021,7 @@ class Trainer:
                 self._train_feed.stop()
 
         if profiling:
-            jax.profiler.stop_trace()
+            spans.stop_profile()
         if self._preempt_requested:
             self._recovery_event({"layer": "train", "action": "preempt_flush",
                                   "signal": self._preempt_requested,
