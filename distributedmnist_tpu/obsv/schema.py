@@ -300,7 +300,8 @@ _declare(EventSchema(
     required=("step",),
     optional=("tp_rank", "queue_depth", "queue_limit", "kv_blocks_free",
               "kv_blocks_total", "kv_blocks_reserved",
-              "decode_waiting", "slots_live", "decode_steps"),
+              "decode_waiting", "slots_live", "decode_steps",
+              "tokens_sampled_device", "tokens_sampled_host"),
 ))
 
 # Load-generator journal (servesvc/loadgen.py loadgen.jsonl): every

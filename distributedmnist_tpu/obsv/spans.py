@@ -64,11 +64,13 @@ SERVE_STEP_INPUTS = "dml.serve.step.inputs"
 #: decode_slots_live_p50 (`live`), decode_sample_ms_per_iter and
 #: decode_stream_ms_per_iter (the count)
 SERVE_STEP_DISPATCH = "dml.serve.step.dispatch"
-#: the blocking fetch of the step's [slots, vocab] logits
+#: the blocking fetch of the step's [slots] greedy tokens: the wait for
+#: the step on the device
 SERVE_STEP_FETCH = "dml.serve.step.fetch"
-#: `_sample` for one slot (id, slot): a row's upload, an eager argmax or
-#: draw, a blocking fetch; read by: decode_sample_ms_per_iter,
-#: serve_idle_sample_share
+#: once an iteration (device, host): the pick-up of every slot's token,
+#: `_sample`'s draws for `temperature > 0` included; and once a prefill
+#: (id, slot): `_sample` on its last row, which waits for the prefill on
+#: the device; read by: decode_sample_ms_per_iter, serve_idle_sample_share
 SERVE_SAMPLE = "dml.serve.sample"
 #: `_stream_token` (id): one JSON line and one `sendall`; read by:
 #: decode_stream_ms_per_iter
@@ -106,7 +108,7 @@ PREFETCH_PUT = "dml.prefetch.put"
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
           "ffn", "head", "loss", "aggregate", "update", "timing")
 
-#: a host span: ``with span(SERVE_SAMPLE, id=..., slot=...):``
+#: a host span: ``with span(SERVE_STREAM, id=...):``
 span = jax.profiler.TraceAnnotation
 
 

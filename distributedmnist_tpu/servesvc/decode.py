@@ -28,6 +28,12 @@ scattered into the sequence's blocks. The first token samples off the
 prefill logits: time-to-first-token is one prefill, not a decode-queue
 wait.
 
+**Sampling.** The compiled step also returns every slot's greedy token,
+and an iteration fetches that one ``[slots]`` int32 array. A request
+with ``temperature <= 0`` takes its entry; one with ``temperature > 0``
+draws from its row of the logits, which stay on the device for it. The
+request decides, there is no setting.
+
 **Weight swaps mid-generation.** The checkpoint follower stages
 digest-verified publishes exactly as the classification replica does;
 the flip happens at a decode-loop boundary under a declared policy
@@ -149,10 +155,15 @@ class DecodeReplica(ServingReplica):
         # calls the program `jit_decode_step`, a partial `jit__unknown`
         def decode_step(params, tokens, positions, k_cache, v_cache,
                         block_tables, lengths):
-            return model_step(
+            logits, k_cache, v_cache = model_step(
                 params, tokens, positions, k_cache, v_cache, block_tables,
                 lengths, block_size=block_size,
                 attention_kernel=attention_kernel)
+            # the greedy pick of every slot, made where the logits are:
+            # 4 bytes a slot to fetch, not a [slots, vocab] float32 array
+            with jax.named_scope("head"):
+                greedy = sample_token(logits)
+            return logits, greedy, k_cache, v_cache
 
         # the cache arrays are rebound to the step's outputs at every
         # call site — donate them so XLA updates in place instead of
@@ -166,6 +177,10 @@ class DecodeReplica(ServingReplica):
         self._seq_counter = 0
         self.tokens_streamed = 0
         self.decode_steps = 0      # dispatches of the jitted decode step
+        # tokens by where they were picked: the step's own greedy pick,
+        # or `_sample` (a draw, and every prefill's first token)
+        self.tokens_sampled_device = 0
+        self.tokens_sampled_host = 0
         self.sequences_finished = 0
         # block-table upload cache: slot→block assignments only change
         # on admit/finish/restart, so the [slots, width] tables array a
@@ -301,7 +316,9 @@ class DecodeReplica(ServingReplica):
                 "kv_blocks_reserved": len(alloc.in_use),
                 "decode_waiting": len(self._waiting),
                 "slots_live": sum(s is not None for s in self._slots),
-                "decode_steps": self.decode_steps}
+                "decode_steps": self.decode_steps,
+                "tokens_sampled_device": self.tokens_sampled_device,
+                "tokens_sampled_host": self.tokens_sampled_host}
 
     # -- the decode loop ------------------------------------------------
 
@@ -412,7 +429,9 @@ class DecodeReplica(ServingReplica):
                 self.cache.write_prompt(s.block_table, ks[:, 0], vs[:, 0],
                                         plen)
             s.length = plen
-            tok = self._sample(slot, s, logits[0, plen - 1])
+            # waits for the prefill on the device
+            with spans.span(spans.SERVE_SAMPLE, id=s.req_id, slot=slot):
+                tok = self._sample(s, logits[0, plen - 1])
             s.tokens.append(tok)
             self._stream_token(s, tok)
             prefill_ms = round((time.time() - t0) * 1e3, 3)
@@ -471,9 +490,9 @@ class DecodeReplica(ServingReplica):
 
     def _step_active(self) -> None:
         """One decode iteration: a single compiled step per live param
-        version over the fixed slot shape, then per-slot sample /
-        stream / finish — a finished slot is free for the NEXT
-        iteration's refill."""
+        version over the fixed slot shape, its greedy tokens fetched
+        as one array, then per-slot stream / finish — a finished slot
+        is free for the NEXT iteration's refill."""
         now = time.time()
         for i, s in enumerate(self._slots):
             if s is not None and now >= s.deadline_at:
@@ -502,29 +521,38 @@ class DecodeReplica(ServingReplica):
                 lengths = jnp.asarray(lengths)
             with spans.span(spans.SERVE_STEP_DISPATCH, live=len(active),
                             waiting=len(self._waiting), version=ver):
-                logits, self.cache.k, self.cache.v = self._decode_jit(
-                    self._params_for(ver), tokens, positions, self.cache.k,
-                    self.cache.v, tables, lengths)
+                logits, greedy, self.cache.k, self.cache.v = (
+                    self._decode_jit(
+                        self._params_for(ver), tokens, positions,
+                        self.cache.k, self.cache.v, tables, lengths))
                 self.decode_steps += 1
             with spans.span(spans.SERVE_STEP_FETCH):
-                logits = np.asarray(jax.device_get(logits))
-            for i, s in mine:
+                on_host = jax.device_get(greedy)
+            draws = sum(s.temperature > 0.0 for _, s in mine)
+            # every slot's token before any is appended or streamed: one
+            # span an iteration, never around a `dml.serve.stream`
+            with spans.span(spans.SERVE_SAMPLE, device=len(mine) - draws,
+                            host=draws):
+                picked = [int(on_host[i]) if s.temperature <= 0.0
+                          else self._sample(s, logits[i]) for i, s in mine]
+            self.tokens_sampled_device += len(mine) - draws
+            for (i, s), tok in zip(mine, picked):
                 s.length += 1  # the fed token's K/V is now cached
-                tok = self._sample(i, s, logits[i])
                 s.tokens.append(tok)
                 self._stream_token(s, tok)
                 self._maybe_finish(i, s)
 
-    def _sample(self, slot: int, s: _DecodeSeq, logits_row) -> int:
-        with spans.span(spans.SERVE_SAMPLE, id=s.req_id, slot=slot):
-            if s.temperature <= 0.0:
-                return int(sample_token(jnp.asarray(logits_row)))
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(s.sample_seed),
-                len(s.tokens) + 1000 * s.restarts)
-            return int(sample_token(jnp.asarray(logits_row), key,
-                                    temperature=s.temperature,
-                                    top_k=s.top_k))
+    def _sample(self, s: _DecodeSeq, logits_row: jax.Array) -> int:
+        """One token from one row of logits on the device: a prefill's
+        first token, or a decode iteration's seeded draw."""
+        self.tokens_sampled_host += 1
+        if s.temperature <= 0.0:
+            return int(sample_token(logits_row))
+        key = jax.random.fold_in(
+            jax.random.PRNGKey(s.sample_seed),
+            len(s.tokens) + 1000 * s.restarts)
+        return int(sample_token(logits_row, key,
+                                temperature=s.temperature, top_k=s.top_k))
 
     # -- streaming + termination ----------------------------------------
 

@@ -493,6 +493,120 @@ def _decode_swap_violations(rep, troot):
 
 
 # ---------------------------------------------------------------------------
+# where a token is picked: by the compiled step (greedy), or drawn from
+# the slot's row of the logits (temperature > 0)
+# ---------------------------------------------------------------------------
+
+def _parents_token(s, row, n_tokens: int) -> int:
+    """What the parent of PR 25 streamed for sequence ``s`` holding
+    ``n_tokens`` tokens, given this row of the step's logits: it fetched
+    the row and ran ``sample_token`` on it, with the sequence's key."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.models.registry import sample_token
+    if s.temperature <= 0.0:
+        return int(sample_token(jnp.asarray(row)))
+    key = jax.random.fold_in(jax.random.PRNGKey(s.sample_seed),
+                             n_tokens + 1000 * s.restarts)
+    return int(sample_token(jnp.asarray(row), key,
+                            temperature=s.temperature, top_k=s.top_k))
+
+
+GREEDY, DRAW, DRAW_K8 = {}, {"temperature": 0.8}, {"temperature": 0.8,
+                                                   "top_k": 8}
+
+
+@pytest.mark.parametrize("case, sampling", [
+    ("all_greedy", [GREEDY, GREEDY, GREEDY]),
+    ("mixed", [GREEDY, DRAW, DRAW_K8]),
+    ("idle_slot", [DRAW_K8, GREEDY]),
+    ("tie", [GREEDY, GREEDY, DRAW]),
+    ("two_versions", [GREEDY, DRAW_K8, GREEDY]),
+])
+def test_streamed_tokens_are_the_parents_rule_on_the_same_logits(
+        lm_published, tmp_path, case, sampling):
+    rep, serve_src = make_replica(lm_published, tmp_path, slots=3,
+                                  max_new=10)
+    rep._load_initial()
+    if case == "tie":
+        # the head is tied to the embedding: rows 2k and 2k+1 made equal
+        # tie every logit with its neighbour, the largest included
+        embed = rep._params["embed"]
+        rep._params = {**rep._params,
+                       "embed": embed.at[1::2].set(embed[0::2])}
+    step, calls = rep._decode_jit, []
+
+    def recording_step(*args):
+        out = step(*args)
+        calls.append(np.asarray(out[0]))
+        return out
+
+    rep._decode_jit = recording_step
+    seqs = []
+
+    def admit(n):
+        seqs.append(admit_direct(rep, {
+            "id": n, "prompt": [1 + n, 2, 3 + n][:2 + n % 2],
+            "max_tokens": 4 + 3 * n, "deadline_ms": 60000,
+            **sampling[n]}))
+
+    picked = {"device": 0, "host": 0}
+    versions_live = set()
+
+    def iteration():
+        rep._admit_new()
+        before = [(i, s, len(s.tokens))
+                  for i, s in enumerate(rep._slots) if s is not None]
+        first = len(calls)
+        rep._step_active()
+        vers = sorted({s.params_step for _, s, _ in before})
+        versions_live.add(len(vers))
+        assert len(calls) - first == len(vers)
+        for logits, ver in zip(calls[first:], vers):
+            for i, s, n in before:
+                if s.params_step != ver:
+                    continue
+                assert s.tokens[n] == _parents_token(s, logits[i], n)
+                picked["host" if s.temperature > 0 else "device"] += 1
+                if case == "tie" and s.temperature <= 0:
+                    top = s.tokens[n]
+                    assert top % 2 == 0     # the first index of the tie
+                    assert logits[i, top] == logits[i, top + 1]
+
+    late = len(sampling) - 1 if case == "two_versions" else len(sampling)
+    for n in range(late):
+        admit(n)
+    iteration()
+    if case == "two_versions":
+        publish_step(lm_published["staging"], serve_src, 20)
+        rep._staged = rep.follower.poll(rep._read_weights)[1:]
+        rep._maybe_swap()
+        admit(late)
+    while any(s is not None for s in rep._slots) or rep._queue.qsize():
+        iteration()
+
+    assert versions_live == ({1, 2} if case == "two_versions" else {1})
+    if case == "two_versions":
+        assert [s.params_step for s, _ in seqs] == [10, 10, 20]
+    for (s, conn), asked in zip(seqs, sampling):
+        assert s.temperature == asked.get("temperature", 0.0)
+        assert len(s.tokens) == s.max_tokens
+        assert [l["token"] for l in conn.lines
+                if l.get("stream") == "token"] == s.tokens
+        assert conn.lines[-1]["tokens"] == s.tokens
+    # every prefill's first token goes through `_sample`, on the host
+    assert rep.tokens_sampled_host == len(seqs) + picked["host"]
+    assert rep.tokens_sampled_device == picked["device"]
+    assert (rep.tokens_sampled_device + rep.tokens_sampled_host
+            == rep.tokens_streamed == sum(len(s.tokens) for s, _ in seqs))
+    # counted from the requests alone: every token of a greedy request
+    # after its first is the step's own pick
+    assert rep.tokens_sampled_device == sum(
+        s.max_tokens - 1 for s, _ in seqs if s.temperature <= 0) > 0
+
+
+# ---------------------------------------------------------------------------
 # the decode_swap invariant over handcrafted journals
 # ---------------------------------------------------------------------------
 

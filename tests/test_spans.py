@@ -101,8 +101,9 @@ def published(tmp_path_factory):
 def drive(published, serve_dir, trace_dir=None):
     """Three requests through a three-slot replica, the batcher's loop
     body called from this thread: a and b admitted together, c two
-    iterations later. Returns the replica, what each client received and
-    the occupancy before every iteration that dispatched a step."""
+    iterations later; a and c greedy, b a seeded draw. Returns the
+    replica, what each client received and the occupancy before every
+    iteration that dispatched a step."""
     from distributedmnist_tpu.core.config import DecodeConfig, ServeConfig
     from distributedmnist_tpu.servesvc.decode import DecodeReplica
 
@@ -114,11 +115,12 @@ def drive(published, serve_dir, trace_dir=None):
     rep._load_initial()
     conns, occupancy = {}, []
 
-    def admit(req_id, prompt, max_tokens):
+    def admit(req_id, prompt, max_tokens, **sampling):
         conns[req_id] = StubConn()
         seq = rep._build_item({"id": req_id, "prompt": prompt,
                                "max_tokens": max_tokens,
-                               "deadline_ms": 600000}, conns[req_id])
+                               "deadline_ms": 600000, **sampling},
+                              conns[req_id])
         rep._journal({"action": "admit", "id": req_id,
                       "deadline_ms": 600000.0})
         rep._queue.put_nowait(seq)
@@ -136,7 +138,7 @@ def drive(published, serve_dir, trace_dir=None):
         spans.start_profile(trace_dir)
     try:
         admit("a", [1, 2, 3], 4)
-        admit("b", [4, 5, 6, 7, 8], 7)
+        admit("b", [4, 5, 6, 7, 8], 7, temperature=0.8, top_k=8)
         iteration()
         iteration()
         admit("c", [9, 10], 3)
@@ -198,13 +200,47 @@ def test_one_id_across_a_requests_prefill_sample_stream_finish(
         mine = [e for e in evs if e.get("id") == req_id]
         count = lambda name: len(_named(mine, name))  # noqa: E731
         assert count(spans.SERVE_PREFILL) == 1
-        assert count(spans.SERVE_SAMPLE) == n_tokens
+        # the prefill's first token; an iteration's span is no request's
+        assert count(spans.SERVE_SAMPLE) == 1
         assert count(spans.SERVE_STREAM) == n_tokens
         [fin] = _named(mine, spans.SERVE_FINISH)
         assert fin["reason"] == "max_tokens"
         assert mine[0]["name"] == spans.SERVE_PREFILL and mine[-1] is fin
-    slots = {e["id"]: e["slot"] for e in _named(evs, spans.SERVE_SAMPLE)}
+    slots = {e["id"]: e["slot"] for e in _named(evs, spans.SERVE_SAMPLE)
+             if "id" in e}
     assert slots == {"a": 0, "b": 1, "c": 2}
+
+
+def test_one_sample_span_an_iteration_whatever_its_slots_ask_for(
+        traced_decode):
+    evs, rep = traced_decode["spans"], traced_decode["rep"]
+    samples = _named(evs, spans.SERVE_SAMPLE)
+    prefills = _named(evs, spans.SERVE_PREFILL)
+    first = [e for e in samples if "id" in e]
+    assert len(first) == len(prefills) == 3
+    for p, e in zip(prefills, first):       # one inside each prefill
+        assert p["start"] <= e["start"] and e["end"] <= p["end"]
+    # the others: one between each dispatch and the next, counting the
+    # slots whose token the step picked (a, c) and those that drew (b)
+    per_iter = [e for e in samples if "id" not in e]
+    dispatches = _named(evs, spans.SERVE_STEP_DISPATCH)
+    assert len(per_iter) == len(dispatches)
+    for i, (d, e) in enumerate(zip(dispatches, per_iter)):
+        nxt = (dispatches[i + 1]["start"] if i + 1 < len(dispatches)
+               else float("inf"))
+        assert d["end"] <= e["start"] and e["end"] <= nxt
+        assert e["device"] + e["host"] == d["live"]
+    # b draws while it lives (6 tokens after its first), alone or not;
+    # an iteration whose slots are all greedy still opens the span
+    assert [e["host"] for e in per_iter] == [1] * 6
+    assert [e["device"] for e in per_iter] == [1, 1, 2, 1, 0, 0]
+    assert rep.tokens_sampled_device == sum(e["device"] for e in per_iter)
+    assert rep.tokens_sampled_host == 3 + sum(e["host"] for e in per_iter)
+    # neither name inside the other: idle time under a sample span is
+    # all counted as sampling (serve_idle_sample_share)
+    for e in samples:
+        assert not any(t["start"] < e["end"] and e["start"] < t["end"]
+                       for t in _named(evs, spans.SERVE_STREAM))
 
 
 def test_live_on_the_dispatch_span_is_the_occupancy_arranged(traced_decode):
@@ -247,9 +283,12 @@ def test_journal_and_heartbeat_carry_the_same_facts(traced_decode):
         assert r["queue_ms"] == pytest.approx(by_id[r["id"]]["queue_ms"])
     beats = read("train_log.jsonl")
     assert beats and all(validate_event(b) == [] for b in beats)
-    assert all({"slots_live", "decode_steps"} <= set(b) for b in beats)
+    assert all({"slots_live", "decode_steps", "tokens_sampled_device",
+                "tokens_sampled_host"} <= set(b) for b in beats)
     assert beats[-1]["slots_live"] == 0
     assert beats[-1]["decode_steps"] == rep.decode_steps
+    assert (beats[-1]["tokens_sampled_device"]
+            + beats[-1]["tokens_sampled_host"]) == rep.tokens_streamed == 14
     # occupancy as the heartbeat saw it: written after a's finish, with
     # b and c still generating
     assert any(b["slots_live"] == 2 for b in beats)
