@@ -15,11 +15,12 @@ once in hours, a benchmark run would save every minute; the save is a
 cell of its own in PERF.md's list).
 
 ``correct``: before the loop, on two seeded sequences, the system's
-loss and its logits at the last 256 positions against
-``lib/reference.py``; after it, every step's loss finite, the mean of
-the window's last five below that of its first five, no NaN rollback,
-no compilation inside the window, and under quorum exactly k
-contributors in every step with every parameter on every chip."""
+loss and its logits at the last 256 positions against the reference of
+the cell's architecture (``benchmark/archs/<arch>.py``); after it,
+every step's loss finite, the mean of the window's last five below that
+of its first five, no NaN rollback, no compilation inside the window,
+and under quorum exactly k contributors in every step with every
+parameter on every chip."""
 
 from __future__ import annotations
 
@@ -30,8 +31,9 @@ import time
 
 import numpy as np
 
-from benchmark.lib import flops, reference, tokens
-from benchmark.lib.cell import BenchmarkError, model_section
+from benchmark.lib import tokens
+from benchmark.lib.cell import BenchmarkError
+from benchmark.lib.compare import max_rel_err
 
 #: Largest error over the reference's largest magnitude. The system
 #: feeds the MXU bf16 operands and keeps bf16 activations (8 mantissa
@@ -62,7 +64,8 @@ def experiment(cell, rt) -> dict:
         "data": {"dataset": "synthetic_lm",
                  "batch_size": train["sequences_per_step_per_chip"]
                  * cell.chips},
-        "model": {**model_section(cell.config), "init_seed": rt.seed},
+        "model": {**cell.arch.model_section(cell.config),
+                  "init_seed": rt.seed},
         "optim": train["optim"],
         "sync": cell.traffic["sync"],
         "train": {"train_dir": str(rt.workdir / "train"), "seed": rt.seed,
@@ -85,10 +88,10 @@ def _datasets(cell, rt, seq_len: int, vocab: int):
                     test=as_set(held)), held
 
 
-def check_against_reference(trainer, held: np.ndarray, heads: int) -> dict:
+def check_against_reference(trainer, held: np.ndarray, cell) -> dict:
     """Loss and last-positions logits of the system (its own ``apply``
-    at its own settings, kernels and all) against the plain reference,
-    on the weights the run starts from."""
+    at its own settings, kernels and all) against the plain reference
+    of the cell's architecture, on the weights the run starts from."""
     import jax
     import jax.numpy as jnp
 
@@ -103,12 +106,13 @@ def check_against_reference(trainer, held: np.ndarray, heads: int) -> dict:
         lg = model.apply(p, t, train=False)
         return model.loss(lg, t), lg[:, -CHECK_LAST:]
 
+    arch, config = cell.arch, cell.config
     ref = jax.jit(lambda p, t: (
-        reference.loss(p, t, heads),
-        reference.logits(p, t, heads, last=CHECK_LAST)))
+        arch.loss(p, t, config),
+        arch.logits(p, t, config, last=CHECK_LAST)))
     sys_loss, sys_logits = system(params, toks)
     ref_loss, ref_logits = ref(params, toks)
-    logits_err = reference.max_rel_err(sys_logits, ref_logits)
+    logits_err = max_rel_err(sys_logits, ref_logits)
     loss_err = abs(float(sys_loss) - float(ref_loss)) / abs(float(ref_loss))
     return {"loss_system": float(sys_loss), "loss_reference": float(ref_loss),
             "loss_rel_err": loss_err, "logits_max_rel_err": logits_err,
@@ -136,7 +140,7 @@ def run(cell, rt) -> dict:
     rt.mark("trainer_built")
     spans = {len(leaf.sharding.device_set)
              for leaf in jax.tree.leaves(trainer.state.params)}
-    check = check_against_reference(trainer, held, model_cfg.num_heads)
+    check = check_against_reference(trainer, held, cell)
     rt.say(event="reference_check", **check,
            tolerances={"logits": LOGITS_TOL, "loss": LOSS_TOL})
     rt.mark("reference_checked")
@@ -220,7 +224,6 @@ def run(cell, rt) -> dict:
            compiles_in_window=rt.compiles_in_window, checks=checks)
     host = trainer.collector.host_step_stats()
     depth = trainer.collector.prefetch_depth_stats()
-    shapes = cell.config
     return {
         "correct": all(checks.values()),
         "attempted": s1 - s0,
@@ -233,11 +236,11 @@ def run(cell, rt) -> dict:
             "tokens_per_s": rate * cell.chips,
             "tokens_per_step": tokens_per_step,
             "steps_per_log_window": every,
-            "model_flops_per_token": flops.train_flops_per_token(
-                shapes, model_cfg.seq_len),
+            "model_flops_per_token": cell.arch.train_flops_per_token(
+                cell.config, model_cfg.seq_len),
             "attention_flops_per_step_per_chip": (
-                flops.attention_train_flops_per_token(
-                    shapes, model_cfg.seq_len)
+                cell.arch.attention_train_flops_per_token(
+                    cell.config, model_cfg.seq_len)
                 * tokens_per_step / cell.chips),
         },
     }

@@ -4,9 +4,27 @@ own that is found by the name in that list, so a later PR adds a cell by
 adding files and entries and edits nothing:
 
 * ``benchmark/configs/<config>.json`` is named by the entry's ``file``;
+* ``benchmark/archs/<arch>.py`` by the configuration file's ``arch``;
 * ``benchmark/traffic/<traffic>.json`` is found beside it by name;
 * ``benchmark/drivers/<kind>.py`` by the traffic file's ``kind``;
 * ``benchmark/layer_metrics/<metric>.py`` by the metric's name.
+
+An architecture file is the one place that knows a model's block: the
+drivers, the harness and the tests reach the block through it and hand
+it the configuration file, whatever keys its source gave it. It is what
+``correct`` is decided against. Its interface:
+
+* ``model_section(config) -> dict``: the source's keys to the program's
+  ``model`` section, sizes only, raising :class:`BenchmarkError` for a
+  shape the program cannot run;
+* ``logits(params, tokens, config, last=None)`` and
+  ``loss(params, tokens, config)``: the plain reference, float32 at
+  ``highest`` matrix precision, importing nothing from the program;
+* ``train_flops_per_token(config, seq_len)``,
+  ``attention_train_flops_per_token(config, seq_len)`` and
+  ``decode_bytes_per_step(config, contexts, weight_bytes=2, kv_bytes=2)``:
+  the model's own counts, as ``lib/flops.py`` defines them
+  (recomputation, padding, casts and copies never count).
 """
 
 from __future__ import annotations
@@ -14,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import types
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -30,6 +49,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict          # the configuration file, as it is run
+    arch: types.ModuleType   # benchmark/archs/<config["arch"]>.py
     traffic_name: str
     traffic: dict         # the traffic mix's parameters
     end_to_end: tuple[dict, ...]   # metric entries this cell reports
@@ -65,9 +85,10 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                              f"{entry['config']!r}, which is not listed")
     traffic_path = (root / "benchmark" / "traffic"
                     / f"{entry['traffic']}.json")
+    config = load_json(root / cfg_entry["file"])
     return Cell(name=name, chips=int(entry["chips"]),
                 config_name=entry["config"],
-                config=load_json(root / cfg_entry["file"]),
+                config=config, arch=load_arch(config, root),
                 traffic_name=entry["traffic"],
                 traffic=load_json(traffic_path),
                 end_to_end=_metrics_for(bench["end_to_end"], name),
@@ -99,23 +120,13 @@ def load_reader(metric: str, root: Path = ROOT):
                         / f"{metric}.py", "reader")
 
 
-# -- the configuration file → the sections handed to the program ----------
-
-def model_section(config: dict) -> dict:
-    """The program's ``model`` section from the configuration's source
-    keys. Only sizes: the choice of attention implementation, dtype and
-    recomputation policy stay at the program's defaults."""
-    d, ffn = config["hidden_size"], config["ffn_dim"]
-    if ffn != 4 * d:
+def load_arch(config: dict, root: Path = ROOT):
+    """The architecture a configuration file names: a module with the
+    interface in this file's docstring. There is no default: a file
+    that does not say what its block is cannot be checked."""
+    if "arch" not in config:
         raise BenchmarkError(
-            f"ffn_dim {ffn} is not 4 x hidden_size {d}: the repo's block "
-            "fixes the FFN width at 4·d and cannot run this shape")
-    if config.get("word_embed_proj_dim", d) != d:
-        raise BenchmarkError("word_embed_proj_dim differs from hidden_size: "
-                             "the repo's block has no embedding projection")
-    return {"name": "transformer", "model_dim": d,
-            "num_heads": config["num_attention_heads"],
-            "num_layers": config["num_hidden_layers"],
-            "seq_len": config["max_position_embeddings"],
-            "vocab_size": config["vocab_size"],
-            **config.get("model_assumed", {})}
+            "the configuration names no architecture: give it an "
+            '"arch" key, the name of a file under benchmark/archs/')
+    return _load_module(root / "benchmark" / "archs"
+                        / f"{config['arch']}.py", "arch")

@@ -98,12 +98,3 @@ def loss_and_grads(params, tokens, num_heads: int):
     plain automatic differentiation of :func:`loss`."""
     return jax.value_and_grad(
         lambda p: loss(p, tokens, num_heads))(_f32(params))
-
-
-def max_rel_err(got, want) -> float:
-    """Largest absolute error over the reference's largest magnitude:
-    the measure every tolerance in the benchmark is written in."""
-    got = jnp.asarray(got, jnp.float32)
-    want = jnp.asarray(want, jnp.float32)
-    return float(jnp.max(jnp.abs(got - want))
-                 / jnp.maximum(jnp.max(jnp.abs(want)), 1e-30))

@@ -1,6 +1,7 @@
 """What the two serving drivers share: weights from the seed published
 as the checkpoint the replica follows, the check of the decode path
-against the plain reference, the replica run in this process as
+against the plain reference of the cell's architecture
+(``benchmark/archs/<arch>.py``), the replica run in this process as
 ``launch serve --decode`` runs it, and the load generator as a child
 that never touches jax.
 
@@ -22,9 +23,10 @@ from pathlib import Path
 
 import numpy as np
 
-from . import flops, reference, serve_metrics, traffic as traffic_lib
+from . import serve_metrics, traffic as traffic_lib
+from .cell import BENCH_DIR, BenchmarkError
+from .compare import max_rel_err
 from .stats import percentile
-from .cell import BENCH_DIR, BenchmarkError, model_section
 
 #: Largest error of the decode path's logits over the reference's
 #: largest magnitude, prefill plus eight decode steps through the paged
@@ -44,7 +46,8 @@ def experiment(cell, rt) -> dict:
     serve = cell.config["serve"]
     return {
         "name": cell.name,
-        "model": {**model_section(cell.config), "init_seed": rt.seed},
+        "model": {**cell.arch.model_section(cell.config),
+                  "init_seed": rt.seed},
         "precision": serve.get("precision", {}),
         "serve": serve["replica"],
         "decode": serve["decode"],
@@ -53,14 +56,14 @@ def experiment(cell, rt) -> dict:
 
 
 def check_decode_against_reference(model, params, dcfg, cache_dtype,
-                                   vocab: int, heads: int,
-                                   seed: int) -> dict:
+                                   vocab: int, cell, seed: int) -> dict:
     """Prefill of a seeded prompt and teacher-forced decode steps
     through a scratch paged cache of the replica's own geometry, by the
     model record's exports (the jitted step is built exactly as
-    ``DecodeReplica`` builds it), against the reference's full forward
-    at the same positions. Logits, never sampled tokens: with random
-    weights the largest logit changes on a rounding."""
+    ``DecodeReplica`` builds it), against the full forward of the
+    reference of the cell's architecture at the same positions. Logits,
+    never sampled tokens: with random weights the largest logit changes
+    on a rounding."""
     import jax
     import jax.numpy as jnp
     from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
@@ -96,9 +99,10 @@ def check_decode_against_reference(model, params, dcfg, cache_dtype,
         rows.append(out[0])
     got = jnp.stack(rows)
     del cache, ks, vs, logits, out     # room for the float32 reference
-    want = jax.jit(lambda p, t: reference.logits(
-        p, t, heads, last=n_steps + 1))(params, jnp.asarray(seq[None]))[0]
-    err = reference.max_rel_err(got, want)
+    arch, config = cell.arch, cell.config
+    want = jax.jit(lambda p, t: arch.logits(
+        p, t, config, last=n_steps + 1))(params, jnp.asarray(seq[None]))[0]
+    err = max_rel_err(got, want)
     finite = bool(jnp.isfinite(got).all())
     return {"decode_logits_max_rel_err": err, "positions": n_steps + 1,
             "ok": bool(finite and err <= DECODE_LOGITS_TOL)}
@@ -165,7 +169,7 @@ def boot_replica(cell, rt):
         check = check_decode_against_reference(
             model, state.params, cfg.decode,
             jax.numpy.dtype(model_cfg.compute_dtype), cfg.model.vocab_size,
-            cfg.model.num_heads, rt.seed)
+            cell, rt.seed)
         del params
     rt.say(event="reference_check", **check, tolerance=DECODE_LOGITS_TOL)
     rt.mark("reference_checked")
@@ -264,7 +268,7 @@ def run_serving(cell, rt, mode: str) -> dict:
         # what one decode step had to move in the middle of the trace
         contexts = serve_metrics.live_contexts(
             load, trace_info["t0"] + trace_info["seconds"] / 2)
-        decode_bytes = flops.decode_bytes_per_step(cell.config, contexts)
+        decode_bytes = cell.arch.decode_bytes_per_step(cell.config, contexts)
     return {
         "correct": all(checks.values()),
         "attempted": summary["attempted"], "failed": summary["failed"],

@@ -7,13 +7,14 @@ from the root of a checkout, on the machine that holds the chips the
 cell asks for. Set-up (bring-up, weights, compile or load, warm-up of
 this cell's shapes and no others) is timed as ``setup_s``; then the cell
 is measured for ``--seconds``; the program's outputs are checked against
-``benchmark/lib/reference.py``. The LAST line of standard output is the
-result object (``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, in a traced run, ``breakdown``); everything else is on
-earlier lines. With ``--trace 0`` the metrics are the cell's end-to-end
-metrics, taken with the profiler off; with ``--trace 1`` they are its
-per-layer metrics, each by its own reader under
-``benchmark/layer_metrics/``.
+the plain reference of the cell's architecture, the file
+``benchmark/archs/<arch>.py`` that its configuration names. The LAST
+line of standard output is the result object (``correct``,
+``attempted``, ``failed``, ``metrics``, ``device`` and, in a traced run,
+``breakdown``); everything else is on earlier lines. With ``--trace 0``
+the metrics are the cell's end-to-end metrics, taken with the profiler
+off; with ``--trace 1`` they are its per-layer metrics, each by its own
+reader under ``benchmark/layer_metrics/``.
 
 It exits non-zero and prints no result where JAX's first device is not
 a TPU, the chip is not in ``lib/peaks.py``, there are fewer chips than

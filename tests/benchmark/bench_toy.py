@@ -11,9 +11,10 @@ import pytest
 from benchmark.lib import cell as cell_lib
 from benchmark.lib.runtime import Runtime
 
-TOY_SHAPES = {"hidden_size": 64, "ffn_dim": 256, "num_attention_heads": 4,
-              "num_hidden_layers": 2, "vocab_size": 512,
-              "max_position_embeddings": 128, "word_embed_proj_dim": 64}
+TOY_SHAPES = {"arch": "opt", "hidden_size": 64, "ffn_dim": 256,
+              "num_attention_heads": 4, "num_hidden_layers": 2,
+              "vocab_size": 512, "max_position_embeddings": 128,
+              "word_embed_proj_dim": 64}
 
 TOY_TRAIN_CONFIG = {
     **TOY_SHAPES,
@@ -80,7 +81,8 @@ def toy_cell(traffic_name: str) -> cell_lib.Cell:
               else TOY_SERVE_CONFIG)
     return cell_lib.Cell(
         name=f"toy.{traffic_name}", chips=real.chips, config_name="toy",
-        config=config, traffic_name=traffic_name,
+        config=config, arch=cell_lib.load_arch(config),
+        traffic_name=traffic_name,
         traffic=TOY_TRAFFIC[traffic_name], end_to_end=real.end_to_end,
         per_layer=real.per_layer)
 

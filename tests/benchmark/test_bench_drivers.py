@@ -4,7 +4,9 @@ points and the result object has exactly the contract's keys. Nothing
 timed here is a device metric; the values are only checked for being
 there."""
 
+import dataclasses
 import json
+import types
 
 import jax
 import numpy as np
@@ -13,9 +15,17 @@ import pytest
 from bench_toy import toy_cell, toy_runtime  # noqa: F401  (fixture)
 from benchmark import run as run_mod
 from benchmark.lib import reference
+from benchmark.lib.compare import max_rel_err
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+def _events(capsys) -> dict:
+    """The run's JSON lines by their ``event``."""
+    return {e["event"]: e for e in map(
+        json.loads, (line for line in capsys.readouterr().out.splitlines()
+                     if line.startswith("{")))}
 
 
 def _check_result(result: dict, cell) -> None:
@@ -36,9 +46,7 @@ def test_driver_end_to_end_at_a_toy_size(traffic, toy_runtime,  # noqa: F811
     cell = toy_cell(traffic)
     result = run_mod.measure(cell, toy_runtime(cell, seconds=1.5))
     _check_result(result, cell)
-    said = [json.loads(line) for line in capsys.readouterr().out.splitlines()
-            if line.startswith("{")]
-    events = {e["event"]: e for e in said}
+    events = _events(capsys)
     assert events["reference_check"]["ok"]
     window = events.get("train_window") or events["serve_window"]
     assert window["compiles_in_window"] == 0
@@ -68,6 +76,26 @@ def test_a_wrong_answer_is_not_correct(toy_runtime,  # noqa: F811
     assert result["metrics"]["train_tokens_per_s_per_chip"]["value"] > 0
 
 
+@pytest.mark.parametrize("traffic", ["train_sync", "serve_closed"])
+def test_the_cells_architecture_file_decides(
+        traffic, toy_runtime, capsys):  # noqa: F811
+    """Both drivers take the reference from the cell's architecture file
+    and from nowhere else: hand them ``archs/opt.py`` with its reference
+    given a different mask, ``lib/reference.py`` untouched, and the same
+    program is reported as incorrect."""
+    cell = toy_cell(traffic)
+    opt = cell.arch
+    other = types.SimpleNamespace(**vars(opt))
+    other.logits = lambda params, tokens, config, last=None: opt.logits(
+        params, tokens[:, ::-1], config, last=last)
+    other.loss = lambda params, tokens, config: opt.loss(
+        params, tokens[:, ::-1], config)
+    cell = dataclasses.replace(cell, arch=other)
+    result = run_mod.measure(cell, toy_runtime(cell, seconds=1.0))
+    assert result["correct"] is False and result["attempted"] > 0
+    assert _events(capsys)["reference_check"]["ok"] is False
+
+
 # -- the reference against the program, forward, loss and gradients -------
 
 def _toy_model(dtype="float32"):
@@ -95,7 +123,7 @@ def test_reference_matches_the_program_in_float32():
     assert float(ref_loss) == pytest.approx(float(sys_loss), rel=1e-5)
     for got, want in zip(jax.tree.leaves(sys_grads),
                          jax.tree.leaves(ref_grads)):
-        assert reference.max_rel_err(got, want) < 1e-3
+        assert max_rel_err(got, want) < 1e-3
 
 
 def test_the_tolerance_would_catch_a_lower_precision():
@@ -110,8 +138,8 @@ def test_the_tolerance_would_catch_a_lower_precision():
     toks = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0, 96)
     want = reference.logits(params, toks, 2)
     bf16 = _toy_model("bfloat16").apply(params, toks, train=False)
-    assert reference.max_rel_err(bf16, want) < tol
+    assert max_rel_err(bf16, want) < tol
     rounded = jax.tree.map(
         lambda a: a.astype(jnp.float8_e4m3fn).astype(jnp.float32), params)
     fp8 = _toy_model().apply(rounded, toks, train=False)
-    assert reference.max_rel_err(fp8, want) > serving.DECODE_LOGITS_TOL
+    assert max_rel_err(fp8, want) > serving.DECODE_LOGITS_TOL

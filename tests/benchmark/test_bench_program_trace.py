@@ -35,6 +35,12 @@ RECORDED = {
     "prefill_cache_write_share_of_busy": 5.0876,
     "decode_attention_ms_per_step": 117.2676,
 }
+#: the nine in the order BENCHMARK.json lists them (PR 23)
+NINE = ["admit_wait_ms_p50", "admit_wait_ms_p90",
+        "decode_sample_ms_per_iter", "decode_stream_ms_per_iter",
+        "decode_slots_live_p50", "serve_idle_sample_share",
+        "serve_idle_unattributed_share",
+        "prefill_cache_write_share_of_busy", "decode_attention_ms_per_step"]
 
 
 def _recorded_trace() -> dict:
@@ -88,8 +94,9 @@ def test_the_chat_cell_reports_the_old_metrics_and_the_new(recorded):
          "ttft_ms_p50": 122.0, "ttft_ms_p90": 171.0,
          "loadgen_late_ms_p99": 3.2, "decode_bytes_per_step": 3.57e9,
          "peak_hbm_bytes_per_s": 819e9})
-    assert len(got) == 22 and set(RECORDED) <= set(got)
-    assert list(got)[-9:] == [m["name"] for m in BENCH["per_layer"][-9:]]
+    # the 13 that were there and the nine; a later PR's may stand beside
+    assert len(got) >= 22 and set(RECORDED) <= set(got)
+    assert [n for n in got if n in RECORDED] == NINE
     # the old readers on the same cut: the step, and what the host adds
     assert got["decode_step_device_ms"]["value"] == pytest.approx(143.07,
                                                                   abs=0.01)
@@ -100,28 +107,30 @@ def test_the_chat_cell_reports_the_old_metrics_and_the_new(recorded):
 
 
 def test_the_new_entries_are_well_formed_and_for_the_chat_cell_alone():
-    new = BENCH["per_layer"][-9:]
-    assert [m["name"] for m in new] == [
-        "admit_wait_ms_p50", "admit_wait_ms_p90",
-        "decode_sample_ms_per_iter", "decode_stream_ms_per_iter",
-        "decode_slots_live_p50", "serve_idle_sample_share",
-        "serve_idle_unattributed_share",
-        "prefill_cache_write_share_of_busy", "decode_attention_ms_per_step"]
-    layers = {m["layer"] for m in BENCH["per_layer"][:-9]}
+    # found by name, not by place: a later PR appends metrics of its own
+    new = [m for m in BENCH["per_layer"] if m["name"] in RECORDED]
+    assert [m["name"] for m in new] == NINE
+    layers = {m["layer"] for m in BENCH["per_layer"]
+              if m["name"] not in RECORDED}
     for m in new:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert m["workloads"] == [CHAT] and m["moves"] == "itl_ms_p90"
+        # of PR 22's four cells the chat cell alone; a cell a later PR
+        # adds may list itself beside it
+        assert CHAT in m["workloads"] and m["moves"] == "itl_ms_p90"
+        assert not set(m["workloads"]) & {
+            "opt-6.7b.train_sync_1chip", "opt-6.7b.train_quorum3of4_4chip",
+            "opt-1.3b.serve_decode_closed"}
         assert m["layer"] in layers          # names PERF.md §3 has
         assert m["source"] in ("program_span", "program_counter",
                                "device_trace")
         doc = " ".join(cell_lib.load_reader(m["name"]).__doc__.split())
         assert f"Layer: {m['layer']}." in doc and "itl_ms_p90" in doc
-    # no other cell's list of metrics has changed
+    # no cell's list of metrics has lost one
     for name, count in (("opt-6.7b.train_sync_1chip", 8),
                         ("opt-6.7b.train_quorum3of4_4chip", 10),
                         ("opt-1.3b.serve_decode_closed", 10), (CHAT, 22)):
-        assert len(cell_lib.load_cell(name).per_layer) == count
+        assert len(cell_lib.load_cell(name).per_layer) >= count
 
 
 # -- this run's files, and no other's --------------------------------------

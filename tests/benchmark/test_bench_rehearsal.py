@@ -129,10 +129,11 @@ def test_decode_step_compiles_for_the_v5e_with_room_for_the_template(
         for_the_chip):
     from distributedmnist_tpu.core.config import ModelConfig
     from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
 
     cell = cell_lib.load_cell("opt-1.3b.serve_decode_closed")
     dev = SingleDeviceSharding(_topology("v5e:1x1").devices[0])
-    model = get_model(ModelConfig(**cell_lib.model_section(cell.config)))
+    model = get_model(ModelConfig(**cell.arch.model_section(cell.config)))
     stored = jnp.dtype(cell.config["serve"]["precision"]["param_dtype"])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=dev)
@@ -148,8 +149,16 @@ def test_decode_step_compiles_for_the_v5e_with_room_for_the_template(
               // d["block_size"])
     layers, heads, head_dim = model.decode_cache_shape
     assert (layers, heads, head_dim, width) == (24, 32, 64, 112)
-    cache = sds((layers, d["num_blocks"], d["block_size"], heads, head_dim),
-                jnp.bfloat16)
+
+    def caches():
+        # as DecodeReplica builds them, whatever their layout; traced
+        # by eval_shape and so never allocated
+        cache = PagedKVCache(layers, d["num_blocks"], d["block_size"],
+                             heads, head_dim, width, dtype=jnp.bfloat16)
+        return cache.k, cache.v
+
+    k_cache, v_cache = (sds(a.shape, a.dtype)
+                        for a in jax.eval_shape(caches))
     slots = d["decode_slots"]
     # built as DecodeReplica builds it, the kernel choice left at the
     # program's default
@@ -157,13 +166,15 @@ def test_decode_step_compiles_for_the_v5e_with_room_for_the_template(
                                      block_size=d["block_size"]),
                    donate_argnums=(3, 4))
     compiled = step.lower(
-        params, sds((slots,), jnp.int32), sds((slots,), jnp.int32), cache,
-        cache, sds((slots, width), jnp.int32),
+        params, sds((slots,), jnp.int32), sds((slots,), jnp.int32), k_cache,
+        v_cache, sds((slots, width), jnp.int32),
         sds((slots,), jnp.int32)).compile()
-    # 5.45 GB of arguments (weights and both caches), 7.28 GB of
-    # temporaries: three times the caches, which the step copies into a
-    # lane-padded layout because the heads are 64 wide
-    assert _total(compiled) / GB == pytest.approx(12.73, abs=0.15)
+    # a ceiling, so that a step that needs less passes. At PR 22 it
+    # needed 12.73 GB: 5.45 of arguments (weights and both caches) and
+    # 7.28 of temporaries, three times the caches, which the step copies
+    # into a lane-padded layout because the heads are 64 wide
+    print(f"decode step: {_total(compiled):.0f} bytes")
+    assert _total(compiled) / GB <= 12.88
     # beside it the replica holds its restore template; 1 GB to spare
     assert _total(compiled) + template + 1 * GB < HBM_USABLE
     assert HBM_USABLE < peaks.peaks_for("TPU v5 lite")["hbm_bytes"]

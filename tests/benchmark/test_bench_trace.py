@@ -109,6 +109,12 @@ def test_collectives_across_four_chips_and_their_exposed_part():
     assert round(got["flash_attention_roofline"]["value"], 1) == 22.9
 
 
+def _holds(got: dict, digits: int, want: dict) -> None:
+    """Every metric listed, at its value; a later PR's metric that
+    reaches the cell too may stand beside them."""
+    assert {k: round(got[k]["value"], digits) for k in want} == want
+
+
 def test_the_readers_read_the_recorded_traces():
     train = cell_lib.load_cell("opt-6.7b.train_sync_1chip")
     got = run_mod.per_layer_metrics(
@@ -118,11 +124,11 @@ def test_the_readers_read_the_recorded_traces():
          "model_flops_per_token": 5010432000.0,
          "attention_flops_per_step_per_chip": 3 * 3 * 4 * 1024.5 * 4096
          * 8192, "peak_bf16_flops_per_s": 197e12})
-    assert {k: round(v["value"], 2) for k, v in got.items()} == {
+    _holds(got, 2, {
         "compile_or_load_s": 1.2, "host_step_ms_p50": 1.5,
         "prefetch_depth_p50": 2.0, "train_step_device_ms": 329.69,
         "train_mfu": 62.8, "train_pallas_share_of_busy": 8.33,
-        "flash_attention_roofline": 22.88, "train_device_idle_share": 0.01}
+        "flash_attention_roofline": 22.88, "train_device_idle_share": 0.01})
     closed = cell_lib.load_cell("opt-1.3b.serve_decode_closed")
     got = run_mod.per_layer_metrics(
         closed, tr.reduce(_trace("v5e_decode_three_steps.json.gz")),
@@ -130,12 +136,12 @@ def test_the_readers_read_the_recorded_traces():
          "tokens_in_trace": 48, "itl_ms_p50": 170.8, "itl_ms_p99": 172.6,
          "loadgen_late_ms_p99": 0.4, "decode_bytes_per_step": 3.57e9,
          "peak_hbm_bytes_per_s": 819e9})
-    assert {k: round(v["value"], 1) for k, v in got.items()} == {
+    _holds(got, 1, {
         "compile_or_load_s": 3.0, "weights_ready_s": 30.0,
         "decode_iter_ms_p50": 170.9, "tokens_per_decode_step": 16.0,
         "decode_step_device_ms": 143.2, "decode_step_roofline": 3.0,
         "serve_device_idle_share": 11.5, "loadgen_late_ms_p99": 0.4,
-        "itl_ms_p50": 170.8, "itl_ms_p99": 172.6}
+        "itl_ms_p50": 170.8, "itl_ms_p99": 172.6})
 
 
 def test_cut_keeps_whole_events_and_the_annotation():
