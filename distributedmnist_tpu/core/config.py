@@ -459,7 +459,17 @@ class CompileConfig:
     (``jit(...).lower(...).compile()``) BEFORE the first batch, so
     compile time is journaled separately from step time (the
     ``event: "compile"`` record in train_log.jsonl) and a warm standby
-    can park fully compiled.
+    can park fully compiled. Where the mesh's devices are TPUs and the
+    replica axis holds more than one, that compile carries
+    ``parallel.api.ASYNC_ALL_REDUCE_OPTIONS`` (no knob: a constant), so
+    the gradient's all-reduces are in flight beside the weight-gradient
+    products; the record names them (``compiler_options``) and counts
+    the asynchronous collectives the executable holds
+    (``async_collectives``). One replica, or any other platform: no
+    option, the program ``jit`` alone makes. The inline path
+    (``precompile: false``, or a precompile that failed) runs that plain
+    program too: the same values, its all-reduces after the backward
+    pass.
     """
 
     persistent_cache: bool = True

@@ -282,10 +282,18 @@ _declare(EventSchema(
 ))
 
 # Compile record: ``compile_s``/``source`` plus whatever the AOT
-# executable cache measured — dynamic by design.
+# executable cache measured — dynamic by design. ``compiler_options``:
+# the names ``build_train_step``'s precompile passed to the compiler
+# (parallel/api.py ASYNC_ALL_REDUCE_OPTIONS on TPU devices with more
+# than one replica; empty elsewhere, and after a compiler refused
+# them). ``async_collectives``: the asynchronous collective starts in
+# the executable that runs, so 0 beside options says they did nothing.
+# Neither is in the inline fallback's record: that program was never
+# given the options.
 _declare(EventSchema(
     COMPILE,
-    optional=("compile_s", "source", "persistent_cache", "error"),
+    optional=("compile_s", "source", "persistent_cache", "error",
+              "compiler_options", "async_collectives"),
     open_payload=True,
 ))
 

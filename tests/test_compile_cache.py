@@ -154,6 +154,9 @@ def test_precompile_first_step_bitwise_equals_cold(tmp_path):
     t_pre = Trainer(_tiny_cfg(str(tmp_path / "pre"), precompile=True))
     info = t_pre.precompile()
     assert info["compile_s"] is not None and info["source"] == "compiled"
+    # a CPU mesh: no compiler option asked for, no collective made
+    # asynchronous (tests/test_async_aggregate.py has the TPU's side)
+    assert info["compiler_options"] == [] and info["async_collectives"] == 0
     assert t_pre.precompile() is info  # idempotent per Trainer
     s_pre = t_pre.run()
     t_cold = Trainer(_tiny_cfg(str(tmp_path / "cold"), precompile=False))
@@ -171,5 +174,11 @@ def test_precompile_first_step_bitwise_equals_cold(tmp_path):
     compile_events = [r for r in pre if r["event"] == "compile"]
     assert len(compile_events) == 1
     assert compile_events[0]["compile_s"] == info["compile_s"]
+    assert compile_events[0]["compiler_options"] == []
+    assert compile_events[0]["async_collectives"] == 0
+    from distributedmnist_tpu.obsv import schema
+    assert schema.validate_event(compile_events[0]) == []
+    assert {"compiler_options", "async_collectives"} <= set(
+        schema.EVENT_SCHEMAS["compile"].optional)
     assert s_pre["compile"]["source"] == "compiled"
     assert s_cold["compile"] is None
