@@ -321,6 +321,7 @@ def _transformer(cfg: ModelConfig) -> Model:
     compute_dtype = jnp.dtype(cfg.compute_dtype)
 
     moe = cfg.num_experts > 0
+    aux_weight = cfg.moe_aux_weight
 
     def init(key):
         return transformer.init(
@@ -341,30 +342,6 @@ def _transformer(cfg: ModelConfig) -> Model:
         inner_bhsd = None
     else:
         raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
-
-    if (cfg.remat and cfg.remat_policy == "save_attn"
-            and cfg.attention_impl != "flash"):
-        # save_attn keeps the attention sublayer's AD residuals
-        # resident; only the flash kernel's custom VJP bounds those at
-        # O(s·d) — dense attention would park the [b, h, s, s] softmax
-        # probabilities in HBM per layer, defeating remat entirely
-        raise ValueError(
-            "model.remat_policy='save_attn' requires "
-            "attention_impl='flash' (dense attention has no fused VJP; "
-            "its resident residuals would be O(seq²) per layer)")
-
-    def apply(params, x, *, train=False, dropout_key=None, return_aux=False):
-        del dropout_key
-        return transformer.apply(params, x, num_heads=cfg.num_heads,
-                                 attention_fn=attention_fn,
-                                 compute_dtype=compute_dtype,
-                                 num_experts=cfg.num_experts,
-                                 capacity_factor=cfg.expert_capacity_factor,
-                                 moe_num_groups=cfg.moe_num_groups,
-                                 moe_router_top_k=cfg.moe_router_top_k,
-                                 remat=cfg.remat,
-                                 remat_policy=cfg.remat_policy,
-                                 return_aux=return_aux)
 
     def make_seq_attn(seq_axis: str | None):
         """The attention callable for a given seq sharding: the plain
@@ -390,50 +367,100 @@ def _transformer(cfg: ModelConfig) -> Model:
             return sharded_attn
         raise ValueError(f"unknown sp_attention {cfg.sp_attention!r}")
 
+    def block_for(seq_axis: str | None = None, model_axis: str | None = None,
+                  expert_axis: str | None = None, *,
+                  pipeline: str | None = None) -> transformer.Block:
+        """The configured layer under the given mesh axes (any may be
+        None: unsharded) — the one reader of the configuration's
+        attention and feed-forward choices, and the one place their
+        combinations are refused. ``pipeline``: the schedule
+        (``"gpipe"`` / ``"1f1b"``) whose train step the block is for."""
+        ring = seq_axis is not None and cfg.sp_attention == "ring"
+        if expert_axis is not None and not moe:
+            raise ValueError("mesh has expert parallelism but the model "
+                             "has no experts (model.num_experts == 0)")
+        if pipeline and cfg.remat and cfg.remat_policy != "full":
+            # a silently-ignored policy would leave the user at
+            # full-remat throughput while believing save_attn is on
+            how = ("the 1f1b schedule (chunk recompute is built into the "
+                   "engine)" if pipeline == "1f1b" else
+                   "pipeline parallelism (stage scans use full per-layer "
+                   "remat)")
+            raise ValueError(
+                f"model.remat_policy={cfg.remat_policy!r} is not "
+                f"supported under {how}; set remat_policy='full'")
+        if cfg.remat and cfg.remat_policy == "save_attn":
+            if cfg.attention_impl != "flash":
+                # save_attn keeps the attention sublayer's AD residuals
+                # resident; only the flash kernel's custom VJP bounds
+                # those at O(s·d) — dense attention would park the
+                # [b, h, s, s] softmax probabilities in HBM per layer,
+                # defeating remat entirely
+                raise ValueError(
+                    "model.remat_policy='save_attn' requires "
+                    "attention_impl='flash' (dense attention has no fused "
+                    "VJP; its resident residuals would be O(seq²) per "
+                    "layer)")
+            if ring:
+                # ring attention has no custom vjp — AD would save its
+                # per-ppermute-step scan residuals instead, exactly the
+                # memory remat exists to avoid
+                raise ValueError(
+                    "model.remat_policy='save_attn' requires an attention "
+                    "with a fused VJP (flash / Ulysses-over-flash); ring "
+                    "attention under sequence parallelism needs "
+                    "remat_policy='full'")
+        if pipeline == "1f1b" and ring:
+            raise ValueError(
+                "pipeline_schedule='1f1b' with sequence parallelism "
+                "requires model.sp_attention='ulysses': ring attention's "
+                "ppermute rendezvouses globally and deadlocks inside the "
+                "fused engine's stage-varying branches (all_to_all is "
+                "group-local and composes; use 'gpipe' for ring)")
+        feed_forward = None  # the dense ReLU product
+        if moe:
+            # SP×MoE: tokens are already seq-sharded; routing runs on
+            # each shard's slice with shard-local capacity (ops/moe.py
+            # module doc), while the aux statistics average over the seq
+            # axis so the load-balance loss stays the exact full-token
+            # value. Under a pipeline each tick's calls see one
+            # microbatch's slice of one seq shard, and the tick
+            # accumulation completes the same average.
+            feed_forward = transformer.moe_feed_forward(
+                num_experts=cfg.num_experts,
+                capacity_factor=cfg.expert_capacity_factor,
+                router_top_k=cfg.moe_router_top_k,
+                num_groups=cfg.moe_num_groups,
+                expert_axis=expert_axis, tp_axis=model_axis,
+                stats_axes=() if seq_axis is None else (seq_axis,))
+        return transformer.make_block(
+            num_heads=cfg.num_heads, attention_fn=make_seq_attn(seq_axis),
+            model_axis=model_axis, feed_forward=feed_forward)
+
+    block = block_for()  # one device, or replicas of the whole model
+
+    def apply(params, x, *, train=False, dropout_key=None, return_aux=False):
+        del dropout_key
+        return transformer.apply(params, x, block=block,
+                                 compute_dtype=compute_dtype,
+                                 remat=cfg.remat,
+                                 remat_policy=cfg.remat_policy,
+                                 return_aux=return_aux)
+
     def sharded_apply_factory(seq_axis: str | None, model_axis: str | None,
                               expert_axis: str | None = None):
         """Sharded apply for the DP×SP×TP×EP train step: tokens arrive
         as [b, seq_local] slices; attention crosses seq shards via the
         configured strategy; params may be tensor-parallel and/or
         expert-parallel shards."""
-        sharded_attn = make_seq_attn(seq_axis)
-
-        if expert_axis is not None and not moe:
-            raise ValueError("mesh has expert parallelism but the model has "
-                             "no experts (model.num_experts == 0)")
-        if (cfg.remat and cfg.remat_policy == "save_attn"
-                and seq_axis is not None and cfg.sp_attention == "ring"):
-            # save_attn keeps the attention sublayer outside the
-            # checkpoint so the flash kernel's O(s·d) custom-vjp
-            # residuals stay resident; ring attention has no custom
-            # vjp — AD would save its per-ppermute-step scan residuals
-            # instead, exactly the memory remat exists to avoid
-            raise ValueError(
-                "model.remat_policy='save_attn' requires an attention "
-                "with a fused VJP (flash / Ulysses-over-flash); ring "
-                "attention under sequence parallelism needs "
-                "remat_policy='full'")
-
-        # SP×MoE: tokens are already seq-sharded; routing runs on each
-        # shard's slice with shard-local capacity (ops/moe.py module
-        # doc), while the aux statistics average over the seq axis so
-        # the load-balance loss stays the exact full-token value.
-        stats_axes = (seq_axis,) if (moe and seq_axis is not None) else ()
+        sharded = block_for(seq_axis, model_axis, expert_axis)
 
         def apply_sharded(params, tokens, positions, return_aux=False):
-            return transformer.apply(params, tokens, num_heads=cfg.num_heads,
-                                     attention_fn=sharded_attn,
+            return transformer.apply(params, tokens, block=sharded,
                                      positions=positions,
                                      compute_dtype=compute_dtype,
-                                     model_axis=model_axis,
-                                     expert_axis=expert_axis,
-                                     num_experts=cfg.num_experts,
-                                     capacity_factor=cfg.expert_capacity_factor,
-                                     moe_num_groups=cfg.moe_num_groups,
-                                     moe_router_top_k=cfg.moe_router_top_k,
                                      remat=cfg.remat,
                                      remat_policy=cfg.remat_policy,
-                                     moe_stats_axes=stats_axes,
                                      return_aux=return_aux)
 
         return apply_sharded
@@ -442,35 +469,13 @@ def _transformer(cfg: ModelConfig) -> Model:
                          model_axis: str | None = None,
                          seq_axis: str | None = None,
                          expert_axis: str | None = None):
-        if expert_axis is not None and not moe:
-            raise ValueError("mesh has expert parallelism but the model has "
-                             "no experts (model.num_experts == 0)")
-        if cfg.remat and cfg.remat_policy != "full":
-            # the pipeline stage scans checkpoint whole layers; a
-            # silently-ignored policy would leave the user at full-remat
-            # throughput while believing save_attn is on
-            raise ValueError(
-                f"model.remat_policy={cfg.remat_policy!r} is not "
-                "supported under pipeline parallelism (stage scans use "
-                "full per-layer remat); set remat_policy='full'")
-        pp_attn = make_seq_attn(seq_axis)
-        # PP×SP×MoE: each tick's MoE calls see one microbatch's SLICE
-        # of one seq shard; averaging the routing stats over the seq
-        # axis (plus the tick accumulation) reconstructs the exact
-        # full-token aux (see sharded_apply_factory's SP×MoE note)
-        stats_axes = (seq_axis,) if (moe and seq_axis is not None) else ()
+        staged = block_for(seq_axis, model_axis, expert_axis,
+                           pipeline="gpipe")
 
         def apply_pp(params, tokens, positions=None, return_aux=False):
             return transformer.apply_pp(
-                params, tokens, num_heads=cfg.num_heads,
-                stage_axis=stage_axis, num_microbatches=num_microbatches,
-                attention_fn=pp_attn, positions=positions,
-                model_axis=model_axis, expert_axis=expert_axis,
-                num_experts=cfg.num_experts,
-                capacity_factor=cfg.expert_capacity_factor,
-                moe_num_groups=cfg.moe_num_groups,
-                moe_router_top_k=cfg.moe_router_top_k,
-                moe_stats_axes=stats_axes,
+                params, tokens, block=staged, stage_axis=stage_axis,
+                num_microbatches=num_microbatches, positions=positions,
                 compute_dtype=compute_dtype, remat=cfg.remat,
                 return_aux=return_aux)
         return apply_pp
@@ -480,55 +485,27 @@ def _transformer(cfg: ModelConfig) -> Model:
                               model_axis: str | None = None,
                               seq_axis: str | None = None,
                               expert_axis: str | None = None):
-        if expert_axis is not None and not moe:
-            raise ValueError("mesh has expert parallelism but the model has "
-                             "no experts (model.num_experts == 0)")
-        if cfg.remat and cfg.remat_policy != "full":
-            raise ValueError(
-                f"model.remat_policy={cfg.remat_policy!r} is not "
-                "supported under the 1f1b schedule (chunk recompute is "
-                "built into the engine); set remat_policy='full'")
-        if seq_axis is not None and cfg.sp_attention == "ring":
-            raise ValueError(
-                "pipeline_schedule='1f1b' with sequence parallelism "
-                "requires model.sp_attention='ulysses': ring attention's "
-                "ppermute rendezvouses globally and deadlocks inside the "
-                "fused engine's stage-varying branches (all_to_all is "
-                "group-local and composes; use 'gpipe' for ring)")
-        pp_attn = make_seq_attn(seq_axis)
+        staged = block_for(seq_axis, model_axis, expert_axis,
+                           pipeline="1f1b")
 
         def grads_fn(params, tokens, labels):
             return transformer.grads_pp_1f1b(
-                params, tokens, labels, num_heads=cfg.num_heads,
+                params, tokens, labels, block=staged,
                 stage_axis=stage_axis, num_microbatches=num_microbatches,
-                num_chunks=num_chunks, attention_fn=pp_attn,
-                model_axis=model_axis, seq_axis=seq_axis,
-                expert_axis=expert_axis, num_experts=cfg.num_experts,
-                capacity_factor=cfg.expert_capacity_factor,
-                moe_num_groups=cfg.moe_num_groups,
-                moe_router_top_k=cfg.moe_router_top_k,
-                aux_weight=cfg.moe_aux_weight,
-                compute_dtype=compute_dtype)
+                num_chunks=num_chunks, seq_axis=seq_axis,
+                aux_weight=aux_weight, compute_dtype=compute_dtype)
         return grads_fn
 
     def pp_1f1b_apply_factory(stage_axis: str, num_microbatches: int,
                               num_chunks: int,
                               model_axis: str | None = None,
                               expert_axis: str | None = None):
-        if expert_axis is not None and not moe:
-            raise ValueError("mesh has expert parallelism but the model has "
-                             "no experts (model.num_experts == 0)")
+        staged = block_for(None, model_axis, expert_axis)
 
         def apply_1f1b(params, tokens):
             return transformer.apply_pp_1f1b(
-                params, tokens, num_heads=cfg.num_heads,
-                stage_axis=stage_axis, num_microbatches=num_microbatches,
-                num_chunks=num_chunks, attention_fn=attention_fn,
-                model_axis=model_axis,
-                expert_axis=expert_axis, num_experts=cfg.num_experts,
-                capacity_factor=cfg.expert_capacity_factor,
-                moe_num_groups=cfg.moe_num_groups,
-                moe_router_top_k=cfg.moe_router_top_k,
+                params, tokens, block=staged, stage_axis=stage_axis,
+                num_microbatches=num_microbatches, num_chunks=num_chunks,
                 compute_dtype=compute_dtype)
         return apply_1f1b
 
@@ -539,8 +516,7 @@ def _transformer(cfg: ModelConfig) -> Model:
     if not moe:
         def decode_prefill(params, tokens, positions=None):
             return transformer.prefill_with_kv(
-                params, tokens, num_heads=cfg.num_heads,
-                attention_fn=attention_fn, positions=positions,
+                params, tokens, block=block, positions=positions,
                 compute_dtype=compute_dtype)
 
         def decode_step_fn(params, tokens, positions, k_cache, v_cache,
@@ -548,7 +524,8 @@ def _transformer(cfg: ModelConfig) -> Model:
                            attention_kernel="dense"):
             return transformer.decode_step(
                 params, tokens, positions, k_cache, v_cache,
-                block_tables, lengths, num_heads=cfg.num_heads,
+                block_tables, lengths, ffn=block.ffn,
+                num_heads=cfg.num_heads,
                 block_size=block_size, compute_dtype=compute_dtype,
                 attention_kernel=attention_kernel)
 
@@ -565,7 +542,7 @@ def _transformer(cfg: ModelConfig) -> Model:
                  decode_cache_shape=decode_cache_shape,
                  sharded_apply_factory=sharded_apply_factory,
                  partition_rules=transformer_partition_rules(cfg.num_experts),
-                 has_aux=moe, aux_weight=cfg.moe_aux_weight,
+                 has_aux=moe, aux_weight=aux_weight,
                  tp_param_specs=lambda axis, expert_axis=None:
                      transformer.param_partition_specs(
                          cfg.num_layers, axis, cfg.num_experts, expert_axis),

@@ -7,19 +7,27 @@ has a first-class consumer, and so the aggregation disciplines can be
 exercised on a transformer-shaped allreduce payload.
 
 Pure init/apply over a param pytree, pre-norm blocks, learned
-positional embeddings, weight-tied LM head. ``attention_fn`` is
-injectable: ``local_self_attention`` single-device, or a closure over
-``ring_self_attention(axis_name=...)`` under a seq-sharded shard_map.
+positional embeddings, weight-tied LM head.
 
-Tensor parallelism (Megatron-style) is built in: pass ``model_axis``
-when params are sharded per :func:`param_partition_specs` — qkv/w1
-column-parallel, wo/w2 row-parallel with one psum per residual add,
-attention heads split across the axis.
+What a layer computes is decided in ONE place, :func:`make_block`: which
+attention (``local_self_attention`` single-device, the flash kernel, or
+a closure over ring/Ulysses attention under a seq-sharded shard_map),
+which feed-forward (the dense ReLU product, or a routed mixture of
+experts), and under which mesh axes (Megatron-style tensor parallelism
+over a ``model_axis`` when params are sharded per
+:func:`param_partition_specs` — qkv/w1 column-parallel, wo/w2
+row-parallel with one psum per residual add, attention heads split
+across the axis). The six forwards (:func:`apply`,
+:func:`prefill_with_kv`, :func:`decode_step`, :func:`apply_pp`,
+:func:`grads_pp_1f1b`, :func:`apply_pp_1f1b`) take the block and decide
+only their schedule. A new kind of feed-forward is one function
+``(h, blk) -> (mlp, aux)`` and its parameters in :func:`init`.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable
+import functools
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -114,66 +122,146 @@ def _rms_norm(x: jax.Array, p: Params) -> jax.Array:
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * p["scale"]).astype(x.dtype)
 
 
-def apply(params: Params, tokens: jax.Array, *, num_heads: int = 4,
-          attention_fn: Callable | None = None,
-          positions: jax.Array | None = None,
-          compute_dtype=jnp.bfloat16,
-          model_axis: str | None = None,
-          expert_axis: str | None = None, num_experts: int = 0,
-          capacity_factor: float = 1.25, remat: bool = False,
-          remat_policy: str = "full",
-          moe_num_groups: int = 0, moe_router_top_k: int = 1,
-          moe_stats_axes: tuple[str, ...] = (),
-          return_aux: bool = False) -> jax.Array:
-    """tokens [batch, seq] int32 → logits [batch, seq, vocab] float32.
+class Block(NamedTuple):
+    """What one transformer layer computes, as its two pre-norm
+    sublayers. :func:`make_block` builds it once; every forward below
+    takes one and decides only its schedule."""
+    # attn(x, blk, return_kv=False) -> x, or (x, k, v) for the prefill
+    attn: Callable[..., Any]
+    # ffn(x, blk) -> (x, aux)
+    ffn: Callable[[jax.Array, Params], tuple[jax.Array, jax.Array]]
 
-    ``positions`` (global positions of this shard's tokens) must be
-    passed when the sequence is sharded; defaults to arange(seq).
+
+def _dense_ffn(h: jax.Array, blk: Params, *,
+               model_axis: str | None) -> tuple[jax.Array, jax.Array]:
+    mlp = jax.nn.relu(h @ blk["w1"]) @ blk["w2"]
+    aux = jnp.zeros((), jnp.float32)
+    if model_axis:
+        mlp = lax.psum(mlp, model_axis)
+    return mlp, aux
+
+
+def moe_feed_forward(**settings) -> Callable:
+    """A mixture-of-experts feed-forward for :func:`make_block`:
+    ``ops.moe.moe_ffn`` over a block's ``router``/``w1``/``w2`` with its
+    settings bound (``num_experts``, ``capacity_factor``,
+    ``router_top_k``, ``num_groups``, and the mesh axes: ``expert_axis``
+    the experts are sharded over, ``tp_axis`` every expert's hidden dim
+    is split over — one fused psum covers both — and ``stats_axes``, the
+    extra token-sharding axes (the seq axis under SP×MoE) the
+    load-balance statistics average over, so the aux loss is the
+    full-token value replicated on every shard)."""
+    from ..ops.moe import moe_ffn
+
+    def feed_forward(h, blk):
+        return moe_ffn(h, blk["router"], blk["w1"], blk["w2"], **settings)
+    return feed_forward
+
+
+def make_block(*, num_heads: int, attention_fn: Callable | None = None,
+               model_axis: str | None = None,
+               feed_forward: Callable | None = None) -> Block:
+    """The one place a layer's kind is decided: which attention, which
+    feed-forward, under which mesh axes.
+
+    ``attention_fn``: ``local_self_attention`` when None, the flash
+    kernel, or a closure over ring/Ulysses attention under a seq-sharded
+    shard_map; its ``layout`` attribute (``bhsd`` default, or ``bshd``)
+    says which head layout it reads.
 
     ``model_axis``: when set (inside shard_map, params sharded per
-    :func:`param_partition_specs`), runs tensor-parallel — this rank
-    computes its ``num_heads / axis_size`` heads and its MLP column
+    :func:`param_partition_specs`), the block is tensor-parallel — this
+    rank computes its ``num_heads / axis_size`` heads and its MLP column
     slice; row-parallel projections psum partial sums back to the full
     residual. Activations stay replicated over the axis, so the logits
     (and any loss) are identical on every TP rank.
 
-    ``expert_axis``/``num_experts``: mixture-of-experts FFNs with the
-    experts sharded over the axis (expert parallelism). Composes with
-    ``model_axis``: heads and every expert's hidden dim are
-    tensor-parallel over the model axis, experts over the expert axis,
-    with one fused psum per MoE block covering both.
-    ``moe_stats_axes``: extra token-sharding axes (the seq axis under
-    SP×MoE) the load-balance statistics average over, so the aux loss
-    is the full-token value replicated on every shard.
+    ``feed_forward``: ``(h, blk) -> (mlp, aux)`` on the normed residual
+    ``h``, returning the WHOLE residual delta (a callable that shards
+    its product sums it itself, as ``moe_ffn`` does) and a scalar
+    auxiliary loss. None is the dense ReLU product over ``w1``/``w2``;
+    :func:`moe_feed_forward` is the routed one. Its ``aux`` is the mean
+    per-group load-balance loss of this block's routing (linear across
+    blocks/ticks/shards: forwards sum over layers and average over
+    microbatches), kept by a forward only where the block's parameters
+    hold a ``router``.
+    """
+    attention = attention_fn or local_self_attention
+    if feed_forward is None:
+        feed_forward = functools.partial(_dense_ffn, model_axis=model_axis)
+
+    @jax.named_scope("attention")
+    def attn(x: jax.Array, blk: Params, return_kv: bool = False):
+        """Pre-norm attention sublayer: x + wo(attn(qkv(ln1(x)))).
+
+        ``return_kv``: also return this layer's K/V in the [b, s, h, hd]
+        residual layout (a free reshape) — what the decode prefill
+        scatters into the paged KV cache."""
+        b, d = x.shape[0], x.shape[-1]
+        # read here, inside the shard_map, not when the block is built
+        m = lax.axis_size(model_axis) if model_axis else 1
+        if num_heads % m != 0:
+            raise ValueError(f"num_heads={num_heads} not divisible by "
+                             f"model-parallel size {m}")
+        h_local, hd = num_heads // m, d // num_heads
+        h = _rms_norm(x, blk["ln1"])
+        qkv = jnp.einsum("bsd,dte->bste", h, blk["wqkv"])  # e = d/m
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if getattr(attention, "layout", "bhsd") == "bshd":
+            # kernel reads the residual layout directly ([b, s, h, hd] is
+            # a free reshape of [b, s, e]) — no head transpose on either
+            # side. At the flash bench shape the transposes a bhsd
+            # attention forces cost ~20 ms/step, 2.5× the kernel itself.
+            # (A fully fused qkv-packed kernel input was also measured:
+            # the strided k/v lane reads cost MORE than the slice copies
+            # they save.)
+            bshd = lambda t: t.reshape(b, -1, h_local, hd)
+            o = attention(bshd(q), bshd(k), bshd(v)).reshape(
+                b, -1, h_local * hd)
+        else:
+            def heads(t):
+                return t.reshape(b, -1, h_local, hd).transpose(0, 2, 1, 3)
+
+            o = attention(heads(q), heads(k), heads(v))
+            o = o.transpose(0, 2, 1, 3).reshape(b, -1, h_local * hd)
+        proj = o @ blk["wo"]  # row-parallel: partial sum of the full d
+        if model_axis:
+            proj = lax.psum(proj, model_axis)
+        out = x + proj
+        if return_kv:
+            return (out, k.reshape(b, -1, h_local, hd),
+                    v.reshape(b, -1, h_local, hd))
+        return out
+
+    @jax.named_scope("ffn")
+    def ffn(x: jax.Array, blk: Params) -> tuple[jax.Array, jax.Array]:
+        """Pre-norm FFN sublayer: x + feed_forward(ln2(x)), aux."""
+        mlp, aux = feed_forward(_rms_norm(x, blk["ln2"]), blk)
+        return x + mlp, aux
+
+    return Block(attn, ffn)
+
+
+def apply(params: Params, tokens: jax.Array, *, block: Block,
+          positions: jax.Array | None = None,
+          compute_dtype=jnp.bfloat16, remat: bool = False,
+          remat_policy: str = "full",
+          return_aux: bool = False) -> jax.Array:
+    """tokens [batch, seq] int32 → logits [batch, seq, vocab] float32
+    through ``block`` (:func:`make_block`), layer after layer.
+
+    ``positions`` (global positions of this shard's tokens) must be
+    passed when the sequence is sharded; defaults to arange(seq).
     ``return_aux``: also return the summed load-balancing aux loss.
     """
-    attn = attention_fn or local_self_attention
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
     p = _cast(params, compute_dtype)
     x = _embed(p, tokens, positions)
-    d = x.shape[-1]
-    hd = d // num_heads
-    m = lax.axis_size(model_axis) if model_axis else 1
-    if num_heads % m != 0:
-        raise ValueError(f"num_heads={num_heads} not divisible by "
-                         f"model-parallel size {m}")
-    h_local = num_heads // m
 
-    def ffn(x, blk):
-        return _ffn_sublayer(x, blk, model_axis=model_axis,
-                             expert_axis=expert_axis,
-                             num_experts=num_experts,
-                             capacity_factor=capacity_factor,
-                             moe_num_groups=moe_num_groups,
-                             moe_router_top_k=moe_router_top_k,
-                             moe_stats_axes=moe_stats_axes)
-
-    def block(x, blk):
-        x = _attn_sublayer(x, blk, h_local=h_local, hd=hd, attn=attn,
-                           model_axis=model_axis)
-        return ffn(x, blk)
+    def layer(x, blk):
+        return block.ffn(block.attn(x, blk), blk)
 
     if remat:
         if remat_policy == "save_attn":
@@ -184,22 +272,20 @@ def apply(params: Params, tokens: jax.Array, *, num_heads: int = 4,
             # the backward never re-runs the attention forward. Costs
             # O(b·s·d) extra bytes per layer over full remat; at the
             # S=8192 long-context bench it buys 1.14x tokens/sec.
-            ffn_ckpt = jax.checkpoint(ffn)
+            ffn_ckpt = jax.checkpoint(block.ffn)
 
-            def block(x, blk):  # noqa: F811 — policy-selected body
-                x = _attn_sublayer(x, blk, h_local=h_local, hd=hd,
-                                   attn=attn, model_axis=model_axis)
-                return ffn_ckpt(x, blk)
+            def layer(x, blk):  # noqa: F811 — policy-selected body
+                return ffn_ckpt(block.attn(x, blk), blk)
         elif remat_policy == "full":
             # trade one extra forward per block for O(layer-boundary)
             # activation memory — the long-sequence HBM lever
-            block = jax.checkpoint(block)
+            layer = jax.checkpoint(layer)
         else:
             raise ValueError(f"unknown remat_policy {remat_policy!r} "
                              "(expected 'full' or 'save_attn')")
     aux_total = jnp.zeros((), jnp.float32)
     for blk in p["blocks"]:
-        x, aux = block(x, blk)
+        x, aux = layer(x, blk)
         aux_total = aux_total + aux
     logits = _head(p, x)
     return (logits, aux_total) if return_aux else logits
@@ -227,94 +313,6 @@ def _head(p: Params, x: jax.Array) -> jax.Array:
     return (x @ p["embed"].T).astype(jnp.float32)
 
 
-@jax.named_scope("attention")
-def _attn_sublayer(x: jax.Array, blk: Params, *, h_local: int, hd: int,
-                   attn: Callable,
-                   model_axis: str | None,
-                   return_kv: bool = False):
-    """Pre-norm attention sublayer: x + wo(attn(qkv(ln1(x)))).
-
-    ``return_kv``: also return this layer's K/V in the [b, s, h, hd]
-    residual layout (a free reshape) — what the decode prefill scatters
-    into the paged KV cache."""
-    b = x.shape[0]
-    h = _rms_norm(x, blk["ln1"])
-    qkv = jnp.einsum("bsd,dte->bste", h, blk["wqkv"])  # e = d/m
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    if getattr(attn, "layout", "bhsd") == "bshd":
-        # kernel reads the residual layout directly ([b, s, h, hd] is a
-        # free reshape of [b, s, e]) — no head transpose on either side.
-        # At the flash bench shape the transposes a bhsd attention
-        # forces cost ~20 ms/step, 2.5× the kernel itself. (A fully
-        # fused qkv-packed kernel input was also measured: the strided
-        # k/v lane reads cost MORE than the slice copies they save.)
-        bshd = lambda t: t.reshape(b, -1, h_local, hd)
-        o = attn(bshd(q), bshd(k), bshd(v)).reshape(b, -1, h_local * hd)
-    else:
-        def heads(t):
-            return t.reshape(b, -1, h_local, hd).transpose(0, 2, 1, 3)
-
-        o = attn(heads(q), heads(k), heads(v))
-        o = o.transpose(0, 2, 1, 3).reshape(b, -1, h_local * hd)
-    proj = o @ blk["wo"]  # row-parallel: partial sum of the full d
-    if model_axis:
-        proj = lax.psum(proj, model_axis)
-    out = x + proj
-    if return_kv:
-        return (out, k.reshape(b, -1, h_local, hd),
-                v.reshape(b, -1, h_local, hd))
-    return out
-
-
-@jax.named_scope("ffn")
-def _ffn_sublayer(x: jax.Array, blk: Params, *, model_axis: str | None,
-                  expert_axis: str | None = None, num_experts: int = 0,
-                  capacity_factor: float = 1.25, moe_num_groups: int = 0,
-                  moe_router_top_k: int = 1,
-                  moe_stats_axes: tuple[str, ...] = ()) -> tuple[jax.Array,
-                                                                 jax.Array]:
-    """Pre-norm FFN sublayer (dense or MoE): x + mlp(ln2(x)), aux."""
-    h = _rms_norm(x, blk["ln2"])
-    if "router" in blk:
-        from ..ops.moe import moe_ffn
-        mlp, aux = moe_ffn(h, blk["router"], blk["w1"], blk["w2"],
-                           num_experts=num_experts,
-                           capacity_factor=capacity_factor,
-                           router_top_k=moe_router_top_k,
-                           num_groups=moe_num_groups,
-                           expert_axis=expert_axis,
-                           tp_axis=model_axis,
-                           stats_axes=moe_stats_axes)
-    else:
-        mlp = jax.nn.relu(h @ blk["w1"]) @ blk["w2"]
-        aux = jnp.zeros((), jnp.float32)
-        if model_axis:
-            mlp = lax.psum(mlp, model_axis)
-    return x + mlp, aux
-
-
-def _apply_block(x: jax.Array, blk: Params, *, h_local: int, hd: int,
-                 attn: Callable, model_axis: str | None,
-                 expert_axis: str | None = None, num_experts: int = 0,
-                 capacity_factor: float = 1.25,
-                 moe_num_groups: int = 0, moe_router_top_k: int = 1,
-                 moe_stats_axes: tuple[str, ...] = ()) -> tuple[jax.Array, jax.Array]:
-    """One pre-norm transformer block (shared by the dense/TP loop, the
-    pipeline stage scans, and the 1F1B chunk bodies). Returns
-    (x, moe_aux) — aux is 0 for dense-FFN blocks, else the mean
-    per-group load-balance loss of this block's routing (linear across
-    blocks/ticks/shards: callers sum over layers and average over
-    microbatches)."""
-    x = _attn_sublayer(x, blk, h_local=h_local, hd=hd, attn=attn,
-                       model_axis=model_axis)
-    return _ffn_sublayer(x, blk, model_axis=model_axis,
-                         expert_axis=expert_axis, num_experts=num_experts,
-                         capacity_factor=capacity_factor,
-                         moe_num_groups=moe_num_groups,
-                         moe_router_top_k=moe_router_top_k,
-                         moe_stats_axes=moe_stats_axes)
-
-
 # ---------------------------------------------------------------------------
 # Autoregressive decode: prompt prefill with K/V export + one-token
 # incremental step over a paged KV cache (servesvc/decode.py)
@@ -324,43 +322,36 @@ _DECODE_NEG = -1e30  # finite mask value: an all-masked idle slot's
 # softmax degrades to uniform-over-garbage (ignored) instead of NaN
 
 
-def prefill_with_kv(params: Params, tokens: jax.Array, *,
-                    num_heads: int = 4,
-                    attention_fn: Callable | None = None,
+def prefill_with_kv(params: Params, tokens: jax.Array, *, block: Block,
                     positions: jax.Array | None = None,
                     compute_dtype=jnp.bfloat16
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Prompt prefill: the standard causal forward (through the
-    CONFIGURED attention kernel — the fused pallas flash path or dense)
-    that also returns every layer's K/V for seeding a decode cache.
+    """Prompt prefill: the standard causal forward (through the block's
+    CONFIGURED attention — the fused pallas flash path or dense) that
+    also returns every layer's K/V for seeding a decode cache.
 
     tokens [b, s] int32 → (logits [b, s, vocab] float32,
     k [L, b, s, h, hd], v [L, b, s, h, hd]) with K/V in the compute
     dtype (the cache dtype). Dense-FFN models only (MoE routing is
     batch-shaped; the registry never exports decode for it)."""
-    attn = attention_fn or local_self_attention
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
     p = _cast(params, compute_dtype)
     x = _embed(p, tokens, positions)
-    d = x.shape[-1]
-    hd = d // num_heads
     ks, vs = [], []
     for blk in p["blocks"]:
-        x, k, v = _attn_sublayer(x, blk, h_local=num_heads, hd=hd,
-                                 attn=attn, model_axis=None,
-                                 return_kv=True)
+        x, k, v = block.attn(x, blk, return_kv=True)
         ks.append(k)
         vs.append(v)
-        x, _ = _ffn_sublayer(x, blk, model_axis=None)
+        x, _ = block.ffn(x, blk)
     return _head(p, x), jnp.stack(ks), jnp.stack(vs)
 
 
 def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
                 k_cache: jax.Array, v_cache: jax.Array,
                 block_tables: jax.Array, lengths: jax.Array, *,
-                num_heads: int = 4, block_size: int = 16,
+                ffn: Callable, num_heads: int = 4, block_size: int = 16,
                 compute_dtype=jnp.bfloat16,
                 attention_kernel: str = "dense"
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -378,6 +369,9 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
       (``positions + 1``; 0 for idle slots, whose rows compute masked
       garbage the caller ignores).
 
+    ``ffn`` is the feed-forward half of the model's block
+    (:func:`make_block`); the attention half is this step's own
+    (:func:`_decode_attn`: one token against the paged cache).
     ``attention_kernel`` selects the cache read: ``"dense"`` gathers
     every table entry into a [S, max_context, h, hd] view (the oracle
     path — O(max context) traffic per token), ``"paged"`` runs the
@@ -412,7 +406,7 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
             x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
             offs, live, num_heads=num_heads, scale=scale,
             attention_kernel=attention_kernel)
-        x, _ = _ffn_sublayer(x, blk, model_axis=None)
+        x, _ = ffn(x, blk)
     return _head(p, x), k_cache, v_cache
 
 
@@ -512,15 +506,9 @@ def pp_param_partition_specs(stage_axis: str,
             "final_norm": {"scale": P()}}
 
 
-def apply_pp(params: Params, tokens: jax.Array, *, num_heads: int,
+def apply_pp(params: Params, tokens: jax.Array, *, block: Block,
              stage_axis: str, num_microbatches: int,
-             attention_fn: Callable | None = None,
              positions: jax.Array | None = None,
-             model_axis: str | None = None,
-             expert_axis: str | None = None, num_experts: int = 0,
-             capacity_factor: float = 1.25,
-             moe_num_groups: int = 0, moe_router_top_k: int = 1,
-             moe_stats_axes: tuple[str, ...] = (),
              compute_dtype=jnp.bfloat16, remat: bool = False,
              return_aux: bool = False) -> jax.Array:
     """Pipeline-parallel forward (inside shard_map, params in the
@@ -531,20 +519,20 @@ def apply_pp(params: Params, tokens: jax.Array, *, num_heads: int,
     (ops/pipeline.py). Embedding/head run replicated on every stage —
     outputs are stage-replicated logits, so loss code is unchanged.
 
-    ``model_axis`` composes tensor parallelism INSIDE each stage: block
-    params additionally carry Megatron column/row shards
-    (``pp_param_partition_specs(stage, model)``), each rank computes its
-    head/MLP slice, and the row-parallel psums inside ``_apply_block``
-    reassemble activations per tick — PP outermost, TP within.
+    A block built with a ``model_axis`` composes tensor parallelism
+    INSIDE each stage: block params additionally carry Megatron
+    column/row shards (``pp_param_partition_specs(stage, model)``), each
+    rank computes its head/MLP slice, and the row-parallel psums inside
+    the block reassemble activations per tick — PP outermost, TP within.
 
-    Sequence parallelism composes through ``attention_fn`` +
-    ``positions``: pass a seq-sharded attention (ring/Ulysses over the
-    seq axis) and this shard's global positions; every (stage, seq)
-    device runs the same tick schedule, so the attention collectives
-    stay lockstep inside the pipeline scan — bubbles included.
+    Sequence parallelism composes through the block's attention +
+    ``positions``: a seq-sharded attention (ring/Ulysses over the seq
+    axis) and this shard's global positions; every (stage, seq) device
+    runs the same tick schedule, so the attention collectives stay
+    lockstep inside the pipeline scan — bubbles included.
 
-    Mixture-of-experts (``num_experts > 0``, optionally expert-sharded
-    over ``expert_axis``) composes too: each tick's MoE calls run the
+    A mixture-of-experts feed-forward (optionally expert-sharded)
+    composes too: each tick's MoE calls run the
     grouped dispatch on that microbatch's tokens, all-to-alls lockstep
     across stages since every device runs every tick. Token groups nest
     inside sequence rows (ops/moe.py), so routing capacity, drops, and
@@ -552,43 +540,29 @@ def apply_pp(params: Params, tokens: jax.Array, *, num_heads: int,
     aux is linear in per-group contributions, so each real tick's aux
     simply accumulates (pipeline_apply ``with_stats``, bubbles masked)
     and the mean over microbatches equals the dense full-batch value
-    exactly. ``return_aux`` returns it. ``moe_stats_axes``: extra
-    token-sharding axes (the seq axis under PP×SP×EP) each call's aux
-    additionally pmeans over.
+    exactly. ``return_aux`` returns it (under PP×SP×EP the
+    feed-forward's ``stats_axes`` name the seq axis, which each call's
+    aux additionally pmeans over).
     """
     from ..ops.pipeline import pipeline_apply
 
-    attn = attention_fn or local_self_attention
     b, s = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "
                          f"num_microbatches={num_microbatches}")
     if positions is None:
         positions = jnp.arange(s)
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
-    d = p["embed"].shape[-1]
-    hd = d // num_heads
-    m = lax.axis_size(model_axis) if model_axis else 1
-    if num_heads % m != 0:
-        raise ValueError(f"num_heads={num_heads} not divisible by "
-                         f"model-parallel size {m}")
-    x = p["embed"][tokens] + p["pos"][positions]
+    p = _cast(params, compute_dtype)
+    x = _embed(p, tokens, positions)
+    d = x.shape[-1]
     mb = b // num_microbatches
     micro = x.reshape(num_microbatches, mb, s, d)
 
-    moe = num_experts > 0
+    moe = "router" in p["blocks"]
 
     def stage_fn(act):
         def layer(carry, blk):
-            out, aux_l = _apply_block(carry, blk, h_local=num_heads // m,
-                                      hd=hd, attn=attn,
-                                      model_axis=model_axis,
-                                      expert_axis=expert_axis,
-                                      num_experts=num_experts,
-                                      capacity_factor=capacity_factor,
-                                      moe_num_groups=moe_num_groups,
-                                      moe_router_top_k=moe_router_top_k,
-                                      moe_stats_axes=moe_stats_axes)
+            out, aux_l = block.ffn(block.attn(carry, blk), blk)
             return out, (aux_l if moe else None)
 
         if remat:
@@ -607,9 +581,7 @@ def apply_pp(params: Params, tokens: jax.Array, *, num_heads: int,
     else:
         out = pipeline_apply(stage_fn, micro, stage_axis)
         aux = jnp.zeros((), jnp.float32)
-    x = out.reshape(b, s, d)
-    x = _rms_norm(x, p["final_norm"])
-    logits = (x @ p["embed"].T).astype(jnp.float32)
+    logits = _head(p, out.reshape(b, s, d))
     return (logits, aux) if return_aux else logits
 
 
@@ -642,13 +614,8 @@ def stack_block_params_chunked(params: Params, num_stages: int,
 
 
 def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
-                  num_heads: int, stage_axis: str, num_microbatches: int,
-                  num_chunks: int, attention_fn: Callable | None = None,
-                  model_axis: str | None = None,
-                  seq_axis: str | None = None,
-                  expert_axis: str | None = None, num_experts: int = 0,
-                  capacity_factor: float = 1.25,
-                  moe_num_groups: int = 0, moe_router_top_k: int = 1,
+                  block: Block, stage_axis: str, num_microbatches: int,
+                  num_chunks: int, seq_axis: str | None = None,
                   aux_weight: float = 0.0,
                   compute_dtype=jnp.bfloat16):
     """Fused interleaved-1F1B training step body (inside shard_map,
@@ -665,9 +632,9 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     contribution. Returns (loss, train_acc, grads) with ``grads``
     matching the parameter layout.
 
-    ``model_axis`` composes Megatron TP inside every chunk and
-    ``seq_axis`` composes SP (a seq-sharded ``attention_fn`` +
-    cross-shard partial loss). Chunk-internal collectives execute
+    A block built with a ``model_axis`` composes Megatron TP inside
+    every chunk and ``seq_axis`` composes SP (the block's seq-sharded
+    attention + cross-shard partial loss). Chunk-internal collectives execute
     INSIDE the engine's device-varying ``lax.switch`` branches; that is
     safe exactly when the collective's runtime rendezvous is
     GROUP-LOCAL and its participant group shares one stage coordinate
@@ -687,7 +654,7 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     exact dense values — same contract as the GPipe PP×SP path); the
     caller performs that psum.
 
-    ``expert_axis``/``num_experts`` compose mixture-of-experts: the
+    A mixture-of-experts feed-forward composes: the
     per-row-group aux (ops/moe.py) is LINEAR across chunks and
     microbatches, so each chunk returns its summed layer aux, the
     engine accumulates it over forward works and seeds each backward
@@ -696,19 +663,13 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     """
     from ..ops.pipeline import pipeline_1f1b_grads
 
-    attn = attention_fn or local_self_attention
     b, s_loc = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "
                          f"num_microbatches={num_microbatches}")
-    m_tp = lax.axis_size(model_axis) if model_axis else 1
-    if num_heads % m_tp != 0:
-        raise ValueError(f"num_heads={num_heads} not divisible by "
-                         f"model-parallel size {m_tp}")
     n_seq = lax.axis_size(seq_axis) if seq_axis else 1
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    p = _cast(params, compute_dtype)
     d = p["embed"].shape[-1]
-    hd = d // num_heads
     if seq_axis is not None:
         positions = lax.axis_index(seq_axis) * s_loc + jnp.arange(s_loc)
     else:
@@ -717,7 +678,8 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     M = num_microbatches
 
     def emb_fn(embed, pos):
-        return (embed[tokens] + pos[positions]).reshape(M, mb, s_loc, d)
+        return _embed({"embed": embed, "pos": pos}, tokens,
+                      positions).reshape(M, mb, s_loc, d)
 
     micro, emb_vjp = jax.vjp(emb_fn, p["embed"], p["pos"])
 
@@ -726,20 +688,11 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     chunk_params = jax.tree.map(
         lambda a: a.reshape((num_chunks, per) + a.shape[1:]), p["blocks"])
 
-    moe = num_experts > 0
-    moe_stats_axes = (seq_axis,) if (moe and seq_axis is not None) else ()
+    moe = "router" in p["blocks"]
 
     def chunk_fn(slot_params, act):
         def layer(carry, blk):
-            out, aux_l = _apply_block(carry, blk, h_local=num_heads // m_tp,
-                                      hd=hd, attn=attn,
-                                      model_axis=model_axis,
-                                      expert_axis=expert_axis,
-                                      num_experts=num_experts,
-                                      capacity_factor=capacity_factor,
-                                      moe_num_groups=moe_num_groups,
-                                      moe_router_top_k=moe_router_top_k,
-                                      moe_stats_axes=moe_stats_axes)
+            out, aux_l = block.ffn(block.attn(carry, blk), blk)
             return out, (aux_l if moe else None)
         out, aux_layers = lax.scan(layer, act, slot_params)
         return (out, jnp.sum(aux_layers)) if moe else out
@@ -749,8 +702,7 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
 
     if seq_axis is None:
         def head_fn(hp, y, m):
-            x = _rms_norm(y, hp["final_norm"])
-            logits = (x @ hp["embed"].T).astype(jnp.float32)
+            logits = _head(hp, y)
             lab = lax.dynamic_index_in_dim(labels_mb, m, 0, keepdims=False)
             return loss_fn(logits, lab), accuracy(logits, lab)
     else:
@@ -767,8 +719,7 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
                                                                    s_loc)
 
         def head_fn(hp, y, m):
-            x = _rms_norm(y, hp["final_norm"])
-            logits = (x @ hp["embed"].T).astype(jnp.float32)
+            logits = _head(hp, y)
             tgt = lax.dynamic_index_in_dim(tgt_mb, m, 0, keepdims=False)
             # this microbatch's global valid-token count normalizes the
             # partials (shared kernel with the GPipe/DP SP loss path)
@@ -818,37 +769,26 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     return loss, jnp.mean(accs), grads
 
 
-def apply_pp_1f1b(params: Params, tokens: jax.Array, *, num_heads: int,
+def apply_pp_1f1b(params: Params, tokens: jax.Array, *, block: Block,
                   stage_axis: str, num_microbatches: int, num_chunks: int,
-                  attention_fn: Callable | None = None,
-                  model_axis: str | None = None,
-                  expert_axis: str | None = None, num_experts: int = 0,
-                  capacity_factor: float = 1.25,
-                  moe_num_groups: int = 0, moe_router_top_k: int = 1,
                   compute_dtype=jnp.bfloat16) -> jax.Array:
     """Forward-only apply for the chunk-interleaved layout (eval under
     schedule="1f1b"): the chunked ring (ops/pipeline.py:
     pipeline_chunked_forward) with embedding/head outside, same
-    contract as :func:`apply_pp`. ``model_axis`` composes Megatron TP
-    and ``expert_axis`` MoE expert sharding inside each chunk — the
+    contract as :func:`apply_pp`. The block's Megatron TP and MoE
+    expert sharding compose inside each chunk — the
     forward ring computes every chunk unconditionally (``jnp.where``
     select, not a branch), so the TP psums / EP all-to-alls run
     lockstep on every device every tick."""
     from ..ops.pipeline import pipeline_chunked_forward
 
-    attn = attention_fn or local_self_attention
     b, s = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "
                          f"num_microbatches={num_microbatches}")
-    m_tp = lax.axis_size(model_axis) if model_axis else 1
-    if num_heads % m_tp != 0:
-        raise ValueError(f"num_heads={num_heads} not divisible by "
-                         f"model-parallel size {m_tp}")
-    p = jax.tree.map(lambda a: a.astype(compute_dtype), params)
-    d = p["embed"].shape[-1]
-    hd = d // num_heads
-    x = p["embed"][tokens] + p["pos"][jnp.arange(s)]
+    p = _cast(params, compute_dtype)
+    x = _embed(p, tokens, jnp.arange(s))
+    d = x.shape[-1]
     mb = b // num_microbatches
     micro = x.reshape(num_microbatches, mb, s, d)
 
@@ -862,23 +802,13 @@ def apply_pp_1f1b(params: Params, tokens: jax.Array, *, num_heads: int,
         slot_params = _index_pytree(chunk_params, slot)
 
         def layer(carry, blk):
-            out, _aux = _apply_block(carry, blk, h_local=num_heads // m_tp,
-                                     hd=hd, attn=attn,
-                                     model_axis=model_axis,
-                                     expert_axis=expert_axis,
-                                     num_experts=num_experts,
-                                     capacity_factor=capacity_factor,
-                                     moe_num_groups=moe_num_groups,
-                                     moe_router_top_k=moe_router_top_k)
+            out, _aux = block.ffn(block.attn(carry, blk), blk)
             return out, None
         out, _ = lax.scan(layer, act, slot_params)
         return out
 
     out = pipeline_chunked_forward(chunk_fn, micro, stage_axis, num_chunks)
-    x = out.reshape(b, s, d)
-    x = _rms_norm(x, p["final_norm"])
-    logits = x @ p["embed"].T
-    return logits.astype(jnp.float32)
+    return _head(p, out.reshape(b, s, d))
 
 
 def sp_partial_token_loss(logits: jax.Array, tgt: jax.Array,

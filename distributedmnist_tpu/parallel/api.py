@@ -140,11 +140,11 @@ def _build_params(model: Model, cfg: ExperimentConfig,
     the abstract-shape path the spec engine maps rules over."""
     params = model.init(jax.random.PRNGKey(cfg.model.init_seed))
     if (topo is not None and topo.mesh.shape[topo.stage_axis] > 1):
-        if getattr(model, "pp_transform", None) is None:
+        if model.pp_transform is None:
             raise ValueError(f"mesh has pipeline stages but model "
                              f"{model.name!r} has no pp_transform")
         if cfg.mesh.pipeline_schedule == "1f1b":
-            if getattr(model, "pp_transform_chunked", None) is None:
+            if model.pp_transform_chunked is None:
                 raise ValueError(
                     f"pipeline_schedule='1f1b' but model {model.name!r} "
                     "has no pp_transform_chunked")
@@ -192,15 +192,14 @@ def params_partition_specs(model: Model, cfg: ExperimentConfig,
     n_model = topo.mesh.shape[topo.model_axis]
     n_stage = topo.mesh.shape[topo.stage_axis]
     n_expert = topo.mesh.shape[topo.expert_axis]
-    if n_model > 1 and getattr(model, "tp_param_specs", None) is None:
+    if n_model > 1 and model.tp_param_specs is None:
         raise ValueError(f"mesh has model_parallelism={n_model} but model "
                          f"{model.name!r} has no tensor-parallel parameter "
                          "specs")
-    if n_expert > 1 and (getattr(model, "tp_param_specs", None) is None
-                         or not getattr(model, "has_aux", False)):
+    if n_expert > 1 and (model.tp_param_specs is None or not model.has_aux):
         raise ValueError(f"mesh has expert_parallelism={n_expert} but model "
                          f"{model.name!r} has no experts to shard")
-    if n_stage > 1 and getattr(model, "pp_param_specs", None) is None:
+    if n_stage > 1 and model.pp_param_specs is None:
         raise ValueError(f"mesh has pipeline_parallelism={n_stage} but model "
                          f"{model.name!r} has no pipeline parameter specs")
     axes = RuleAxes(
@@ -944,7 +943,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
     expert_ax = topo.expert_axis
     n_expert = topo.mesh.shape[expert_ax]
     if ((n_seq > 1 or n_model > 1 or n_expert > 1) and n_stage == 1
-            and getattr(model, "sharded_apply_factory", None) is None):
+            and model.sharded_apply_factory is None):
         raise ValueError(
             f"mesh has seq_parallelism={n_seq} / model_parallelism="
             f"{n_model} / expert_parallelism={n_expert} but model "
@@ -955,7 +954,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
         raise ValueError(f"unknown pipeline_schedule {pp_schedule!r}")
     pp_1f1b_grads_fn = None
     if n_stage > 1:
-        if getattr(model, "pp_apply_factory", None) is None:
+        if model.pp_apply_factory is None:
             raise ValueError(f"mesh has pipeline_parallelism={n_stage} but "
                              f"model {model.name!r} has no pipeline apply")
         if pp_schedule == "1f1b":
@@ -966,7 +965,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             # stage-varying switch branches; that is safe because they
             # reduce over NON-stage axes whose participant groups share
             # a stage coordinate and hence a branch (ops/pipeline.py).
-            if getattr(model, "pp_1f1b_grads_factory", None) is None:
+            if model.pp_1f1b_grads_factory is None:
                 raise ValueError(f"model {model.name!r} has no 1f1b "
                                  "pipeline support")
             pp_1f1b_grads_fn = model.pp_1f1b_grads_factory(
@@ -999,7 +998,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
     # instead of silently training a dropout model without dropout.
     if ((sharded_apply is not None or pp_apply is not None
             or pp_1f1b_grads_fn is not None)
-            and getattr(model, "uses_dropout", False)):
+            and model.uses_dropout):
         raise ValueError(
             f"model {model.name!r} uses dropout, but the sharded "
             "(SP/TP/PP) loss paths do not thread a dropout key; set "
@@ -1026,8 +1025,8 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             "replica axis is 1" if n <= 1 else
             f"sync.mode={mode!r} keeps the full windowed accumulator")
 
-    has_aux = getattr(model, "has_aux", False)
-    aux_w = getattr(model, "aux_weight", 0.0)
+    has_aux = model.has_aux
+    aux_w = model.aux_weight
 
     def local_loss(params, batch, dropout_key):
         if has_aux:
@@ -1479,14 +1478,14 @@ def build_eval_step(model: Model, cfg: ExperimentConfig, topo: Topology):
         # eval rows (capped by the training cadence) — M=1 would run
         # the stages fully serialized, an S× eval slowdown measured in
         # the tens of minutes on deep CPU-mesh evals.
-        if getattr(model, "pp_apply_factory", None) is None:
+        if model.pp_apply_factory is None:
             raise ValueError(f"mesh has pipeline_parallelism={n_stage} but "
                              f"model {model.name!r} has no pipeline apply")
         tp_ax = model_ax if n_model > 1 else None
         ep_ax = topo.expert_axis if n_expert > 1 else None
         pspec: Any = params_partition_specs(model, cfg, topo)
         if (cfg.mesh.pipeline_schedule == "1f1b"
-                and getattr(model, "pp_1f1b_apply_factory", None) is None):
+                and model.pp_1f1b_apply_factory is None):
             # mirror the train-path guard: fail with a clear error at
             # build time instead of an opaque trace-time NoneType call
             raise ValueError(f"model {model.name!r} has no 1f1b "
@@ -1514,8 +1513,8 @@ def build_eval_step(model: Model, cfg: ExperimentConfig, topo: Topology):
     elif n_model > 1 or n_expert > 1:
         # tensor-/expert-parallel params: sharded apply (full sequence
         # per device — eval batches are not seq-sharded), sharded in_spec
-        if (getattr(model, "tp_param_specs", None) is None
-                or getattr(model, "sharded_apply_factory", None) is None):
+        if (model.tp_param_specs is None
+                or model.sharded_apply_factory is None):
             raise ValueError(f"mesh has model_parallelism={n_model} / "
                              f"expert_parallelism={n_expert} but model "
                              f"{model.name!r} is not tensor-/expert-parallel "

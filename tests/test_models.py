@@ -7,7 +7,7 @@ import pytest
 
 from distributedmnist_tpu.core.config import ModelConfig
 from distributedmnist_tpu.models import available, get_model
-from distributedmnist_tpu.models import cnn
+from distributedmnist_tpu.models import cnn, transformer
 
 
 def test_registry_lists_families():
@@ -133,14 +133,14 @@ def test_resnet20_learns_a_step():
 
 
 def test_transformer_next_token_loss_decreases():
-    from distributedmnist_tpu.models import transformer
     params = transformer.init(jax.random.PRNGKey(0), vocab_size=17,
                               model_dim=32, num_heads=2, num_layers=1,
                               max_seq_len=16)
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 17)
 
     def loss(p):
-        logits = transformer.apply(p, toks, num_heads=2,
+        logits = transformer.apply(p, toks,
+                                   block=transformer.make_block(num_heads=2),
                                    compute_dtype=jnp.float32)
         return transformer.loss_fn(logits, toks)
 
@@ -148,3 +148,116 @@ def test_transformer_next_token_loss_decreases():
     g = jax.grad(loss)(params)
     params2 = jax.tree.map(lambda p_, g_: p_ - 0.5 * g_, params, g)
     assert float(loss(params2)) < l0
+
+
+# ---------------------------------------------------------------------------
+# the seam: a layer's kind is handed to make_block, and no forward knows it
+# ---------------------------------------------------------------------------
+
+def _gated_unit(h, blk):
+    """A feed-forward no model here has: w1's columns split into a value
+    and a SiLU gate, over the dense block's own w1/w2."""
+    value, gate = jnp.split(h @ blk["w1"], 2, axis=-1)
+    mlp = (jax.nn.silu(gate) * value) @ blk["w2"][:value.shape[-1]]
+    return mlp, jnp.zeros((), jnp.float32)
+
+
+def _through_the_cache(params, block, seq, want):
+    """Prefill seven tokens, then the rest one by one over a paged
+    cache, teacher-forced: every step's logits against the full
+    forward's at that position."""
+    import functools
+
+    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
+
+    plen, total = 7, seq.shape[1]
+    cache = PagedKVCache(4, 16, 4, 4, 8, max_blocks_per_seq=4,
+                         dtype=jnp.float32)
+    logits, ks, vs = transformer.prefill_with_kv(
+        params, seq, block=block, compute_dtype=jnp.float32)
+    # causal: the prompt's rows do not see what is padded after them
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    table = cache.alloc_sequence(total)
+    cache.write_prompt(table, ks[:, 0], vs[:, 0], plen)
+    step = jax.jit(functools.partial(
+        transformer.decode_step, ffn=block.ffn, num_heads=4, block_size=4,
+        compute_dtype=jnp.float32))
+    slot, slots = 1, 3
+    for pos in range(plen, total):
+        tokens, positions, lengths = (np.zeros(slots, np.int32)
+                                      for _ in range(3))
+        tables = np.zeros((slots, 4), np.int32)
+        tokens[slot], positions[slot], lengths[slot] = (seq[0, pos], pos,
+                                                        pos + 1)
+        tables[slot] = table
+        got, cache.k, cache.v = step(
+            params, jnp.asarray(tokens), jnp.asarray(positions), cache.k,
+            cache.v, jnp.asarray(tables), jnp.asarray(lengths))
+        np.testing.assert_allclose(np.asarray(got[slot]),
+                                   np.asarray(want[0, pos]),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("forward", ["prefill_decode", "apply_pp",
+                                     "apply_pp_1f1b", "grads_pp_1f1b"])
+def test_a_new_feed_forward_goes_through_every_forward(forward):
+    """The constructor is the only place that knows the layer: a gated
+    unit written HERE runs through ``apply`` and through each other
+    forward, and they agree (tolerances of test_decode.py and
+    test_pipeline_parallel.py)."""
+    from jax.sharding import PartitionSpec as P
+
+    from conftest import LOSS_TOL, assert_update_parity
+    from distributedmnist_tpu.core.config import MeshConfig
+    from distributedmnist_tpu.core.mesh import make_topology
+
+    params = transformer.init(jax.random.PRNGKey(0), vocab_size=37,
+                              model_dim=32, num_heads=4, num_layers=4,
+                              max_seq_len=16)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 37)
+    block = transformer.make_block(num_heads=4, feed_forward=_gated_unit)
+    f32 = dict(block=block, compute_dtype=jnp.float32)
+    want = transformer.apply(params, toks, **f32)
+    dense = transformer.apply(params, toks, compute_dtype=jnp.float32,
+                              block=transformer.make_block(num_heads=4))
+    assert float(jnp.max(jnp.abs(want - dense))) > 1e-3   # it is another layer
+
+    if forward == "prefill_decode":
+        _through_the_cache(params, block, toks[:1],
+                           transformer.apply(params, toks[:1], **f32))
+        return
+    stages, chunks = (4, 1) if forward == "apply_pp" else (2, 2)
+    topo = make_topology(MeshConfig(num_replicas=1,
+                                    pipeline_parallelism=stages))
+    staged = dict(f32, stage_axis=topo.stage_axis, num_microbatches=2)
+    specs = transformer.pp_param_partition_specs(topo.stage_axis)
+    if forward == "apply_pp":
+        stacked = transformer.stack_block_params(params)
+        fn = lambda p, t: transformer.apply_pp(p, t, **staged)
+        out_specs = P()
+    else:
+        stacked = transformer.stack_block_params_chunked(params, stages,
+                                                         chunks)
+        if forward == "apply_pp_1f1b":
+            fn = lambda p, t: transformer.apply_pp_1f1b(
+                p, t, num_chunks=chunks, **staged)
+            out_specs = P()
+        else:
+            fn = lambda p, t: transformer.grads_pp_1f1b(
+                p, t, t, num_chunks=chunks, **staged)
+            out_specs = (P(), P(), specs)
+    got = jax.jit(jax.shard_map(fn, mesh=topo.mesh, in_specs=(specs, P()),
+                                out_specs=out_specs))(
+        topo.device_put_state(stacked, specs), toks)
+    if forward != "grads_pp_1f1b":
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        return
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: transformer.loss_fn(transformer.apply(p, toks, **f32),
+                                      toks))(params)
+    loss, _, grads = got
+    np.testing.assert_allclose(float(loss), float(want_loss), **LOSS_TOL)
+    assert_update_parity(jax.device_get(grads),
+                         transformer.stack_block_params_chunked(
+                             want_grads, stages, chunks))

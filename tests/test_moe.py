@@ -187,12 +187,15 @@ def _dense_moe_update(cfg, batch):
     params = model.init(jax.random.PRNGKey(cfg.model.init_seed))
 
     def loss_fn(p):
+        block = transformer.make_block(
+            num_heads=cfg.model.num_heads,
+            feed_forward=transformer.moe_feed_forward(
+                num_experts=cfg.model.num_experts,
+                capacity_factor=cfg.model.expert_capacity_factor,
+                num_groups=cfg.model.moe_num_groups,
+                router_top_k=cfg.model.moe_router_top_k))
         logits, aux = transformer.apply(
-            p, batch["image"], num_heads=cfg.model.num_heads,
-            compute_dtype=jnp.float32, num_experts=cfg.model.num_experts,
-            capacity_factor=cfg.model.expert_capacity_factor,
-            moe_num_groups=cfg.model.moe_num_groups,
-            moe_router_top_k=cfg.model.moe_router_top_k,
+            p, batch["image"], block=block, compute_dtype=jnp.float32,
             return_aux=True)
         return (transformer.loss_fn(logits, batch["label"])
                 + cfg.model.moe_aux_weight * aux)

@@ -80,7 +80,8 @@ def test_transformer_with_ring_attention_matches_local():
                               model_dim=16, num_heads=2, num_layers=2,
                               max_seq_len=64)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 31)
-    want = transformer.apply(params, toks, num_heads=2,
+    want = transformer.apply(params, toks,
+                             block=transformer.make_block(num_heads=2),
                              compute_dtype=jnp.float32)
 
     topo = make_seq_topology(8)
@@ -89,8 +90,8 @@ def test_transformer_with_ring_attention_matches_local():
     def fn(params, toks, positions):
         def ring_attn(q, k, v):
             return ring_self_attention(q, k, v, axis, causal=True)
-        return transformer.apply(params, toks, num_heads=2,
-                                 attention_fn=ring_attn,
+        block = transformer.make_block(num_heads=2, attention_fn=ring_attn)
+        return transformer.apply(params, toks, block=block,
                                  positions=positions,
                                  compute_dtype=jnp.float32)
 

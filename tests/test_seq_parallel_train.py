@@ -45,9 +45,10 @@ def _dense_reference_update(cfg, batch):
     params = model.init(jax.random.PRNGKey(cfg.model.init_seed))
 
     def loss_fn(p):
-        logits = transformer.apply(p, batch["image"],
-                                   num_heads=cfg.model.num_heads,
-                                   compute_dtype=jnp.float32)
+        logits = transformer.apply(
+            p, batch["image"],
+            block=transformer.make_block(num_heads=cfg.model.num_heads),
+            compute_dtype=jnp.float32)
         return transformer.loss_fn(logits, batch["label"])
 
     loss, grads = jax.value_and_grad(loss_fn)(params)

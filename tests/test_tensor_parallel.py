@@ -47,7 +47,8 @@ def test_tp_forward_matches_dense():
     model = get_model(cfg.model)
     params = model.init(jax.random.PRNGKey(0))
     toks = _tokens(cfg)["image"]
-    want = transformer.apply(params, toks, num_heads=4,
+    want = transformer.apply(params, toks,
+                             block=transformer.make_block(num_heads=4),
                              compute_dtype=jnp.float32)
 
     topo = make_topology(MeshConfig(num_replicas=1, model_parallelism=4))
@@ -68,9 +69,10 @@ def _dense_update(cfg, batch):
     params = model.init(jax.random.PRNGKey(cfg.model.init_seed))
 
     def loss_fn(p):
-        logits = transformer.apply(p, batch["image"],
-                                   num_heads=cfg.model.num_heads,
-                                   compute_dtype=jnp.float32)
+        logits = transformer.apply(
+            p, batch["image"],
+            block=transformer.make_block(num_heads=cfg.model.num_heads),
+            compute_dtype=jnp.float32)
         return transformer.loss_fn(logits, batch["label"])
 
     loss, grads = jax.value_and_grad(loss_fn)(params)
