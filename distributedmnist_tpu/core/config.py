@@ -781,8 +781,11 @@ class DecodeConfig:
                 "null block)")
 
     def max_blocks_per_seq(self) -> int:
-        """Blocks one sequence can ever need (prompt + generation) —
-        the fixed block-table width every compiled decode shape uses."""
+        """Blocks one sequence can ever need (prompt + generation):
+        the width of a sequence's block table, and the widest of the
+        four table widths (its quarters) the decode step is compiled
+        for; an iteration is handed the narrowest that holds its
+        longest live sequence (servesvc/decode.py)."""
         total = self.max_prompt_len + self.max_new_tokens
         return -(-total // self.block_size)
 

@@ -441,7 +441,10 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
     else:
         # gather the slot's pages into one dense context view: the
         # block table IS the indirection, so this read is identical
-        # for a 3-token and a 90-token sequence — one compiled shape
+        # for a 3-token and a 90-token sequence, at the width of the
+        # table it is handed: one compiled shape a width, and the
+        # decode loop picks the width (at most four) by its longest
+        # live sequence (servesvc/decode.py::_table_width)
         with jax.named_scope("cache_gather"):
             kp = k_cache[li][block_tables].reshape(
                 num_slots, ctx, num_heads, hd)

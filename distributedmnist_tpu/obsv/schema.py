@@ -215,9 +215,11 @@ _declare(EventSchema(
                             ("initial", "sequences_pinned",
                              "sequences_restarted")),
         # -- decode service (servesvc/decode.py) ----------------------
+        # table_widths: the block-table widths the step is compiled for
         "decode_start": _act(("slots", "block_size", "num_blocks",
                               "max_prompt_len", "max_new_tokens",
-                              "swap_policy", "model_step")),
+                              "table_widths", "swap_policy",
+                              "model_step")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of
@@ -309,7 +311,8 @@ _declare(EventSchema(
     optional=("tp_rank", "queue_depth", "queue_limit", "kv_blocks_free",
               "kv_blocks_total", "kv_blocks_reserved",
               "decode_waiting", "slots_live", "decode_steps",
-              "tokens_sampled_device", "tokens_sampled_host"),
+              "decode_table_blocks", "tokens_sampled_device",
+              "tokens_sampled_host"),
 ))
 
 # Load-generator journal (servesvc/loadgen.py loadgen.jsonl): every

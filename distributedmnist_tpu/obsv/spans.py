@@ -59,10 +59,11 @@ SERVE_PREFILL_CACHE_WRITE = "dml.serve.prefill.cache_write"
 #: `_step_active` up to the dispatch: the numpy vectors, their three
 #: uploads and `_tables_for`
 SERVE_STEP_INPUTS = "dml.serve.step.inputs"
-#: the call of the jitted decode step (live, waiting, version): one per
-#: iteration and params version, so it counts iterations; read by:
-#: decode_slots_live_p50 (`live`), decode_sample_ms_per_iter and
-#: decode_stream_ms_per_iter (the count)
+#: the call of the jitted decode step (live, waiting, version, blocks:
+#: the width of the block table it is handed): one per iteration and
+#: params version, so it counts iterations; read by:
+#: decode_slots_live_p50 (`live`), decode_table_blocks_p50 (`blocks`),
+#: decode_sample_ms_per_iter and decode_stream_ms_per_iter (the count)
 SERVE_STEP_DISPATCH = "dml.serve.step.dispatch"
 #: the blocking fetch of the step's [slots] greedy tokens: the wait for
 #: the step on the device

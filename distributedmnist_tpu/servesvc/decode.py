@@ -18,6 +18,15 @@ deadline / client gone) frees its blocks and its slot is refilled from
 the admission queue the SAME iteration — no padded rounds, no waiting
 for a batch to drain.
 
+**The table's width follows the longest live sequence.** The dense
+step reads every position its block table spans, so an iteration hands
+it the narrowest of at most four widths (the quarters of
+``max_blocks_per_seq``: ``_table_widths``) that holds its longest live
+sequence and the token being written. Four compiled programs of one
+jitted callable, all compiled in :meth:`DecodeReplica.start` before a
+request is accepted; the full width, and its cost, only when a
+sequence is that long.
+
 **Prefill.** Prompts are admitted through the existing bounded queue
 (typed ``overloaded`` shed when full), padded to power-of-2 buckets
 (each bucket's prefill compiles once) and run through the model's
@@ -146,6 +155,17 @@ class DecodeReplica(ServingReplica):
         self.cache = PagedKVCache(
             layers, self.dcfg.num_blocks, self.dcfg.block_size,
             heads, head_dim, self.dcfg.max_blocks_per_seq(), dtype=dtype)
+        # placed where the weights are, once (no copy): a fresh
+        # `jnp.zeros` is committed to no device, a jitted call is
+        # compiled again for an argument placed differently, and every
+        # later call takes the arrays a jitted call returned
+        self.cache.k, self.cache.v = self.topo.device_put_replicated(
+            (self.cache.k, self.cache.v))
+        # the block-table widths an iteration chooses from: the quarters
+        # of the full table (fewer where a toy table has under four
+        # blocks). Each is a compiled program and a load at start-up
+        full = self.cache.max_blocks_per_seq
+        self._table_widths = sorted({-(-full * k // 4) for k in (1, 2, 3, 4)})
         self._prefill_jit = jax.jit(self.model.decode_prefill)
         model_step = self.model.decode_step
         block_size = self.dcfg.block_size
@@ -177,6 +197,7 @@ class DecodeReplica(ServingReplica):
         self._seq_counter = 0
         self.tokens_streamed = 0
         self.decode_steps = 0      # dispatches of the jitted decode step
+        self.decode_table_blocks = 0  # the last dispatch's table width
         # tokens by where they were picked: the step's own greedy pick,
         # or `_sample` (a draw, and every prefill's first token)
         self.tokens_sampled_device = 0
@@ -185,12 +206,14 @@ class DecodeReplica(ServingReplica):
         # block-table upload cache: slot→block assignments only change
         # on admit/finish/restart, so the [slots, width] tables array a
         # decode iteration feeds the jitted step is IDENTICAL between
-        # those events — rebuild + re-upload it once per (params
-        # version, table epoch) instead of every generated token. The
-        # epoch counter is bumped by every mutation of any slot's table
-        # or version assignment; bumping clears the cache.
+        # those events at one width — rebuild + re-upload it once per
+        # (params version, table epoch, width) instead of every
+        # generated token. The epoch counter is bumped by every mutation
+        # of any slot's table or version assignment; bumping clears the
+        # cache. The width is in the key because a sequence grows past a
+        # rung of `_table_widths` with no such event.
         self._tables_epoch = 0
-        self._tables_cache: dict[tuple[int, int], jax.Array] = {}
+        self._tables_cache: dict[tuple[int, int, int], jax.Array] = {}
         self.table_uploads = 0
         self.table_upload_reuses = 0
 
@@ -317,6 +340,7 @@ class DecodeReplica(ServingReplica):
                 "decode_waiting": len(self._waiting),
                 "slots_live": sum(s is not None for s in self._slots),
                 "decode_steps": self.decode_steps,
+                "decode_table_blocks": self.decode_table_blocks,
                 "tokens_sampled_device": self.tokens_sampled_device,
                 "tokens_sampled_host": self.tokens_sampled_host}
 
@@ -464,35 +488,52 @@ class DecodeReplica(ServingReplica):
     def _tables_for(self, ver: int, mine, num_slots: int,
                     width: int) -> jax.Array:
         """The device-resident [slots, width] block-tables array for
-        one params version's compiled step. Rows of slots NOT on this
-        version are zero (the null block) — load-bearing, not padding:
-        the step scatters the new token's K/V through row
+        one params version's compiled step: the first ``width`` entries
+        of each of its sequences' tables (``_table_width`` chose a
+        width that holds them all). Rows of slots NOT on this version
+        are zero (the null block) at every width — load-bearing, not
+        padding: the step scatters the new token's K/V through row
         ``positions[i] // block_size`` of EVERY slot, and zero routes
         the not-mine writes into the reserved null block instead of a
-        live sequence's block 0. Cached per (version, table epoch):
-        between admit/finish/restart events the array is bit-identical
-        every iteration, so steady-state decoding reuses one upload
-        instead of paying a host rebuild + transfer per token
-        (measured in bench_decode_throughput's ``table_prep`` detail).
+        live sequence's block 0. Cached per (version, table epoch,
+        width): between admit/finish/restart events the array at one
+        width is bit-identical every iteration, so steady-state
+        decoding reuses one upload instead of paying a host rebuild +
+        transfer per token (measured in bench_decode_throughput's
+        ``table_prep`` detail); a sequence that grows past a rung gets a
+        fresh, wider one.
         """
-        key = (ver, self._tables_epoch)
+        key = (ver, self._tables_epoch, width)
         cached = self._tables_cache.get(key)
         if cached is not None:
             self.table_upload_reuses += 1
             return cached
         tables = np.zeros((num_slots, width), np.int32)
         for i, s in mine:
-            tables[i] = s.block_table
+            tables[i] = s.block_table[:width]
         dev = jnp.asarray(tables)
         self._tables_cache[key] = dev
         self.table_uploads += 1
         return dev
 
+    def _table_width(self, mine) -> int:
+        """The narrowest of ``_table_widths`` that holds every one of
+        these sequences AND the position the step is about to write:
+        ``s.length + 1``, since this token's K/V goes to position
+        ``s.length`` through ``block_tables[i, s.length // block_size]``,
+        which has to lie inside the table the step is given. Read from
+        the current lengths, so it narrows again when a long sequence
+        finishes."""
+        need = max(s.length for _, s in mine) + 1
+        return next(w for w in self._table_widths
+                    if w * self.cache.block_size >= need)
+
     def _step_active(self) -> None:
         """One decode iteration: a single compiled step per live param
-        version over the fixed slot shape, its greedy tokens fetched
-        as one array, then per-slot stream / finish — a finished slot
-        is free for the NEXT iteration's refill."""
+        version over the fixed slot shape, at the table width its
+        longest sequence needs, its greedy tokens fetched as one array,
+        then per-slot stream / finish — a finished slot is free for the
+        NEXT iteration's refill."""
         now = time.time()
         for i, s in enumerate(self._slots):
             if s is not None and now >= s.deadline_at:
@@ -502,13 +543,13 @@ class DecodeReplica(ServingReplica):
         if not active:
             return
         num_slots = self.dcfg.decode_slots
-        width = self.cache.max_blocks_per_seq
         # pin policy: at most a handful of live versions — one compiled
         # step per version, idle-for-this-version slots masked via the
         # null block table + zero length
         for ver in sorted({s.params_step for _, s in active}):
             with spans.span(spans.SERVE_STEP_INPUTS):
                 mine = [(i, s) for i, s in active if s.params_step == ver]
+                width = self._table_width(mine)
                 tokens = np.zeros((num_slots,), np.int32)
                 positions = np.zeros((num_slots,), np.int32)
                 lengths = np.zeros((num_slots,), np.int32)
@@ -520,12 +561,14 @@ class DecodeReplica(ServingReplica):
                 tables = self._tables_for(ver, mine, num_slots, width)
                 lengths = jnp.asarray(lengths)
             with spans.span(spans.SERVE_STEP_DISPATCH, live=len(active),
-                            waiting=len(self._waiting), version=ver):
+                            waiting=len(self._waiting), version=ver,
+                            blocks=width):
                 logits, greedy, self.cache.k, self.cache.v = (
                     self._decode_jit(
                         self._params_for(ver), tokens, positions,
                         self.cache.k, self.cache.v, tables, lengths))
                 self.decode_steps += 1
+                self.decode_table_blocks = width
             with spans.span(spans.SERVE_STEP_FETCH):
                 on_host = jax.device_get(greedy)
             draws = sum(s.temperature > 0.0 for _, s in mine)
@@ -636,6 +679,22 @@ class DecodeReplica(ServingReplica):
                 "eos_token": self.dcfg.eos_token,
                 "swap_policy": self.dcfg.swap_policy}
 
+    def _warm_up(self) -> None:
+        """Every table width's decode step compiled (or loaded from the
+        compile cache) before a request is accepted: a rung first
+        reached by a live conversation would otherwise stall every slot
+        for one compile. Each width runs once with every slot idle
+        (tables and lengths zero: the writes land in the null block,
+        which is what it is for), on cache arrays placed as every later
+        call finds them (``__init__``)."""
+        num_slots = self.dcfg.decode_slots
+        idle = jnp.asarray(np.zeros((num_slots,), np.int32))
+        for width in self._table_widths:
+            _, greedy, self.cache.k, self.cache.v = self._decode_jit(
+                self._params, idle, idle, self.cache.k, self.cache.v,
+                jnp.asarray(np.zeros((num_slots, width), np.int32)), idle)
+        jax.block_until_ready(greedy)
+
     def start(self) -> None:
         super().start()
         self._journal({"action": "decode_start",
@@ -644,5 +703,6 @@ class DecodeReplica(ServingReplica):
                        "num_blocks": self.dcfg.num_blocks,
                        "max_prompt_len": self.dcfg.max_prompt_len,
                        "max_new_tokens": self.dcfg.max_new_tokens,
+                       "table_widths": self._table_widths,
                        "swap_policy": self.dcfg.swap_policy,
                        "model_step": self.model_step})

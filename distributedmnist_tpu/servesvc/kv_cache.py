@@ -8,8 +8,13 @@ a fixed-width ``[max_blocks_per_seq]`` int32 map from its position
 range to blocks — so the compiled decode step reads any mix of
 sequence lengths through one gather, and finishing a 7-token sequence
 returns its blocks to the pool the same step a 90-token neighbor keeps
-generating. This is what lets wildly different lengths share a single
-compiled decode shape instead of bucket-padding rounds.
+generating. This is what lets wildly different lengths share a
+compiled decode shape instead of bucket-padding rounds. The dense
+gather reads every position of the table it is handed, so the decode
+loop hands the step the first ``w`` entries of every table, ``w`` the
+narrowest of four widths (the quarters of ``max_blocks_per_seq``) that
+holds its longest live sequence: four compiled shapes, not one, and
+the full width only for a sequence that long (servesvc/decode.py).
 
 Block 0 is the **reserved null block**: idle decode slots point their
 whole table (and their writes) at it, so the fixed-shape step never

@@ -458,6 +458,11 @@ class ServingReplica:
             f"no loadable checkpoint in {self.train_dir} within "
             f"{timeout_s:.0f}s")
 
+    def _warm_up(self) -> None:
+        """What a replica runs on its first weights before it accepts a
+        request. Nothing here: a predict bucket compiles on first use.
+        The decode replica compiles its step's shapes."""
+
     def _follow_loop(self) -> None:
         while not self._stop.is_set():
             try:
@@ -760,6 +765,7 @@ class ServingReplica:
         endpoint_path = self.serve_dir / "serve.json"
         endpoint_path.unlink(missing_ok=True)  # stale incarnation
         self._load_initial()
+        self._warm_up()
         self._sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._sock.bind((self.scfg.host, self.scfg.port))
