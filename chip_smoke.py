@@ -458,7 +458,61 @@ def phase_kernels(*, model: dict, batch: int, block_size: int = 16,
              and err <= tol, f"decode step logits, paged vs dense: {err}")
     out["decode_step_argmax_equal"] = bool(
         int(jnp.argmax(lg["paged"][0])) == int(jnp.argmax(lg["dense"][0])))
+    out["latent_routed_block"] = _latent_routed_arm(model, batch)
     return out
+
+
+def _latent_routed_arm(model: dict, batch: int) -> dict:
+    """(d) one train step's loss and gradients of the block with latent
+    attention, per-token routing over a held share, four residual
+    streams and the next-next-token module, at the smoke's width cut
+    eightfold, two layers: flash through the padded 24 + 8 | 16 heads,
+    the grouped product's loop, the Sinkhorn iteration. Finite loss and
+    gradients, every position's ids different experts in range, the
+    flag's logits the unflagged to the bit."""
+    import jax
+    import jax.numpy as jnp
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+
+    d, heads = max(model["model_dim"] // 8, 32), 4
+    seq = min(model["seq_len"], 256)
+    mdl = get_model(ModelConfig(**{
+        **model, "model_dim": d, "num_heads": heads, "num_layers": 2,
+        "seq_len": seq, "q_latent_dim": d // 2, "kv_latent_dim": d // 4,
+        "qk_nope_dim": 24, "qk_rope_dim": 8, "v_head_dim": 16,
+        "rope_factor": 4.0, "rope_original_len": seq // 4,
+        "rope_mscale_all_dim": 1.0, "ffn_dim": 2 * d, "routed_experts": 16,
+        "held_experts": 8, "experts_per_token": 4, "shared_experts": 1,
+        "expert_ffn_dim": d // 2, "routed_scaling": 2.0, "dense_layers": 1,
+        "residual_streams": 4, "nextn_layers": 1, "remat": True}))
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 1), 2)
+    params = mdl.init(keys[0])
+    toks = jax.random.randint(keys[1], (batch, seq), 0, model["vocab_size"])
+
+    def loss_of(p):
+        logits, aux = mdl.apply(p, toks, train=True, return_aux=True)
+        return mdl.loss(logits, toks) + aux["loss"], aux
+
+    step = jax.jit(jax.value_and_grad(loss_of, has_aux=True))
+    (loss, aux), grads = step(params)
+    ids = np.sort(np.asarray(aux["routing"]), axis=-1)
+    finite = all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
+    flagged = jax.jit(lambda p: mdl.apply(p, toks, return_aux=True)[0])
+    same = bool(jnp.array_equal(flagged(params),
+                                jax.jit(lambda p: mdl.apply(p, toks))(params)))
+    _require(bool(jnp.isfinite(loss)) and finite,
+             f"latent routed block: loss {float(loss)}, gradients finite: "
+             f"{finite}")
+    _require(ids.min() >= 0 and ids.max() < 16
+             and (ids[..., 1:] != ids[..., :-1]).all() and same,
+             "latent routed block: a position's experts repeat or leave "
+             f"the range, or the routing flag moved the logits ({same})")
+    return {"loss": round(float(loss), 4),
+            "pairs_held": int(np.asarray(aux["counts"]).sum()),
+            "pairs": int(ids.size),
+            "mosaic_calls": _mosaic_calls(
+                step.lower(params).compile().as_text())}
 
 
 # ---------------------------------------------------------------------------

@@ -130,6 +130,62 @@ class ModelConfig:
     #     sequences): measured 1.14x tokens/sec at the S=8192
     #     long-context bench shape on v5e.
     remat_policy: str = "full"
+    # -- sizes of the mechanisms below; each is absent at its default, and
+    # a size is all there is: no key here chooses between two paths for
+    # one thing (models/registry.py::block_for reads them) --------------
+    # Latent attention (arXiv:2405.04434): kv_latent_dim > 0 replaces
+    # wqkv by the low-rank query and key-value projections, with per-head
+    # widths qk_nope_dim + qk_rope_dim for query and key and v_head_dim
+    # for the value; the qk_rope_dim columns are rotated by position
+    # (YaRN, rope_* below), so the model has no learned position table,
+    # and its head is a matrix of its own (untied).
+    q_latent_dim: int = 0
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0          # YaRN: 1 = plain rotary
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # ffn_dim > 0: the dense feed-forward is a gated SiLU unit this wide
+    # (0: ReLU over 4 x model_dim).
+    ffn_dim: int = 0
+    # Per-token routing over routed_experts (arXiv:2412.19437 s2.1.2):
+    # sigmoid scores, a selection bias, experts_per_token of them, none
+    # dropped, gates renormalised and scaled by routed_scaling; gated
+    # experts expert_ffn_dim wide, shared_experts more that every token
+    # takes. This chip holds held_experts of them (0: all) from
+    # first_held_expert on: it routes over all and computes its share.
+    # The first dense_layers layers keep the dense feed-forward.
+    routed_experts: int = 0
+    held_experts: int = 0
+    first_held_expert: int = 0
+    experts_per_token: int = 0
+    shared_experts: int = 0
+    expert_ffn_dim: int = 0
+    routed_scaling: float = 1.0
+    # the selection bias's step toward even load, in units of the
+    # learning rate: lowered for an expert that took more than its share
+    # of the step's tokens, raised for the others (0: held constant)
+    router_bias_rate: float = 0.0
+    dense_layers: int = 0
+    # residual_streams > 1: every sublayer reads from and writes to that
+    # many residual streams through three learned maps, the stream-to-
+    # stream one made doubly stochastic by sinkhorn_iters rounds
+    # (arXiv:2512.24880).
+    residual_streams: int = 1
+    sinkhorn_iters: int = 20
+    residual_eps: float = 1e-6
+    residual_clamp: float = 30.0
+    # nextn_layers > 0: that many next-next-token modules after the
+    # trunk (arXiv:2412.19437 s2.2; only 1 is built), their loss added
+    # at nextn_loss_weight when training.
+    nextn_layers: int = 0
+    nextn_loss_weight: float = 0.3
 
 
 @dataclass(frozen=True)

@@ -181,9 +181,13 @@ class Model:
     decode_prefill: Callable[..., tuple] | None = None
     decode_step: Callable[..., tuple] | None = None
     decode_cache_shape: tuple[int, int, int] | None = None
-    # Auxiliary loss (MoE load balancing): when True, ``apply`` and the
-    # sharded applies accept ``return_aux=True`` and return
-    # (logits, aux); the train step adds ``aux_weight * aux``.
+    # When True, ``apply`` and the sharded applies accept
+    # ``return_aux=True`` and return (logits, aux), ``aux`` a mapping:
+    # the train step adds ``aux_weight * aux["loss"]`` (the load-balance
+    # loss of capacity-routed experts; with ``train=True`` the
+    # next-next-token module's term) and logs ``aux["counts"]`` where a
+    # per-token routed layer gives it; ``aux["routing"]`` is the expert
+    # ids such layers chose.
     has_aux: bool = False
     aux_weight: float = 0.0
     # True when ``apply(train=True)`` consumes ``dropout_key``. The
@@ -321,13 +325,44 @@ def _transformer(cfg: ModelConfig) -> Model:
     compute_dtype = jnp.dtype(cfg.compute_dtype)
 
     moe = cfg.num_experts > 0
-    aux_weight = cfg.moe_aux_weight
+    # the sizes beyond the plain block, each absent at its default
+    latent = cfg.kv_latent_dim > 0
+    routed = cfg.routed_experts > 0
+    held = (cfg.first_held_expert, cfg.held_experts or cfg.routed_experts)
+    sizes = None
+    if (latent or routed or cfg.ffn_dim or cfg.residual_streams > 1
+            or cfg.nextn_layers):
+        if moe and routed:
+            raise ValueError("model.num_experts (capacity routing) and "
+                             "model.routed_experts (per-token routing) "
+                             "name two feed-forwards for one block")
+        if routed and not (0 <= held[0] and held[0] + held[1]
+                           <= cfg.routed_experts
+                           and 0 < cfg.experts_per_token
+                           <= cfg.routed_experts and cfg.expert_ffn_dim > 0):
+            raise ValueError(
+                f"held experts {held} of {cfg.routed_experts}, "
+                f"{cfg.experts_per_token} a token, {cfg.expert_ffn_dim} "
+                "wide: not a share of a routed layer")
+        sizes = transformer.Sizes(
+            q_latent_dim=cfg.q_latent_dim, kv_latent_dim=cfg.kv_latent_dim,
+            qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
+            v_head_dim=cfg.v_head_dim, ffn_dim=cfg.ffn_dim,
+            routed_experts=cfg.routed_experts, held=held,
+            shared_experts=cfg.shared_experts,
+            expert_ffn_dim=cfg.expert_ffn_dim, dense_layers=cfg.dense_layers,
+            residual_streams=cfg.residual_streams,
+            nextn_layers=cfg.nextn_layers)
+    # what the train step adds of aux["loss"]: the load-balance loss at
+    # its weight; the next-next-token module's term carries its own
+    aux_weight = cfg.moe_aux_weight if moe else 1.0
 
     def init(key):
         return transformer.init(
             key, vocab_size=cfg.vocab_size, model_dim=cfg.model_dim,
             num_heads=cfg.num_heads, num_layers=cfg.num_layers,
-            max_seq_len=cfg.seq_len, num_experts=cfg.num_experts)
+            max_seq_len=cfg.seq_len, num_experts=cfg.num_experts,
+            sizes=sizes)
 
     if cfg.attention_impl == "flash":
         from ..ops.pallas_attention import (flash_attention,
@@ -369,13 +404,21 @@ def _transformer(cfg: ModelConfig) -> Model:
 
     def block_for(seq_axis: str | None = None, model_axis: str | None = None,
                   expert_axis: str | None = None, *,
-                  pipeline: str | None = None) -> transformer.Block:
-        """The configured layer under the given mesh axes (any may be
-        None: unsharded) — the one reader of the configuration's
-        attention and feed-forward choices, and the one place their
-        combinations are refused. ``pipeline``: the schedule
+                  pipeline: str | None = None,
+                  layer: int = 0) -> transformer.Block:
+        """The configured layer ``layer`` under the given mesh axes (any
+        may be None: unsharded) — the one reader of the configuration's
+        attention, feed-forward and residual choices, and the one place
+        their combinations are refused. ``pipeline``: the schedule
         (``"gpipe"`` / ``"1f1b"``) whose train step the block is for."""
         ring = seq_axis is not None and cfg.sp_attention == "ring"
+        if sizes is not None and (seq_axis or model_axis or expert_axis
+                                  or pipeline):
+            raise NotImplementedError(
+                "latent attention, per-token routing, the gated unit, "
+                "residual streams and the next-next-token module run "
+                "unsharded or data-parallel; no partition rule, sharded "
+                "apply or pipeline stage is written for them")
         if expert_axis is not None and not moe:
             raise ValueError("mesh has expert parallelism but the model "
                              "has no experts (model.num_experts == 0)")
@@ -433,11 +476,41 @@ def _transformer(cfg: ModelConfig) -> Model:
                 num_groups=cfg.moe_num_groups,
                 expert_axis=expert_axis, tp_axis=model_axis,
                 stats_axes=() if seq_axis is None else (seq_axis,))
+        projections, residual = None, transformer.PLAIN
+        if routed and layer >= cfg.dense_layers:
+            feed_forward = transformer.moe_feed_forward(
+                total=cfg.routed_experts, held=held,
+                top_k=cfg.experts_per_token, scaling=cfg.routed_scaling,
+                bias_rate=cfg.router_bias_rate)
+        elif cfg.ffn_dim:
+            feed_forward = transformer.gated_feed_forward
+        if latent:
+            projections = transformer.latent_projections(
+                num_heads=cfg.num_heads, qk_nope_dim=cfg.qk_nope_dim,
+                qk_rope_dim=cfg.qk_rope_dim, v_head_dim=cfg.v_head_dim,
+                rope_theta=cfg.rope_theta, rope_factor=cfg.rope_factor,
+                rope_original_len=cfg.rope_original_len or cfg.seq_len,
+                rope_beta_fast=cfg.rope_beta_fast,
+                rope_beta_slow=cfg.rope_beta_slow,
+                rope_mscale=cfg.rope_mscale,
+                rope_mscale_all_dim=cfg.rope_mscale_all_dim)
+        if cfg.residual_streams > 1:
+            residual = transformer.stream_residual(
+                cfg.residual_streams, iters=cfg.sinkhorn_iters,
+                eps=cfg.residual_eps, clamp=cfg.residual_clamp)
         return transformer.make_block(
             num_heads=cfg.num_heads, attention_fn=make_seq_attn(seq_axis),
-            model_axis=model_axis, feed_forward=feed_forward)
+            model_axis=model_axis, feed_forward=feed_forward,
+            projections=projections, residual=residual)
 
-    block = block_for()  # one device, or replicas of the whole model
+    # one device, or replicas of the whole model. Where the leading
+    # layers' feed-forward differs from the others', a block a layer,
+    # the last also the next-next-token module's
+    block = block_for()
+    if routed and cfg.dense_layers:
+        per_kind = {False: block, True: block_for(layer=cfg.dense_layers)}
+        block = tuple(per_kind[i >= cfg.dense_layers]
+                      for i in range(cfg.num_layers))
 
     def apply(params, x, *, train=False, dropout_key=None, return_aux=False):
         del dropout_key
@@ -445,7 +518,8 @@ def _transformer(cfg: ModelConfig) -> Model:
                                  compute_dtype=compute_dtype,
                                  remat=cfg.remat,
                                  remat_policy=cfg.remat_policy,
-                                 return_aux=return_aux)
+                                 return_aux=return_aux, train=train,
+                                 nextn_loss_weight=cfg.nextn_loss_weight)
 
     def sharded_apply_factory(seq_axis: str | None, model_axis: str | None,
                               expert_axis: str | None = None):
@@ -509,11 +583,15 @@ def _transformer(cfg: ModelConfig) -> Model:
                 compute_dtype=compute_dtype)
         return apply_1f1b
 
-    # Decode exports: dense-FFN causal LMs only (MoE routing is
-    # batch-statistics-shaped; an incremental one-token step has no
-    # well-defined group routing to run)
+    # Decode exports: the plain block only. Capacity routing
+    # (num_experts) is computed over groups of a sequence's tokens, which
+    # one incremental token does not have. Per-token routing would run a
+    # token at a time, but the step has no expert in its export, and the
+    # models that route so here attend through a latent: the step and
+    # the paged cache hold keys and values a head, with no latent row,
+    # no rotation and no absorbed projection (transformer._decode_attn)
     decode_prefill = decode_step_fn = decode_cache_shape = None
-    if not moe:
+    if not moe and sizes is None:
         def decode_prefill(params, tokens, positions=None):
             return transformer.prefill_with_kv(
                 params, tokens, block=block, positions=positions,
@@ -541,8 +619,12 @@ def _transformer(cfg: ModelConfig) -> Model:
                  decode_step=decode_step_fn,
                  decode_cache_shape=decode_cache_shape,
                  sharded_apply_factory=sharded_apply_factory,
-                 partition_rules=transformer_partition_rules(cfg.num_experts),
-                 has_aux=moe, aux_weight=aux_weight,
+                 partition_rules=(replicated_partition_rules
+                                  if sizes is not None else
+                                  transformer_partition_rules(
+                                      cfg.num_experts)),
+                 has_aux=moe or routed or cfg.nextn_layers > 0,
+                 aux_weight=aux_weight,
                  tp_param_specs=lambda axis, expert_axis=None:
                      transformer.param_partition_specs(
                          cfg.num_layers, axis, cfg.num_experts, expert_axis),

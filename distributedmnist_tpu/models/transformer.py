@@ -6,14 +6,19 @@ ring attention over the mesh's ``seq`` axis (ops/ring_attention.py) —
 has a first-class consumer, and so the aggregation disciplines can be
 exercised on a transformer-shaped allreduce payload.
 
-Pure init/apply over a param pytree, pre-norm blocks, learned
-positional embeddings, weight-tied LM head.
+Pure init/apply over a param pytree, pre-norm blocks; learned positional
+embeddings and a weight-tied LM head, or (a tree with no ``pos`` and a
+``head`` of its own) rotary positions inside the attention and an untied
+head.
 
 What a layer computes is decided in ONE place, :func:`make_block`: which
 attention (``local_self_attention`` single-device, the flash kernel, or
-a closure over ring/Ulysses attention under a seq-sharded shard_map),
-which feed-forward (the dense ReLU product, or a routed mixture of
-experts), and under which mesh axes (Megatron-style tensor parallelism
+a closure over ring/Ulysses attention under a seq-sharded shard_map)
+behind which projections (``wqkv``, or the latent ones of
+:func:`latent_projections`), which feed-forward (the dense ReLU product,
+a gated unit, or a routed mixture of experts), how a sublayer reads from
+and writes to the residual (:data:`PLAIN`: ``x + F(norm x)``, or
+:func:`stream_residual`), and under which mesh axes (Megatron-style tensor parallelism
 over a ``model_axis`` when params are sharded per
 :func:`param_partition_specs` — qkv/w1 column-parallel, wo/w2
 row-parallel with one psum per residual add, attention heads split
@@ -27,10 +32,12 @@ only their schedule. A new kind of feed-forward is one function
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec
 
@@ -42,9 +49,15 @@ Params = dict[str, Any]
 
 def init(key: jax.Array, vocab_size: int = 256, model_dim: int = 128,
          num_heads: int = 4, num_layers: int = 2,
-         max_seq_len: int = 512, num_experts: int = 0) -> Params:
+         max_seq_len: int = 512, num_experts: int = 0,
+         sizes: "Sizes | None" = None) -> Params:
     """``num_experts > 0`` makes every block's FFN a top-1-routed
-    mixture of experts (ops/moe.py) instead of a dense MLP."""
+    mixture of experts (ops/moe.py) instead of a dense MLP. ``sizes``
+    (:class:`Sizes`) adds the parameters of what it names; None is the
+    block above."""
+    if sizes is not None:
+        return _init_sized(key, vocab_size, model_dim, num_heads, num_layers,
+                           max_seq_len, sizes)
     assert model_dim % num_heads == 0
     keys = iter(jax.random.split(key, 4 + 5 * num_layers))
     scale = 0.02
@@ -75,6 +88,133 @@ def init(key: jax.Array, vocab_size: int = 256, model_dim: int = 128,
             blk["w1"] = truncated_normal_init(next(keys), (model_dim, ff), scale)
             blk["w2"] = truncated_normal_init(next(keys), (ff, model_dim), scale)
         params["blocks"].append(blk)
+    return params
+
+
+class Sizes(NamedTuple):
+    """The sizes of the mechanisms beyond the block above, as
+    ``core/config.py::ModelConfig`` names them; each absent at 0 (1 for
+    the streams). ``held`` is ``(first, count)`` of ``routed_experts``."""
+    q_latent_dim: int = 0
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    ffn_dim: int = 0
+    routed_experts: int = 0
+    held: tuple[int, int] = (0, 0)
+    shared_experts: int = 0
+    expert_ffn_dim: int = 0
+    dense_layers: int = 0
+    residual_streams: int = 1
+    nextn_layers: int = 0
+
+
+#: leaves that stay float32 in the forward whatever the compute dtype:
+#: a top-k and a Sinkhorn iteration amplify what a rounding changes
+_F32_LEAVES = ("router", "router_bias", "mix1", "mix2")
+
+
+def _init_mix(key: jax.Array, n: int, d: int) -> Params:
+    """One sublayer's three maps between ``n`` residual streams
+    (:func:`stream_residual`): ``phi`` [n·d, n + n + n²] for the read,
+    the write and the stream-to-stream map, their three scales
+    ``alpha`` at 0.01 and biases ``beta``, the stream-to-stream one so
+    that the map starts near the identity."""
+    beta = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
+                            (8.0 * jnp.eye(n, dtype=jnp.float32)).reshape(-1)])
+    return {"phi": truncated_normal_init(key, (n * d, 2 * n + n * n), 0.02),
+            "alpha": jnp.full((3,), 0.01, jnp.float32), "beta": beta}
+
+
+def _norm_scale(n: int) -> Params:
+    return {"scale": jnp.ones((n,), jnp.float32)}
+
+
+def _init_gated(key: jax.Array, lead: tuple, d: int, f: int) -> Params:
+    kg, ku, kd = jax.random.split(key, 3)
+    return {"w_gate": truncated_normal_init(kg, (*lead, d, f), 0.02),
+            "w_up": truncated_normal_init(ku, (*lead, d, f), 0.02),
+            "w_down": truncated_normal_init(kd, (*lead, f, d), 0.02)}
+
+
+def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
+                      routed: bool) -> Params:
+    keys = iter(jax.random.split(key, 12))
+    ones = _norm_scale
+    tn = lambda shape: truncated_normal_init(next(keys), shape, 0.02)  # noqa: E731
+    blk = {"ln1": ones(d), "ln2": ones(d)}
+    if z.kv_latent_dim:
+        qk = z.qk_nope_dim + z.qk_rope_dim
+        blk.update(
+            wq_a=tn((d, z.q_latent_dim)), q_norm=ones(z.q_latent_dim),
+            wq_b=tn((z.q_latent_dim, heads, qk)),
+            wkv_a=tn((d, z.kv_latent_dim + z.qk_rope_dim)),
+            kv_norm=ones(z.kv_latent_dim),
+            wkv_b=tn((z.kv_latent_dim, heads, z.qk_nope_dim + z.v_head_dim)),
+            wo=tn((heads * z.v_head_dim, d)))
+    else:
+        blk.update(wqkv=tn((d, 3, d)), wo=tn((d, d)))
+    if z.residual_streams > 1:
+        blk["mix1"] = _init_mix(next(keys), z.residual_streams, d)
+        blk["mix2"] = _init_mix(next(keys), z.residual_streams, d)
+    if routed:
+        blk["router"] = tn((d, z.routed_experts))
+        # the selection bias: the loss's gradient does not reach it; it
+        # moves by each expert's load (ops.moe.balance_term)
+        blk["router_bias"] = truncated_normal_init(
+            next(keys), (z.routed_experts,), 0.03)
+        blk["experts"] = _init_gated(next(keys), (z.held[1],), d,
+                                     z.expert_ffn_dim)
+        if z.shared_experts:
+            blk["shared"] = _init_gated(
+                next(keys), (), d, z.shared_experts * z.expert_ffn_dim)
+    elif z.ffn_dim:
+        blk.update(_init_gated(next(keys), (), d, z.ffn_dim))
+    else:
+        blk.update(w1=tn((d, 4 * d)), w2=tn((4 * d, d)))
+    return blk
+
+
+def _init_sized(key: jax.Array, vocab_size: int, d: int, heads: int,
+                num_layers: int, max_seq_len: int, z: Sizes) -> Params:
+    """The tree of a model with :class:`Sizes`: no position table and an
+    untied ``head`` where the attention is latent (its positions are
+    rotary), the first ``dense_layers`` blocks with the dense
+    feed-forward and the rest routed, and under ``nextn`` the
+    next-next-token module: two norms, the ``[2d, d]`` projection, one
+    block of the routed kind and a final norm of its own."""
+    if z.nextn_layers > 1:
+        raise ValueError("only one next-next-token module is built "
+                         f"(nextn_layers={z.nextn_layers})")
+    keys = iter(jax.random.split(key, 6 + num_layers))
+    ones = _norm_scale
+    routed_at = lambda i: z.routed_experts > 0 and i >= z.dense_layers  # noqa: E731
+    params: Params = {
+        # at unit scale, fifty times the matrices': a token's own
+        # embedding then stays most of what its layers read. With the
+        # embedding at 0.02 too, the running mean of the values that
+        # attention adds (one vector for all late positions, four times
+        # the embedding's size) makes every token read alike, and a
+        # router sends them all to the same experts from step 1
+        "embed": truncated_normal_init(next(keys), (vocab_size, d), 1.0),
+        "blocks": [_init_sized_block(next(keys), d, heads, z, routed_at(i))
+                   for i in range(num_layers)],
+        "final_norm": ones(d),
+    }
+    if z.kv_latent_dim:
+        params["head"] = truncated_normal_init(next(keys), (d, vocab_size),
+                                               0.02)
+    else:
+        params["pos"] = truncated_normal_init(next(keys), (max_seq_len, d),
+                                              0.02)
+    if z.nextn_layers:
+        params["nextn"] = {
+            "norm_h": ones(d), "norm_e": ones(d),
+            "proj": truncated_normal_init(next(keys), (2 * d, d), 0.02),
+            "block": _init_sized_block(next(keys), d, heads, z,
+                                       routed_at(num_layers)),
+            "final_norm": ones(d)}
     return params
 
 
@@ -122,14 +262,114 @@ def _rms_norm(x: jax.Array, p: Params) -> jax.Array:
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * p["scale"]).astype(x.dtype)
 
 
+class Residual(NamedTuple):
+    """How a sublayer reads from and writes to the residual: ``read(x,
+    maps) -> (u, kept)`` gives the sublayer's input, ``write(kept, y)``
+    the residual after its output ``y``; ``start`` makes the residual of
+    the embedding and ``end`` what the final norm reads. ``maps`` is the
+    sublayer's own parameters of the rule (None where it has none)."""
+    read: Callable[[jax.Array, Any], tuple[jax.Array, Any]]
+    write: Callable[[Any, jax.Array], jax.Array]
+    start: Callable[[jax.Array], jax.Array]
+    end: Callable[[jax.Array], jax.Array]
+    streams: int = 1
+
+
+#: ``x + F(norm x)``: the sublayer reads the residual and adds to it
+PLAIN = Residual(read=lambda x, maps: (x, x), write=lambda x, y: x + y,
+                 start=lambda x: x, end=lambda x: x)
+
+
+def _sinkhorn(r: jax.Array, iters: int, eps: float, clamp: float) -> jax.Array:
+    """``r`` [n, n, tokens] → positive matrices whose rows and columns
+    sum to one: ``exp`` of the clipped entries, then ``iters`` times
+    rows and then columns divided by their sums plus ``eps``."""
+    m = jnp.exp(jnp.clip(r, -clamp, clamp))
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+    return m
+
+
+def _f32_product(x: jax.Array, phi: jax.Array) -> jax.Array:
+    """``einsum("ntd,ndk->kt")`` of the residual with a float32 ``phi``,
+    to float32's precision. A bfloat16 residual times the three
+    bfloat16 pieces a float32 number splits into is that product
+    exactly, in three passes of the MXU with no float32 copy of the
+    residual (six, and a copy, at ``HIGHEST``); the weight's gradient
+    goes through the leading piece."""
+    if x.dtype != jnp.bfloat16:
+        return jnp.einsum("ntd,ndk->kt", x.astype(jnp.float32), phi,
+                          precision=lax.Precision.HIGHEST)
+    hi = phi.astype(jnp.bfloat16)
+    rest = lax.stop_gradient(phi - hi.astype(jnp.float32))
+    mid = rest.astype(jnp.bfloat16)
+    low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    z = jnp.einsum("ntd,ndk->kt", x, jnp.concatenate([hi, mid, low], -1),
+                   preferred_element_type=jnp.float32)
+    return sum(jnp.split(z, 3, axis=0))
+
+
+def stream_residual(n: int, *, iters: int, eps: float,
+                    clamp: float) -> Residual:
+    """``n`` residual streams under three learned maps a sublayer
+    (manifold-constrained hyper-connections, arXiv:2512.24880, on
+    arXiv:2409.19606). The residual is ``[n, b, s, d]``, streams
+    outermost so that the two tiled dimensions stay ``s`` and ``d``. A
+    token's ``n·d`` values, RMS-normed without a scale, times ``phi``
+    (plus the biases, times the scales) give ``a`` [n], ``p`` [n] and
+    ``R`` [n, n]: the sublayer reads ``σ(a) · X``, and the residual
+    becomes ``SK(R) X + (2σ(p))ᵀ y``, ``SK`` the Sinkhorn iteration of
+    :func:`_sinkhorn`. All of it in float32, whatever the residual is
+    stored in, and what the sublayer reads stays float32 through its
+    norm: a router downstream is a top-k, and each rounding on its way
+    costs it near ties. It starts as ``n`` copies of the embedding and
+    ends as the sum of the streams."""
+    def read(x, maps):
+        _, b, s, d = x.shape
+        xf = x.astype(jnp.float32).reshape(n, b * s, d)
+        inv_rms = lax.rsqrt(jnp.mean(jnp.square(xf), axis=(0, 2)) + 1e-6)
+        z = _f32_product(x.reshape(n, b * s, d),
+                         maps["phi"].reshape(n, d, -1)) * inv_rms
+        alpha = maps["alpha"][np.repeat(np.arange(3), [n, n, n * n])]
+        z = alpha[:, None] * z + maps["beta"][:, None]
+        h_pre = jax.nn.sigmoid(z[:n])                        # [n, t]
+        h_post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+        h_res = _sinkhorn(z[2 * n:].reshape(n, n, -1), iters, eps, clamp)
+        # sums of n scaled streams, spelled out: a contraction over n
+        # with the tokens as its batch would be 8,192 tiny products
+        u = sum(h_pre[m, :, None] * xf[m] for m in range(n))
+        return u.reshape(b, s, d), (x, h_res, h_post)
+
+    def write(kept, y):
+        x, h_res, h_post = kept
+        _, b, s, d = x.shape
+        xf = x.astype(jnp.float32).reshape(n, b * s, d)
+        yf = y.astype(jnp.float32).reshape(b * s, d)
+        out = jnp.stack([
+            sum(h_res[i, m, :, None] * xf[m] for m in range(n))
+            + h_post[i, :, None] * yf for i in range(n)])
+        return out.reshape(x.shape).astype(x.dtype)
+
+    return Residual(
+        read=jax.named_scope("residual_mix")(read),
+        write=jax.named_scope("residual_mix")(write),
+        start=lambda x: jnp.broadcast_to(x, (n, *x.shape)),
+        end=lambda x: jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype),
+        streams=n)
+
+
 class Block(NamedTuple):
     """What one transformer layer computes, as its two pre-norm
     sublayers. :func:`make_block` builds it once; every forward below
     takes one and decides only its schedule."""
-    # attn(x, blk, return_kv=False) -> x, or (x, k, v) for the prefill
+    # attn(x, blk, return_kv=False, positions=None) -> x, or (x, k, v)
+    # for the prefill
     attn: Callable[..., Any]
     # ffn(x, blk) -> (x, aux)
-    ffn: Callable[[jax.Array, Params], tuple[jax.Array, jax.Array]]
+    ffn: Callable[[jax.Array, Params], tuple[jax.Array, Any]]
+    # how both read from and write to the residual
+    residual: Residual = PLAIN
 
 
 def _dense_ffn(h: jax.Array, blk: Params, *,
@@ -141,28 +381,141 @@ def _dense_ffn(h: jax.Array, blk: Params, *,
     return mlp, aux
 
 
+def gated_feed_forward(h: jax.Array, blk: Params):
+    """The gated SiLU unit over a block's ``w_gate``/``w_up``/``w_down``
+    for :func:`make_block`."""
+    from ..ops.moe import gated_unit
+    return (gated_unit(h.astype(blk["w_gate"].dtype), blk["w_gate"],
+                       blk["w_up"], blk["w_down"]),
+            jnp.zeros((), jnp.float32))
+
+
 def moe_feed_forward(**settings) -> Callable:
-    """A mixture-of-experts feed-forward for :func:`make_block`:
-    ``ops.moe.moe_ffn`` over a block's ``router``/``w1``/``w2`` with its
-    settings bound (``num_experts``, ``capacity_factor``,
+    """A mixture-of-experts feed-forward for :func:`make_block`.
+
+    With ``held``: per-token routing as it is deployed
+    (``ops.moe.routed_ffn`` over a block's ``router``/``router_bias``/
+    ``experts``/``shared``): ``total`` experts routed over, ``held =
+    (first, count)`` of them computed here, ``top_k`` a token, gates
+    scaled by ``scaling``, none dropped, the selection bias moved toward
+    even load at ``bias_rate``. Its ``aux`` is a mapping: ``routing`` [b,
+    s, k] int32, the ids chosen, ``counts`` [count], the pairs each held
+    expert took, and ``loss``, the zero-valued term that carries the
+    bias's update.
+
+    Without: ``ops.moe.moe_ffn`` over a block's ``router``/``w1``/``w2``
+    with its settings bound (``num_experts``, ``capacity_factor``,
     ``router_top_k``, ``num_groups``, and the mesh axes: ``expert_axis``
     the experts are sharded over, ``tp_axis`` every expert's hidden dim
     is split over — one fused psum covers both — and ``stats_axes``, the
     extra token-sharding axes (the seq axis under SP×MoE) the
     load-balance statistics average over, so the aux loss is the
     full-token value replicated on every shard)."""
-    from ..ops.moe import moe_ffn
+    from ..ops.moe import moe_ffn, routed_ffn
+
+    if "held" in settings:
+        @jax.named_scope("moe")
+        def routed(h, blk):
+            out, ids, counts, balance = routed_ffn(
+                h, blk["router"], blk["router_bias"], blk["experts"],
+                blk.get("shared"), **settings)
+            return out, {"routing": ids, "counts": counts, "loss": balance}
+        return routed
 
     def feed_forward(h, blk):
         return moe_ffn(h, blk["router"], blk["w1"], blk["w2"], **settings)
     return feed_forward
 
 
+def _yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                   beta_fast: float, beta_slow: float):
+    """Rotary frequencies [dim/2] under YaRN (arXiv:2309.00071, as
+    DeepSeek-V3 computes them): each frequency a blend of ``f`` and
+    ``f / factor`` by a linear ramp between the two correction
+    dimensions, the ones that turn ``beta_fast`` and ``beta_slow`` times
+    over ``original_len`` positions."""
+    f = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor == 1.0:
+        return f
+
+    def correction_dim(turns):
+        return (dim * math.log(original_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return f / factor * ramp + f * (1 - ramp)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rotate(x: jax.Array, positions: jax.Array, inv_freq, mscale: float):
+    """Rotary embedding of ``x`` [..., s, heads, dim] at ``positions``
+    [s], pairs laid out as halves: column ``i`` turns with column ``i +
+    dim/2``. In float32."""
+    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
+        inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(angle) * mscale)[:, None, :]
+    sin = (jnp.sin(angle) * mscale)[:, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
+                       v_head_dim: int, rope_theta: float = 10000.0,
+                       rope_factor: float = 1.0, rope_original_len: int = 0,
+                       rope_beta_fast: float = 32.0,
+                       rope_beta_slow: float = 1.0, rope_mscale: float = 1.0,
+                       rope_mscale_all_dim: float = 0.0) -> Callable:
+    """The projections of latent attention for :func:`make_block`
+    (arXiv:2405.04434 §2.1): ``project(h, blk, positions) -> (q, k, v,
+    scale)`` in the [b, s, heads, width] layout, over a block's
+    ``wq_a``/``q_norm``/``wq_b``/``wkv_a``/``kv_norm``/``wkv_b``. Query
+    and key are ``qk_nope_dim + qk_rope_dim`` wide, the rotated part
+    last and the key's one row shared by all heads; the value
+    ``v_head_dim``. ``scale`` is ``(qk width)^-½`` times the square of
+    YaRN's ``mscale_all_dim`` factor."""
+    inv_freq = _yarn_inv_freq(qk_rope_dim, rope_theta, rope_factor,
+                              rope_original_len, rope_beta_fast,
+                              rope_beta_slow)
+    cos_scale = (_yarn_mscale(rope_factor, rope_mscale)
+                 / _yarn_mscale(rope_factor, rope_mscale_all_dim))
+    scale = ((qk_nope_dim + qk_rope_dim) ** -0.5
+             * _yarn_mscale(rope_factor, rope_mscale_all_dim) ** 2)
+
+    def project(h, blk, positions):
+        b, s, _ = h.shape
+        if positions is None:
+            positions = jnp.arange(s)
+        q = jnp.einsum("bsr,rhe->bshe", _rms_norm(h @ blk["wq_a"],
+                                                   blk["q_norm"]),
+                       blk["wq_b"])
+        kv_a = h @ blk["wkv_a"]
+        latent, k_rope = kv_a[..., :-qk_rope_dim], kv_a[..., -qk_rope_dim:]
+        kv = jnp.einsum("bsr,rhe->bshe", _rms_norm(latent, blk["kv_norm"]),
+                        blk["wkv_b"])
+        rot = functools.partial(_rotate, positions=positions,
+                                inv_freq=inv_freq, mscale=cos_scale)
+        q = jnp.concatenate([q[..., :qk_nope_dim],
+                             rot(q[..., qk_nope_dim:])], axis=-1)
+        k_rope = jnp.broadcast_to(rot(k_rope[:, :, None, :]),
+                                  (b, s, num_heads, qk_rope_dim))
+        k = jnp.concatenate([kv[..., :qk_nope_dim], k_rope], axis=-1)
+        return q, k, kv[..., qk_nope_dim:], scale
+    return project
+
+
 def make_block(*, num_heads: int, attention_fn: Callable | None = None,
                model_axis: str | None = None,
-               feed_forward: Callable | None = None) -> Block:
-    """The one place a layer's kind is decided: which attention, which
-    feed-forward, under which mesh axes.
+               feed_forward: Callable | None = None,
+               projections: Callable | None = None,
+               residual: Residual = PLAIN) -> Block:
+    """The one place a layer's kind is decided: which attention behind
+    which projections, which feed-forward, which residual rule, under
+    which mesh axes.
 
     ``attention_fn``: ``local_self_attention`` when None, the flash
     kernel, or a closure over ring/Ulysses attention under a seq-sharded
@@ -178,36 +531,64 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
 
     ``feed_forward``: ``(h, blk) -> (mlp, aux)`` on the normed residual
     ``h``, returning the WHOLE residual delta (a callable that shards
-    its product sums it itself, as ``moe_ffn`` does) and a scalar
-    auxiliary loss. None is the dense ReLU product over ``w1``/``w2``;
-    :func:`moe_feed_forward` is the routed one. Its ``aux`` is the mean
+    its product sums it itself, as ``moe_ffn`` does) and an ``aux``: a
+    scalar auxiliary loss, or a mapping (``routing``, ``counts``) from
+    the per-token routed one. None is the dense ReLU product over
+    ``w1``/``w2``; :func:`gated_feed_forward` the gated unit;
+    :func:`moe_feed_forward` the routed ones. The scalar is the mean
     per-group load-balance loss of this block's routing (linear across
     blocks/ticks/shards: forwards sum over layers and average over
     microbatches), kept by a forward only where the block's parameters
     hold a ``router``.
+
+    ``projections``: ``(h, blk, positions) -> (q, k, v, scale)`` in the
+    [b, s, heads, width] layout; None is ``wqkv`` split in three
+    (:func:`latent_projections` is the other). A value narrower than
+    the key is padded with zero columns up to it for the kernel, and the
+    output cut back.
+
+    ``residual``: the :class:`Residual` rule of both sublayers,
+    :data:`PLAIN` or :func:`stream_residual`; a sublayer's maps are the
+    block's ``mix1`` (attention) and ``mix2`` (feed-forward).
     """
     attention = attention_fn or local_self_attention
     if feed_forward is None:
         feed_forward = functools.partial(_dense_ffn, model_axis=model_axis)
+    bshd = getattr(attention, "layout", "bhsd") == "bshd"
 
-    @jax.named_scope("attention")
-    def attn(x: jax.Array, blk: Params, return_kv: bool = False):
-        """Pre-norm attention sublayer: x + wo(attn(qkv(ln1(x)))).
-
-        ``return_kv``: also return this layer's K/V in the [b, s, h, hd]
-        residual layout (a free reshape) — what the decode prefill
-        scatters into the paged KV cache."""
-        b, d = x.shape[0], x.shape[-1]
+    def wqkv_projections(h, blk, positions):
+        del positions  # the embedding carries them
+        b, d = h.shape[0], h.shape[-1]
         # read here, inside the shard_map, not when the block is built
         m = lax.axis_size(model_axis) if model_axis else 1
         if num_heads % m != 0:
             raise ValueError(f"num_heads={num_heads} not divisible by "
                              f"model-parallel size {m}")
-        h_local, hd = num_heads // m, d // num_heads
-        h = _rms_norm(x, blk["ln1"])
         qkv = jnp.einsum("bsd,dte->bste", h, blk["wqkv"])  # e = d/m
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if getattr(attention, "layout", "bhsd") == "bshd":
+        return (*(qkv[:, :, i].reshape(b, -1, num_heads // m,
+                                       d // num_heads) for i in range(3)),
+                None)
+
+    project = projections or wqkv_projections
+
+    @jax.named_scope("attention")
+    def attn(x: jax.Array, blk: Params, return_kv: bool = False,
+             positions: jax.Array | None = None):
+        """Pre-norm attention sublayer: the residual after
+        wo(attn(project(ln1(read(x))))).
+
+        ``return_kv``: also return this layer's K/V in the [b, s, h, hd]
+        residual layout — what the decode prefill scatters into the
+        paged KV cache."""
+        u, kept = residual.read(x, blk.get("mix1"))
+        b = u.shape[0]
+        # the norm in what the rule read; the products in the weights'
+        q, k, v, scale = project(
+            _rms_norm(u, blk["ln1"]).astype(blk["wo"].dtype), blk, positions)
+        kw = {} if scale is None else {"scale": scale}
+        wide = q.shape[-1] - v.shape[-1]
+        vk = jnp.pad(v, ((0, 0),) * 3 + ((0, wide),)) if wide else v
+        if bshd:
             # kernel reads the residual layout directly ([b, s, h, hd] is
             # a free reshape of [b, s, e]) — no head transpose on either
             # side. At the flash bench shape the transposes a bhsd
@@ -215,80 +596,137 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
             # (A fully fused qkv-packed kernel input was also measured:
             # the strided k/v lane reads cost MORE than the slice copies
             # they save.)
-            bshd = lambda t: t.reshape(b, -1, h_local, hd)
-            o = attention(bshd(q), bshd(k), bshd(v)).reshape(
-                b, -1, h_local * hd)
+            o = attention(q, k, vk, **kw)
         else:
-            def heads(t):
-                return t.reshape(b, -1, h_local, hd).transpose(0, 2, 1, 3)
-
-            o = attention(heads(q), heads(k), heads(v))
-            o = o.transpose(0, 2, 1, 3).reshape(b, -1, h_local * hd)
-        proj = o @ blk["wo"]  # row-parallel: partial sum of the full d
+            heads = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+            o = attention(heads(q), heads(k), heads(vk), **kw)
+            o = o.transpose(0, 2, 1, 3)
+        if wide:
+            o = o[..., :v.shape[-1]]
+        # row-parallel: partial sum of the full d
+        proj = o.reshape(b, -1, o.shape[2] * o.shape[3]) @ blk["wo"]
         if model_axis:
             proj = lax.psum(proj, model_axis)
-        out = x + proj
+        out = residual.write(kept, proj)
         if return_kv:
-            return (out, k.reshape(b, -1, h_local, hd),
-                    v.reshape(b, -1, h_local, hd))
+            return out, k, v
         return out
 
     @jax.named_scope("ffn")
-    def ffn(x: jax.Array, blk: Params) -> tuple[jax.Array, jax.Array]:
-        """Pre-norm FFN sublayer: x + feed_forward(ln2(x)), aux."""
-        mlp, aux = feed_forward(_rms_norm(x, blk["ln2"]), blk)
-        return x + mlp, aux
+    def ffn(x: jax.Array, blk: Params) -> tuple[jax.Array, Any]:
+        """Pre-norm FFN sublayer: the residual after
+        feed_forward(ln2(read(x))), aux."""
+        u, kept = residual.read(x, blk.get("mix2"))
+        mlp, aux = feed_forward(_rms_norm(u, blk["ln2"]), blk)
+        return residual.write(kept, mlp), aux
 
-    return Block(attn, ffn)
+    return Block(attn, ffn, residual)
 
 
-def apply(params: Params, tokens: jax.Array, *, block: Block,
+def apply(params: Params, tokens: jax.Array, *,
+          block: "Block | tuple[Block, ...]",
           positions: jax.Array | None = None,
           compute_dtype=jnp.bfloat16, remat: bool = False,
           remat_policy: str = "full",
-          return_aux: bool = False) -> jax.Array:
+          return_aux: bool = False, train: bool = False,
+          nextn_loss_weight: float = 0.0) -> jax.Array:
     """tokens [batch, seq] int32 → logits [batch, seq, vocab] float32
-    through ``block`` (:func:`make_block`), layer after layer.
+    through ``block`` (:func:`make_block`), layer after layer; a tuple
+    gives each layer its own (the last also serves the next-next-token
+    module where the tree has one).
 
     ``positions`` (global positions of this shard's tokens) must be
     passed when the sequence is sharded; defaults to arange(seq).
-    ``return_aux``: also return the summed load-balancing aux loss.
+    ``return_aux``: also return the mapping ``aux``: ``loss``, the term
+    the train step adds to the next-token loss (the summed
+    load-balancing loss; with ``train`` and a ``nextn`` module in the
+    tree, ``nextn_loss_weight`` times the module's loss, which nothing
+    else runs), and from per-token routed layers ``routing`` [layers, b,
+    s, k] and ``counts`` [layers, held] (the module's layer last, when
+    it ran). The flag adds outputs and changes nothing else.
     """
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
     p = _cast(params, compute_dtype)
-    x = _embed(p, tokens, positions)
-
-    def layer(x, blk):
-        return block.ffn(block.attn(x, blk), blk)
-
-    if remat:
-        if remat_policy == "save_attn":
-            # Selective remat: the FFN sublayer (and its norms)
-            # recomputes in the backward, but the attention sublayer
-            # stays OUTSIDE the checkpoint, so the flash kernel's
-            # custom-vjp residuals (q/k/v/out/lse) remain resident and
-            # the backward never re-runs the attention forward. Costs
-            # O(b·s·d) extra bytes per layer over full remat; at the
-            # S=8192 long-context bench it buys 1.14x tokens/sec.
-            ffn_ckpt = jax.checkpoint(block.ffn)
-
-            def layer(x, blk):  # noqa: F811 — policy-selected body
-                return ffn_ckpt(block.attn(x, blk), blk)
-        elif remat_policy == "full":
-            # trade one extra forward per block for O(layer-boundary)
-            # activation memory — the long-sequence HBM lever
-            layer = jax.checkpoint(layer)
-        else:
-            raise ValueError(f"unknown remat_policy {remat_policy!r} "
-                             "(expected 'full' or 'save_attn')")
+    blocks = (list(block) if not isinstance(block, Block)
+              else [block] * len(p["blocks"]))
+    layers = {id(bk): _layer(bk, positions, remat, remat_policy)
+              for bk in blocks}
+    x = blocks[0].residual.start(_embed(p, tokens, positions))
     aux_total = jnp.zeros((), jnp.float32)
-    for blk in p["blocks"]:
-        x, aux = layer(x, blk)
-        aux_total = aux_total + aux
-    logits = _head(p, x)
-    return (logits, aux_total) if return_aux else logits
+    routed = []
+    for bk, blk in zip(blocks, p["blocks"]):
+        x, aux = layers[id(bk)](x, blk)
+        aux_total = aux_total + _loss_of(aux, routed)
+    h = blocks[-1].residual.end(x)
+    logits = _head(p, h)
+    if train and "nextn" in p:
+        loss, aux = _nextn_loss(p, h, tokens, layers[id(blocks[-1])],
+                                blocks[-1].residual)
+        aux_total = (aux_total + nextn_loss_weight * loss
+                     + _loss_of(aux, routed))
+    if not return_aux:
+        return logits
+    stacked = ({k: jnp.stack([a[k] for a in routed]) for k in routed[0]}
+               if routed else {})
+    return logits, {"loss": aux_total, **stacked}
+
+
+def _loss_of(aux, routed: list):
+    """A feed-forward's ``aux`` as the term it adds to the loss; a
+    mapping (per-token routing) leaves the rest of itself on ``routed``."""
+    if not isinstance(aux, dict):
+        return aux
+    routed.append({k: v for k, v in aux.items() if k != "loss"})
+    return aux["loss"]
+
+
+def _layer(block: Block, positions, remat: bool, remat_policy: str):
+    """One layer of ``block`` under the recomputation policy."""
+    def layer(x, blk):
+        return block.ffn(block.attn(x, blk, positions=positions), blk)
+
+    if not remat:
+        return layer
+    if remat_policy == "save_attn":
+        # Selective remat: the FFN sublayer (and its norms)
+        # recomputes in the backward, but the attention sublayer
+        # stays OUTSIDE the checkpoint, so the flash kernel's
+        # custom-vjp residuals (q/k/v/out/lse) remain resident and
+        # the backward never re-runs the attention forward. Costs
+        # O(b·s·d) extra bytes per layer over full remat; at the
+        # S=8192 long-context bench it buys 1.14x tokens/sec.
+        ffn_ckpt = jax.checkpoint(block.ffn)
+        return lambda x, blk: ffn_ckpt(
+            block.attn(x, blk, positions=positions), blk)
+    if remat_policy == "full":
+        # trade one extra forward per block for O(layer-boundary)
+        # activation memory — the long-sequence HBM lever
+        return jax.checkpoint(layer)
+    raise ValueError(f"unknown remat_policy {remat_policy!r} "
+                     "(expected 'full' or 'save_attn')")
+
+
+@jax.named_scope("mtp")
+def _nextn_loss(p: Params, h: jax.Array, tokens: jax.Array, layer,
+                residual: Residual):
+    """The next-next-token module (arXiv:2412.19437 §2.2, depth 1) on
+    the trunk's output ``h`` [b, s, d] (before the final norm): position
+    ``i`` joins ``norm(h_i)`` and ``norm(Emb(t_{i+1}))`` through ``proj``,
+    runs one layer and its own final norm, and predicts ``t_{i+2}``
+    through the shared head. Returns its mean cross-entropy over the
+    positions that have such a target, and the layer's ``aux``. The
+    last position is given ``t_0`` for want of a successor: no position
+    before it attends to it, and it has no target."""
+    m = p["nextn"]
+    joined = jnp.concatenate(
+        [_rms_norm(h, m["norm_h"]),
+         _rms_norm(p["embed"][jnp.roll(tokens, -1, axis=1)], m["norm_e"])],
+        axis=-1) @ m["proj"]
+    x, aux = layer(residual.start(joined), m["block"])
+    logits = _head(p, residual.end(x), m["final_norm"])
+    return loss_fn(logits[:, :-1], tokens[:, 1:]), aux
 
 
 # Device scopes (obsv/spans.py SCOPES): every HLO operation carries the
@@ -297,20 +735,40 @@ def apply(params: Params, tokens: jax.Array, *, block: Block,
 
 @jax.named_scope("cast")
 def _cast(params: Params, compute_dtype) -> Params:
-    """The stored weights in the compute dtype, once per program."""
-    return jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    """The stored weights in the compute dtype, once per program; the
+    leaves of :data:`_F32_LEAVES` stay as they are stored."""
+    def cast(path, a):
+        keep = any(getattr(k, "key", None) in _F32_LEAVES for k in path)
+        return a if keep else a.astype(compute_dtype)
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 @jax.named_scope("embed")
 def _embed(p: Params, tokens: jax.Array, positions: jax.Array) -> jax.Array:
-    return p["embed"][tokens] + p["pos"][positions]
+    """The embedding, plus the learned position where the tree has a
+    table (one without rotates inside its attention)."""
+    x = p["embed"][tokens]
+    return x + p["pos"][positions] if "pos" in p else x
 
 
 @jax.named_scope("head")
-def _head(p: Params, x: jax.Array) -> jax.Array:
-    """Final norm and the tied head: [..., d] → float32 logits."""
-    x = _rms_norm(x, p["final_norm"])
-    return (x @ p["embed"].T).astype(jnp.float32)
+def _head(p: Params, x: jax.Array, final_norm: Params | None = None
+          ) -> jax.Array:
+    """Final norm (the tree's, or the one handed in) and the head: [...,
+    d] → float32 logits, through the tree's own ``head`` or the
+    embedding transposed."""
+    x = _rms_norm(x, final_norm or p["final_norm"])
+    w = p["head"] if "head" in p else p["embed"].T
+    return (x @ w).astype(jnp.float32)
+
+
+def _one_stream(block: Block, forward: str) -> None:
+    """The forwards that carry one ``[.., d]`` residual between stages
+    or into a cache refuse a rule with more streams."""
+    if block.residual.streams != 1:
+        raise NotImplementedError(
+            f"{forward} carries one residual stream; the block has "
+            f"{block.residual.streams} (stream_residual)")
 
 
 # ---------------------------------------------------------------------------
@@ -334,6 +792,7 @@ def prefill_with_kv(params: Params, tokens: jax.Array, *, block: Block,
     k [L, b, s, h, hd], v [L, b, s, h, hd]) with K/V in the compute
     dtype (the cache dtype). Dense-FFN models only (MoE routing is
     batch-shaped; the registry never exports decode for it)."""
+    _one_stream(block, "prefill_with_kv")
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
@@ -549,6 +1008,7 @@ def apply_pp(params: Params, tokens: jax.Array, *, block: Block,
     """
     from ..ops.pipeline import pipeline_apply
 
+    _one_stream(block, "apply_pp")
     b, s = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "
@@ -585,7 +1045,7 @@ def apply_pp(params: Params, tokens: jax.Array, *, block: Block,
         out = pipeline_apply(stage_fn, micro, stage_axis)
         aux = jnp.zeros((), jnp.float32)
     logits = _head(p, out.reshape(b, s, d))
-    return (logits, aux) if return_aux else logits
+    return (logits, {"loss": aux}) if return_aux else logits
 
 
 def stack_block_params_chunked(params: Params, num_stages: int,
@@ -666,6 +1126,7 @@ def grads_pp_1f1b(params: Params, tokens: jax.Array, labels: jax.Array, *,
     """
     from ..ops.pipeline import pipeline_1f1b_grads
 
+    _one_stream(block, "grads_pp_1f1b")
     b, s_loc = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "
@@ -785,6 +1246,7 @@ def apply_pp_1f1b(params: Params, tokens: jax.Array, *, block: Block,
     lockstep on every device every tick."""
     from ..ops.pipeline import pipeline_chunked_forward
 
+    _one_stream(block, "apply_pp_1f1b")
     b, s = tokens.shape
     if b % num_microbatches != 0:
         raise ValueError(f"batch {b} not divisible by "

@@ -263,13 +263,15 @@ _declare(EventSchema(
 # optional ``discipline`` field is the [k, timeout_ms] pair in force
 # when the step ran — written only when the adaptive controller is
 # armed, and the per-step observation the ``discipline`` replay
-# invariant matches licensed changes against.
+# invariant matches licensed changes against. ``expert_counts``: from a
+# model with per-token routed layers, the (token, expert) pairs each
+# expert this chip holds took in the step, a row a routed layer.
 _declare(EventSchema(
     STEP,
     required=("step", "time", "loss", "train_acc", "lr",
               "updates_applied", "num_contributors", "examples_per_sec",
               "flags"),
-    optional=("discipline",),
+    optional=("discipline", "expert_counts"),
 ))
 
 # Checkpoint-save marker.  Deliberately ``at_step``, NOT ``step``: the

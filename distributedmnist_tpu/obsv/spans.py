@@ -105,9 +105,15 @@ PREFETCH_PUT = "dml.prefetch.put"
 #: embed, attention, ffn, head; in the decode step cache_write and
 #: cache_gather inside attention; loss), servesvc/kv_cache.py
 #: (cache_write), parallel/api.py and ops/masked_psum.py (aggregate,
-#: update, timing)
+#: update, timing). Opened where the block is made, inside attention and
+#: ffn: residual_mix (a stream residual's maps, Sinkhorn iteration, read
+#: and write) and moe (router, sort, grouped product, combine, shared
+#: expert); mtp is the next-next-token module, whose layer's scopes nest
+#: in it. Read by: latent_attention_ms_per_step, moe_ms_per_step,
+#: residual_mix_ms_per_step, mtp_ms_per_step
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
-          "ffn", "head", "loss", "aggregate", "update", "timing")
+          "ffn", "head", "loss", "aggregate", "update", "timing",
+          "residual_mix", "moe", "mtp")
 
 #: a host span: ``with span(SERVE_STREAM, id=...):``
 span = jax.profiler.TraceAnnotation

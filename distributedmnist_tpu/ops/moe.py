@@ -247,3 +247,220 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w1: jax.Array, w2: jax.Array,
     if reduce:
         aux = lax.pmean(aux, reduce)
     return out, aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Routing as it is deployed: per-token top-k of many experts with a
+# selection bias, none dropped, over the share of experts this chip holds
+# ---------------------------------------------------------------------------
+
+#: rows of one tile of the grouped product: every held expert's pairs
+#: are padded to a whole number of tiles, so one tile meets one expert's
+#: weights
+TILE_ROWS = 512
+
+
+def gated_unit(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+               w_down: jax.Array) -> jax.Array:
+    """``(silu(x W_g) * x W_u) W_d``: the gated feed-forward unit, of an
+    expert or of a dense layer."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def route_tokens(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+                 top_k: int, scaling: float):
+    """Sigmoid routing with a selection bias (arXiv:2412.19437 §2.1.2,
+    ``noaux_tc`` with one group): ``x`` [t, d] → the ``top_k`` expert ids
+    [t, k] with the largest ``sigmoid(x W_r) + bias`` and their gates
+    [t, k] float32, the chosen scores WITHOUT the bias renormalised to
+    sum to ``scaling``. Scores in float32 at full matrix precision: a
+    top-k is a discontinuity. The loss's gradient does not reach the
+    bias (:func:`balance_term` moves it)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, ids = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), gates * scaling
+
+
+def balance_term(ids: jax.Array, bias: jax.Array, rate: float) -> jax.Array:
+    """The selection bias's update by load (arXiv:2412.19437 §2.1.2, from
+    arXiv:2408.15664) as a term of the loss that is always zero and whose
+    gradient in the bias is ``rate`` times the sign of each expert's load
+    less the mean load: gradient descent then lowers the bias of an
+    expert that took more than its share of ``ids`` [t, k] and raises
+    the others', by ``rate`` times the learning rate a step."""
+    total = bias.shape[0]
+    load = jnp.sum(ids[..., None] == jnp.arange(total), axis=(0, 1))
+    excess = jnp.sign(load - ids.size / total).astype(jnp.float32)
+    bias = bias.astype(jnp.float32)
+    return rate * jnp.sum(excess * (bias - lax.stop_gradient(bias)))
+
+
+def _plan(ids: jax.Array, first: int, count: int, tile: int):
+    """Where each (token, expert) pair that lands on a held expert goes
+    in a buffer in which every held expert's pairs are contiguous and
+    start on a tile boundary. ``ids`` [t, k] → ``row_pair`` [rows]: the
+    pair in each row (``t·k`` for an empty row); ``pair_row`` [t·k]: the
+    row of each pair (``rows`` for a pair on an expert not held);
+    ``tile_expert`` [rows/tile]: whose weights each tile meets;
+    ``tiles``: how many tiles hold anything; ``counts`` [count]: pairs
+    per held expert. ``rows`` is the worst case, every pair held, so
+    nothing is ever dropped; the work done follows ``tiles``."""
+    pairs = ids.size
+    rows = (-(-pairs // tile) + count) * tile
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)             # pairs by expert
+    counts = jnp.sum(key[:, None] == jnp.arange(count + 1)[None, :],
+                     axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts              # in sorted order
+    padded = -(-counts[:count] // tile) * tile
+    ends = jnp.cumsum(padded)                         # in the buffer
+    sorted_key = key[order]
+    offset = jnp.append(ends - padded, rows)[sorted_key]
+    dest = jnp.where(sorted_key < count,
+                     offset + jnp.arange(pairs) - starts[sorted_key], rows)
+    row_pair = jnp.full((rows,), pairs, jnp.int32).at[dest].set(
+        order.astype(jnp.int32), mode="drop")
+    pair_row = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        dest.astype(jnp.int32))
+    tile_expert = jnp.sum(
+        (jnp.arange(rows // tile) * tile)[:, None] >= ends[None, :],
+        axis=1, dtype=jnp.int32)
+    return (row_pair, pair_row, jnp.minimum(tile_expert, count - 1),
+            ends[-1] // tile, counts[:count])
+
+
+def _zeros(shape, dtype, *like) -> jax.Array:
+    """Zeros that vary over the mesh axes ``like`` vary over: what a loop
+    carries has to enter it as device-varying as its body leaves it."""
+    vma = tuple(sorted(set().union(*(jax.typeof(a).vma for a in like))))
+    z = jnp.zeros(shape, dtype)
+    return lax.pcast(z, vma, to="varying") if vma else z
+
+
+def _rows(table: jax.Array, index: jax.Array) -> jax.Array:
+    """``table[index]`` where index ``len(table)`` reads zeros."""
+    return jnp.take(table, index, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _held_experts(x, gates, weights, plan):
+    return _held_experts_fwd(x, gates, weights, plan)[0]
+
+
+def _tile_rows(plan, t, tile, top_k):
+    row_pair = lax.dynamic_slice_in_dim(plan[0], t * tile, tile)
+    return row_pair, row_pair // top_k
+
+
+def _held_experts_fwd(x, gates, weights, plan):
+    """``x`` [t, d], ``gates`` [t, k] → Σ over a token's pairs on held
+    experts of gate · expert(x), as one product grouped by expert: a
+    loop over the tiles that hold anything, each gathering its rows of
+    ``x``, meeting one expert's weights and writing its rows of the
+    buffer; then every pair reads its row back."""
+    row_pair, pair_row, tile_expert, tiles, _ = plan
+    tile, top_k = TILE_ROWS, gates.shape[1]
+    w_gate, w_up, w_down = weights
+
+    def body(t, ys):
+        e = tile_expert[t]
+        _, token = _tile_rows(plan, t, tile, top_k)
+        yt = gated_unit(_rows(x, token), w_gate[e], w_up[e], w_down[e])
+        return lax.dynamic_update_slice_in_dim(ys, yt.astype(ys.dtype),
+                                               t * tile, axis=0)
+
+    ys = lax.fori_loop(0, tiles, body, _zeros(
+        (row_pair.shape[0], x.shape[1]), x.dtype, x, *weights))
+    per_pair = _rows(ys, pair_row).reshape(*gates.shape, -1)
+    out = jnp.sum(gates[:, :, None] * per_pair.astype(jnp.float32), axis=1)
+    return out.astype(x.dtype), (x, gates, weights, plan)
+
+
+def _held_experts_bwd(saved, dy):
+    """Every direction is a gather: a row's cotangent is its token's
+    times its gate, a token's is the sum over its pairs' rows. Each tile
+    computes its expert's unit again and takes its vector-Jacobian
+    product; the weights' cotangents add up in float32."""
+    x, gates, weights, plan = saved
+    row_pair, pair_row, tile_expert, tiles, _ = plan
+    tile, top_k = TILE_ROWS, gates.shape[1]
+    flat_gates = gates.reshape(-1)
+
+    def body(t, carry):
+        dxs, dgs, dws = carry
+        e = tile_expert[t]
+        pair, token = _tile_rows(plan, t, tile, top_k)
+        w_e = jax.tree.map(lambda w: w[e], weights)
+        yt, vjp = jax.vjp(gated_unit, _rows(x, token), *w_e)
+        dyt = _rows(dy, token).astype(jnp.float32)
+        dgt = jnp.sum(dyt * yt.astype(jnp.float32), axis=-1)
+        dxt, *dwt = vjp((dyt * _rows(flat_gates, pair)[:, None])
+                        .astype(yt.dtype))
+        dws = jax.tree.map(
+            lambda acc, d: acc.at[e].add(d.astype(jnp.float32)),
+            dws, tuple(dwt))
+        return (lax.dynamic_update_slice_in_dim(dxs, dxt, t * tile, axis=0),
+                lax.dynamic_update_slice_in_dim(dgs, dgt, t * tile, axis=0),
+                dws)
+
+    rows = row_pair.shape[0]
+    like = (x, dy, gates, *weights)
+    dxs, dgs, dws = lax.fori_loop(0, tiles, body, (
+        _zeros((rows, x.shape[1]), x.dtype, *like),
+        _zeros((rows,), jnp.float32, *like),
+        jax.tree.map(lambda w: _zeros(w.shape, jnp.float32, *like),
+                     weights)))
+    dx = jnp.sum(_rows(dxs, pair_row).reshape(*gates.shape, -1)
+                 .astype(jnp.float32), axis=1).astype(x.dtype)
+    dgates = _rows(dgs, pair_row).reshape(gates.shape)
+    return (dx, dgates, jax.tree.map(lambda d, w: d.astype(w.dtype),
+                                     dws, weights), None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def routed_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+               experts: dict, shared: dict | None, *, total: int,
+               held: tuple[int, int], top_k: int, scaling: float,
+               bias_rate: float = 0.0):
+    """The routed feed-forward of one chip that holds ``held = (first,
+    count)`` of a layer's ``total`` experts: ``x`` [b, s, d] is routed
+    over all ``total`` (:func:`route_tokens`), the pairs that land on
+    held experts are sorted by expert (:func:`_plan`) and go through one
+    grouped product with **no pair dropped and no capacity**, and the
+    result is that partial sum plus the shared expert, which every chip
+    computes alike. The chips that hold the other experts are not stood
+    in for: summing the partial sums of every share gives the whole
+    layer once the shared expert is counted once.
+
+    Returns ``(out [b, s, d], ids [b, s, k] int32, counts [count],
+    balance)``: ``counts`` the pairs each held expert took, ``balance``
+    :func:`balance_term` at ``bias_rate`` (0 holds the bias constant)."""
+    if router_w.shape[1] != total or experts["w_gate"].shape[0] != held[1]:
+        raise ValueError(
+            f"router over {router_w.shape[1]} experts and "
+            f"{experts['w_gate'].shape[0]} held do not match total={total}, "
+            f"held={held}")
+    b, s, d = x.shape
+    flat = x.reshape(b * s, d)
+    # routed on what it is handed, float32 where the block keeps its
+    # norm there; the experts' products in their weights' dtype
+    ids, gates = route_tokens(flat, router_w, bias, top_k, scaling)
+    flat = flat.astype(experts["w_gate"].dtype)
+    plan = _plan(ids, held[0], held[1], TILE_ROWS)
+    out = _held_experts(flat, gates,
+                        (experts["w_gate"], experts["w_up"],
+                         experts["w_down"]), plan)
+    if shared is not None:
+        out = out + gated_unit(flat, shared["w_gate"], shared["w_up"],
+                               shared["w_down"])
+    balance = (balance_term(ids, bias, bias_rate) if bias_rate
+               else jnp.zeros((), jnp.float32))
+    return out.reshape(b, s, d), ids.reshape(b, s, top_k), plan[4], balance

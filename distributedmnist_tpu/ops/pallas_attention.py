@@ -139,10 +139,16 @@ _TUNED_BLOCKS = (
 )
 
 
-def _auto_blocks(s: int) -> tuple[int, int]:
+def _auto_blocks(s: int, d: int = _LANE) -> tuple[int, int]:
+    """The table's blocks for ``s``; a head wider than one lane tile
+    (padded to two) holds blocks twice as large in VMEM, and
+    (1024, 1024) then passes the scoped limit in the dkv kernel by 1 MB
+    at compile time: such a head takes at most 512. Chosen to fit, not
+    swept."""
     for bound, blocks in _TUNED_BLOCKS:
         if s <= bound:
-            return blocks
+            return blocks if d <= _LANE else tuple(min(b, 512)
+                                                   for b in blocks)
     raise AssertionError  # unreachable: table ends with a sentinel
 
 
@@ -521,7 +527,7 @@ def _resolve(s: int, d: int, scale, block_q, block_k, interpret):
         interpret = jax.default_backend() != "tpu"
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    auto_q, auto_k = _auto_blocks(s)
+    auto_q, auto_k = _auto_blocks(s, d)
     return scale, block_q or auto_q, block_k or auto_k, interpret
 
 

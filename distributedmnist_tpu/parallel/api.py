@@ -1029,20 +1029,25 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
     aux_w = model.aux_weight
 
     def local_loss(params, batch, dropout_key):
+        """-> (loss, (logits, counts)): ``counts`` the pairs each held
+        expert of each per-token routed layer took (``aux["counts"]``,
+        [layers, held]), None from a model that gives none."""
         if has_aux:
             logits, aux = model.apply(params, batch["image"], train=True,
                                       dropout_key=dropout_key,
                                       return_aux=True)
-            return model.loss(logits, batch["label"]) + aux_w * aux, logits
+            return (model.loss(logits, batch["label"]) + aux_w * aux["loss"],
+                    (logits, aux.get("counts")))
         logits = model.apply(params, batch["image"], train=True,
                              dropout_key=dropout_key)
-        return model.loss(logits, batch["label"]), logits
+        return model.loss(logits, batch["label"]), (logits, None)
 
     def local_loss_pp(params, batch, dropout_key):
         del dropout_key
         if has_aux:  # MoE: per-group aux, tick-accumulated (apply_pp)
             logits, aux = pp_apply(params, batch["image"], return_aux=True)
-            return model.loss(logits, batch["label"]) + aux_w * aux, logits
+            return (model.loss(logits, batch["label"]) + aux_w * aux["loss"],
+                    logits)
         logits = pp_apply(params, batch["image"])  # stage-replicated
         return model.loss(logits, batch["label"]), logits
 
@@ -1068,6 +1073,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             if with_aux:  # MoE: EP-only, SP×EP, or PP×SP×EP
                 logits, aux = apply_fn(params, tokens, positions,
                                        return_aux=True)
+                aux = aux["loss"]
             else:
                 logits = apply_fn(params, tokens, positions)  # [b, s_loc, V]
                 aux = 0.0
@@ -1130,9 +1136,11 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
         fwd_params = fwd_view(local_params)
 
         def compute_grads(mb_batch, dkey):
-            """(loss, train_acc, grads) for ONE microbatch — the
+            """(loss, train_acc, grads, counts) for ONE microbatch — the
             per-parallelism branch chain, shared by the single-shot and
-            the accumulation paths."""
+            the accumulation paths. ``counts``: ``local_loss``'s, None on
+            the other branches."""
+            counts = None
             if pp_1f1b_grads_fn is not None:
                 # fused 1F1B: the engine computes loss, accuracy and
                 # grads in one interleaved scan — no outer
@@ -1158,14 +1166,16 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
                     local_loss_pp, has_aux=True)(fwd_params, mb_batch, dkey)
                 train_acc = model.accuracy(logits, mb_batch["label"])
             else:
-                (loss, logits), grads = jax.value_and_grad(
+                (loss, (logits, counts)), grads = jax.value_and_grad(
                     local_loss, has_aux=True)(fwd_params, mb_batch, dkey)
                 train_acc = model.accuracy(logits, mb_batch["label"])
-            return loss, train_acc, grads
+            return loss, train_acc, grads, counts
 
+        expert_counts = None
         if accum == 1:
             dkey = prng.replica_key(state.root_key, "dropout", step, me)
-            loss, train_acc, grads = compute_grads(batch, dkey)
+            loss, train_acc, grads, expert_counts = compute_grads(batch,
+                                                                  dkey)
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         else:
             # microbatch scan: fp32 accumulation, one optimizer apply.
@@ -1187,7 +1197,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
 
             l_zero, a_zero, g_zero = jax.tree.map(
                 zeros_as, jax.eval_shape(
-                    compute_grads,
+                    lambda *a: compute_grads(*a)[:3],
                     jax.tree.map(lambda x: x[0], mb_batch),
                     prng.replica_key(state.root_key, "dropout", step, me)))
 
@@ -1196,7 +1206,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
                 one_batch, idx = xs
                 dkey = prng.replica_key(state.root_key, "dropout",
                                         step * accum + idx, me)
-                l, a, g = compute_grads(one_batch, dkey)
+                l, a, g, _ = compute_grads(one_batch, dkey)
                 g_acc = jax.tree.map(
                     lambda s, gi: s + gi.astype(jnp.float32), g_acc, g)
                 return (g_acc, l_acc + l, a_acc + a), None
@@ -1294,6 +1304,9 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
                 "flags": _gather_replicated(flag, axis, n),          # [n]
                 "applied": applied,
             }
+            if expert_counts is not None:
+                # pairs each held expert took, summed over the replicas
+                metrics["expert_counts"] = lax.psum(expert_counts, axis)
         return new_state, metrics
 
     @jax.named_scope("update")
@@ -1341,11 +1354,7 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             updates_applied=state.updates_applied + applied), applied
 
     mesh = topo.mesh
-    metrics_specs = {
-        "loss": P(), "train_acc": P(), "lr": P(), "num_contributors": P(),
-        "updates_applied": P(), "step_times_ms": P(), "flags": P(),
-        "applied": P(),
-    }
+    metrics_specs = P()  # every metric comes out replicated
     batch_spec = P(axis, seq_ax) if n_seq > 1 else P(axis)
     sharded = mesh_lib.shard_map(
         shard_fn, mesh=mesh,

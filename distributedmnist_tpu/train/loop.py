@@ -817,6 +817,11 @@ class Trainer:
                     # every pending step ran under the current pair
                     **({"discipline": self._discipline.params_list()}
                        if self._discipline is not None else {}),
+                    # per-token routed layers: the pairs each held
+                    # expert took this step, a row a layer
+                    **({"expert_counts":
+                        np.asarray(m["expert_counts"]).astype(int).tolist()}
+                       if "expert_counts" in m else {}),
                 }
                 self._sink_write(record)
                 final_metrics = record

@@ -198,7 +198,7 @@ def _dense_moe_update(cfg, batch):
             p, batch["image"], block=block, compute_dtype=jnp.float32,
             return_aux=True)
         return (transformer.loss_fn(logits, batch["label"])
-                + cfg.model.moe_aux_weight * aux)
+                + cfg.model.moe_aux_weight * aux["loss"])
 
     loss, grads = jax.value_and_grad(loss_fn)(params)
     return loss, jax.tree.map(lambda p, g: p - LR * g, params, grads)
