@@ -459,6 +459,97 @@ def phase_kernels(*, model: dict, batch: int, block_size: int = 16,
     out["decode_step_argmax_equal"] = bool(
         int(jnp.argmax(lg["paged"][0])) == int(jnp.argmax(lg["dense"][0])))
     out["latent_routed_block"] = _latent_routed_arm(model, batch)
+    out["wide_cache_rows"] = _wide_cache_arm(model, block_size, context)
+    return out
+
+
+def _wide_cache_arm(model: dict, block_size: int, context: int,
+                    steps: int = 8) -> dict:
+    """(e) the cache stored as wide as the device keeps its rows whole
+    (``stored_head_dim``, what the decode replica builds) against the
+    cache of the head's own width, at 64-wide heads, where a TPU's
+    default layout makes the block index minor and a step transposes
+    both arrays in and out: a written prompt and ``steps`` greedy steps
+    through ``write_prompt`` and ``jax.jit(model.decode_step)`` on each.
+    Same logits to the bit, the same bytes read back from both caches,
+    and no copy of a whole cache array in the wide one's step. On a CPU
+    both widths are the head's and the arm proves the plumbing only."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
+                                                        stored_head_dim)
+
+    heads = max(model["model_dim"] // 64, 1)
+    mdl = get_model(ModelConfig(**{**model, "num_heads": heads}))
+    params = mdl.init(jax.random.PRNGKey(SEED))
+    layers, _, hd = mdl.decode_cache_shape
+    plen = min(context, model["seq_len"] - steps - 1)
+    width = -(-(plen + steps + 1) // block_size)
+    slots, nblocks = 2, 16 * width + 1
+    dtype = jnp.dtype(model["compute_dtype"])
+    wide = stored_head_dim((layers, nblocks, block_size, heads, hd), dtype)
+    toks = np.zeros((1, 1 << (plen - 1).bit_length()), np.int32)
+    toks[0, :plen] = np.random.default_rng(SEED + 1).integers(
+        0, model["vocab_size"], plen)
+    logits, ks, vs = jax.jit(mdl.decode_prefill)(params, jnp.asarray(toks))
+    first = int(jnp.argmax(logits[0, plen - 1]))
+    step = jax.jit(functools.partial(mdl.decode_step, block_size=block_size),
+                   donate_argnums=(3, 4))
+
+    def decode(head_dim):
+        cache = PagedKVCache(layers, nblocks, block_size, heads, head_dim,
+                             max_blocks_per_seq=width, dtype=dtype)
+        dims = re.escape(f"[{','.join(map(str, cache.k.shape))}]")
+        ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        text = step.lower(params, ints(slots), ints(slots), cache.k, cache.v,
+                          ints(slots, width), ints(slots)).compile().as_text()
+        said = {"cache_shape": list(cache.k.shape),
+                "cache_layout": str(cache.k.format.layout),
+                "cache_device_bytes": cache.k.on_device_size_in_bytes(),
+                "whole_cache_copies": len(re.findall(
+                    rf"= \w+{dims}\{{[^}}]*\}} copy\(", text))}
+        table = cache.alloc_sequence(plen + steps)
+        cache.write_prompt(table, ks[:, 0], vs[:, 0], plen)
+        tables = jnp.asarray(np.stack([table, np.zeros_like(table)]))
+        tok, rows = first, []
+        for pos in range(plen, plen + steps):
+            out, cache.k, cache.v = step(
+                params, jnp.asarray([tok, 0], jnp.int32),
+                jnp.asarray([pos, 0], jnp.int32), cache.k, cache.v,
+                tables, jnp.asarray([pos + 1, 0], jnp.int32))
+            rows.append(np.asarray(out[0]))
+            tok = int(rows[-1].argmax())
+        # the paged kernel takes the rows as stored, one step more
+        paged = jax.jit(functools.partial(
+            mdl.decode_step, block_size=block_size,
+            attention_kernel="paged"))(
+                params, jnp.asarray([tok, 0], jnp.int32),
+                jnp.asarray([plen + steps, 0], jnp.int32), cache.k, cache.v,
+                tables, jnp.asarray([plen + steps + 1, 0], jnp.int32))[0]
+        kv = [a[..., :hd] for a in cache.gather_dense(table, plen + steps)]
+        return np.stack(rows), kv, np.asarray(paged[0]), said
+
+    want, want_kv, want_paged, plain_said = decode(hd)
+    got, got_kv, got_paged, wide_said = decode(wide)
+    out = {"head_dim": hd, "stored_head_dim": wide, "steps": steps,
+           "head_wide": plain_said, "stored_wide": wide_said,
+           "logits_bit_equal": bool(np.array_equal(got, want)),
+           "logits_max_abs_diff": float(np.abs(
+               got.astype(np.float32) - want.astype(np.float32)).max()),
+           "cache_bytes_equal": all(
+               np.array_equal(g, w) for g, w in zip(got_kv, want_kv)),
+           "paged_logits_bit_equal": bool(
+               np.array_equal(got_paged, want_paged))}
+    _require(np.isfinite(got).all() and out["logits_bit_equal"]
+             and out["cache_bytes_equal"]
+             and _max_err(got_paged, want_paged) <= 2e-2
+             and wide_said["whole_cache_copies"] == 0,
+             f"the cache with whole rows decodes otherwise: {out}")
     return out
 
 

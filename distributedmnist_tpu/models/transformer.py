@@ -821,7 +821,10 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     * ``tokens`` [S] int32 — each slot's newest token,
     * ``positions`` [S] — that token's 0-based sequence position,
     * ``k_cache``/``v_cache`` [L, N, B, h, hd] — the paged cache
-      (N blocks of B positions; block 0 is the reserved null block),
+      (N blocks of B positions; block 0 is the reserved null block).
+      A row may be stored wider than the head
+      (servesvc/kv_cache.py::stored_head_dim): the step writes and
+      reads its first ``hd`` elements and leaves the rest as they are,
     * ``block_tables`` [S, P] int32 — each slot's position→block map
       (idle slots: all zeros),
     * ``lengths`` [S] — context length INCLUDING this token
@@ -886,17 +889,21 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
     with jax.named_scope("cache_write"):
         kh = k.reshape(num_slots, num_heads, hd)
         vh = v.reshape(num_slots, num_heads, hd)
-        k_cache = k_cache.at[li, blk_ids, offs].set(
+        k_cache = k_cache.at[li, blk_ids, offs, :, :hd].set(
             kh.astype(k_cache.dtype))
-        v_cache = v_cache.at[li, blk_ids, offs].set(
+        v_cache = v_cache.at[li, blk_ids, offs, :, :hd].set(
             vh.astype(v_cache.dtype))
     qh = q.reshape(num_slots, num_heads, hd)
     if attention_kernel == "paged":
         # fused path: the kernel walks the block table itself, so
-        # per-token traffic is O(actual context) — no dense view
+        # per-token traffic is O(actual context) — no dense view. It
+        # takes the rows as stored: a query zero beyond the head adds
+        # nothing to a score
         from ..ops.pallas_paged_attention import paged_attention
-        o = paged_attention(qh, k_cache[li], v_cache[li],
-                            block_tables, lengths, scale=scale)
+        wide = k_cache.shape[-1] - hd
+        o = paged_attention(jnp.pad(qh, ((0, 0), (0, 0), (0, wide))),
+                            k_cache[li], v_cache[li],
+                            block_tables, lengths, scale=scale)[..., :hd]
     else:
         # gather the slot's pages into one dense context view: the
         # block table IS the indirection, so this read is identical
@@ -905,9 +912,9 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
         # decode loop picks the width (at most four) by its longest
         # live sequence (servesvc/decode.py::_table_width)
         with jax.named_scope("cache_gather"):
-            kp = k_cache[li][block_tables].reshape(
+            kp = k_cache[li][block_tables][..., :hd].reshape(
                 num_slots, ctx, num_heads, hd)
-            vp = v_cache[li][block_tables].reshape(
+            vp = v_cache[li][block_tables][..., :hd].reshape(
                 num_slots, ctx, num_heads, hd)
         scores = jnp.einsum("shd,skhd->shk", qh.astype(jnp.float32),
                             kp.astype(jnp.float32)) * scale

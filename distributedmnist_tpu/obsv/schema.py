@@ -216,10 +216,14 @@ _declare(EventSchema(
                              "sequences_restarted")),
         # -- decode service (servesvc/decode.py) ----------------------
         # table_widths: the block-table widths the step is compiled for
+        # cache_layout, cache_device_bytes: the arrays as placed;
+        # step_temp_bytes, whole_cache_copies: a value a table width
         "decode_start": _act(("slots", "block_size", "num_blocks",
                               "max_prompt_len", "max_new_tokens",
                               "table_widths", "swap_policy",
-                              "model_step")),
+                              "model_step", "cache_layout",
+                              "cache_device_bytes", "step_temp_bytes",
+                              "whole_cache_copies")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of

@@ -83,6 +83,7 @@ from __future__ import annotations
 import collections
 import json
 import queue
+import re
 import time
 
 import jax
@@ -92,7 +93,7 @@ import numpy as np
 from ..core.config import ConfigError
 from ..models.registry import sample_token
 from ..obsv import spans
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, stored_head_dim
 from .server import ServingReplica, _Pending
 
 
@@ -152,6 +153,11 @@ class DecodeReplica(ServingReplica):
         dtype = jnp.dtype(
             effective_model_config(self.cfg, serving=True).compute_dtype)
         layers, heads, head_dim = self.model.decode_cache_shape
+        # a head as wide as the device stores whole (kv_cache.py): the
+        # step and the prompt's scatter then take the arrays as they lie
+        head_dim = stored_head_dim(
+            (layers, self.dcfg.num_blocks, self.dcfg.block_size, heads,
+             head_dim), dtype, self.topo.replicated)
         self.cache = PagedKVCache(
             layers, self.dcfg.num_blocks, self.dcfg.block_size,
             heads, head_dim, self.dcfg.max_blocks_per_seq(), dtype=dtype)
@@ -186,9 +192,11 @@ class DecodeReplica(ServingReplica):
             return logits, greedy, k_cache, v_cache
 
         # the cache arrays are rebound to the step's outputs at every
-        # call site — donate them so XLA updates in place instead of
-        # copying the whole [L, N, B, h, hd] pair per generated token
+        # call site, so they are donated: the token's scatter writes in
+        # place
         self._decode_jit = jax.jit(decode_step, donate_argnums=(3, 4))
+        # {table width: the executable `_warm_up` compiled from it}
+        self._steps: dict[int, jax.stages.Compiled] = {}
         # decode-loop-owned state (single writer: the batcher thread)
         self._slots: list[_DecodeSeq | None] = (
             [None] * self.dcfg.decode_slots)
@@ -564,7 +572,7 @@ class DecodeReplica(ServingReplica):
                             waiting=len(self._waiting), version=ver,
                             blocks=width):
                 logits, greedy, self.cache.k, self.cache.v = (
-                    self._decode_jit(
+                    self._step(width)(
                         self._params_for(ver), tokens, positions,
                         self.cache.k, self.cache.v, tables, lengths))
                 self.decode_steps += 1
@@ -679,21 +687,54 @@ class DecodeReplica(ServingReplica):
                 "eos_token": self.dcfg.eos_token,
                 "swap_policy": self.dcfg.swap_policy}
 
+    def _step(self, width: int):
+        """The step for a table ``width`` blocks wide: the executable
+        `_warm_up` compiled, else the jitted function (a replica driven
+        without ``start()`` compiles a width at its first use)."""
+        return self._steps.get(width, self._decode_jit)
+
     def _warm_up(self) -> None:
         """Every table width's decode step compiled (or loaded from the
         compile cache) before a request is accepted: a rung first
         reached by a live conversation would otherwise stall every slot
-        for one compile. Each width runs once with every slot idle
-        (tables and lengths zero: the writes land in the null block,
-        which is what it is for), on cache arrays placed as every later
-        call finds them (``__init__``)."""
+        for one compile. Compiled ahead of time, so that ``start()`` can
+        say of each what it needs beside its arguments and whether it
+        copies the cache. Each width then runs once with every slot
+        idle (tables and lengths zero: the writes land in the null
+        block, which is what it is for), on cache arrays placed as every
+        later call finds them (``__init__``)."""
         num_slots = self.dcfg.decode_slots
         idle = jnp.asarray(np.zeros((num_slots,), np.int32))
         for width in self._table_widths:
-            _, greedy, self.cache.k, self.cache.v = self._decode_jit(
+            tables = jnp.asarray(np.zeros((num_slots, width), np.int32))
+            self._steps[width] = self._decode_jit.lower(
                 self._params, idle, idle, self.cache.k, self.cache.v,
-                jnp.asarray(np.zeros((num_slots, width), np.int32)), idle)
+                tables, idle).compile()
+            _, greedy, self.cache.k, self.cache.v = self._steps[width](
+                self._params, idle, idle, self.cache.k, self.cache.v,
+                tables, idle)
         jax.block_until_ready(greedy)
+
+    def _cache_said(self) -> dict:
+        """How the cache lies on the device and what each width's step
+        does with it, for ``decode_start``: ``whole_cache_copies`` counts
+        the ``copy`` instructions of the cache's shape in a compiled
+        step (0 where it takes the arrays as they lie, 4 where it
+        transposes both on the way in and back on the way out)."""
+        at = self.cache.k.format.layout
+        dims = re.escape(f"[{','.join(map(str, self.cache.k.shape))}]")
+        steps = [self._steps[w] for w in self._table_widths]
+        return {
+            "cache_layout": (f"major_to_minor={tuple(at.major_to_minor)} "
+                             f"tiling={tuple(at.tiling or ())}"),
+            "cache_device_bytes": (self.cache.k.on_device_size_in_bytes()
+                                   + self.cache.v.on_device_size_in_bytes()),
+            "step_temp_bytes": [s.memory_analysis().temp_size_in_bytes
+                                for s in steps],
+            "whole_cache_copies": [
+                len(re.findall(rf"= \w+{dims}\{{[^}}]*\}} copy\(",
+                               s.as_text()))
+                for s in steps]}
 
     def start(self) -> None:
         super().start()
@@ -705,4 +746,5 @@ class DecodeReplica(ServingReplica):
                        "max_new_tokens": self.dcfg.max_new_tokens,
                        "table_widths": self._table_widths,
                        "swap_policy": self.dcfg.swap_policy,
-                       "model_step": self.model_step})
+                       "model_step": self.model_step,
+                       **self._cache_said()})

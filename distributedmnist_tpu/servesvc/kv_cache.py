@@ -16,6 +16,13 @@ narrowest of four widths (the quarters of ``max_blocks_per_seq``) that
 holds its longest live sequence: four compiled shapes, not one, and
 the full width only for a sequence that long (servesvc/decode.py).
 
+A TPU's default layout of that shape, with a head under 128 wide, makes
+the BLOCK index minor, so each program that takes the cache transposes
+both arrays in and back out. The programs touch a row's first
+``head_dim`` elements however wide it is stored, so an owner that runs
+them a token (the decode replica) builds the cache as wide as
+:func:`stored_head_dim` answers (ROADMAP S1).
+
 Block 0 is the **reserved null block**: idle decode slots point their
 whole table (and their writes) at it, so the fixed-shape step never
 needs a branch — garbage lands in a block no sequence owns.
@@ -87,6 +94,33 @@ class BlockAllocator:
             self._free.append(b)
 
 
+def stored_head_dim(shape: tuple[int, ...], dtype,
+                    sharding: jax.sharding.Sharding | None = None) -> int:
+    """The head width at which a cache of ``shape`` (``[layers,
+    num_blocks, block_size, heads, head_dim]``) is stored with its rows
+    whole. The compiler is asked how the device lays the shape out (a
+    program that returns zeros of it, compiled and not run): where the
+    last dimension stays minor, ``head_dim`` itself (a CPU; a 128-wide
+    head on a TPU); where another dimension would be minor, ``head_dim``
+    rounded up to the layout's lanes, if the device keeps that shape's
+    rows whole."""
+    def layout(shape):
+        zeros = jax.jit(lambda: jnp.zeros(shape, dtype),
+                        out_shardings=sharding)
+        return zeros.lower().compile().output_formats.layout
+
+    def rows_whole(at):
+        return tuple(at.major_to_minor) == tuple(range(len(shape)))
+
+    head_dim = shape[-1]
+    asked = layout(shape)
+    if rows_whole(asked) or not asked.tiling:
+        return head_dim
+    lanes = asked.tiling[0][-1]
+    wide = -(-head_dim // lanes) * lanes
+    return wide if rows_whole(layout((*shape[:-1], wide))) else head_dim
+
+
 @jax.named_scope("cache_write")
 def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
                     ks: jax.Array, vs: jax.Array,
@@ -95,7 +129,8 @@ def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
     """Scatter one sequence's prefill K/V into its blocks.
 
     ``ks``/``vs`` [L, s_pad, h, hd] (the prefill export for ONE
-    sequence, padded to its prompt bucket); positions ``< length`` land
+    sequence, padded to its prompt bucket) go into the first ``hd``
+    elements of the cache's rows; positions ``< length`` land
     at ``block_table[pos // block_size]`` offset ``pos % block_size``,
     padding positions are routed to the null block. jit this once per
     prompt bucket shape."""
@@ -104,8 +139,11 @@ def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
     blk_ids = jnp.where(pos < length,
                         block_table[pos // block_size], NULL_BLOCK)
     offs = pos % block_size
-    k_cache = k_cache.at[:, blk_ids, offs].set(ks.astype(k_cache.dtype))
-    v_cache = v_cache.at[:, blk_ids, offs].set(vs.astype(v_cache.dtype))
+    hd = ks.shape[-1]     # a row may be stored wider (stored_head_dim)
+    k_cache = k_cache.at[:, blk_ids, offs, :, :hd].set(
+        ks.astype(k_cache.dtype))
+    v_cache = v_cache.at[:, blk_ids, offs, :, :hd].set(
+        vs.astype(v_cache.dtype))
     return k_cache, v_cache
 
 

@@ -1,0 +1,297 @@
+"""How the decode step takes the paged KV cache
+(servesvc/kv_cache.py::stored_head_dim, models/transformer.py::
+decode_step, servesvc/decode.py).
+
+Two halves. At ``opt-1.3b``'s geometry, compiled for a v5e that is
+described and not attached (rehearsal 3 of the on-chip-measurement
+guide: nothing runs, nothing here is a time; skipped where the
+installation cannot describe the chip): with the head stored as wide as
+``stored_head_dim`` answers, the step and the prompt's scatter copy no
+whole cache array. And on the CPU test mesh: a cache with wider rows
+decodes to the bit what one with the head's own width decodes, through
+the model's step, the prompt's scatter and a replica."""
+
+import functools
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributedmnist_tpu.servesvc import decode as decode_mod
+from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
+                                                    stored_head_dim,
+                                                    write_prompt_kv)
+
+GB = 1e9
+#: opt-1.3b as served (benchmark/configs/opt-1.3b.json): 24 layers, 32
+#: heads of 64, bf16; 16 slots, 769 blocks of 16, tables of 28..112
+OPT_1_3B = {"name": "transformer", "model_dim": 2048, "num_heads": 32,
+            "num_layers": 24, "seq_len": 2048, "vocab_size": 50272}
+SLOTS, BLOCKS, BLOCK = 16, 769, 16
+CACHE_SHAPE = (24, BLOCKS, BLOCK, 32, 64)
+WIDTHS = [28, 112]
+
+
+def _total(compiled) -> float:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _count(compiled, shape, op: str) -> int:
+    """Instructions ``op`` whose result has ``shape``, any layout."""
+    dims = re.escape("[" + ",".join(map(str, shape)) + "]")
+    return len(re.findall(rf"= \w+{dims}\{{[^}}]*\}} {op}\(",
+                          compiled.as_text()))
+
+
+@pytest.fixture(scope="module")
+def for_the_chip():
+    """``model.decode_step`` jitted as the replica jits it (both caches
+    donated) at two table widths and the prompt's scatter, on a cache
+    as wide as ``stored_head_dim`` answers for the chip, for a
+    described ``v5e:1x1``; these compiles kept out of the persistent
+    cache (written without a chip they cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+
+    for name, value in (("TPU_LOG_DIR", "disabled"),
+                        ("TPU_ACCELERATOR_TYPE", "v5litepod-1"),
+                        ("TPU_WORKER_HOSTNAMES", "localhost")):
+        os.environ.setdefault(name, value)
+    try:
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chip_config_name="default", chips_per_host_bounds=(1, 1, 1),
+            num_slices=1).devices[0]
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe v5e:1x1: {type(e).__name__}: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        on_chip = SingleDeviceSharding(device)
+        sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=on_chip)
+        model = get_model(ModelConfig(**OPT_1_3B))
+        params = jax.tree.map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+        wide = stored_head_dim(CACHE_SHAPE, jnp.bfloat16, on_chip)
+        step = jax.jit(functools.partial(model.decode_step, block_size=BLOCK),
+                       donate_argnums=(3, 4))
+
+        def compiled(width, head_dim):
+            cache = sds((*CACHE_SHAPE[:-1], head_dim), jnp.bfloat16)
+            return step.lower(
+                params, sds((SLOTS,), jnp.int32), sds((SLOTS,), jnp.int32),
+                cache, cache, sds((SLOTS, width), jnp.int32),
+                sds((SLOTS,), jnp.int32)).compile()
+
+        cache = sds((*CACHE_SHAPE[:-1], wide), jnp.bfloat16)
+        prompt = sds((24, 256, 32, 64), jnp.bfloat16)
+        yield {"stored_head_dim": wide, "on_chip": on_chip,
+               "stored_wide": {w: compiled(w, wide) for w in WIDTHS},
+               "write": jax.jit(
+                   write_prompt_kv, static_argnames="block_size",
+                   donate_argnums=(0, 1)).lower(
+                       cache, cache, prompt, prompt,
+                       sds((WIDTHS[-1],), jnp.int32), sds((), jnp.int32),
+                       block_size=BLOCK).compile()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_the_chip_keeps_a_128_wide_heads_rows_whole(for_the_chip):
+    """The v5e's default layout of ``[24, 769, 16, 32, 64]`` makes the
+    block index minor; of ``[..., 128]`` the head."""
+    on_chip = for_the_chip["on_chip"]
+    assert for_the_chip["stored_head_dim"] == 128
+    assert stored_head_dim((*CACHE_SHAPE[:-1], 128), jnp.bfloat16,
+                           on_chip) == 128
+    assert stored_head_dim((*CACHE_SHAPE[:-1], 160), jnp.bfloat16,
+                           on_chip) == 256
+
+
+@pytest.mark.parametrize("width, temporaries_gb", zip(WIDTHS, (0.5, 1.0)))
+def test_the_step_on_whole_rows_copies_no_cache_array(
+        for_the_chip, width, temporaries_gb):
+    """What the replica runs: no ``copy`` of the cache's shape, the
+    cache arrays taken and returned in one layout, and the memory that
+    leaves (12.73 GB at the head's own width, 7.27 of it temporaries:
+    ``tests/benchmark/test_bench_rehearsal.py``)."""
+    step = for_the_chip["stored_wide"][width]
+    shape = (*CACHE_SHAPE[:-1], for_the_chip["stored_head_dim"])
+    assert _count(step, shape, "copy") == 0
+    layouts = {f.layout for f in (*step.input_formats[0][3:5],
+                                  *step.output_formats[1:])}
+    assert [tuple(at.major_to_minor) for at in layouts] == [(0, 1, 2, 3, 4)]
+    assert step.memory_analysis().temp_size_in_bytes / GB <= temporaries_gb
+    assert _total(step) / GB <= 8.4
+
+
+def test_the_prompts_scatter_on_whole_rows_copies_no_cache_array(
+        for_the_chip):
+    write = for_the_chip["write"]
+    shape = (*CACHE_SHAPE[:-1], for_the_chip["stored_head_dim"])
+    assert _count(write, shape, "copy") == 0
+    assert write.memory_analysis().temp_size_in_bytes / GB <= 0.1
+
+
+# -- the CPU test mesh ------------------------------------------------------
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((2, 40, 4, 4, 16), jnp.float32), ((24, 97, 16, 32, 64), jnp.bfloat16),
+    ((1, 5, 16, 1, 128), jnp.bfloat16)])
+def test_a_cpu_keeps_every_heads_rows_whole(shape, dtype):
+    """Where the compiler's answer is the default, nothing is widened."""
+    assert stored_head_dim(shape, dtype) == shape[-1]
+    assert stored_head_dim(shape, dtype, jax.sharding.SingleDeviceSharding(
+        jax.devices()[0])) == shape[-1]
+
+
+LM_MODEL = {"name": "transformer", "seq_len": 64, "model_dim": 64,
+            "num_heads": 4, "num_layers": 2, "vocab_size": 32,
+            "compute_dtype": "float32", "attention_impl": "dense"}
+GEOMETRY = {"decode_slots": 3, "block_size": 4, "num_blocks": 40,
+            "max_prompt_len": 16, "max_new_tokens": 16}
+
+
+@pytest.fixture(scope="module")
+def lm():
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    model = get_model(ModelConfig(**LM_MODEL))
+    return model, model.init(jax.random.PRNGKey(3))
+
+
+def _cache(model, head_dim=None, blocks=12):
+    layers, heads, hd = model.decode_cache_shape
+    return PagedKVCache(layers, 40, 4, heads, head_dim or hd,
+                        max_blocks_per_seq=blocks, dtype=jnp.float32)
+
+
+def test_a_cache_built_with_todays_arguments_is_placed_as_before(lm):
+    """Every caller but the replica: the head's own width, the default
+    layout, committed to no device."""
+    cache = _cache(lm[0])
+    plain = jnp.zeros((2, 40, 4, 4, 16), jnp.float32)
+    for a in (cache.k, cache.v):
+        assert a.shape == plain.shape and a.dtype == plain.dtype
+        assert a.format.layout == plain.format.layout
+        assert not a.committed
+
+
+@pytest.mark.parametrize("kernel", ["dense", "paged"])
+@pytest.mark.parametrize("stored, width", [(24, 4), (32, 6), (128, 12)])
+def test_wider_rows_decode_to_the_bit_what_the_heads_own_width_decodes(
+        lm, kernel, stored, width):
+    """A written prompt and six greedy steps for two slots of three
+    (the third idle) through ``write_prompt`` and the model's step, at
+    table widths 4, 6 and 12 blocks: logits equal to the bit, the same
+    elements in the head's part of every row, zeros beside it."""
+    model, params = lm
+    hd = model.decode_cache_shape[2]
+    prefill = jax.jit(model.decode_prefill)
+    step = jax.jit(functools.partial(model.decode_step, block_size=4,
+                                     attention_kernel=kernel))
+    prompts = [[1, 2, 3, 4, 5], list(range(1, 9))]
+
+    def decode(head_dim):
+        cache = _cache(model, head_dim, blocks=width)
+        tables = np.zeros((3, width), np.int32)
+        toks, rows = [], []
+        for slot, prompt in enumerate(prompts):
+            padded = np.zeros((1, 8), np.int32)
+            padded[0, :len(prompt)] = prompt
+            logits, ks, vs = prefill(params, jnp.asarray(padded))
+            tables[slot] = cache.alloc_sequence(len(prompt) + 6)
+            cache.write_prompt(tables[slot], ks[:, 0], vs[:, 0], len(prompt))
+            toks.append(int(jnp.argmax(logits[0, len(prompt) - 1])))
+        for i in range(6):
+            pos = [len(p) + i for p in prompts]
+            out, cache.k, cache.v = step(
+                params, jnp.asarray([*toks, 0], jnp.int32),
+                jnp.asarray([*pos, 0], jnp.int32), cache.k, cache.v,
+                jnp.asarray(tables),
+                jnp.asarray([pos[0] + 1, pos[1] + 1, 0], jnp.int32))
+            rows.append(np.asarray(out[:2]))
+            toks = [int(t) for t in rows[-1].argmax(-1)]
+        return np.stack(rows), np.asarray(cache.k), np.asarray(cache.v)
+
+    want, want_k, want_v = decode(hd)
+    got, got_k, got_v = decode(stored)
+    np.testing.assert_array_equal(got, want)
+    for got_a, want_a in ((got_k, want_k), (got_v, want_v)):
+        assert got_a.shape[-1] == stored and want_a.any()
+        np.testing.assert_array_equal(got_a[..., :hd], want_a)
+        assert not got_a[..., hd:].any()
+
+
+@pytest.mark.parametrize("stored", [None, 48])
+def test_a_replica_decodes_what_the_full_context_forward_decodes(
+        tmp_path, monkeypatch, stored):
+    """Through every table width a toy replica has (2, 4, 6, 8 blocks),
+    at the head's own width (what a CPU answers) and with rows three
+    times as wide as the 16-wide head; and ``decode_start`` says how the
+    cache lies and what each width's step does with it."""
+    from distributedmnist_tpu.core.config import (DecodeConfig,
+                                                  ExperimentConfig,
+                                                  ServeConfig)
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.obsv.schema import validate_event
+    from distributedmnist_tpu.parallel.api import init_train_state
+    from distributedmnist_tpu.servesvc.client import ServeClient
+    from distributedmnist_tpu.train.checkpoint import save_checkpoint
+
+    if stored:
+        monkeypatch.setattr(decode_mod, "stored_head_dim",
+                            lambda shape, dtype, sharding: stored)
+    train_dir = tmp_path / "published"
+    cfg = ExperimentConfig.from_dict({
+        "model": dict(LM_MODEL), "train": {"train_dir": str(train_dir)}})
+    model = get_model(cfg.model)
+    state = init_train_state(model, cfg)
+    save_checkpoint(train_dir, state, 0, extra={"config": cfg.to_dict()})
+    rep = decode_mod.DecodeReplica(
+        train_dir, serve_dir=tmp_path / "replica",
+        scfg=ServeConfig(poll_secs=0.05), dcfg=DecodeConfig(**GEOMETRY),
+        cfg=cfg)
+    head_dim = stored or model.decode_cache_shape[2]
+    assert rep.cache.k.shape == rep.cache.v.shape == (2, 40, 4, 4, head_dim)
+    prompts = [[1, 2, 3, 4, 5], list(range(1, 17)), [7]]
+    rep.start()
+    try:
+        client = ServeClient([("127.0.0.1", rep.bound_port)],
+                             deadline_s=60.0)
+        outs = [client.generate(p, max_tokens=12) for p in prompts]
+    finally:
+        rep.stop()
+    apply = jax.jit(lambda p, t: model.apply(p, t, train=False))
+    for prompt, out in zip(prompts, outs):
+        assert out["status"] == "ok" and len(out["tokens"]) == 12
+        seq = list(prompt)
+        for _ in range(12):
+            logits = apply(state.params, jnp.asarray([seq], jnp.int32))
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert out["tokens"] == seq[len(prompt):]
+    journal = (rep.serve_dir / "serve_log.jsonl").read_text().splitlines()
+    [started] = [r for r in map(json.loads, journal)
+                 if r.get("action") == "decode_start"]
+    assert validate_event(started) == []
+    assert started["table_widths"] == [2, 4, 6, 8]
+    assert started["cache_layout"] == "major_to_minor=(0, 1, 2, 3, 4) tiling=()"
+    assert started["cache_device_bytes"] == 2 * 2 * 40 * 4 * 4 * head_dim * 4
+    assert len(started["step_temp_bytes"]) == 4
+    assert all(isinstance(n, int) and n > 0
+               for n in started["step_temp_bytes"])
+    assert len(started["whole_cache_copies"]) == 4

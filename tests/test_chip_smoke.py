@@ -140,6 +140,10 @@ def test_phases_pass_tiny_on_the_cpu_mesh(tmp_path, scratch_cache):
                                     context=20, tol=1e-4)
     assert kern["decode_step_argmax_equal"]
     assert kern["paged_vs_dense"] <= 1e-4
+    # a CPU stores every head's rows whole: the arm proves its plumbing
+    wide = kern["wide_cache_rows"]
+    assert wide["stored_head_dim"] == wide["head_dim"] == 64
+    assert wide["logits_bit_equal"] and wide["cache_bytes_equal"]
 
 
 def test_phase_check_failure_raises(tmp_path, scratch_cache):

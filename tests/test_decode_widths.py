@@ -348,10 +348,13 @@ def test_every_width_is_compiled_by_start_and_none_after(published,
     from distributedmnist_tpu.servesvc.client import ServeClient
 
     rep = make_replica(published, tmp_path / "replica")
-    assert rep._decode_jit._cache_size() == 0
+    assert rep._steps == {} and rep._decode_jit._cache_size() == 0
     rep.start()
     try:
-        assert rep._decode_jit._cache_size() == len(RUNGS)
+        # an executable a width, compiled ahead of time; the jitted
+        # function is what a width without one would fall back to
+        assert sorted(rep._steps) == RUNGS
+        assert rep._decode_jit._cache_size() == 0
         assert rep.decode_steps == 0 and rep.tokens_streamed == 0
         assert not rep.cache.allocator.in_use     # the null block alone
         client = ServeClient([("127.0.0.1", rep.bound_port)],
@@ -364,7 +367,7 @@ def test_every_width_is_compiled_by_start_and_none_after(published,
         assert rep.decode_table_blocks == RUNGS[0]
         assert long_["status"] == short["status"] == "ok"
         assert len(long_["tokens"]) == MAX_NEW and len(short["tokens"]) == 4
-        assert rep._decode_jit._cache_size() == len(RUNGS)
+        assert rep._decode_jit._cache_size() == 0      # no width fell back
     finally:
         rep.stop()
     [started] = [r for r in read_jsonl(rep.serve_dir / "serve_log.jsonl")
