@@ -460,6 +460,7 @@ def phase_kernels(*, model: dict, batch: int, block_size: int = 16,
         int(jnp.argmax(lg["paged"][0])) == int(jnp.argmax(lg["dense"][0])))
     out["latent_routed_block"] = _latent_routed_arm(model, batch)
     out["wide_cache_rows"] = _wide_cache_arm(model, block_size, context)
+    out["latent_decode"] = _latent_decode_arm(model, block_size, tol)
     return out
 
 
@@ -604,6 +605,76 @@ def _latent_routed_arm(model: dict, batch: int) -> dict:
             "pairs": int(ids.size),
             "mosaic_calls": _mosaic_calls(
                 step.lower(params).compile().as_text())}
+
+
+def _latent_decode_arm(model: dict, block_size: int, tol: float) -> dict:
+    """(e) the absorbed decode step against the expanded form: a block
+    with latent attention, sandwich norms and per-token routing over a
+    held share (one residual stream: the kind with a decode export), at
+    the smoke's width cut eightfold, two layers. A prompt's latents and
+    rotated keys written into a paged cache stored as the device keeps
+    its rows whole, 8 greedy steps through it, each step's logits
+    against the full forward's at that position (flash, the expanded
+    form) within the kernels' tolerance; every step's expert ids valid."""
+    import jax
+    import jax.numpy as jnp
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
+                                                        cache_shapes,
+                                                        stored_head_dim)
+
+    d, heads, plen, steps, slots = max(model["model_dim"] // 8, 32), 4, 40, 8, 4
+    seq = 64
+    mdl = get_model(ModelConfig(**{
+        **model, "model_dim": d, "num_heads": heads, "num_layers": 2,
+        "seq_len": seq, "q_latent_dim": d // 2, "kv_latent_dim": d // 4,
+        "qk_nope_dim": 24, "qk_rope_dim": 8, "v_head_dim": 16,
+        "rope_theta": 25.6e6, "ffn_dim": 2 * d, "routed_experts": 16,
+        "held_experts": 8, "experts_per_token": 4, "shared_experts": 1,
+        "expert_ffn_dim": d // 2, "routed_scaling": 2.5, "dense_layers": 1,
+        "sandwich_norm": True, "norm_eps": 1e-5}))
+    params = mdl.init(jax.random.PRNGKey(SEED + 2))
+    dtype = jnp.dtype(model["compute_dtype"])
+    layers, one, widths = mdl.decode_cache_shape
+    blocks = slots * seq // block_size + 1
+    stored = tuple(
+        stored_head_dim(shape, dtype) for shape in cache_shapes(
+            layers, blocks, block_size, one, widths))
+    cache = PagedKVCache(layers, blocks, block_size, one, stored,
+                         seq // block_size, dtype=dtype)
+    toks = np.zeros((1, seq), np.int32)
+    toks[0, :plen] = np.random.default_rng(SEED + 2).integers(
+        0, model["vocab_size"], plen)
+    logits, cs, krs = jax.jit(mdl.decode_prefill)(params,
+                                                  jnp.asarray(toks[:, :plen]))
+    table = cache.alloc_sequence(plen + steps)
+    cache.write_prompt(table, cs[:, 0], krs[:, 0], plen)
+    step = jax.jit(lambda *a: mdl.decode_step(
+        *a, block_size=block_size, return_routing=True), donate_argnums=(3, 4))
+    tables = np.zeros((slots, seq // block_size), np.int32)
+    tables[1] = table
+    tok, rows, valid = int(jnp.argmax(logits[0, plen - 1])), [], True
+    for pos in range(plen, plen + steps):
+        toks[0, pos] = tok
+        vec = lambda v: jnp.zeros((slots,), jnp.int32).at[1].set(v)  # noqa: E731
+        out, cache.k, cache.v, ids = step(
+            params, vec(tok), vec(pos), cache.k, cache.v,
+            jnp.asarray(tables), vec(pos + 1))
+        rows.append(out[1])
+        ids = np.sort(np.asarray(ids[:, 1]), axis=-1)
+        valid = valid and bool(ids.min() >= 0 and ids.max() < 16
+                               and (ids[:, 1:] != ids[:, :-1]).all())
+        tok = int(jnp.argmax(out[1]))
+    want = jax.jit(mdl.apply)(params, jnp.asarray(toks[:, :plen + steps]))
+    err = _max_err(jnp.stack(rows), want[0, plen:plen + steps])
+    _require(np.isfinite(np.asarray(jnp.stack(rows))).all() and err <= tol
+             and valid,
+             f"latent decode: absorbed against expanded {err}, expert ids "
+             f"valid: {valid}")
+    return {"absorbed_vs_expanded": round(err, 5), "steps": steps,
+            "cache_arrays": [list(cache.k.shape), list(cache.v.shape)],
+            "cache_row_widths_stored": list(stored)}
 
 
 # ---------------------------------------------------------------------------

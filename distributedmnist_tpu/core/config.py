@@ -186,6 +186,12 @@ class ModelConfig:
     # at nextn_loss_weight when training.
     nextn_layers: int = 0
     nextn_loss_weight: float = 0.3
+    # sandwich_norm: a second RMSNorm a sublayer, on its output before it
+    # joins the residual (arXiv:2504.07866 s2), its scale initialised
+    # depth-scaled, c / sqrt(num_layers).
+    # norm_eps: the epsilon inside the root of every RMSNorm of the model.
+    sandwich_norm: bool = False
+    norm_eps: float = 1e-6
 
 
 @dataclass(frozen=True)

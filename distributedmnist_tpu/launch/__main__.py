@@ -557,8 +557,10 @@ def main(argv=None) -> None:
                     help="serve continuous-batching autoregressive "
                          "decode (streaming tokens over a paged KV "
                          "cache) instead of one-shot classification; "
-                         "the followed checkpoint must be a dense-FFN "
-                         "causal LM")
+                         "the followed checkpoint must be a causal LM "
+                         "with a decode export (the plain block, or a "
+                         "latent one with dense, gated or per-token "
+                         "routed feed-forwards)")
     pv.add_argument("--decode-slots", type=int, default=None,
                     dest="decode_slots",
                     help="concurrently-generating sequences per "

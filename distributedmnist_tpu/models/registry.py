@@ -166,8 +166,10 @@ class Model:
     # tp_param_specs/pp_param_specs builders above remain the models'
     # hand-built originals and the parity oracle for the tables.
     partition_rules: Callable[..., list] | None = None
-    # Autoregressive-decode exports (causal LMs with dense FFNs only;
-    # None elsewhere — the decode service refuses models without them):
+    # Autoregressive-decode exports (causal LMs of the plain block, or
+    # of a latent one with one residual stream, whatever its
+    # feed-forwards but capacity routing; None elsewhere — the decode
+    # service refuses models without them):
     # decode_prefill(params, tokens [b, s]) -> (logits [b, s, vocab],
     # k [L, b, s, h, hd], v [L, b, s, h, hd]) — the prompt forward
     # through the configured attention kernel that also exports every
@@ -177,10 +179,20 @@ class Model:
     # [S, vocab], k_cache, v_cache) — one incremental token over the
     # paged cache, a single compiled shape for any mix of sequence
     # lengths. decode_cache_shape = (num_layers, num_heads, head_dim),
-    # the geometry the cache is allocated with.
+    # the geometry the cache is allocated with, handed to
+    # ``PagedKVCache`` as it is: a latent model's is (num_layers, 1,
+    # (kv_latent, qk_rope)), one row a token for all heads, the latent in
+    # ``k`` and the rotated key in ``v``. A model with per-token routed
+    # layers takes ``return_routing=True`` on both exports, which adds
+    # the chosen expert ids as a last output ([routed_layers, b, s, k];
+    # [routed_layers, S, k]) and changes nothing else; where
+    # ``decode_counts`` is set, ``decode_step(..., return_counts=True)``
+    # adds, last, the pairs each held expert took of this step's tokens,
+    # int32 [routed_layers, held].
     decode_prefill: Callable[..., tuple] | None = None
     decode_step: Callable[..., tuple] | None = None
-    decode_cache_shape: tuple[int, int, int] | None = None
+    decode_cache_shape: tuple | None = None
+    decode_counts: bool = False
     # When True, ``apply`` and the sharded applies accept
     # ``return_aux=True`` and return (logits, aux), ``aux`` a mapping:
     # the train step adds ``aux_weight * aux["loss"]`` (the load-balance
@@ -331,7 +343,7 @@ def _transformer(cfg: ModelConfig) -> Model:
     held = (cfg.first_held_expert, cfg.held_experts or cfg.routed_experts)
     sizes = None
     if (latent or routed or cfg.ffn_dim or cfg.residual_streams > 1
-            or cfg.nextn_layers):
+            or cfg.nextn_layers or cfg.sandwich_norm):
         if moe and routed:
             raise ValueError("model.num_experts (capacity routing) and "
                              "model.routed_experts (per-token routing) "
@@ -352,7 +364,10 @@ def _transformer(cfg: ModelConfig) -> Model:
             shared_experts=cfg.shared_experts,
             expert_ffn_dim=cfg.expert_ffn_dim, dense_layers=cfg.dense_layers,
             residual_streams=cfg.residual_streams,
-            nextn_layers=cfg.nextn_layers)
+            nextn_layers=cfg.nextn_layers, sandwich_norm=cfg.sandwich_norm,
+            # a bias that never moves is no bias: a served model without
+            # one in its source keeps the leaf at zeros
+            **({} if cfg.router_bias_rate else {"router_bias_init": 0.0}))
     # what the train step adds of aux["loss"]: the load-balance loss at
     # its weight; the next-next-token module's term carries its own
     aux_weight = cfg.moe_aux_weight if moe else 1.0
@@ -493,7 +508,10 @@ def _transformer(cfg: ModelConfig) -> Model:
                 rope_beta_fast=cfg.rope_beta_fast,
                 rope_beta_slow=cfg.rope_beta_slow,
                 rope_mscale=cfg.rope_mscale,
-                rope_mscale_all_dim=cfg.rope_mscale_all_dim)
+                rope_mscale_all_dim=cfg.rope_mscale_all_dim,
+                norm_eps=cfg.norm_eps)
+        if cfg.sandwich_norm:
+            residual = transformer.FLOAT32
         if cfg.residual_streams > 1:
             residual = transformer.stream_residual(
                 cfg.residual_streams, iters=cfg.sinkhorn_iters,
@@ -501,7 +519,8 @@ def _transformer(cfg: ModelConfig) -> Model:
         return transformer.make_block(
             num_heads=cfg.num_heads, attention_fn=make_seq_attn(seq_axis),
             model_axis=model_axis, feed_forward=feed_forward,
-            projections=projections, residual=residual)
+            projections=projections, residual=residual,
+            out_norm=cfg.sandwich_norm, norm_eps=cfg.norm_eps)
 
     # one device, or replicas of the whole model. Where the leading
     # layers' feed-forward differs from the others', a block a layer,
@@ -583,32 +602,51 @@ def _transformer(cfg: ModelConfig) -> Model:
                 compute_dtype=compute_dtype)
         return apply_1f1b
 
-    # Decode exports: the plain block only. Capacity routing
+    # Decode exports: the plain block, and a latent one with one residual
+    # stream (its dense, gated and per-token routed feed-forwards run a
+    # token at a time as they run a batch). Capacity routing
     # (num_experts) is computed over groups of a sequence's tokens, which
-    # one incremental token does not have. Per-token routing would run a
-    # token at a time, but the step has no expert in its export, and the
-    # models that route so here attend through a latent: the step and
-    # the paged cache hold keys and values a head, with no latent row,
-    # no rotation and no absorbed projection (transformer._decode_attn)
+    # one incremental token does not have; several residual streams have
+    # no cache-side forward (transformer._one_stream); and the plain
+    # step's own attention (transformer._decode_attn) spells out the
+    # plain block: no output norm, no other epsilon, no gated tree
     decode_prefill = decode_step_fn = decode_cache_shape = None
-    if not moe and sizes is None:
-        def decode_prefill(params, tokens, positions=None):
+    blocks = ((block,) * cfg.num_layers
+              if isinstance(block, transformer.Block) else block)
+    first = blocks[0]
+    if not moe and (sizes is None or (first.decode_attn is not None
+                                      and cfg.residual_streams == 1)):
+        def decode_prefill(params, tokens, positions=None,
+                           return_routing=False):
             return transformer.prefill_with_kv(
                 params, tokens, block=block, positions=positions,
-                compute_dtype=compute_dtype)
+                compute_dtype=compute_dtype,
+                return_routing=return_routing)
 
         def decode_step_fn(params, tokens, positions, k_cache, v_cache,
                            block_tables, lengths, *, block_size,
-                           attention_kernel="dense"):
-            return transformer.decode_step(
+                           attention_kernel="dense", return_routing=False,
+                           return_counts=False):
+            out = transformer.decode_step(
                 params, tokens, positions, k_cache, v_cache,
-                block_tables, lengths, ffn=block.ffn,
-                num_heads=cfg.num_heads,
+                block_tables, lengths,
+                ffn=tuple(bk.ffn for bk in blocks),
+                attn=first.decode_attn, norm=first.norm,
+                residual=first.residual, num_heads=cfg.num_heads,
                 block_size=block_size, compute_dtype=compute_dtype,
-                attention_kernel=attention_kernel)
+                attention_kernel=attention_kernel,
+                return_aux=return_routing or return_counts)
+            if not (return_routing or return_counts):
+                return out
+            aux = out[3]
+            return (*out[:3],
+                    *((aux["routing"],) if return_routing else ()),
+                    *((aux["counts"],) if return_counts else ()))
 
-        decode_cache_shape = (cfg.num_layers, cfg.num_heads,
-                              cfg.model_dim // cfg.num_heads)
+        decode_cache_shape = (
+            (cfg.num_layers, 1, (cfg.kv_latent_dim, cfg.qk_rope_dim))
+            if latent else
+            (cfg.num_layers, cfg.num_heads, cfg.model_dim // cfg.num_heads))
 
     return Model(name=cfg.name, init=init, apply=apply,
                  loss=transformer.loss_fn, accuracy=transformer.accuracy,
@@ -618,6 +656,7 @@ def _transformer(cfg: ModelConfig) -> Model:
                  decode_prefill=decode_prefill,
                  decode_step=decode_step_fn,
                  decode_cache_shape=decode_cache_shape,
+                 decode_counts=routed and decode_step_fn is not None,
                  sharded_apply_factory=sharded_apply_factory,
                  partition_rules=(replicated_partition_rules
                                   if sizes is not None else

@@ -18,7 +18,9 @@ behind which projections (``wqkv``, or the latent ones of
 :func:`latent_projections`), which feed-forward (the dense ReLU product,
 a gated unit, or a routed mixture of experts), how a sublayer reads from
 and writes to the residual (:data:`PLAIN`: ``x + F(norm x)``, or
-:func:`stream_residual`), and under which mesh axes (Megatron-style tensor parallelism
+:func:`stream_residual`), whether a sublayer's output is normed before
+it joins the residual (``out_norm``), how the block attends to a paged
+cache one token at a time (``Block.decode_attn``), and under which mesh axes (Megatron-style tensor parallelism
 over a ``model_axis`` when params are sharded per
 :func:`param_partition_specs` — qkv/w1 column-parallel, wo/w2
 row-parallel with one psum per residual add, attention heads split
@@ -108,6 +110,10 @@ class Sizes(NamedTuple):
     dense_layers: int = 0
     residual_streams: int = 1
     nextn_layers: int = 0
+    sandwich_norm: bool = False
+    # the scale the selection bias starts at; 0 for a router without one
+    # (``router_bias_rate`` 0: the leaf stays zeros and selects nothing)
+    router_bias_init: float = 0.03
 
 
 #: leaves that stay float32 in the forward whatever the compute dtype:
@@ -138,12 +144,25 @@ def _init_gated(key: jax.Array, lead: tuple, d: int, f: int) -> Params:
             "w_down": truncated_normal_init(kd, (*lead, f, d), 0.02)}
 
 
+#: Depth-scaled sandwich norm (Pangu Ultra, arXiv:2504.07866 §2): the
+#: scale of the norm on a sublayer's output starts at ``c / sqrt(L)``, ``L``
+#: the model's depth, so that what ``L`` layers add to the residual stays
+#: of the embedding's order: ``c`` for attention and for the feed-forward
+SANDWICH_C = (0.283, 0.432)
+
+
 def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
-                      routed: bool) -> Params:
+                      routed: bool, depth: int = 1) -> Params:
     keys = iter(jax.random.split(key, 12))
     ones = _norm_scale
     tn = lambda shape: truncated_normal_init(next(keys), shape, 0.02)  # noqa: E731
     blk = {"ln1": ones(d), "ln2": ones(d)}
+    if z.sandwich_norm:
+        # a norm on each sublayer's output (make_block's ``out_norm``),
+        # its scale depth-scaled
+        for name, c in zip(("ln1_out", "ln2_out"), SANDWICH_C):
+            blk[name] = {"scale": jnp.full((d,), c / math.sqrt(depth),
+                                           jnp.float32)}
     if z.kv_latent_dim:
         qk = z.qk_nope_dim + z.qk_rope_dim
         blk.update(
@@ -163,7 +182,7 @@ def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
         # the selection bias: the loss's gradient does not reach it; it
         # moves by each expert's load (ops.moe.balance_term)
         blk["router_bias"] = truncated_normal_init(
-            next(keys), (z.routed_experts,), 0.03)
+            next(keys), (z.routed_experts,), z.router_bias_init)
         blk["experts"] = _init_gated(next(keys), (z.held[1],), d,
                                      z.expert_ffn_dim)
         if z.shared_experts:
@@ -198,7 +217,8 @@ def _init_sized(key: jax.Array, vocab_size: int, d: int, heads: int,
         # the embedding's size) makes every token read alike, and a
         # router sends them all to the same experts from step 1
         "embed": truncated_normal_init(next(keys), (vocab_size, d), 1.0),
-        "blocks": [_init_sized_block(next(keys), d, heads, z, routed_at(i))
+        "blocks": [_init_sized_block(next(keys), d, heads, z, routed_at(i),
+                                     num_layers)
                    for i in range(num_layers)],
         "final_norm": ones(d),
     }
@@ -257,9 +277,9 @@ def param_partition_specs(num_layers: int, model_axis: str | None,
             "final_norm": {"scale": P()}}
 
 
-def _rms_norm(x: jax.Array, p: Params) -> jax.Array:
+def _rms_norm(x: jax.Array, p: Params, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * p["scale"]).astype(x.dtype)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps) * p["scale"]).astype(x.dtype)
 
 
 class Residual(NamedTuple):
@@ -278,6 +298,17 @@ class Residual(NamedTuple):
 #: ``x + F(norm x)``: the sublayer reads the residual and adds to it
 PLAIN = Residual(read=lambda x, maps: (x, x), write=lambda x, y: x + y,
                  start=lambda x: x, end=lambda x: x)
+
+
+#: the same rule over a float32 residual, whatever the sublayers compute
+#: in: what a sublayer adds is rounded once, not again at every later
+#: write. For a block whose sublayers add little to a residual the
+#: embedding dominates (depth-scaled output norms: 0.13 to 0.19 at five
+#: layers): a bfloat16 residual near 1 resolves 0.004, a thirtieth of
+#: such an addition, and every rounding on a router's way costs it near
+#: ties (top 8 of 256: 0.95 of the sets equal for 0.97, on the chip)
+FLOAT32 = Residual(read=lambda x, maps: (x, x), write=lambda x, y: x + y,
+                   start=lambda x: x.astype(jnp.float32), end=lambda x: x)
 
 
 def _sinkhorn(r: jax.Array, iters: int, eps: float, clamp: float) -> jax.Array:
@@ -370,6 +401,13 @@ class Block(NamedTuple):
     ffn: Callable[[jax.Array, Params], tuple[jax.Array, Any]]
     # how both read from and write to the residual
     residual: Residual = PLAIN
+    # the block's RMSNorm (its epsilon bound), which the final norm shares
+    norm: Callable[[jax.Array, Params], jax.Array] = _rms_norm
+    # the attention sublayer of :func:`decode_step`, one token against the
+    # paged cache: None is the plain block's (:func:`_decode_attn`), else
+    # ``(x, blk, li, k_cache, v_cache, block_tables, positions, blk_ids,
+    # offs, live, attention_kernel=) -> (x, k_cache, v_cache)``
+    decode_attn: Callable[..., Any] | None = None
 
 
 def _dense_ffn(h: jax.Array, blk: Params, *,
@@ -416,9 +454,14 @@ def moe_feed_forward(**settings) -> Callable:
     if "held" in settings:
         @jax.named_scope("moe")
         def routed(h, blk):
+            # the decode step's tokens [slots, d] are one row of them
+            one_row = h.ndim == 2
             out, ids, counts, balance = routed_ffn(
-                h, blk["router"], blk["router_bias"], blk["experts"],
-                blk.get("shared"), **settings)
+                h[None] if one_row else h, blk["router"],
+                blk["router_bias"], blk["experts"], blk.get("shared"),
+                **settings)
+            if one_row:
+                out, ids = out[0], ids[0]
             return out, {"routing": ids, "counts": counts, "loss": balance}
         return routed
 
@@ -469,15 +512,20 @@ def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
                        rope_factor: float = 1.0, rope_original_len: int = 0,
                        rope_beta_fast: float = 32.0,
                        rope_beta_slow: float = 1.0, rope_mscale: float = 1.0,
-                       rope_mscale_all_dim: float = 0.0) -> Callable:
+                       rope_mscale_all_dim: float = 0.0,
+                       norm_eps: float = 1e-6) -> Callable:
     """The projections of latent attention for :func:`make_block`
     (arXiv:2405.04434 §2.1): ``project(h, blk, positions) -> (q, k, v,
-    scale)`` in the [b, s, heads, width] layout, over a block's
+    scale, rows)`` in the [b, s, heads, width] layout, over a block's
     ``wq_a``/``q_norm``/``wq_b``/``wkv_a``/``kv_norm``/``wkv_b``. Query
     and key are ``qk_nope_dim + qk_rope_dim`` wide, the rotated part
     last and the key's one row shared by all heads; the value
     ``v_head_dim``. ``scale`` is ``(qk width)^-½`` times the square of
-    YaRN's ``mscale_all_dim`` factor."""
+    YaRN's ``mscale_all_dim`` factor. ``rows`` is what a decode cache
+    keeps of a token, one row for all heads: the normed latent [b, s,
+    kv_latent] and the rotated key [b, s, qk_rope_dim]. ``project.decode``
+    is the same attention for one token against a cache of such rows
+    (:func:`_latent_decode_attention`)."""
     inv_freq = _yarn_inv_freq(qk_rope_dim, rope_theta, rope_factor,
                               rope_original_len, rope_beta_fast,
                               rope_beta_slow)
@@ -491,20 +539,27 @@ def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
         if positions is None:
             positions = jnp.arange(s)
         q = jnp.einsum("bsr,rhe->bshe", _rms_norm(h @ blk["wq_a"],
-                                                   blk["q_norm"]),
+                                                   blk["q_norm"], norm_eps),
                        blk["wq_b"])
         kv_a = h @ blk["wkv_a"]
         latent, k_rope = kv_a[..., :-qk_rope_dim], kv_a[..., -qk_rope_dim:]
-        kv = jnp.einsum("bsr,rhe->bshe", _rms_norm(latent, blk["kv_norm"]),
-                        blk["wkv_b"])
+        latent = _rms_norm(latent, blk["kv_norm"], norm_eps)
+        kv = jnp.einsum("bsr,rhe->bshe", latent, blk["wkv_b"])
         rot = functools.partial(_rotate, positions=positions,
                                 inv_freq=inv_freq, mscale=cos_scale)
         q = jnp.concatenate([q[..., :qk_nope_dim],
                              rot(q[..., qk_nope_dim:])], axis=-1)
-        k_rope = jnp.broadcast_to(rot(k_rope[:, :, None, :]),
-                                  (b, s, num_heads, qk_rope_dim))
-        k = jnp.concatenate([kv[..., :qk_nope_dim], k_rope], axis=-1)
-        return q, k, kv[..., qk_nope_dim:], scale
+        k_rope = rot(k_rope[:, :, None, :])
+        k = jnp.concatenate(
+            [kv[..., :qk_nope_dim],
+             jnp.broadcast_to(k_rope, (b, s, num_heads, qk_rope_dim))],
+            axis=-1)
+        return q, k, kv[..., qk_nope_dim:], scale, (latent, k_rope[:, :, 0])
+
+    project.decode = functools.partial(
+        _latent_decode_attention, qk_nope_dim=qk_nope_dim, scale=scale,
+        rotate=functools.partial(_rotate, inv_freq=inv_freq,
+                                 mscale=cos_scale))
     return project
 
 
@@ -512,7 +567,8 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
                model_axis: str | None = None,
                feed_forward: Callable | None = None,
                projections: Callable | None = None,
-               residual: Residual = PLAIN) -> Block:
+               residual: Residual = PLAIN, out_norm: bool = False,
+               norm_eps: float = 1e-6) -> Block:
     """The one place a layer's kind is decided: which attention behind
     which projections, which feed-forward, which residual rule, under
     which mesh axes.
@@ -550,7 +606,14 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
     ``residual``: the :class:`Residual` rule of both sublayers,
     :data:`PLAIN` or :func:`stream_residual`; a sublayer's maps are the
     block's ``mix1`` (attention) and ``mix2`` (feed-forward).
+
+    ``out_norm``: a second RMSNorm a sublayer, on its OUTPUT before it
+    joins the residual (sandwich norms, arXiv:2504.07866 §2): ``x +
+    norm(F(norm x))`` over the block's ``ln1_out`` and ``ln2_out``.
+    ``norm_eps`` is the epsilon of every norm of the block.
     """
+    norm = (_rms_norm if norm_eps == 1e-6
+            else functools.partial(_rms_norm, eps=norm_eps))
     attention = attention_fn or local_self_attention
     if feed_forward is None:
         feed_forward = functools.partial(_dense_ffn, model_axis=model_axis)
@@ -567,7 +630,7 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         qkv = jnp.einsum("bsd,dte->bste", h, blk["wqkv"])  # e = d/m
         return (*(qkv[:, :, i].reshape(b, -1, num_heads // m,
                                        d // num_heads) for i in range(3)),
-                None)
+                None, None)
 
     project = projections or wqkv_projections
 
@@ -577,14 +640,15 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         """Pre-norm attention sublayer: the residual after
         wo(attn(project(ln1(read(x))))).
 
-        ``return_kv``: also return this layer's K/V in the [b, s, h, hd]
-        residual layout — what the decode prefill scatters into the
-        paged KV cache."""
+        ``return_kv``: also return what the decode prefill scatters
+        into the paged KV cache: this layer's K/V in the [b, s, h, hd]
+        residual layout, or the rows the projections say a cache keeps
+        (a latent and a rotated key a token, [b, s, width])."""
         u, kept = residual.read(x, blk.get("mix1"))
         b = u.shape[0]
         # the norm in what the rule read; the products in the weights'
-        q, k, v, scale = project(
-            _rms_norm(u, blk["ln1"]).astype(blk["wo"].dtype), blk, positions)
+        q, k, v, scale, rows = project(
+            norm(u, blk["ln1"]).astype(blk["wo"].dtype), blk, positions)
         kw = {} if scale is None else {"scale": scale}
         wide = q.shape[-1] - v.shape[-1]
         vk = jnp.pad(v, ((0, 0),) * 3 + ((0, wide),)) if wide else v
@@ -607,9 +671,11 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         proj = o.reshape(b, -1, o.shape[2] * o.shape[3]) @ blk["wo"]
         if model_axis:
             proj = lax.psum(proj, model_axis)
+        if out_norm:
+            proj = norm(proj, blk["ln1_out"])
         out = residual.write(kept, proj)
         if return_kv:
-            return out, k, v
+            return (out, *(rows or (k, v)))
         return out
 
     @jax.named_scope("ffn")
@@ -617,10 +683,16 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         """Pre-norm FFN sublayer: the residual after
         feed_forward(ln2(read(x))), aux."""
         u, kept = residual.read(x, blk.get("mix2"))
-        mlp, aux = feed_forward(_rms_norm(u, blk["ln2"]), blk)
+        mlp, aux = feed_forward(norm(u, blk["ln2"]), blk)
+        if out_norm:
+            mlp = norm(mlp, blk["ln2_out"])
         return residual.write(kept, mlp), aux
 
-    return Block(attn, ffn, residual)
+    decode_attn = None
+    if hasattr(project, "decode"):
+        decode_attn = functools.partial(project.decode, norm=norm,
+                                        out_norm=out_norm)
+    return Block(attn, ffn, residual, norm, decode_attn)
 
 
 def apply(params: Params, tokens: jax.Array, *,
@@ -649,8 +721,7 @@ def apply(params: Params, tokens: jax.Array, *,
     if positions is None:
         positions = jnp.arange(s)
     p = _cast(params, compute_dtype)
-    blocks = (list(block) if not isinstance(block, Block)
-              else [block] * len(p["blocks"]))
+    blocks = _per_layer(block, len(p["blocks"]))
     layers = {id(bk): _layer(bk, positions, remat, remat_policy)
               for bk in blocks}
     x = blocks[0].residual.start(_embed(p, tokens, positions))
@@ -660,7 +731,7 @@ def apply(params: Params, tokens: jax.Array, *,
         x, aux = layers[id(bk)](x, blk)
         aux_total = aux_total + _loss_of(aux, routed)
     h = blocks[-1].residual.end(x)
-    logits = _head(p, h)
+    logits = _head(p, h, norm=blocks[-1].norm)
     if train and "nextn" in p:
         loss, aux = _nextn_loss(p, h, tokens, layers[id(blocks[-1])],
                                 blocks[-1].residual)
@@ -668,9 +739,21 @@ def apply(params: Params, tokens: jax.Array, *,
                      + _loss_of(aux, routed))
     if not return_aux:
         return logits
-    stacked = ({k: jnp.stack([a[k] for a in routed]) for k in routed[0]}
-               if routed else {})
-    return logits, {"loss": aux_total, **stacked}
+    return logits, {"loss": aux_total, **_stacked(routed)}
+
+
+def _per_layer(block: "Block | tuple[Block, ...]", layers: int
+               ) -> tuple[Block, ...]:
+    """One block a layer: a forward's ``block`` is one for all, or one
+    each."""
+    return tuple(block) if not isinstance(block, Block) else (block,) * layers
+
+
+def _stacked(routed: list) -> dict:
+    """What per-token routed layers said, a leading dimension a layer
+    (empty without such layers)."""
+    return ({k: jnp.stack([a[k] for a in routed]) for k in routed[0]}
+            if routed else {})
 
 
 def _loss_of(aux, routed: list):
@@ -752,14 +835,15 @@ def _embed(p: Params, tokens: jax.Array, positions: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("head")
-def _head(p: Params, x: jax.Array, final_norm: Params | None = None
-          ) -> jax.Array:
-    """Final norm (the tree's, or the one handed in) and the head: [...,
-    d] → float32 logits, through the tree's own ``head`` or the
-    embedding transposed."""
-    x = _rms_norm(x, final_norm or p["final_norm"])
+def _head(p: Params, x: jax.Array, final_norm: Params | None = None,
+          norm: Callable = _rms_norm) -> jax.Array:
+    """Final norm (the tree's, or the one handed in; ``norm`` the
+    block's) and the head: [..., d] → float32 logits, through the tree's
+    own ``head`` or the embedding transposed."""
+    x = norm(x, final_norm or p["final_norm"])
     w = p["head"] if "head" in p else p["embed"].T
-    return (x @ w).astype(jnp.float32)
+    # (a float32 residual meets the head in the head's dtype)
+    return (x.astype(w.dtype) @ w).astype(jnp.float32)
 
 
 def _one_stream(block: Block, forward: str) -> None:
@@ -780,40 +864,57 @@ _DECODE_NEG = -1e30  # finite mask value: an all-masked idle slot's
 # softmax degrades to uniform-over-garbage (ignored) instead of NaN
 
 
-def prefill_with_kv(params: Params, tokens: jax.Array, *, block: Block,
+def prefill_with_kv(params: Params, tokens: jax.Array, *,
+                    block: "Block | tuple[Block, ...]",
                     positions: jax.Array | None = None,
-                    compute_dtype=jnp.bfloat16
-                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                    compute_dtype=jnp.bfloat16, return_routing: bool = False
+                    ) -> tuple[jax.Array, ...]:
     """Prompt prefill: the standard causal forward (through the block's
     CONFIGURED attention — the fused pallas flash path or dense) that
-    also returns every layer's K/V for seeding a decode cache.
+    also returns what every layer keeps of a token for seeding a decode
+    cache.
 
     tokens [b, s] int32 → (logits [b, s, vocab] float32,
     k [L, b, s, h, hd], v [L, b, s, h, hd]) with K/V in the compute
-    dtype (the cache dtype). Dense-FFN models only (MoE routing is
-    batch-shaped; the registry never exports decode for it)."""
-    _one_stream(block, "prefill_with_kv")
+    dtype (the cache dtype); under latent projections the normed latent
+    [L, b, s, kv_latent] and the rotated key [L, b, s, qk_rope] in their
+    place. ``block`` as :func:`apply` takes it. Capacity routing is
+    batch-shaped and the registry exports no decode for it; per-token
+    routed layers route the prompt as :func:`apply` does, and
+    ``return_routing`` adds their choices [routed_layers, b, s, k] as a
+    fourth output and changes nothing else."""
+    for bk in _per_layer(block, 1):
+        _one_stream(bk, "prefill_with_kv")
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
     p = _cast(params, compute_dtype)
-    x = _embed(p, tokens, positions)
-    ks, vs = [], []
-    for blk in p["blocks"]:
-        x, k, v = block.attn(x, blk, return_kv=True)
+    blocks = _per_layer(block, len(p["blocks"]))
+    x = blocks[0].residual.start(_embed(p, tokens, positions))
+    ks, vs, routed = [], [], []
+    for bk, blk in zip(blocks, p["blocks"]):
+        x, k, v = bk.attn(x, blk, return_kv=True, positions=positions)
         ks.append(k)
         vs.append(v)
-        x, _ = block.ffn(x, blk)
-    return _head(p, x), jnp.stack(ks), jnp.stack(vs)
+        x, aux = bk.ffn(x, blk)
+        _loss_of(aux, routed)
+    out = (_head(p, blocks[-1].residual.end(x), norm=blocks[-1].norm),
+           jnp.stack(ks), jnp.stack(vs))
+    if return_routing:
+        out += (_stacked(routed)["routing"],)
+    return out
 
 
 def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
                 k_cache: jax.Array, v_cache: jax.Array,
                 block_tables: jax.Array, lengths: jax.Array, *,
-                ffn: Callable, num_heads: int = 4, block_size: int = 16,
+                ffn: "Callable | tuple[Callable, ...]",
+                attn: Callable | None = None, norm: Callable = _rms_norm,
+                residual: Residual = PLAIN,
+                num_heads: int = 4, block_size: int = 16,
                 compute_dtype=jnp.bfloat16,
-                attention_kernel: str = "dense"
-                ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                attention_kernel: str = "dense", return_aux: bool = False
+                ) -> tuple[jax.Array, ...]:
     """One incremental decode step over S slots sharing one paged KV
     cache — the single compiled shape every in-flight sequence runs
     in, whatever its length.
@@ -832,8 +933,16 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
       garbage the caller ignores).
 
     ``ffn`` is the feed-forward half of the model's block
-    (:func:`make_block`); the attention half is this step's own
-    (:func:`_decode_attn`: one token against the paged cache).
+    (:func:`make_block`), or one a layer; ``attn`` the block's
+    ``decode_attn``, its attention for one token against the paged cache
+    (None: the plain block's, :func:`_decode_attn`; a latent block's
+    keeps ``k_cache`` [L, N, B, kv_latent] and ``v_cache`` [L, N, B,
+    qk_rope], one row a token for all heads); ``norm`` the block's norm,
+    for the final one, and ``residual`` its rule (one stream).
+    ``return_aux`` adds a fourth output and changes nothing else: what
+    the per-token routed layers said of this step's tokens, ``routing``
+    [routed_layers, S, k] and ``counts`` [routed_layers, held] (empty
+    without such layers).
     ``attention_kernel`` selects the cache read: ``"dense"`` gathers
     every table entry into a [S, max_context, h, hd] view (the oracle
     path — O(max context) traffic per token), ``"paged"`` runs the
@@ -853,7 +962,7 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
             f"got {attention_kernel!r}")
     p = _cast(params, compute_dtype)
     num_slots = tokens.shape[0]
-    x = _embed(p, tokens, positions)  # [S, d]
+    x = residual.start(_embed(p, tokens, positions))  # [S, d]
     d = x.shape[-1]
     hd = d // num_heads
     scale = 1.0 / (hd ** 0.5)
@@ -863,13 +972,24 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
     offs = positions % block_size
     live = ctx_pos[None, :] < lengths[:, None]  # [S, ctx]
+    ffns = ffn if isinstance(ffn, tuple) else (ffn,) * len(p["blocks"])
+    routed = []
     for li, blk in enumerate(p["blocks"]):
-        x, k_cache, v_cache = _decode_attn(
-            x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
-            offs, live, num_heads=num_heads, scale=scale,
-            attention_kernel=attention_kernel)
-        x, _ = ffn(x, blk)
-    return _head(p, x), k_cache, v_cache
+        if attn is None:
+            x, k_cache, v_cache = _decode_attn(
+                x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
+                offs, live, num_heads=num_heads, scale=scale,
+                attention_kernel=attention_kernel)
+        else:
+            x, k_cache, v_cache = attn(
+                x, blk, li, k_cache, v_cache, block_tables, positions,
+                blk_ids, offs, live, attention_kernel=attention_kernel)
+        x, aux = ffns[li](x, blk)
+        _loss_of(aux, routed)
+    out = (_head(p, residual.end(x), norm=norm), k_cache, v_cache)
+    if return_aux:
+        out += (_stacked(routed),)
+    return out
 
 
 @jax.named_scope("attention")
@@ -923,6 +1043,70 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
         o = jnp.einsum("shk,skhd->shd", w, vp.astype(jnp.float32))
     o = o.astype(x.dtype).reshape(num_slots, d)
     return x + o @ blk["wo"], k_cache, v_cache
+
+
+@jax.named_scope("attention")
+def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
+                             positions, blk_ids, offs, live, *, qk_nope_dim,
+                             scale, rotate, norm, out_norm,
+                             attention_kernel):
+    """One layer's latent attention sublayer of :func:`decode_step`
+    (arXiv:2405.04434 §2.1, the absorbed form): the same function as
+    :func:`latent_projections`' expanded one, reassociated so that no
+    key or value a head of a cached token is ever built.
+
+    The cache keeps one row a token for all heads: ``k_cache`` [L, N, B,
+    kv_latent] the normed latent ``c``, ``v_cache`` [L, N, B, qk_rope or
+    wider] the rotated key ``k_r`` in its first ``qk_rope`` elements.
+    With ``wkv_b`` = ``[W_uk | W_uv]`` a head: the query's unrotated part
+    goes through ``W_uk`` (``q_c[h] = q_n[h] W_uk[h]ᵀ``, scope
+    ``latent_absorb``), scores are ``(q_c[h]·c_t + q_r[h]·k_r,t)·scale``
+    over the rows gathered once for all heads (``cache_gather``), the
+    softmax in float32, the weighted sum of latents ``o_c[h]`` goes
+    through ``W_uv`` (``latent_absorb``), then ``wo``, the output's norm
+    where the block has one, and the residual."""
+    if attention_kernel != "dense":
+        raise NotImplementedError(
+            "decode.attention_kernel='paged': the paged kernel reads keys "
+            "and values a head; a latent cache is read by the dense gather")
+    num_slots = x.shape[0]
+    latent_dim = blk["wkv_b"].shape[0]
+    rope_dim = blk["wkv_a"].shape[1] - latent_dim
+    ctx = live.shape[1]
+    h = norm(x, blk["ln1"]).astype(blk["wo"].dtype)
+    q = jnp.einsum("sr,rhe->she", norm(h @ blk["wq_a"], blk["q_norm"]),
+                   blk["wq_b"])
+    kv_a = h @ blk["wkv_a"]
+    c = norm(kv_a[:, :latent_dim], blk["kv_norm"])
+    # a slot's token is its own sequence's: positions [S] turn rows [S]
+    q_r = rotate(q[..., qk_nope_dim:], positions)
+    k_r = rotate(kv_a[:, None, latent_dim:], positions)[:, 0]
+    with jax.named_scope("cache_write"):
+        k_cache = k_cache.at[li, blk_ids, offs, :latent_dim].set(
+            c.astype(k_cache.dtype))
+        v_cache = v_cache.at[li, blk_ids, offs, :rope_dim].set(
+            k_r.astype(v_cache.dtype))
+    with jax.named_scope("latent_absorb"):
+        q_c = jnp.einsum("shn,rhn->shr", q[..., :qk_nope_dim],
+                         blk["wkv_b"][..., :qk_nope_dim])
+    with jax.named_scope("cache_gather"):
+        cs = k_cache[li][block_tables][..., :latent_dim].reshape(
+            num_slots, ctx, latent_dim)
+        krs = v_cache[li][block_tables][..., :rope_dim].reshape(
+            num_slots, ctx, rope_dim)
+    scores = (jnp.einsum("shr,skr->shk", q_c, cs,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("she,ske->shk", q_r, krs,
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
+    w = jax.nn.softmax(scores, axis=-1)
+    o_c = jnp.einsum("shk,skr->shr", w.astype(cs.dtype), cs)
+    with jax.named_scope("latent_absorb"):
+        o = jnp.einsum("shr,rhv->shv", o_c, blk["wkv_b"][..., qk_nope_dim:])
+    proj = o.astype(blk["wo"].dtype).reshape(num_slots, -1) @ blk["wo"]
+    if out_norm:
+        proj = norm(proj, blk["ln1_out"])
+    return x + proj, k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------

@@ -223,7 +223,11 @@ _declare(EventSchema(
                               "table_widths", "swap_policy",
                               "model_step", "cache_layout",
                               "cache_device_bytes", "step_temp_bytes",
-                              "whole_cache_copies")),
+                              "whole_cache_copies"),
+                             # cache_row_bytes: device bytes a cached
+                             # token takes, all layers; cache_arrays: the
+                             # two arrays' shapes
+                             ("cache_row_bytes", "cache_arrays")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of
@@ -318,7 +322,11 @@ _declare(EventSchema(
               "kv_blocks_total", "kv_blocks_reserved",
               "decode_waiting", "slots_live", "decode_steps",
               "decode_table_blocks", "tokens_sampled_device",
-              "tokens_sampled_host"),
+              "tokens_sampled_host",
+              # of the last decode step of a model that routes: the
+              # (token, expert) pairs on experts held here, and how many
+              # of those experts took any
+              "expert_pairs_held", "experts_touched"),
 ))
 
 # Load-generator journal (servesvc/loadgen.py loadgen.jsonl): every

@@ -110,10 +110,15 @@ PREFETCH_PUT = "dml.prefetch.put"
 #: and write) and moe (router, sort, grouped product, combine, shared
 #: expert); mtp is the next-next-token module, whose layer's scopes nest
 #: in it. Read by: latent_attention_ms_per_step, moe_ms_per_step,
-#: residual_mix_ms_per_step, mtp_ms_per_step
+#: residual_mix_ms_per_step, mtp_ms_per_step. In a latent block's decode
+#: step, inside attention beside cache_write and cache_gather:
+#: latent_absorb (the query through W_uk, the weighted latents through
+#: W_uv), and moe inside ffn as in training. Read by:
+#: decode_absorb_ms_per_step, decode_moe_ms_per_step (and attention
+#: whole by decode_attention_ms_per_step)
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
           "ffn", "head", "loss", "aggregate", "update", "timing",
-          "residual_mix", "moe", "mtp")
+          "residual_mix", "moe", "mtp", "latent_absorb")
 
 #: a host span: ``with span(SERVE_STREAM, id=...):``
 span = jax.profiler.TraceAnnotation

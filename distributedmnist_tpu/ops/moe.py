@@ -254,10 +254,23 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w1: jax.Array, w2: jax.Array,
 # selection bias, none dropped, over the share of experts this chip holds
 # ---------------------------------------------------------------------------
 
-#: rows of one tile of the grouped product: every held expert's pairs
-#: are padded to a whole number of tiles, so one tile meets one expert's
-#: weights
+#: rows of one tile of the grouped product at most: every held expert's
+#: pairs are padded to a whole number of tiles, so one tile meets one
+#: expert's weights
 TILE_ROWS = 512
+#: and at least: a bfloat16 tile's sublanes
+MIN_TILE_ROWS = 16
+
+
+def tile_rows(pairs: int, total: int) -> int:
+    """The tile for ``pairs`` (token, expert) pairs over ``total``
+    experts: the power of two at or above twice an expert's even share,
+    between :data:`MIN_TILE_ROWS` and :data:`TILE_ROWS`. A training
+    batch (thousands of pairs an expert) takes the largest; a decode
+    step's few pairs an expert would each be padded to a tile sized for
+    it, 512 rows of products for two of tokens."""
+    share = 2 * -(-pairs // total)
+    return min(TILE_ROWS, max(MIN_TILE_ROWS, 1 << (share - 1).bit_length()))
 
 
 def gated_unit(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
@@ -353,6 +366,11 @@ def _held_experts(x, gates, weights, plan):
     return _held_experts_fwd(x, gates, weights, plan)[0]
 
 
+def _tile_of(plan) -> int:
+    """The tile a plan was made for: its rows over its tiles."""
+    return plan[0].shape[0] // plan[2].shape[0]
+
+
 def _tile_rows(plan, t, tile, top_k):
     row_pair = lax.dynamic_slice_in_dim(plan[0], t * tile, tile)
     return row_pair, row_pair // top_k
@@ -365,7 +383,7 @@ def _held_experts_fwd(x, gates, weights, plan):
     ``x``, meeting one expert's weights and writing its rows of the
     buffer; then every pair reads its row back."""
     row_pair, pair_row, tile_expert, tiles, _ = plan
-    tile, top_k = TILE_ROWS, gates.shape[1]
+    tile, top_k = _tile_of(plan), gates.shape[1]
     w_gate, w_up, w_down = weights
 
     def body(t, ys):
@@ -389,7 +407,7 @@ def _held_experts_bwd(saved, dy):
     product; the weights' cotangents add up in float32."""
     x, gates, weights, plan = saved
     row_pair, pair_row, tile_expert, tiles, _ = plan
-    tile, top_k = TILE_ROWS, gates.shape[1]
+    tile, top_k = _tile_of(plan), gates.shape[1]
     flat_gates = gates.reshape(-1)
 
     def body(t, carry):
@@ -454,7 +472,7 @@ def routed_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     # norm there; the experts' products in their weights' dtype
     ids, gates = route_tokens(flat, router_w, bias, top_k, scaling)
     flat = flat.astype(experts["w_gate"].dtype)
-    plan = _plan(ids, held[0], held[1], TILE_ROWS)
+    plan = _plan(ids, held[0], held[1], tile_rows(ids.size, total))
     out = _held_experts(flat, gates,
                         (experts["w_gate"], experts["w_up"],
                          experts["w_down"]), plan)

@@ -93,7 +93,7 @@ import numpy as np
 from ..core.config import ConfigError
 from ..models.registry import sample_token
 from ..obsv import spans
-from .kv_cache import PagedKVCache, stored_head_dim
+from .kv_cache import PagedKVCache, cache_shapes, stored_head_dim
 from .server import ServingReplica, _Pending
 
 
@@ -137,9 +137,13 @@ class DecodeReplica(ServingReplica):
         if (self.model.decode_prefill is None
                 or self.model.decode_step is None):
             raise ConfigError(
-                f"model {self.cfg.model.name!r} exports no decode step "
-                "(decode needs a dense-FFN causal LM; MoE and "
-                "classifier families have no incremental export)")
+                f"model {self.cfg.model.name!r} exports no decode step: "
+                "the registry exports one for a causal LM with the plain "
+                "block or a latent one (dense, gated or per-token routed "
+                "feed-forward), and none for a classifier, for capacity "
+                "routing (model.num_experts), for more than one residual "
+                "stream, or for a gated or sandwich-normed block that "
+                "does not attend through a latent")
         self.dcfg = dcfg or self.cfg.decode
         self.dcfg.validate()
         if (self.dcfg.max_prompt_len + self.dcfg.max_new_tokens
@@ -153,11 +157,19 @@ class DecodeReplica(ServingReplica):
         dtype = jnp.dtype(
             effective_model_config(self.cfg, serving=True).compute_dtype)
         layers, heads, head_dim = self.model.decode_cache_shape
-        # a head as wide as the device stores whole (kv_cache.py): the
-        # step and the prompt's scatter then take the arrays as they lie
-        head_dim = stored_head_dim(
-            (layers, self.dcfg.num_blocks, self.dcfg.block_size, heads,
-             head_dim), dtype, self.topo.replicated)
+        # rows as wide as the device stores whole (kv_cache.py): the
+        # step and the prompt's scatter then take the arrays as they
+        # lie. One width for keys and values a head; one each where the
+        # model keeps a pair of rows a token (a latent, a rotated key)
+        shapes = cache_shapes(layers, self.dcfg.num_blocks,
+                              self.dcfg.block_size, heads, head_dim)
+        if isinstance(head_dim, tuple):
+            head_dim = tuple(stored_head_dim(shape, dtype,
+                                             self.topo.replicated)
+                             for shape in shapes)
+        else:
+            head_dim = stored_head_dim(shapes[0], dtype,
+                                       self.topo.replicated)
         self.cache = PagedKVCache(
             layers, self.dcfg.num_blocks, self.dcfg.block_size,
             heads, head_dim, self.dcfg.max_blocks_per_seq(), dtype=dtype)
@@ -177,19 +189,25 @@ class DecodeReplica(ServingReplica):
         block_size = self.dcfg.block_size
         attention_kernel = self.dcfg.attention_kernel
 
+        # a model with per-token routed layers also says how many
+        # (token, expert) pairs each expert held here took of the step's
+        # tokens, [routed_layers, held]: fetched with the greedy tokens
+        counts = ({"return_counts": True} if self.model.decode_counts
+                  else {})
+
         # a named function, not a functools.partial: a profiler trace
         # calls the program `jit_decode_step`, a partial `jit__unknown`
         def decode_step(params, tokens, positions, k_cache, v_cache,
                         block_tables, lengths):
-            logits, k_cache, v_cache = model_step(
+            logits, k_cache, v_cache, *pairs = model_step(
                 params, tokens, positions, k_cache, v_cache, block_tables,
                 lengths, block_size=block_size,
-                attention_kernel=attention_kernel)
+                attention_kernel=attention_kernel, **counts)
             # the greedy pick of every slot, made where the logits are:
             # 4 bytes a slot to fetch, not a [slots, vocab] float32 array
             with jax.named_scope("head"):
                 greedy = sample_token(logits)
-            return logits, greedy, k_cache, v_cache
+            return logits, greedy, k_cache, v_cache, *pairs
 
         # the cache arrays are rebound to the step's outputs at every
         # call site, so they are donated: the token's scatter writes in
@@ -206,6 +224,10 @@ class DecodeReplica(ServingReplica):
         self.tokens_streamed = 0
         self.decode_steps = 0      # dispatches of the jitted decode step
         self.decode_table_blocks = 0  # the last dispatch's table width
+        # of the last step, where the model routes: its held experts'
+        # pair counts [routed_layers, held], left on the device until a
+        # heartbeat reads them
+        self._expert_pairs: jax.Array | None = None
         # tokens by where they were picked: the step's own greedy pick,
         # or `_sample` (a draw, and every prefill's first token)
         self.tokens_sampled_device = 0
@@ -350,7 +372,19 @@ class DecodeReplica(ServingReplica):
                 "decode_steps": self.decode_steps,
                 "decode_table_blocks": self.decode_table_blocks,
                 "tokens_sampled_device": self.tokens_sampled_device,
-                "tokens_sampled_host": self.tokens_sampled_host}
+                "tokens_sampled_host": self.tokens_sampled_host,
+                **self._routing_fields()}
+
+    def _routing_fields(self) -> dict:
+        """Of the last step of a model that routes: the pairs that
+        landed on experts held here, and how many of those experts took
+        any. Fetched here, where a heartbeat is written, and not an
+        iteration."""
+        if self._expert_pairs is None:
+            return {}
+        pairs = np.asarray(self._expert_pairs)
+        return {"expert_pairs_held": int(pairs.sum()),
+                "experts_touched": int(np.count_nonzero(pairs))}
 
     # -- the decode loop ------------------------------------------------
 
@@ -571,7 +605,7 @@ class DecodeReplica(ServingReplica):
             with spans.span(spans.SERVE_STEP_DISPATCH, live=len(active),
                             waiting=len(self._waiting), version=ver,
                             blocks=width):
-                logits, greedy, self.cache.k, self.cache.v = (
+                logits, greedy, self.cache.k, self.cache.v, *pairs = (
                     self._step(width)(
                         self._params_for(ver), tokens, positions,
                         self.cache.k, self.cache.v, tables, lengths))
@@ -579,6 +613,8 @@ class DecodeReplica(ServingReplica):
                 self.decode_table_blocks = width
             with spans.span(spans.SERVE_STEP_FETCH):
                 on_host = jax.device_get(greedy)
+            if pairs:
+                self._expert_pairs = pairs[0]
             draws = sum(s.temperature > 0.0 for _, s in mine)
             # every slot's token before any is appended or streamed: one
             # span an iteration, never around a `dml.serve.stream`
@@ -710,7 +746,7 @@ class DecodeReplica(ServingReplica):
             self._steps[width] = self._decode_jit.lower(
                 self._params, idle, idle, self.cache.k, self.cache.v,
                 tables, idle).compile()
-            _, greedy, self.cache.k, self.cache.v = self._steps[width](
+            _, greedy, self.cache.k, self.cache.v, *_ = self._steps[width](
                 self._params, idle, idle, self.cache.k, self.cache.v,
                 tables, idle)
         jax.block_until_ready(greedy)
@@ -724,11 +760,16 @@ class DecodeReplica(ServingReplica):
         at = self.cache.k.format.layout
         dims = re.escape(f"[{','.join(map(str, self.cache.k.shape))}]")
         steps = [self._steps[w] for w in self._table_widths]
+        arrays = (self.cache.k, self.cache.v)
+        device_bytes = sum(a.on_device_size_in_bytes() for a in arrays)
         return {
             "cache_layout": (f"major_to_minor={tuple(at.major_to_minor)} "
                              f"tiling={tuple(at.tiling or ())}"),
-            "cache_device_bytes": (self.cache.k.on_device_size_in_bytes()
-                                   + self.cache.v.on_device_size_in_bytes()),
+            "cache_device_bytes": device_bytes,
+            # what a cached token takes on the device, every layer's rows
+            "cache_row_bytes": device_bytes // (self.dcfg.num_blocks
+                                                * self.dcfg.block_size),
+            "cache_arrays": [list(a.shape) for a in arrays],
             "step_temp_bytes": [s.memory_analysis().temp_size_in_bytes
                                 for s in steps],
             "whole_cache_copies": [

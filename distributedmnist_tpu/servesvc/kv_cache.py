@@ -23,6 +23,12 @@ both arrays in and back out. The programs touch a row's first
 them a token (the decode replica) builds the cache as wide as
 :func:`stored_head_dim` answers (ROADMAP S1).
 
+A model that attends through a latent keeps, in place of keys and values
+a head, ONE row a token a layer for all heads in each array: the latent
+in ``k`` and the rotated key in ``v``, ``[layers, num_blocks, block_size,
+width]`` (:func:`cache_shapes`; its ``decode_cache_shape`` carries the
+pair of widths). The allocator, the tables and the scatter are the same.
+
 Block 0 is the **reserved null block**: idle decode slots point their
 whole table (and their writes) at it, so the fixed-shape step never
 needs a branch — garbage lands in a block no sequence owns.
@@ -94,10 +100,26 @@ class BlockAllocator:
             self._free.append(b)
 
 
+def cache_shapes(num_layers: int, num_blocks: int, block_size: int,
+                 num_heads: int, head_dim) -> tuple[tuple[int, ...], ...]:
+    """The shapes of the cache's two arrays from a model's
+    ``decode_cache_shape``: keys and values a head, ``[layers,
+    num_blocks, block_size, heads, head_dim]`` both; or, where
+    ``head_dim`` is a pair of widths, one row a token for all heads in
+    each (a latent beside a rotated key), ``[layers, num_blocks,
+    block_size, width]``: a block's positions are then the second-minor
+    dimension, which a tiled layout pads to eight rows and would pad a
+    dimension of one head to as well."""
+    if isinstance(head_dim, (tuple, list)):
+        return tuple((num_layers, num_blocks, block_size, int(w))
+                     for w in head_dim)
+    return ((num_layers, num_blocks, block_size, num_heads, head_dim),) * 2
+
+
 def stored_head_dim(shape: tuple[int, ...], dtype,
                     sharding: jax.sharding.Sharding | None = None) -> int:
-    """The head width at which a cache of ``shape`` (``[layers,
-    num_blocks, block_size, heads, head_dim]``) is stored with its rows
+    """The row width at which a cache array of ``shape`` (one of
+    :func:`cache_shapes`', the row's width last) is stored with its rows
     whole. The compiler is asked how the device lays the shape out (a
     program that returns zeros of it, compiled and not run): where the
     last dimension stays minor, ``head_dim`` itself (a CPU; a 128-wide
@@ -129,8 +151,9 @@ def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
     """Scatter one sequence's prefill K/V into its blocks.
 
     ``ks``/``vs`` [L, s_pad, h, hd] (the prefill export for ONE
-    sequence, padded to its prompt bucket) go into the first ``hd``
-    elements of the cache's rows; positions ``< length`` land
+    sequence, padded to its prompt bucket; [L, s_pad, width] each where
+    the cache keeps one row a token) go into the first elements of the
+    cache's rows; positions ``< length`` land
     at ``block_table[pos // block_size]`` offset ``pos % block_size``,
     padding positions are routed to the null block. jit this once per
     prompt bucket shape."""
@@ -139,10 +162,10 @@ def write_prompt_kv(k_cache: jax.Array, v_cache: jax.Array,
     blk_ids = jnp.where(pos < length,
                         block_table[pos // block_size], NULL_BLOCK)
     offs = pos % block_size
-    hd = ks.shape[-1]     # a row may be stored wider (stored_head_dim)
-    k_cache = k_cache.at[:, blk_ids, offs, :, :hd].set(
+    # a row may be stored wider (stored_head_dim)
+    k_cache = k_cache.at[:, blk_ids, offs, ..., :ks.shape[-1]].set(
         ks.astype(k_cache.dtype))
-    v_cache = v_cache.at[:, blk_ids, offs, :, :hd].set(
+    v_cache = v_cache.at[:, blk_ids, offs, ..., :vs.shape[-1]].set(
         vs.astype(v_cache.dtype))
     return k_cache, v_cache
 
@@ -155,14 +178,15 @@ class PagedKVCache:
     (single-writer: the decode loop thread)."""
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
-                 num_heads: int, head_dim: int,
+                 num_heads: int, head_dim: "int | tuple[int, int]",
                  max_blocks_per_seq: int, dtype=jnp.float32):
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.allocator = BlockAllocator(num_blocks)
-        shape = (num_layers, num_blocks, block_size, num_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        k_shape, v_shape = cache_shapes(num_layers, num_blocks, block_size,
+                                        num_heads, head_dim)
+        self.k = jnp.zeros(k_shape, dtype)
+        self.v = jnp.zeros(v_shape, dtype)
         # write_prompt's caller rebinds self.k/self.v to the outputs —
         # donate the cache operands so the scatter updates in place.
         # The function itself with a static argument, not a partial of

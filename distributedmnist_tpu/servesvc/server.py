@@ -159,7 +159,11 @@ class ServingReplica:
                 f"tier; valid tiers: "
                 f"{', '.join(SERVING_PRECISION_TIERS)}")
         try:
-            self.template = init_train_state(self.model, cfg, self.topo)
+            # shapes and dtypes only: a restore reads the tree's
+            # structure off it and returns the saved arrays, so no
+            # second copy of the weights lies beside the served one
+            self.template = jax.eval_shape(
+                lambda: init_train_state(self.model, cfg, self.topo))
             self._param_specs = state_partition_specs(
                 self.model, cfg, self.topo).params
         except ValueError as e:

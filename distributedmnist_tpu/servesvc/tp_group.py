@@ -327,7 +327,9 @@ def run_rank_follower(train_dir: str | Path, serve_dir: str | Path,
     topo = make_topology(MeshConfig(num_replicas=1),
                          devices=jax.devices()[:1])
     model = get_model(effective_model_config(cfg, serving=True))
-    template = init_train_state(model, cfg, topo)
+    # shapes and dtypes only (as servesvc/server.py): the restore
+    # returns the saved arrays
+    template = jax.eval_shape(lambda: init_train_state(model, cfg, topo))
     tp_specs = (model.tp_param_specs("model")
                 if getattr(model, "tp_param_specs", None) else None)
 

@@ -144,6 +144,10 @@ def test_phases_pass_tiny_on_the_cpu_mesh(tmp_path, scratch_cache):
     wide = kern["wide_cache_rows"]
     assert wide["stored_head_dim"] == wide["head_dim"] == 64
     assert wide["logits_bit_equal"] and wide["cache_bytes_equal"]
+    # the absorbed decode step against the expanded forward, float32
+    latent = kern["latent_decode"]
+    assert latent["steps"] == 8 and latent["absorbed_vs_expanded"] <= 1e-4
+    assert latent["cache_arrays"] == [[2, 33, 8, 8], [2, 33, 8, 8]]
 
 
 def test_phase_check_failure_raises(tmp_path, scratch_cache):
