@@ -47,6 +47,7 @@ import os
 import re
 import struct
 import sys
+from bisect import bisect_left
 from pathlib import Path
 
 if __package__ in (None, ""):            # run as a script
@@ -432,9 +433,10 @@ def scope_table(trace: dict, program: str) -> dict:
     ops = [e for e in tr._line(tr.device_planes(trace)[0], tr.OPS_LINE)
            if e[4].get("program_id") in fingerprints] if runs else []
     own = {id(ev): ns for ev, ns in tr.self_times(ops)}
-    scoped = _scoped(ops)
-    found = [(run[0], [row for row in scoped
-                       if run[1] <= row[0][1] < run[1] + run[2]])
+    scoped = _scoped(ops)                      # in start order
+    starts = [row[0][1] for row in scoped]
+    found = [(run[0], scoped[bisect_left(starts, run[1]):
+                             bisect_left(starts, run[1] + run[2])])
              for run in runs]
     counts: dict[str, list] = {}
     for name, mine in found:

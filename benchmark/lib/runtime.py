@@ -58,6 +58,8 @@ class Runtime:
         self.compiles_in_window: int | None = None
         self._annotation = None
         self.traced = False
+        #: seconds the run spent on its own trace, and the trace's sizes
+        self.trace_cost: dict[str, float] = {}
         self.marks: dict[str, float] = {}   # seconds since process start
 
     # -- stdout: JSON lines, the result last -----------------------------
@@ -104,14 +106,24 @@ class Runtime:
 
         self._annotation.__exit__(None, None, None)
         self._annotation = None
+        t0 = time.time()
         jax.profiler.stop_trace()
+        self.trace_cost["stop_s"] = time.time() - t0
         self.traced = True
 
     def reduced_trace(self) -> dict:
+        """The trace as numbers; what reading it cost and the sizes the
+        cost grows with go into ``trace_cost``."""
         if not self.traced:
             raise BenchmarkError("the driver took no trace")
         path = trace_reduce.find_xplane(str(self.trace_dir))
-        return trace_reduce.reduce(trace_reduce.load(path))
+        t0 = time.time()
+        trace = trace_reduce.load(path)
+        t1 = time.time()
+        reduced, sizes = trace_reduce.reduce_sized(trace)
+        self.trace_cost.update(load_s=t1 - t0, reduce_s=time.time() - t1,
+                               **sizes)
+        return reduced
 
     # -- the device ------------------------------------------------------
 

@@ -32,6 +32,7 @@ import glob
 import os
 import re
 import sys
+from bisect import insort
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
@@ -138,11 +139,6 @@ def merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return [(lo, hi) for lo, hi in out]
 
 
-def clip(intervals, lo: float, hi: float):
-    return [(max(a, lo), min(b, hi)) for a, b in intervals
-            if min(b, hi) > max(a, lo)]
-
-
 def total(intervals) -> float:
     return sum(b - a for a, b in intervals)
 
@@ -160,11 +156,24 @@ def gaps(merged, lo: float, hi: float) -> list[tuple[float, float]]:
 
 
 def subtract(intervals, cover) -> list[tuple[float, float]]:
-    """The part of ``intervals`` that ``cover`` does not overlap."""
+    """The part of ``intervals`` that ``cover`` does not overlap. Both
+    are merged first, so one walk over the two sorted lists does it: the
+    trace of a fast decode step has 150,000 idle gaps to take a few
+    thousand spans from."""
     cover = merge(cover)
-    out = []
+    out, first = [], 0
     for a, b in merge(intervals):
-        out += gaps(clip(cover, a, b), a, b)
+        # what ends before this interval begins meets no later one
+        while first < len(cover) and cover[first][1] <= a:
+            first += 1
+        at, k = a, first
+        while k < len(cover) and cover[k][0] < b:
+            if cover[k][0] > at:
+                out.append((at, cover[k][0]))
+            at = max(at, min(cover[k][1], b))
+            k += 1
+        if b > at:
+            out.append((at, b))
     return out
 
 
@@ -219,7 +228,8 @@ def is_collective(opcode: str) -> bool:
 def _host_label(host: list[list], lo: float, hi: float) -> str:
     """What the host was doing during lo..hi: the span that overlaps it
     most, the shortest such where several cover it whole (the innermost
-    of nested spans), the benchmark's own window annotation aside."""
+    of nested spans), the benchmark's own window annotation aside.
+    Among equals the first in ``host`` wins."""
     best, best_key = "nothing recorded on the host", (0.0, 0.0)
     for name, start, dur, *_ in host:
         if name == WINDOW_ANNOTATION:
@@ -233,10 +243,36 @@ def _host_label(host: list[list], lo: float, hi: float) -> str:
     return best
 
 
+def host_labels(host: list[list], spans) -> list[str]:
+    """:func:`_host_label` of each of ``spans``, which come in the order
+    of their starts (as :func:`gaps` gives them), in one walk over them
+    and the host's events: a span is judged against the events open at
+    it, a handful (nesting depth times threads), and not against every
+    event of the trace. They are kept in the order ``host`` has them, so
+    the ties fall as they do there."""
+    by_start = sorted(range(len(host)), key=lambda i: host[i][1])
+    out, open_, nxt = [], [], 0          # open_: indices into host, sorted
+    for lo, hi in spans:
+        while nxt < len(by_start) and host[by_start[nxt]][1] < hi:
+            insort(open_, by_start[nxt])
+            nxt += 1
+        # what has ended by here overlaps no later span either
+        open_ = [i for i in open_ if host[i][1] + host[i][2] > lo]
+        out.append(_host_label([host[i] for i in open_], lo, hi))
+    return out
+
+
 def reduce(trace: dict, top: int = 10) -> dict:
     """Everything the per-layer readers and the ``breakdown`` need.
     Times are seconds, averaged over the chips; program executions
     (``modules``) and idle gaps are the first chip's."""
+    return reduce_sized(trace, top)[0]
+
+
+def reduce_sized(trace: dict, top: int = 10) -> tuple[dict, dict]:
+    """:func:`reduce`, and beside it the sizes its cost grows with: the
+    operations of all chips inside the window, the first chip's idle
+    gaps and the host's events."""
     planes = device_planes(trace)
     if not planes:
         raise TraceError("no /device:TPU:<n> plane in the trace: "
@@ -251,11 +287,13 @@ def reduce(trace: dict, top: int = 10) -> dict:
 
     per_device, op_time, modules0, gap_time = [], {}, {}, {}
     pallas = collective = exposed = 0.0
+    sizes = {"ops": 0, "gaps": 0, "host_events": len(host)}
     for plane in planes:
         ops = inside(_line(plane, OPS_LINE))
         if not ops:
             raise TraceError(f"no operation ran on {plane['name']} in "
                              "the traced window")
+        sizes["ops"] += len(ops)
         busy = merge([(e[1], e[1] + e[2]) for e in ops])
         # the window is cut to the device's own activity: what the
         # profiler needs to start and stop is not the system's idleness
@@ -287,8 +325,9 @@ def reduce(trace: dict, top: int = 10) -> dict:
                         name, {"starts_ms": [], "durations_ms": []})
                     m["starts_ms"].append(start / 1e6)
                     m["durations_ms"].append(dur / 1e6)
-            for a, b in gaps(busy, lo, hi):
-                label = _host_label(host, a, b)
+            idle = gaps(busy, lo, hi)
+            sizes["gaps"] = len(idle)
+            for (a, b), label in zip(idle, host_labels(host, idle)):
                 gap_time[label] = gap_time.get(label, 0.0) + (b - a) / 1e9
     n = len(planes)
     ranked = lambda d: [[k, v] for k, v in sorted(  # noqa: E731
@@ -306,7 +345,7 @@ def reduce(trace: dict, top: int = 10) -> dict:
         # first chip by what the host was doing in each gap
         "device_ops": ranked({k: v / n for k, v in op_time.items()}),
         "idle_gaps": ranked(gap_time),
-    }
+    }, sizes
 
 
 def main_module(reduced: dict) -> tuple[str, dict]:

@@ -11,7 +11,11 @@ then shuffled) and an open loop sends exactly ``round(rate x seconds)``
 requests at the order statistics of uniform times, which is a Poisson
 process conditioned on its count. Two seeds then offer the same load
 in a different order, so run-to-run spread measures the system and not
-the dice.
+the dice. Where the order itself decides the work (a closed loop whose
+window opens on a young replica: which lengths come first decides when
+the longest context passes the step's next table width), the file
+gives ``sizes_seed``: every run then offers the sizes that seed draws,
+in its order, and ``--seed`` decides the tokens (and the weights) alone.
 
 Length specifications: ``{"dist": "loguniform", "lo", "hi"}``,
 ``{"dist": "lognormal", "median", "sigma", "lo", "hi"}`` (values
@@ -88,7 +92,18 @@ def closed_queues(traffic: dict, seed: int, clients: int,
     ``stagger_first_wave`` each client's first request asks for a
     seeded share of its drawn length, as if it had been running for a
     while: the slots then finish at different times from the start, as
-    they do in the steady state, and not all at once."""
+    they do in the steady state, and not all at once. With
+    ``sizes_seed`` the lengths, their order and the shares are that
+    seed's in every run, and only the prompts' tokens are ``seed``'s."""
+    if "sizes_seed" in traffic:
+        sized = dict(traffic)
+        queues = closed_queues(sized, sized.pop("sizes_seed"), clients, vocab)
+        rng = np.random.default_rng([int(seed), 0x70CE])
+        for queue in queues:
+            for req in queue:
+                req["prompt"] = rng.integers(
+                    0, vocab, len(req["prompt"])).tolist()
+        return queues
     rng = np.random.default_rng([int(seed), 0xC105])
     per_client = int(traffic["requests_per_client"])
     n = clients * per_client

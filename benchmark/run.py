@@ -13,7 +13,10 @@ line of standard output is the result object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, in a traced run
 ``breakdown``, and last ``compared``: each number the run compared
 beside its limit, which are also the last lines of standard error);
-everything else is on earlier lines. With ``--trace 0``
+everything else is on earlier lines, in a traced run ``{"event":
+"trace_reduced", ...}`` next before the result: the seconds the run spent
+stopping the profiler, loading the trace, reducing it and in the readers,
+and the trace's sizes that those grow with. With ``--trace 0``
 the metrics are the cell's end-to-end metrics, taken with the profiler
 off; with ``--trace 1`` they are its per-layer metrics, each by its own
 reader under ``benchmark/layer_metrics/``.
@@ -91,7 +94,11 @@ def measure(cell, rt: Runtime, root: Path = cell_lib.ROOT) -> dict:
               "failed": int(got["failed"])}
     if rt.trace:
         reduced = rt.reduced_trace()
+        t0 = time.time()
         result["metrics"] = per_layer_metrics(cell, reduced, counters, root)
+        # not a metric: how far this cell's traced run is from its limit
+        rt.say(event="trace_reduced", **rt.trace_cost,
+               readers_s=time.time() - t0)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"][:10],

@@ -105,6 +105,19 @@ def routed_toy_cell(traffic_name: str, experts: int = 64) -> cell_lib.Cell:
         "serve": TOY_SERVE_CONFIG["serve"]})
 
 
+@pytest.fixture(autouse=True)
+def routed_toy_unregistered():
+    """After a test that built a routed toy cell the stand-in leaves the
+    program's registry again: the registry is the process's, and a test
+    elsewhere that walks every registered model
+    (``tests/test_models.py``) would meet it, or not, by the order a
+    worker was handed its files in. Autouse in the modules that import
+    it beside :func:`routed_toy_cell`."""
+    yield
+    from distributedmnist_tpu.models import registry
+    registry._REGISTRY.pop("toy_routed", None)
+
+
 @pytest.fixture()
 def toy_runtime(tmp_path):
     def make(cell, seconds=2.0, seed=7, trace=False):

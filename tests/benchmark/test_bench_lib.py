@@ -65,7 +65,6 @@ def test_merge_gaps_subtract():
     assert merged == [(0, 3), (5, 8)]
     assert tr.total(merged) == 6
     assert tr.gaps(merged, 0, 10) == [(3, 5), (8, 10)]
-    assert tr.clip(merged, 2, 6) == [(2, 3), (5, 6)]
     # exposed collective time: the part no compute covers
     assert tr.subtract([(0, 10)], [(2, 4), (3, 6), (9, 12)]) == [
         (0, 2), (6, 9)]
@@ -184,18 +183,60 @@ def test_lengths_are_stratified_over_the_distribution():
                                 rng).tolist() == [7, 7, 7]
 
 
+CLOSED = {"requests_per_client": 3, "deadline_ms": 5, "prompt_len":
+          {"dist": "loguniform", "lo": 8, "hi": 16}, "max_tokens":
+          {"dist": "loguniform", "lo": 100, "hi": 200},
+          "stagger_first_wave": True}
+
+
+def _sizes(queues):
+    return [[(r["id"], len(r["prompt"]), r["max_tokens"]) for r in c]
+            for c in queues]
+
+
 def test_closed_queues_and_first_wave():
-    spec = {"requests_per_client": 3, "deadline_ms": 5, "prompt_len":
-            {"dist": "loguniform", "lo": 8, "hi": 16}, "max_tokens":
-            {"dist": "loguniform", "lo": 100, "hi": 200},
-            "stagger_first_wave": True}
-    q = traffic.closed_queues(spec, 1, 4, 50)
-    assert q == traffic.closed_queues(spec, 1, 4, 50)
+    q = traffic.closed_queues(CLOSED, 1, 4, 50)
+    assert q == traffic.closed_queues(CLOSED, 1, 4, 50)
     assert len(q) == 4 and all(len(c) == 3 for c in q)
     assert all(c[0]["max_tokens"] <= 200 for c in q)
     assert all(100 <= r["max_tokens"] <= 200 for c in q for r in c[1:])
     assert any(c[0]["max_tokens"] < 100 for c in q)
     assert len({r["id"] for c in q for r in c}) == 12
+
+
+def test_sizes_seed_gives_every_seed_one_seeds_sizes_and_its_own_tokens():
+    theirs = traffic.closed_queues(CLOSED, 7, 4, 50)
+    pinned = {**CLOSED, "sizes_seed": 7}
+    a = traffic.closed_queues(pinned, 1, 4, 50)
+    b = traffic.closed_queues(pinned, 2 ** 31 + 5, 4, 50)
+    assert a == traffic.closed_queues(pinned, 1, 4, 50)
+    # the work is seed 7's, shares of the first wave and order included
+    assert _sizes(a) == _sizes(b) == _sizes(theirs)
+    assert _sizes(traffic.closed_queues(CLOSED, 1, 4, 50)) != _sizes(theirs)
+    # the tokens are the run's own
+    prompts = lambda q: [r["prompt"] for c in q for r in c]  # noqa: E731
+    assert prompts(a) != prompts(b) and prompts(a) != prompts(theirs)
+    assert all(0 <= t < 50 for p in prompts(a) for t in p)
+    assert {k: v for k, v in a[0][0].items() if k != "prompt"} == {
+        k: v for k, v in theirs[0][0].items() if k != "prompt"}
+
+
+@pytest.mark.parametrize("cell_name, clients, vocab", [
+    ("opt-1.3b.serve_decode_closed", 32, 50272),
+    ("openpangu-ultra-moe-718b.serve_reason_closed", 128, 19200)])
+def test_a_closed_cell_offers_every_seed_the_same_sizes(cell_name, clients,
+                                                        vocab):
+    """A closed cell opens its window on a young replica, so the order
+    of the lengths decides the work: how long ``opt-1.3b``'s step runs
+    at its narrowest table; how many prefills stall the latent cell's
+    window and whether its end meets the widest table. The files pin
+    the sizes (PERF.md, PR 38)."""
+    from benchmark.lib import cell as cell_lib
+    tr = cell_lib.load_cell(cell_name).traffic
+    assert "sizes_seed" in tr
+    a, b = (traffic.closed_queues(tr, s, clients, vocab) for s in (1, 2))
+    assert _sizes(a) == _sizes(b)
+    assert a[0][0]["prompt"] != b[0][0]["prompt"]
 
 
 def test_warmup_lengths_reach_every_power_of_two_bucket():

@@ -19,7 +19,8 @@ import pytest
 
 import toy_routed as arch
 import toy_routed_model as standin
-from bench_toy import routed_toy_cell
+from bench_toy import (routed_toy_cell,  # noqa: F401  (fixture)
+                       routed_toy_unregistered)
 from benchmark.lib import cell as cell_lib, compare, serving
 from benchmark.lib.compare import max_rel_err
 

@@ -11,7 +11,8 @@ import json
 import pytest
 
 import toy_routed_model as standin
-from bench_toy import routed_toy_cell, toy_runtime  # noqa: F401  (fixture)
+from bench_toy import (routed_toy_cell,  # noqa: F401  (fixtures)
+                       routed_toy_unregistered, toy_runtime)
 from benchmark import run as run_mod
 from benchmark.lib import compare
 
