@@ -482,6 +482,7 @@ def _wide_cache_arm(model: dict, block_size: int, context: int,
     import jax.numpy as jnp
     from distributedmnist_tpu.core.config import ModelConfig
     from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.models.transformer import decode_attention_arm
     from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
                                                         stored_head_dim)
 
@@ -499,7 +500,10 @@ def _wide_cache_arm(model: dict, block_size: int, context: int,
         0, model["vocab_size"], plen)
     logits, ks, vs = jax.jit(mdl.decode_prefill)(params, jnp.asarray(toks))
     first = int(jnp.argmax(logits[0, plen - 1]))
-    step = jax.jit(functools.partial(mdl.decode_step, block_size=block_size),
+    # the gather by name: the same arithmetic on either width, which is
+    # what "to the bit" says (``auto`` takes the kernel on whole rows)
+    step = jax.jit(functools.partial(mdl.decode_step, block_size=block_size,
+                                     attention_kernel="dense"),
                    donate_argnums=(3, 4))
 
     def decode(head_dim):
@@ -545,11 +549,20 @@ def _wide_cache_arm(model: dict, block_size: int, context: int,
            "cache_bytes_equal": all(
                np.array_equal(g, w) for g, w in zip(got_kv, want_kv)),
            "paged_logits_bit_equal": bool(
-               np.array_equal(got_paged, want_paged))}
+               np.array_equal(got_paged, want_paged)),
+           # what a step takes when nobody names an arm
+           "auto_arm": {"head_wide": decode_attention_arm(
+                            "auto", tuple(plain_said["cache_shape"])),
+                        "stored_wide": decode_attention_arm(
+                            "auto", tuple(wide_said["cache_shape"]))}}
+    on_tpu = jax.devices()[0].platform == "tpu"
     _require(np.isfinite(got).all() and out["logits_bit_equal"]
              and out["cache_bytes_equal"]
              and _max_err(got_paged, want_paged) <= 2e-2
-             and wide_said["whole_cache_copies"] == 0,
+             and wide_said["whole_cache_copies"] == 0
+             and out["auto_arm"] == {
+                 "head_wide": "gather",
+                 "stored_wide": "paged" if on_tpu else "gather"},
              f"the cache with whole rows decodes otherwise: {out}")
     return out
 

@@ -11,8 +11,11 @@ bit-identical — only the cost model breaks):
   host-side test oracle) or ``take_along_axis``-style whole-table
   gathers inside a step/loop/batch/run-shaped function in
   ``servesvc/`` re-materializes ``[slots, max_context]`` K/V every
-  iteration.  The paged kernel walks block tables in-kernel; the
-  oracle is for tests and the dense *kernel* arm lives in
+  iteration.  On a TPU the step reads the cache through the paged
+  kernel, which walks block tables in-kernel over the rows as stored
+  (``ops/pallas_paged_attention.py``); the oracle is for tests, and
+  the gather *arm* (a CPU, a toy head's rows, a latent block, or
+  ``decode.attention_kernel = dense``) lives in
   ``models/transformer.py``, outside this lint's scope on purpose.
 * **per-iteration table rebuild** — constructing the block-table
   array (``zeros``/``asarray``/``array`` over a ``table``-named

@@ -741,9 +741,10 @@ QUANT_TIERS = ("bf16", "int8")
 # Mid-generation weight-swap disciplines for the decode service.
 DECODE_SWAP_POLICIES = ("pin", "restart")
 
-# Cache-read implementations for the decode step: the dense full-table
-# gather (the oracle) and the fused Pallas paged-attention kernel.
-DECODE_ATTENTION_KERNELS = ("dense", "paged")
+# How the plain block's decode step reads the paged cache: the block
+# decides by what it is handed, or one arm by name: the full-table
+# gather (the oracle), the Pallas kernel that walks the table.
+DECODE_ATTENTION_KERNELS = ("auto", "dense", "paged")
 
 
 @dataclass(frozen=True)
@@ -783,14 +784,22 @@ class DecodeConfig:
       the causal license the ``decode_swap`` replay invariant
       requires whenever a sequence finishes on a different step than
       it started on).
-    * ``attention_kernel`` — how the decode step reads the paged
-      cache: ``"dense"`` (default) gathers each slot's full block
-      table into a dense [slots, max_context, h, hd] view before
-      attending — O(max context) traffic per token, and the oracle
-      the parity tests pin; ``"paged"`` runs the fused Pallas kernel
-      (``ops/pallas_paged_attention.py``) that walks the table
-      in-kernel — O(actual context) per token. Numerics are pinned
-      equal for live slots (tests/test_paged_attention.py).
+    * ``attention_kernel`` — how the plain block's decode step reads
+      the paged cache (``models/transformer.py::decode_attention_arm``;
+      ``decode_start`` says which arm each table width compiled to).
+      ``"auto"`` (default): the block decides by what it is handed. On
+      a TPU, with rows stored in whole lanes (what the replica's
+      ``kv_cache.stored_head_dim`` builds there), every token goes
+      through the Pallas kernel (``ops/pallas_paged_attention.py``)
+      that walks the table over the rows as stored — O(live context)
+      per token; on a CPU, or with a toy head's rows, through the
+      gather of each slot's table into a dense [slots, context, h, hd]
+      view — O(table width) per token. ``"dense"`` names the gather
+      (the oracle the parity tests pin), ``"paged"`` the kernel
+      (interpreted off the TPU). Numerics are pinned equal for live
+      slots (tests/test_paged_attention.py). A latent block reads its
+      one row a token through its own gather under ``"auto"`` and
+      ``"dense"``.
     """
 
     decode_slots: int = 4
@@ -802,7 +811,7 @@ class DecodeConfig:
     temperature: float = 0.0
     top_k: int = 0
     swap_policy: str = "pin"
-    attention_kernel: str = "dense"  # dense | paged
+    attention_kernel: str = "auto"  # auto | dense | paged
 
     def validate(self) -> None:
         """Build-time validation (DecodeReplica construction): a bad

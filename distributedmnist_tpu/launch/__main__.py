@@ -579,10 +579,12 @@ def main(argv=None) -> None:
                          "(decode.swap_policy)")
     pv.add_argument("--attention-kernel", default=None,
                     dest="attention_kernel",
-                    help="dense | paged — decode attention path "
-                         "(decode.attention_kernel); paged walks each "
-                         "slot's block table in-kernel, O(actual "
-                         "context) per token")
+                    help="auto | dense | paged — how the decode step "
+                         "reads the cache (decode.attention_kernel); "
+                         "auto: on a TPU the kernel that walks each "
+                         "slot's block table over the rows as stored, "
+                         "O(live context) per token, elsewhere the "
+                         "gather; dense and paged name an arm")
     pv.add_argument("--tp-ranks", type=int, default=None,
                     dest="tp_ranks",
                     help="boot the replica as an N-rank tensor-"

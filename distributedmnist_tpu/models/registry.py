@@ -625,7 +625,7 @@ def _transformer(cfg: ModelConfig) -> Model:
 
         def decode_step_fn(params, tokens, positions, k_cache, v_cache,
                            block_tables, lengths, *, block_size,
-                           attention_kernel="dense", return_routing=False,
+                           attention_kernel="auto", return_routing=False,
                            return_counts=False):
             out = transformer.decode_step(
                 params, tokens, positions, k_cache, v_cache,

@@ -44,6 +44,7 @@ from jax import lax
 from jax.sharding import PartitionSpec
 
 from .cnn import truncated_normal_init
+from ..core.config import DECODE_ATTENTION_KERNELS
 from ..ops.ring_attention import local_self_attention
 
 Params = dict[str, Any]
@@ -913,7 +914,7 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
                 residual: Residual = PLAIN,
                 num_heads: int = 4, block_size: int = 16,
                 compute_dtype=jnp.bfloat16,
-                attention_kernel: str = "dense", return_aux: bool = False
+                attention_kernel: str = "auto", return_aux: bool = False
                 ) -> tuple[jax.Array, ...]:
     """One incremental decode step over S slots sharing one paged KV
     cache — the single compiled shape every in-flight sequence runs
@@ -943,23 +944,24 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     the per-token routed layers said of this step's tokens, ``routing``
     [routed_layers, S, k] and ``counts`` [routed_layers, held] (empty
     without such layers).
-    ``attention_kernel`` selects the cache read: ``"dense"`` gathers
-    every table entry into a [S, max_context, h, hd] view (the oracle
-    path — O(max context) traffic per token), ``"paged"`` runs the
-    fused Pallas kernel that walks the table in-kernel (O(actual
-    context); see ops/pallas_paged_attention.py). Both share the
-    pinned numerics below; parity across them is tested in
-    tests/test_paged_attention.py.
+    ``attention_kernel`` says how the plain block reads the cache
+    (:func:`decode_attention_arm`): ``"auto"``, the default, lets the
+    block decide by what it is handed: rows stored in whole lanes on a
+    TPU go through the Pallas kernel that walks the table over the rows
+    as stored (O(live context) traffic a token;
+    ops/pallas_paged_attention.py), anything else through the gather of
+    every table entry into a [S, context, h, hd] view (O(table width),
+    and the oracle); ``"dense"`` and ``"paged"`` name an arm whatever
+    the input. Both share the pinned numerics below; parity across them
+    is tested in tests/test_paged_attention.py. A latent block has its
+    own read (``attn``), a gather.
 
     Returns (logits [S, vocab] float32, k_cache, v_cache) with this
     token's K/V written at its block/offset. Attention numerics match
     ``local_self_attention`` (f32 scores/softmax, 1/sqrt(hd) scale),
     so greedy decode through the cache reproduces the full-context
     forward (pinned in tests/test_decode.py)."""
-    if attention_kernel not in ("dense", "paged"):
-        raise ValueError(
-            f"decode.attention_kernel must be 'dense' or 'paged', "
-            f"got {attention_kernel!r}")
+    arm = decode_attention_arm(attention_kernel, k_cache.shape)
     p = _cast(params, compute_dtype)
     num_slots = tokens.shape[0]
     x = residual.start(_embed(p, tokens, positions))  # [S, d]
@@ -978,8 +980,7 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
         if attn is None:
             x, k_cache, v_cache = _decode_attn(
                 x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
-                offs, live, num_heads=num_heads, scale=scale,
-                attention_kernel=attention_kernel)
+                offs, live, num_heads=num_heads, scale=scale, arm=arm)
         else:
             x, k_cache, v_cache = attn(
                 x, blk, li, k_cache, v_cache, block_tables, positions,
@@ -992,14 +993,41 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     return out
 
 
+def decode_attention_arm(attention_kernel: str,
+                         cache_shape: tuple[int, ...]) -> str:
+    """``"paged"`` or ``"gather"``: how a decode step asked for
+    ``attention_kernel`` (``decode.attention_kernel``) reads a cache
+    array of ``cache_shape``. ``"dense"`` and ``"paged"`` name the arm.
+    ``"auto"`` decides by what is there: keys and values a head
+    ([L, N, B, h, width]; a latent's one row a token for all heads is
+    read by its own block's gather) stored in whole lanes (what
+    ``kv_cache.stored_head_dim`` answers on a TPU, and not on a CPU or
+    for a toy head) where the process's devices are TPUs
+    (``jax.devices()``: what its jitted step runs on) go through the
+    kernel, which takes such rows as they lie; anything else through
+    the gather."""
+    if attention_kernel not in DECODE_ATTENTION_KERNELS:
+        raise ValueError(
+            f"decode.attention_kernel must be one of "
+            f"{', '.join(DECODE_ATTENTION_KERNELS)}, got "
+            f"{attention_kernel!r}")
+    if len(cache_shape) != 5:
+        return "gather"
+    if attention_kernel == "auto":
+        whole_lanes = cache_shape[-1] % 128 == 0
+        on_tpus = jax.devices()[0].platform == "tpu"
+        return "paged" if whole_lanes and on_tpus else "gather"
+    return "paged" if attention_kernel == "paged" else "gather"
+
+
 @jax.named_scope("attention")
 def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
-                 blk_ids, offs, live, *, num_heads, scale,
-                 attention_kernel):
+                 blk_ids, offs, live, *, num_heads, scale, arm):
     """One layer's attention sublayer of :func:`decode_step`: this
     token's K/V written through the block table (scope ``cache_write``),
-    the context read back (``cache_gather`` on the dense arm; the paged
-    kernel walks the table itself), x + wo(attn)."""
+    the context read back by ``arm`` (:func:`decode_attention_arm`:
+    ``cache_gather`` on the gather arm; the paged kernel walks the table
+    itself), x + wo(attn)."""
     num_slots, d = x.shape
     hd = d // num_heads
     ctx = live.shape[1]
@@ -1014,16 +1042,14 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
         v_cache = v_cache.at[li, blk_ids, offs, :, :hd].set(
             vh.astype(v_cache.dtype))
     qh = q.reshape(num_slots, num_heads, hd)
-    if attention_kernel == "paged":
-        # fused path: the kernel walks the block table itself, so
-        # per-token traffic is O(actual context) — no dense view. It
-        # takes the rows as stored: a query zero beyond the head adds
-        # nothing to a score
+    if arm == "paged":
+        # the kernel walks the block table over the rows as stored, the
+        # cache arrays passed whole with the layer's index: no gathered
+        # copy of the context, no float32 view of one, and what a token
+        # reads is what its slot's live pages hold
         from ..ops.pallas_paged_attention import paged_attention
-        wide = k_cache.shape[-1] - hd
-        o = paged_attention(jnp.pad(qh, ((0, 0), (0, 0), (0, wide))),
-                            k_cache[li], v_cache[li],
-                            block_tables, lengths, scale=scale)[..., :hd]
+        o = paged_attention(qh, k_cache, v_cache, block_tables, lengths,
+                            layer=li, scale=scale)
     else:
         # gather the slot's pages into one dense context view: the
         # block table IS the indirection, so this read is identical
@@ -1065,7 +1091,7 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     softmax in float32, the weighted sum of latents ``o_c[h]`` goes
     through ``W_uv`` (``latent_absorb``), then ``wo``, the output's norm
     where the block has one, and the residual."""
-    if attention_kernel != "dense":
+    if attention_kernel == "paged":
         raise NotImplementedError(
             "decode.attention_kernel='paged': the paged kernel reads keys "
             "and values a head; a latent cache is read by the dense gather")

@@ -226,8 +226,12 @@ _declare(EventSchema(
                               "whole_cache_copies"),
                              # cache_row_bytes: device bytes a cached
                              # token takes, all layers; cache_arrays: the
-                             # two arrays' shapes
-                             ("cache_row_bytes", "cache_arrays")),
+                             # two arrays' shapes; attention_arm
+                             # ("paged" | "gather") and paged_calls
+                             # (Mosaic calls of the paged kernel in the
+                             # compiled step): a value a table width
+                             ("cache_row_bytes", "cache_arrays",
+                              "attention_arm", "paged_calls")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of

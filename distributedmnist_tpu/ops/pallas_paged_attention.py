@@ -1,43 +1,56 @@
 """Single-query paged attention as a Pallas TPU kernel.
 
-The decode twin of :mod:`ops.pallas_attention`. The decode service's
-hot path reads each slot's K/V through a block table into the paged
-cache (:mod:`servesvc.kv_cache`: ``[layers, num_blocks, block_size,
-heads, head_dim]`` arrays). The dense path gathers EVERY table entry
-into a ``[slots, max_context, heads, head_dim]`` view before attending,
-so a 10-token sequence pays the same HBM traffic as a 1k-token one.
+The decode twin of :mod:`ops.pallas_attention`, and on a TPU the read
+the plain block's decode step makes of the paged cache
+(:mod:`servesvc.kv_cache`: ``[layers, num_blocks, block_size, heads,
+width]`` arrays, ``width`` the head's size or wider). The gather arm
+(``models/transformer.py::_decode_attn``, and
+:func:`paged_attention_dense` here) copies EVERY entry of the table it
+is handed into a ``[slots, context, heads, head_dim]`` array and makes
+a float32 view of that before it attends: a 10-token sequence pays what
+a 1k-token one pays, several times over.
 
-This kernel fuses the table walk into the K/V tile load: the grid is
-``(slots, max_blocks_per_seq)`` and the K/V BlockSpec index map reads
-the prefetched block table — ``(tables[s, j], 0, 0)`` — so each grid
-step DMAs exactly one cache block. Two properties make per-token
-traffic O(actual context) instead of O(max context):
+This kernel reads the rows where they lie. The cache arrays stay in HBM
+and are passed whole, with the layer's index; one call serves every
+slot of one layer:
 
-* dead table entries all point at the reserved null block
-  (:data:`servesvc.kv_cache.NULL_BLOCK` = 0), and Pallas skips the DMA
-  when consecutive grid steps map to the same block — the dead tail of
-  a short sequence's table costs one null-block fetch, not P fetches;
-* the accumulation body is wrapped in ``pl.when(j*block_size < length)``
-  so dead blocks do no compute at all.
+* outside the kernel the live pages are cut into **work items**, one a
+  (slot, chunk of ``pages_per_step`` table entries): an idle slot and a
+  table's dead tail make none, so they are neither fetched nor
+  computed (their entries, all
+  :data:`servesvc.kv_cache.NULL_BLOCK`, are never looked up);
+* inside, one loop walks the items: the pages of item ``i + 1`` are in
+  flight (one DMA a page into the other half of a double buffer, across
+  the edge between two slots too) while item ``i`` is computed;
+* an item's scores are ONE product on the matrix unit: the slot's query
+  ``[heads, width]`` against the chunk's rows as stored, ``[pages ·
+  block_size · heads, width]``, every head against every row, of which
+  a mask keeps a head's own rows (the unit has the room: a product a
+  head would be ``heads`` one-row products a page); the weighted sum of
+  values is one product the same way. The operands go in as stored
+  (bfloat16 on the chip) and accumulate in float32.
 
-Numeric semantics are pinned to the dense decode path in
-``models/transformer.py decode_step`` (and its parity tests): scores
-and softmax in f32, scale ``1/sqrt(head_dim)``, masked positions get
-the finite ``-1e30`` (whose exp underflows to exactly 0.0 in f32), one
-online-softmax accumulator per head in VMEM scratch. The ONE documented
-divergence: an idle slot (``length == 0``) returns exact zeros here,
-while the dense path softmaxes a fully-masked row into a uniform
-average of cache garbage — both are unspecified-by-contract (the
-decode loop never reads idle rows), and the parity tests compare live
-slots only.
+Numeric semantics are pinned to the gather arm (and its parity tests):
+scores and softmax in float32, scale ``1/sqrt(head_dim)``, masked
+positions get the finite ``-1e30`` (whose exp underflows to exactly 0.0
+in float32), an online softmax over chunks, and the weighted sum of
+values accumulated in float32 **from float32 weights**: against
+bfloat16 values the weights go to the matrix unit as three bfloat16
+pieces (``w = w1 + w2 + w3`` to float32's last bit), never rounded to
+one. The ONE documented divergence: an idle slot (``length == 0``)
+returns exact zeros here, while the gather softmaxes a fully-masked row
+into a uniform average of cache garbage — both are
+unspecified-by-contract (the decode loop never reads idle rows), and
+the parity tests compare live slots only.
 
-Layout notes: heads are a static in-kernel unroll (decode head counts
-are small); K/V tiles ride with heads folded into the lane dim. For
-compiled-TPU efficiency size ``block_size`` to a multiple of 8 and
-``head_dim`` to a multiple of 128 — other shapes are padded per call
-(correct everywhere, and free in interpret mode, but the cache pad is
-a real copy on-chip). ``interpret=None`` auto-selects the pallas
-interpreter off-TPU, same as the training kernel.
+Rows are taken as stored: a query padded with zeros beyond ``head_dim``
+adds nothing to a score whatever the lanes beyond it hold, and the
+output's lanes beyond ``head_dim`` are dropped. Compiled for a TPU the
+row has to fill whole lanes (``width % 128 == 0``) and a block's rows
+whole tiles (``block_size · heads % 16 == 0`` in bfloat16): what
+``kv_cache.stored_head_dim`` makes of a real model's cache. Anything
+else runs interpreted (``interpret=None`` picks the interpreter off the
+TPU, same as the training kernel) or through the gather.
 """
 
 from __future__ import annotations
@@ -52,171 +65,284 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30  # finite: matches decode_step's mask, exp -> exact 0.0
 
 _LANE = 128
-_SUBLANE = 8
+
+#: table entries an item holds: their DMAs are in flight together, and
+#: an item's fixed cost (the loop, the products' fill and drain, the
+#: softmax's bookkeeping) is paid once for them; a slot's last item
+#: computes its dead pages too, masked (PERF.md has the chip's readings)
+PAGES_PER_STEP = 8
 
 
-def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, num_heads: int,
-                  block_size: int, hdp: int):
-    """One (slot, table-entry) grid step.
+def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
+                  layer_ref,
+                  q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, bias_ref, pos_ref, m_ref, l_ref, acc_ref,
+                  *,
+                  scale: float, num_heads: int, block_size: int,
+                  pages: int, table_width: int):
+    """Every work item of one layer, in one loop.
 
-    ``tables_ref``/``lengths_ref`` are the scalar-prefetch operands
-    (SMEM); the K/V tile for THIS step was already selected by the
-    index map reading ``tables_ref[s, j]``, so the kernel body never
-    sees a block id — only its tile."""
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    np_ = pl.num_programs(1)
-    length = lengths_ref[s]
+    ``tables_ref`` .. ``layer_ref`` are the scalar-prefetch operands
+    (SMEM): the block tables, the lengths, each item's slot and chunk,
+    the number of items, the layer. ``k_hbm``/``v_hbm`` are the cache
+    arrays where they lie, ``[layers, num_blocks, block_size · heads,
+    width]``; ``k_buf``/``v_buf`` two chunks of rows each."""
+    page_rows = block_size * num_heads
+    rows = pages * page_rows
+    layer, num_items = layer_ref[0], count_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def live_pages(slot):
+        return jnp.minimum(pl.cdiv(lengths_ref[slot], block_size),
+                           table_width)
 
-    # Dead blocks (entirely past the sequence) do no compute; their
-    # table entries are all NULL_BLOCK so the DMA was skipped too.
-    @pl.when(j * block_size < length)
-    def _accumulate():
-        k_tile = k_ref[0].astype(jnp.float32)   # [Bp, h*hdp]
-        v_tile = v_ref[0].astype(jnp.float32)
-        q_all = q_ref[0].astype(jnp.float32)    # [hp, hdp]
-        bp = k_tile.shape[0]
-        tile_pos = jax.lax.broadcasted_iota(jnp.int32, (1, bp), 1)
-        live = ((tile_pos < block_size)
-                & (j * block_size + tile_pos < length))  # [1, Bp]
-        for hh in range(num_heads):
-            qh = q_all[hh:hh + 1, :]                       # [1, hdp]
-            kh = k_tile[:, hh * hdp:(hh + 1) * hdp]        # [Bp, hdp]
-            vh = v_tile[:, hh * hdp:(hh + 1) * hdp]
-            sc = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [1, Bp]
-            sc = jnp.where(live, sc, _NEG_INF)
-            m_prev = m_ref[hh:hh + 1, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            p = jnp.exp(sc - m_new)                          # [1, Bp]
-            corr = jnp.exp(m_prev - m_new)                   # [1, 1]
-            l_new = (l_ref[hh:hh + 1, :1] * corr
-                     + jnp.sum(p, axis=1, keepdims=True))
-            acc_ref[hh:hh + 1, :] = (acc_ref[hh:hh + 1, :] * corr
-                                     + jnp.dot(
-                                         p, vh,
-                                         preferred_element_type=jnp.float32))
-            m_ref[hh:hh + 1, :] = jnp.broadcast_to(m_new, (1, _LANE))
-            l_ref[hh:hh + 1, :] = jnp.broadcast_to(l_new, (1, _LANE))
+    def is_live(item, p):
+        return chunk_ref[item] * pages + p < live_pages(slot_ref[item])
 
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        # idle slots (length 0) never accumulated: l == 0 -> output 0.
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+    def page_rows_of(p):
+        return pl.ds(p * page_rows, page_rows)
+
+    def copies(item, half, p):
+        """The two DMAs of page ``p`` of the item's chunk, made for a
+        live page only: a dead entry of the table is not looked up."""
+        block = tables_ref[slot_ref[item], chunk_ref[item] * pages + p]
+        return (pltpu.make_async_copy(k_hbm.at[layer, block],
+                                      k_buf.at[half, page_rows_of(p)],
+                                      sem.at[half, 0]),
+                pltpu.make_async_copy(v_hbm.at[layer, block],
+                                      v_buf.at[half, page_rows_of(p)],
+                                      sem.at[half, 1]))
+
+    def start(item, half):
+        for p in range(pages):
+            @pl.when(is_live(item, p))
+            def _():
+                for dma in copies(item, half, p):
+                    dma.start()
+
+    def wait(item, half):
+        """One wait a start; a page never fetched holds what the buffer
+        held, and a weight of exactly 0 times a value that is no number
+        is no number: its values are zeroed."""
+        for p in range(pages):
+            live = is_live(item, p)
+
+            @pl.when(live)
+            def _():
+                for dma in copies(item, half, p):
+                    dma.wait()
+
+            @pl.when(jnp.logical_not(live))
+            def _():
+                v_buf[half, page_rows_of(p)] = jnp.zeros(
+                    (page_rows, v_buf.shape[-1]), v_buf.dtype)
+
+    # column c of an item's scores is row c of its chunk: position
+    # c // heads of the chunk, head c % heads. A head keeps its own
+    # rows; the rest gets the mask's value through this addend
+    col = jax.lax.broadcasted_iota(jnp.int32, (num_heads, rows), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (num_heads, rows), 0)
+    bias_ref[...] = jnp.where(col % num_heads == head, 0.0, _NEG_INF)
+    pos_ref[...] = jax.lax.broadcasted_iota(
+        jnp.int32, pos_ref.shape, 1) // num_heads
+    o_ref[...] = jnp.zeros_like(o_ref)  # an idle slot's rows
+
+    @pl.when(num_items > 0)
+    def _():
+        start(0, 0)
+
+    def item_step(item, _):
+        half = item % 2
+
+        @pl.when(item + 1 < num_items)
+        def _():
+            start(item + 1, 1 - half)
+
+        slot, chunk = slot_ref[item], chunk_ref[item]
+        length = lengths_ref[slot]
+        num_chunks = pl.cdiv(live_pages(slot), pages)
+
+        @pl.when(chunk == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        wait(item, half)
+        q = q_ref[slot]                                   # [h, width]
+        k = k_buf[half]                                   # [rows, width]
+        v = v_buf[half]
+        exact = (jax.lax.Precision.HIGHEST
+                 if k.dtype == jnp.float32 else None)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32)           # [h, rows]
+        sc = sc * scale + bias_ref[...]
+        left = length - chunk * (pages * block_size)
+        sc = jnp.where(pos_ref[...] < left, sc, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        w = jnp.exp(sc - m_new)                           # [h, rows] f32
+        corr = jnp.exp(m_prev - m_new)                    # [h, 1]
+        l_new = l_ref[:, :1] * corr + jnp.sum(w, axis=1, keepdims=True)
+        if v.dtype == jnp.bfloat16:
+            # float32 weights against bfloat16 values, on a unit that
+            # multiplies bfloat16: three pieces that sum to the weight,
+            # one product, three row groups summed
+            w1 = w.astype(jnp.bfloat16)
+            r1 = w - w1.astype(jnp.float32)
+            w2 = r1.astype(jnp.bfloat16)
+            w3 = (r1 - w2.astype(jnp.float32)).astype(jnp.bfloat16)
+            parts = jnp.dot(jnp.concatenate([w1, w2, w3], axis=0), v,
+                            preferred_element_type=jnp.float32)
+            wv = (parts[:num_heads] + parts[num_heads:2 * num_heads]
+                  + parts[2 * num_heads:])
+        else:
+            wv = jnp.dot(w, v.astype(jnp.float32), precision=exact,
+                         preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + wv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        @pl.when(chunk == num_chunks - 1)
+        def _():
+            o_ref[slot] = acc_ref[...] / l_ref[:, :1]
+
+    jax.lax.fori_loop(0, num_items, item_step, None)
 
 
-def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
-    pad = (-x.shape[axis]) % multiple
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+def _work_items(lengths: jax.Array, block_size: int, table_width: int,
+                pages: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The live pages cut into items: (each item's slot, its chunk of
+    the slot's table, the number of items), the first two as long as
+    the most a call can hold."""
+    num_slots = lengths.shape[0]
+    live_pages = jnp.minimum(-(-lengths // block_size), table_width)
+    chunks = -(-live_pages // pages)                      # [slots]
+    ends = jnp.cumsum(chunks)
+    item = jnp.arange(num_slots * -(-table_width // pages),
+                      dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1),
+                       num_slots - 1).astype(jnp.int32)
+    chunk = item - (ends - chunks)[slot]
+    return slot, chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_step",
+                                             "interpret"))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array, *,
-                    scale: float | None = None,
+                    layer: jax.Array | int = 0, scale: float | None = None,
+                    pages_per_step: int = PAGES_PER_STEP,
                     interpret: bool | None = None) -> jax.Array:
     """Single-query attention over a paged KV cache, one layer.
 
     ``q``: [slots, heads, head_dim] (the current token's query, AFTER
     its K/V were scattered into the cache — position ``length-1``
-    attends to itself through the cache, exactly like the dense path).
-    ``k_pages``/``v_pages``: [num_blocks, block_size, heads, head_dim]
-    (one layer of :class:`servesvc.kv_cache.PagedKVCache`).
-    ``block_tables``: [slots, max_blocks_per_seq] int32, dead entries
-    ``NULL_BLOCK``. ``lengths``: [slots] int32 — position count
-    INCLUDING the current token; 0 marks an idle slot (output zeros).
+    attends to itself through the cache, exactly like the gather arm).
+    ``k_pages``/``v_pages``: the cache arrays whole, [layers,
+    num_blocks, block_size, heads, width] with ``layer`` the one to
+    read (:class:`servesvc.kv_cache.PagedKVCache`'s, passed as they
+    lie: no slice, no copy), or one layer's [num_blocks, block_size,
+    heads, width]; ``width >= head_dim``, the row's first ``head_dim``
+    elements are the head's. ``block_tables``: [slots, table width]
+    int32, dead entries ``NULL_BLOCK``. ``lengths``: [slots] int32 —
+    position count INCLUDING the current token; 0 marks an idle slot
+    (output zeros). ``scale`` defaults to ``1/sqrt(head_dim)``.
 
     Returns [slots, heads, head_dim] float32.
     """
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
     num_slots, num_heads, hd = q.shape
-    num_blocks, block_size, h2, hd2 = k_pages.shape
-    assert (h2, hd2) == (num_heads, hd), (q.shape, k_pages.shape)
+    layers, num_blocks, block_size, h2, row = k_pages.shape
+    assert h2 == num_heads and row >= hd, (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
     assert block_tables.shape[0] == num_slots == lengths.shape[0]
-    width = block_tables.shape[1]
+    table_width = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if not interpret and row % _LANE:
+        # compiled, a row has to fill whole lanes: this layer's pages
+        # padded, a copy a call (a cache as wide as
+        # kv_cache.stored_head_dim answers never comes here)
+        lanes = ((0, 0),) * 4 + ((0, -row % _LANE),)
+        k_pages, v_pages = (
+            jnp.pad(jax.lax.dynamic_index_in_dim(a, layer, keepdims=True),
+                    lanes) for a in (k_pages, v_pages))
+        layers, layer, row = 1, 0, row + (-row % _LANE)
+    pages = min(pages_per_step, table_width)
+    page_rows = block_size * num_heads
+    rows = pages * page_rows
 
-    # tile-align: lanes (head_dim -> 128) and sublanes (block rows -> 8,
-    # head rows -> 8). No-ops for TPU-sized models; real copies for the
-    # tiny CPU-test shapes, where only correctness matters.
-    hdp = hd + ((-hd) % _LANE)
-    hp = num_heads + ((-num_heads) % _SUBLANE)
-    qp = _pad_axis(_pad_axis(q, 2, _LANE), 1, _SUBLANE)       # [S, hp, hdp]
-    kp = _pad_axis(_pad_axis(k_pages, 3, _LANE), 1, _SUBLANE)
-    vp = _pad_axis(_pad_axis(v_pages, 3, _LANE), 1, _SUBLANE)
-    bp = kp.shape[1]
-    # heads fold into the lane dim of the K/V tiles (contiguous ->
-    # free reshape); per-head lane slices select them in-kernel
-    kp = kp.reshape(num_blocks, bp, num_heads * hdp)
-    vp = vp.reshape(num_blocks, bp, num_heads * hdp)
+    lengths = lengths.astype(jnp.int32)
+    slot, chunk, num_items = _work_items(lengths, block_size, table_width,
+                                         pages)
+    # a query zero beyond the head adds nothing to a score; a block's
+    # positions and heads are one run of rows as stored (no copy)
+    qp = jnp.pad(q.astype(k_pages.dtype), ((0, 0), (0, 0), (0, row - hd)))
+    kp = k_pages.reshape(layers, num_blocks, page_rows, row)
+    vp = v_pages.reshape(layers, num_blocks, page_rows, row)
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, num_heads=num_heads,
-        block_size=block_size, hdp=hdp)
+        block_size=block_size, pages=pages, table_width=table_width)
+    whole = lambda *_: (0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(num_slots, width),
+        num_scalar_prefetch=6,
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, hp, hdp), lambda s, j, t, l: (s, 0, 0)),
-            # the fused gather: this tile load IS the table walk
-            pl.BlockSpec((1, bp, num_heads * hdp),
-                         lambda s, j, t, l: (t[s, j], 0, 0)),
-            pl.BlockSpec((1, bp, num_heads * hdp),
-                         lambda s, j, t, l: (t[s, j], 0, 0)),
+            pl.BlockSpec((num_slots, num_heads, row), whole),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hp, hdp), lambda s, j, t, l: (s, 0, 0)),
+        out_specs=pl.BlockSpec((num_slots, num_heads, row), whole),
         scratch_shapes=[
-            pltpu.VMEM((hp, _LANE), jnp.float32),  # running max
-            pltpu.VMEM((hp, _LANE), jnp.float32),  # running denom
-            pltpu.VMEM((hp, hdp), jnp.float32),    # output accumulator
+            pltpu.VMEM((2, rows, row), k_pages.dtype),
+            pltpu.VMEM((2, rows, row), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((num_heads, rows), jnp.float32),   # a head's rows
+            pltpu.VMEM((1, rows), jnp.int32),             # a row's position
+            pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running max
+            pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running denom
+            pltpu.VMEM((num_heads, row), jnp.float32),    # accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_slots, hp, hdp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((num_slots, num_heads, row),
+                                       jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lengths, slot, chunk, num_items,
+      jnp.asarray(layer, jnp.int32).reshape(1),
       qp, kp, vp)
-    return out[:, :num_heads, :hd]
+    return out[..., :hd]
 
 
 def paged_attention_dense(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, block_tables: jax.Array,
                           lengths: jax.Array, *,
                           scale: float | None = None) -> jax.Array:
-    """The dense-gather oracle: same signature/semantics as
-    :func:`paged_attention`, implemented with the full-table gather the
-    decode path used before the kernel (and still uses under
-    ``decode.attention_kernel = dense``). Parity tests pin the kernel
-    against this for live slots; idle rows differ by design (see module
-    docstring)."""
+    """The dense-gather oracle: :func:`paged_attention` of one layer's
+    pages [num_blocks, block_size, heads, width], implemented with the
+    full-table gather the decode step makes off the TPU, for a toy head
+    and under ``decode.attention_kernel = dense``. Parity tests pin the
+    kernel against this for live slots; idle rows differ by design (see
+    module docstring)."""
     num_slots, num_heads, hd = q.shape
     block_size = k_pages.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     ctx = block_tables.shape[1] * block_size
-    kd = k_pages[block_tables].reshape(num_slots, ctx, num_heads, hd)
-    vd = v_pages[block_tables].reshape(num_slots, ctx, num_heads, hd)
+    kd = k_pages[block_tables][..., :hd].reshape(num_slots, ctx, num_heads,
+                                                 hd)
+    vd = v_pages[block_tables][..., :hd].reshape(num_slots, ctx, num_heads,
+                                                 hd)
     live = jnp.arange(ctx)[None, :] < lengths[:, None]
     scores = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32),
                         kd.astype(jnp.float32)) * scale

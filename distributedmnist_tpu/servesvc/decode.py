@@ -18,14 +18,17 @@ deadline / client gone) frees its blocks and its slot is refilled from
 the admission queue the SAME iteration — no padded rounds, no waiting
 for a batch to drain.
 
-**The table's width follows the longest live sequence.** The dense
-step reads every position its block table spans, so an iteration hands
-it the narrowest of at most four widths (the quarters of
+**The table's width follows the longest live sequence.** The step's
+gather arm reads every position its block table spans, so an iteration
+hands the step the narrowest of at most four widths (the quarters of
 ``max_blocks_per_seq``: ``_table_widths``) that holds its longest live
 sequence and the token being written. Four compiled programs of one
 jitted callable, all compiled in :meth:`DecodeReplica.start` before a
 request is accepted; the full width, and its cost, only when a
-sequence is that long.
+sequence is that long. (The paged kernel, the arm a TPU runs, reads a
+slot's live pages whatever the table's width:
+``transformer.decode_attention_arm``; ``decode_start`` says which arm
+each width compiled to.)
 
 **Prefill.** Prompts are admitted through the existing bounded queue
 (typed ``overloaded`` shed when full), padded to power-of-2 buckets
@@ -92,6 +95,7 @@ import numpy as np
 
 from ..core.config import ConfigError
 from ..models.registry import sample_token
+from ..models.transformer import decode_attention_arm
 from ..obsv import spans
 from .kv_cache import PagedKVCache, cache_shapes, stored_head_dim
 from .server import ServingReplica, _Pending
@@ -756,10 +760,12 @@ class DecodeReplica(ServingReplica):
         does with it, for ``decode_start``: ``whole_cache_copies`` counts
         the ``copy`` instructions of the cache's shape in a compiled
         step (0 where it takes the arrays as they lie, 4 where it
-        transposes both on the way in and back on the way out)."""
+        transposes both on the way in and back on the way out), and
+        ``attention_arm`` / ``paged_calls`` how it reads them."""
         at = self.cache.k.format.layout
         dims = re.escape(f"[{','.join(map(str, self.cache.k.shape))}]")
         steps = [self._steps[w] for w in self._table_widths]
+        texts = [s.as_text() for s in steps]
         arrays = (self.cache.k, self.cache.v)
         device_bytes = sum(a.on_device_size_in_bytes() for a in arrays)
         return {
@@ -773,9 +779,19 @@ class DecodeReplica(ServingReplica):
             "step_temp_bytes": [s.memory_analysis().temp_size_in_bytes
                                 for s in steps],
             "whole_cache_copies": [
-                len(re.findall(rf"= \w+{dims}\{{[^}}]*\}} copy\(",
-                               s.as_text()))
-                for s in steps]}
+                len(re.findall(rf"= \w+{dims}\{{[^}}]*\}} copy\(", text))
+                for text in texts],
+            # how each width's step reads the cache: the arm the block
+            # chose, and the Mosaic calls of the paged kernel in the
+            # compiled step (one a layer on a TPU; none where the kernel
+            # runs interpreted, or not at all)
+            "attention_arm": [decode_attention_arm(
+                self.dcfg.attention_kernel, self.cache.k.shape)] * len(steps),
+            "paged_calls": [
+                len(re.findall(r"%paged_decode[.\d]* = [^\n]*"
+                               r"custom_call_target=\"tpu_custom_call\"",
+                               text))
+                for text in texts]}
 
     def start(self) -> None:
         super().start()

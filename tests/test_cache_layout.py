@@ -7,7 +7,11 @@ described and not attached (rehearsal 3 of the on-chip-measurement
 guide: nothing runs, nothing here is a time; skipped where the
 installation cannot describe the chip): with the head stored as wide as
 ``stored_head_dim`` answers, the step and the prompt's scatter copy no
-whole cache array. And on the CPU test mesh: a cache with wider rows
+whole cache array; the step a TPU runs reads the cache through one
+paged kernel a layer at each of the replica's four table widths, with
+no gathered copy of the context and no float32 view of one, and the
+gather arm, still there by name, keeps its sizes; which arm a step
+takes is decided by what it is handed. And on the CPU test mesh: a cache with wider rows
 decodes to the bit what one with the head's own width decodes, through
 the model's step, the prompt's scatter and a replica."""
 
@@ -15,12 +19,15 @@ import functools
 import json
 import os
 import re
+import types
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributedmnist_tpu.models.transformer import decode_attention_arm
 from distributedmnist_tpu.servesvc import decode as decode_mod
 from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
                                                     stored_head_dim,
@@ -33,7 +40,9 @@ OPT_1_3B = {"name": "transformer", "model_dim": 2048, "num_heads": 32,
             "num_layers": 24, "seq_len": 2048, "vocab_size": 50272}
 SLOTS, BLOCKS, BLOCK = 16, 769, 16
 CACHE_SHAPE = (24, BLOCKS, BLOCK, 32, 64)
-WIDTHS = [28, 112]
+WIDTHS = [28, 56, 84, 112]      # DecodeReplica._table_widths of 112
+GATHER_WIDTHS = [28, 112]
+MB = 1e6
 
 
 def _total(compiled) -> float:
@@ -49,13 +58,25 @@ def _count(compiled, shape, op: str) -> int:
                           compiled.as_text()))
 
 
+def _as_on_a_tpu():
+    """What the program asks of its devices and its backend answers as
+    on the chip, so that the step takes the arm
+    (``decode_attention_arm`` asks ``jax.devices()``) and the kernel
+    lowers for Mosaic (it asks ``jax.default_backend()``): a described
+    device leaves both the CPU's."""
+    tpu = types.SimpleNamespace(platform="tpu")
+    return mock.patch.multiple(jax, devices=lambda *a: [tpu],
+                               default_backend=lambda: "tpu")
+
+
 @pytest.fixture(scope="module")
 def for_the_chip():
     """``model.decode_step`` jitted as the replica jits it (both caches
-    donated) at two table widths and the prompt's scatter, on a cache
-    as wide as ``stored_head_dim`` answers for the chip, for a
-    described ``v5e:1x1``; these compiles kept out of the persistent
-    cache (written without a chip they cannot be read back)."""
+    donated) at its four table widths, as a TPU runs it, the gather arm
+    by name at two, and the prompt's scatter, on a cache as wide as
+    ``stored_head_dim`` answers for the chip, for a described
+    ``v5e:1x1``; these compiles kept out of the persistent cache
+    (written without a chip they cannot be read back)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
@@ -86,20 +107,25 @@ def for_the_chip():
             lambda a: sds(a.shape, jnp.bfloat16),
             jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
         wide = stored_head_dim(CACHE_SHAPE, jnp.bfloat16, on_chip)
-        step = jax.jit(functools.partial(model.decode_step, block_size=BLOCK),
-                       donate_argnums=(3, 4))
 
-        def compiled(width, head_dim):
+        def compiled(width, head_dim, **how):
+            step = jax.jit(functools.partial(model.decode_step,
+                                             block_size=BLOCK, **how),
+                           donate_argnums=(3, 4))
             cache = sds((*CACHE_SHAPE[:-1], head_dim), jnp.bfloat16)
-            return step.lower(
-                params, sds((SLOTS,), jnp.int32), sds((SLOTS,), jnp.int32),
-                cache, cache, sds((SLOTS, width), jnp.int32),
-                sds((SLOTS,), jnp.int32)).compile()
+            with _as_on_a_tpu():
+                return step.lower(
+                    params, sds((SLOTS,), jnp.int32),
+                    sds((SLOTS,), jnp.int32), cache, cache,
+                    sds((SLOTS, width), jnp.int32),
+                    sds((SLOTS,), jnp.int32)).compile()
 
         cache = sds((*CACHE_SHAPE[:-1], wide), jnp.bfloat16)
         prompt = sds((24, 256, 32, 64), jnp.bfloat16)
         yield {"stored_head_dim": wide, "on_chip": on_chip,
                "stored_wide": {w: compiled(w, wide) for w in WIDTHS},
+               "gather": {w: compiled(w, wide, attention_kernel="dense")
+                          for w in GATHER_WIDTHS},
                "write": jax.jit(
                    write_prompt_kv, static_argnames="block_size",
                    donate_argnums=(0, 1)).lower(
@@ -122,21 +148,110 @@ def test_the_chip_keeps_a_128_wide_heads_rows_whole(for_the_chip):
                            on_chip) == 256
 
 
-@pytest.mark.parametrize("width, temporaries_gb", zip(WIDTHS, (0.5, 1.0)))
-def test_the_step_on_whole_rows_copies_no_cache_array(
-        for_the_chip, width, temporaries_gb):
-    """What the replica runs: no ``copy`` of the cache's shape, the
-    cache arrays taken and returned in one layout, and the memory that
-    leaves (12.73 GB at the head's own width, 7.27 of it temporaries:
-    ``tests/benchmark/test_bench_rehearsal.py``)."""
-    step = for_the_chip["stored_wide"][width]
-    shape = (*CACHE_SHAPE[:-1], for_the_chip["stored_head_dim"])
+def _takes_the_cache_as_it_lies(step, shape):
     assert _count(step, shape, "copy") == 0
     layouts = {f.layout for f in (*step.input_formats[0][3:5],
                                   *step.output_formats[1:])}
     assert [tuple(at.major_to_minor) for at in layouts] == [(0, 1, 2, 3, 4)]
+
+
+@pytest.mark.parametrize("width, temporaries_gb",
+                         zip(GATHER_WIDTHS, (0.5, 1.0)))
+def test_the_step_on_whole_rows_copies_no_cache_array(
+        for_the_chip, width, temporaries_gb):
+    """The gather arm, by name (what every token took before the paged
+    kernel, and still the oracle): no ``copy`` of the cache's shape, the
+    cache arrays taken and returned in one layout, and the memory that
+    leaves (12.73 GB at the head's own width, 7.27 of it temporaries:
+    ``tests/benchmark/test_bench_rehearsal.py``)."""
+    step = for_the_chip["gather"][width]
+    shape = (*CACHE_SHAPE[:-1], for_the_chip["stored_head_dim"])
+    _takes_the_cache_as_it_lies(step, shape)
     assert step.memory_analysis().temp_size_in_bytes / GB <= temporaries_gb
     assert _total(step) / GB <= 8.4
+    assert "paged_decode" not in step.as_text()
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_the_step_a_tpu_runs_reads_the_cache_where_it_lies(
+        for_the_chip, width):
+    """What the replica runs on the chip, at each of its four widths:
+    one Mosaic call of the paged kernel a layer; no array of a gathered
+    context (``[16, w, 16, 32, *]``) in float32 or bfloat16; no
+    ``copy`` or ``slice`` of a cache array or of a layer of one; and
+    temporaries of 24 MB where the gather's are 134 / 390 / 582 / 741
+    MB (PR 38's ``decode_start``), whatever the width."""
+    step = for_the_chip["stored_wide"][width]
+    text = step.as_text()
+    shape = (*CACHE_SHAPE[:-1], for_the_chip["stored_head_dim"])
+    _takes_the_cache_as_it_lies(step, shape)
+    calls = re.findall(r"%paged_decode[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == OPT_1_3B["num_layers"]
+    assert not re.findall(rf"= (?:f32|bf16)\[{SLOTS},{width},{BLOCK},32,\d+\]",
+                          text)
+    for dims in (shape, shape[1:]):
+        for op in ("copy", "slice", "dynamic-slice", "fusion"):
+            assert _count(step, dims, op) == 0, (dims, op)
+    assert step.memory_analysis().temp_size_in_bytes / MB <= 64
+    assert _total(step) / GB <= 7.6
+
+
+def test_a_step_takes_the_arm_its_input_decides():
+    """``decode.attention_kernel = auto``: rows stored in whole lanes on
+    a TPU go through the kernel; the head's own width, a CPU, and a
+    latent's one row a token for all heads through the gather. A name
+    is an arm whatever the input."""
+    wide, own = (24, 769, 16, 32, 128), (24, 769, 16, 32, 64)
+    latent = (5, 16385, 16, 512)
+    assert jax.devices()[0].platform == "cpu"
+    assert decode_attention_arm("auto", wide) == "gather"
+    # the kernels' own question alone (what the accepted compile tests
+    # of tests/benchmark patch) moves no arm
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert decode_attention_arm("auto", wide) == "gather"
+    with _as_on_a_tpu():
+        assert decode_attention_arm("auto", wide) == "paged"
+        assert decode_attention_arm("auto", (2, 40, 4, 4, 256)) == "paged"
+        assert decode_attention_arm("auto", own) == "gather"
+        assert decode_attention_arm("auto", (2, 40, 4, 4, 192)) == "gather"
+        assert decode_attention_arm("auto", latent) == "gather"
+        assert decode_attention_arm("dense", wide) == "gather"
+        assert decode_attention_arm("paged", own) == "paged"
+    assert decode_attention_arm("paged", wide) == "paged"
+    assert decode_attention_arm("dense", own) == "gather"
+    with pytest.raises(ValueError, match="attention_kernel"):
+        decode_attention_arm("flash", wide)
+
+
+def test_a_latent_blocks_step_is_the_same_program_on_a_tpu():
+    """A latent block has its own read of its cache: lowered as on a TPU
+    with rows in whole lanes, its step under ``auto`` is, to the letter,
+    the step under ``dense`` (the parent's default), and holds no paged
+    kernel."""
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+
+    model = get_model(ModelConfig(
+        name="transformer", model_dim=64, num_heads=4, num_layers=2,
+        seq_len=64, vocab_size=96, q_latent_dim=24, kv_latent_dim=128,
+        qk_nope_dim=8, qk_rope_dim=8, v_head_dim=8, ffn_dim=96,
+        compute_dtype="bfloat16", attention_impl="dense"))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    sds = jax.ShapeDtypeStruct
+    args = (params, sds((3,), jnp.int32), sds((3,), jnp.int32),
+            sds((2, 16, 4, 128), jnp.bfloat16),
+            sds((2, 16, 4, 128), jnp.bfloat16), sds((3, 8), jnp.int32),
+            sds((3,), jnp.int32))
+
+    def lowered(**how):
+        with _as_on_a_tpu():
+            return jax.jit(functools.partial(
+                model.decode_step, block_size=4, **how)).lower(
+                    *args).as_text()
+
+    assert lowered() == lowered(attention_kernel="dense")
+    assert "paged_decode" not in lowered()
 
 
 def test_the_prompts_scatter_on_whole_rows_copies_no_cache_array(
@@ -295,3 +410,6 @@ def test_a_replica_decodes_what_the_full_context_forward_decodes(
     assert all(isinstance(n, int) and n > 0
                for n in started["step_temp_bytes"])
     assert len(started["whole_cache_copies"]) == 4
+    # on a CPU, whatever the rows' width: the gather, and no Mosaic call
+    assert started["attention_arm"] == ["gather"] * 4
+    assert started["paged_calls"] == [0] * 4

@@ -289,6 +289,9 @@ def test_the_replica_serves_a_latent_routed_model(tmp_path):
                .splitlines()]
     start = next(r for r in records if r.get("action") == "decode_start")
     assert start["cache_arrays"] == [[3, 33, 4, 16], [3, 33, 4, 8]]
+    # one row a token for all heads: the block's own gather, no kernel
+    assert set(start["attention_arm"]) == {"gather"}
+    assert set(start["paged_calls"]) == {0}
     # float32 here: 3 layers x (16 + 8) x 4 bytes a cached token
     assert start["cache_row_bytes"] == 3 * 24 * 4
     beats = [json.loads(line) for line in

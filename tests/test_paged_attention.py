@@ -1,23 +1,27 @@
 """Paged-attention kernel parity pins (ops/pallas_paged_attention.py).
 
 The decode twin of the flash-kernel parity tests: the Pallas paged
-kernel that walks each slot's block table IN-kernel must agree with
-the dense-gather oracle across every slot mix the decode service
-produces — fresh, mid-generation, near-max, idle (all-null table),
-and post-free block reuse.  Tolerances are the documented contract
-(see the kernel module docstring), not wishful thinking:
+kernel that walks each slot's block table IN-kernel, over the cache
+rows as they are stored, must agree with the dense-gather oracle across
+every slot mix the decode service produces — fresh, mid-generation,
+near-max, idle (all-null table), and post-free block reuse.  Tolerances
+are the documented contract (see the kernel module docstring), not
+wishful thinking:
 
 * live slots: f32 online-softmax vs dense softmax agree to
   accumulation-order noise (~4e-7 observed; 1e-5 pinned),
-* idle slots (length 0): the paged kernel returns EXACT zeros (its
-  accumulator never runs); the dense oracle's idle rows are
+* idle slots (length 0): the paged kernel returns EXACT zeros (it makes
+  no work item for them); the dense oracle's idle rows are
   unspecified garbage — by contract the caller ignores both,
+* a dead table entry is never looked up: the null block may hold
+  anything, a value that is no number included,
 * the cache scatter is shared by both paths, so after a decode step
   the caches agree everywhere OUTSIDE the reserved null block (an
   idle slot's garbage row legitimately lands there, divergently).
 
-CPU/GPU run the kernel in interpret mode — same index arithmetic and
-masking as compiled TPU, so these pins hold on every backend.
+CPU/GPU run the kernel in interpret mode — same index arithmetic, DMAs
+and masking as compiled TPU, so these pins hold on every backend; the
+compile for the chip itself is pinned in tests/test_cache_layout.py.
 """
 
 import numpy as np
@@ -180,7 +184,9 @@ def test_attention_kernel_knob_validation():
 
     from distributedmnist_tpu.core.config import ConfigError, DecodeConfig
 
-    DecodeConfig(attention_kernel="paged").validate()
+    assert DecodeConfig().attention_kernel == "auto"
+    for known in ("auto", "dense", "paged"):
+        DecodeConfig(attention_kernel=known).validate()
     with pytest.raises(ConfigError, match="attention_kernel"):
         DecodeConfig(attention_kernel="flash").validate()
 
@@ -194,3 +200,145 @@ def test_attention_kernel_knob_validation():
                           z((2, 4, 8, 4, 16)), z((2, 4, 8, 4, 16)),
                           z((1, 2), jnp.int32), z((1,), jnp.int32),
                           block_size=8, attention_kernel="flash")
+
+
+# -- the rows as stored -----------------------------------------------------
+
+#: (table width, pages an item, each slot's length): blocks of 4, so a
+#: table ``w`` wide holds ``4 w`` positions. The widths are the four
+#: rungs a replica with tables of 12 blocks compiles
+#: (``DecodeReplica._table_widths``); the pages divide none of them but
+#: the last case's, and 8 is capped to a table of 3
+ROWS_AS_STORED = {
+    "one_token_beside_an_idle_slot_and_a_full_table": (3, 8, [1, 0, 12]),
+    "a_blocks_edge_one_past_it_and_the_full_width": (6, 4, [4, 5, 24, 0]),
+    "mixed_lengths_in_one_call": (9, 4, [36, 1, 17, 0, 30, 8, 9]),
+    "idle_slots_first_and_last": (12, 8, [0, 48, 33, 7, 0]),
+    "every_slot_idle_but_one": (12, 5, [0, 0, 41, 0]),
+    "pages_that_divide_the_width": (12, 3, [48, 12, 13, 25]),
+}
+
+
+def _stored(rng, lengths, width, dtype, *, heads=2, hd=64, row=128, bs=4):
+    """Pages with rows ``row`` wide for a head of ``hd``, garbage that is
+    not zero beyond the head, each live slot's table filled as far as
+    its length and ``NULL_BLOCK`` from there on; block 0 holds no
+    number. Returns (q, k, v, the same k and v with a finite null block
+    for the oracle, tables, lengths)."""
+    import jax.numpy as jnp
+    num_blocks = 1 + sum(-(-n // bs) for n in lengths)
+    k = rng.standard_normal((num_blocks, bs, heads, row)).astype(np.float32)
+    v = rng.standard_normal((num_blocks, bs, heads, row)).astype(np.float32)
+    k[..., hd:] = 7.0 + rng.standard_normal(k[..., hd:].shape)
+    v[..., hd:] = -5.0 + rng.standard_normal(v[..., hd:].shape)
+    tables = np.zeros((len(lengths), width), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_blocks)))
+    for slot, n in enumerate(lengths):
+        for j in range(-(-n // bs)):
+            tables[slot, j] = next(free)
+    no_number = [a.copy() for a in (k, v)]
+    for a in no_number:
+        a[0] = np.nan
+    q = jnp.asarray(rng.standard_normal((len(lengths), heads, hd)), dtype)
+    return (q, *(jnp.asarray(a, dtype) for a in (*no_number, k, v)),
+            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ROWS_AS_STORED)
+def test_kernel_reads_rows_stored_wider_than_the_head(case, dtype):
+    """A 64-wide head in 128-wide rows with garbage beside it, tables
+    whose dead tail is ``NULL_BLOCK`` (which holds no number here: a dead
+    entry is never looked up), through the interpreter: the oracle's
+    output for every live slot, exact zeros for an idle one. In bfloat16
+    (what the chip stores) the weights go to the product as three
+    pieces, so the float32 oracle is met as closely as in float32."""
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_attention, paged_attention_dense)
+
+    width, pages, lengths = ROWS_AS_STORED[case]
+    q, k, v, k_finite, v_finite, tables, lens = _stored(
+        np.random.default_rng(len(case)), lengths, width, dtype)
+    got = np.asarray(paged_attention(q, k, v, tables, lens,
+                                     pages_per_step=pages, interpret=True))
+    want = np.asarray(paged_attention_dense(q, k_finite, v_finite, tables,
+                                            lens))
+    assert got.shape == want.shape == (len(lengths), 2, 64)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
+@pytest.mark.tier1
+def test_kernel_takes_the_cache_whole_with_the_layers_index():
+    """The arrays as ``PagedKVCache`` holds them, [L, N, B, h, width],
+    and a traced layer index: what one layer's pages give."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_attention)
+
+    rng = np.random.default_rng(5)
+    q, k, v, _, _, tables, lens = _stored(rng, [9, 0, 16], 4, "float32",
+                                          hd=16, row=32)
+    k, v = k.at[0].set(1.0), v.at[0].set(1.0)
+    whole_k = jnp.stack([k + 1.0, k, k - 1.0])
+    whole_v = jnp.stack([v - 2.0, v, v + 2.0])
+    read = jax.jit(lambda li: paged_attention(
+        q, whole_k, whole_v, tables, lens, layer=li, interpret=True))
+    want = paged_attention(q, k, v, tables, lens, interpret=True)
+    np.testing.assert_array_equal(np.asarray(read(1)), np.asarray(want))
+    assert np.abs(np.asarray(read(2)) - np.asarray(want)).max() > 0.5
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("stored", [16, 128])
+def test_both_arms_decode_the_same_greedy_tokens(stored):
+    """A written prompt and eight greedy steps for two slots of three
+    through ``decode_step`` on each arm, at the head's own width and on
+    128-wide rows: the same tokens, logits within the tolerance of the
+    one-step test above."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
+
+    model = get_model(ModelConfig(**LM_MODEL))
+    params = model.init(jax.random.PRNGKey(3))
+    prompts = [[1, 2, 3, 4, 5], list(range(1, 13))]
+
+    def decode(kernel):
+        step = jax.jit(functools.partial(model.decode_step, block_size=4,
+                                         attention_kernel=kernel))
+        cache = PagedKVCache(2, 40, 4, 4, stored, max_blocks_per_seq=6)
+        tables = np.zeros((3, 6), np.int32)
+        toks, rows, picked = [], [], []
+        for slot, prompt in enumerate(prompts):
+            logits, ks, vs = model.decode_prefill(
+                params, jnp.asarray([prompt], jnp.int32))
+            tables[slot] = cache.alloc_sequence(len(prompt) + 8)
+            cache.write_prompt(tables[slot], ks[:, 0], vs[:, 0], len(prompt))
+            toks.append(int(jnp.argmax(logits[0, -1])))
+        for i in range(8):
+            pos = [len(p) + i for p in prompts]
+            out, cache.k, cache.v = step(
+                params, jnp.asarray([*toks, 0], jnp.int32),
+                jnp.asarray([*pos, 0], jnp.int32), cache.k, cache.v,
+                jnp.asarray(tables),
+                jnp.asarray([pos[0] + 1, pos[1] + 1, 0], jnp.int32))
+            rows.append(np.asarray(out[:2]))
+            toks = [int(t) for t in rows[-1].argmax(-1)]
+            picked.append(toks)
+        return picked, np.stack(rows)
+
+    tokens_paged, logits_paged = decode("paged")
+    tokens_dense, logits_dense = decode("dense")
+    assert tokens_paged == tokens_dense
+    np.testing.assert_allclose(logits_paged, logits_dense, atol=1e-4,
+                               rtol=1e-4)
