@@ -330,7 +330,11 @@ _declare(EventSchema(
               # of the last decode step of a model that routes: the
               # (token, expert) pairs on experts held here, and how many
               # of those experts took any
-              "expert_pairs_held", "experts_touched"),
+              "expert_pairs_held", "experts_touched",
+              # the decode loop's clock (obsv/timing.LoopClock): cumulative
+              # seconds by phase, a flat object, and of the whole loop;
+              # counters, so two heartbeats give ms an iteration by phase
+              "loop_s", "loop_wall_s"),
 ))
 
 # Load-generator journal (servesvc/loadgen.py loadgen.jsonl): every

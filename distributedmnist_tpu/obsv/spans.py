@@ -1,11 +1,30 @@
 """The program's own names in a ``jax.profiler`` trace: host spans,
 device scopes, and the one way a trace is started.
 
-One mechanism, the profiler's own, and no store beside it. A host span
-is a ``jax.profiler.TraceAnnotation``: it lands on the thread's line of
-plane ``/host:CPU`` in the same ``.xplane.pb`` as the device planes, on
-the same clock, so a reader can say what the host was doing while the
-chip ran nothing. With no profiler session it costs about 1 µs
+One mechanism, the profiler's own, and no store beside it. (The decode
+loop's ``obsv/timing.LoopClock`` reads the host's clock at the same
+phase boundaries and keeps one cumulative number a phase, which the
+heartbeat carries as ``loop_s``: a counter as ``decode_steps`` is, so
+that a replica under no profiler still says where an iteration goes;
+it stores no span.) A host span is a ``jax.profiler.TraceAnnotation``:
+it lands on the thread's line of plane ``/host:CPU`` in the same
+``.xplane.pb`` as the device planes, in the same file but NOT on the
+same clock: the device plane lies early against the host plane by an
+amount that is constant within a trace, differs between profiler
+sessions, and has to be measured by the reader. In the two recorded v5e
+traces beside the benchmark's tests (jax 0.9.0, libtpu 0.0.34, months
+apart) every execution of ``jit_decode_step`` begins 0.437-0.701 ms
+BEFORE the ``dml.serve.step.dispatch`` span that launched it opens, so
+the device plane is at least 0.601 and 0.701 ms early there, and at most
+2.12 and 2.77 (the step's end against the end of the fetch that waited
+for it); of PR 40's seven traced chip runs the first profiler session
+on each of three machines read at least 0.776-0.836 and the four later
+ones at least 0.000 (at most 1.09-1.16).
+``benchmark/lib/host_gaps.py`` joins spans to executions by order,
+takes every listed number as a difference within one clock, and
+reports that bracket (``trace_clock_offset_ms``). With it a reader can
+say what the host was doing while the chip ran nothing. With no
+profiler session a span costs about 1 µs
 (0.5 µs without keyword facts; measured, jax 0.9.0). A device scope is
 a ``jax.named_scope``: it becomes part of every HLO operation's
 ``op_name`` metadata and changes nothing else of the compiled program.
@@ -24,8 +43,12 @@ Spans of one request share its ``id``; a count rides on the span at
 whose boundary it is true.
 
 Readers: ``benchmark/lib/program_trace.py`` (whose ``__main__`` prints
-the span table and the scope table of any trace directory) and the
-per-layer metrics named beside each span below; PERF.md §3 has the
+the span table and the scope table of any trace directory),
+``benchmark/lib/host_gaps.py`` (whose ``__main__`` prints each gap
+between two decode steps split by what the host was doing) and the
+per-layer metrics named beside each span below (each a reader under
+``benchmark/layer_metrics/``; ``BENCHMARK.json`` lists PR 23's, and PR
+40's wait as files that ``host_gaps.py`` prints); PERF.md §3 has the
 same list from the metrics' side.
 """
 
@@ -37,11 +60,13 @@ PREFIX = "dml."
 
 # -- the decode replica's batcher thread (servesvc/decode.py) --------------
 #: the park on the admission queue while no slot is live and nothing
-#: waits (`_admit_new`); read by: serve_idle_unattributed_share (an idle
-#: replica's gaps are the queue's, not the loop's)
+#: waits (`_admit_new`); read by: serve_idle_no_request_share,
+#: serve_idle_unattributed_share (an idle replica's gaps are the
+#: queue's, not the loop's)
 SERVE_IDLE = "dml.serve.idle"
 #: queue drain and slot assignment in `_admit_new`, less the prefill it
-#: calls; read by: the span table, serve_idle_unattributed_share
+#: calls; read by: decode_gap_rest_ms (it is not one of the gap's named
+#: parts), the span table, serve_idle_unattributed_share
 SERVE_ADMIT = "dml.serve.admit"
 #: `_maybe_swap`, only when a publish was staged; read by: the span table
 #: (a swap is the one stall of the loop that is not a request's)
@@ -57,27 +82,37 @@ SERVE_PREFILL_FORWARD = "dml.serve.prefill.forward"
 #: program, this span says where the host waits for it)
 SERVE_PREFILL_CACHE_WRITE = "dml.serve.prefill.cache_write"
 #: `_step_active` up to the dispatch: the numpy vectors, their three
-#: uploads and `_tables_for`
+#: uploads and `_tables_for`; read by: decode_gap_inputs_ms
 SERVE_STEP_INPUTS = "dml.serve.step.inputs"
 #: the call of the jitted decode step (live, waiting, version, blocks:
 #: the width of the block table it is handed): one per iteration and
 #: params version, so it counts iterations; read by:
 #: decode_slots_live_p50 (`live`), decode_table_blocks_p50 (`blocks`),
-#: decode_sample_ms_per_iter and decode_stream_ms_per_iter (the count)
+#: decode_sample_ms_per_iter and decode_stream_ms_per_iter (the count);
+#: the k-th one launched the k-th execution of `jit_decode_step`
+#: (host_gaps.join): decode_gap_beneath_ms, trace_clock_offset_ms
 SERVE_STEP_DISPATCH = "dml.serve.step.dispatch"
 #: the blocking fetch of the step's [slots] greedy tokens: the wait for
-#: the step on the device
+#: the step on the device; read by: decode_gap_beneath_ms (a gap's host
+#: part runs from its end to the next dispatch's start),
+#: trace_clock_offset_ms, decode_loop_host_share (a trace's fallback)
 SERVE_STEP_FETCH = "dml.serve.step.fetch"
 #: once an iteration (device, host): the pick-up of every slot's token,
 #: `_sample`'s draws for `temperature > 0` included; and once a prefill
 #: (id, slot): `_sample` on its last row, which waits for the prefill on
-#: the device; read by: decode_sample_ms_per_iter, serve_idle_sample_share
+#: the device; read by: decode_sample_ms_per_iter,
+#: serve_idle_sample_share, decode_gap_emit_ms
 SERVE_SAMPLE = "dml.serve.sample"
 #: `_stream_token` (id): one JSON line and one `sendall`; read by:
-#: decode_stream_ms_per_iter
+#: decode_stream_ms_per_iter, decode_gap_emit_ms
 SERVE_STREAM = "dml.serve.stream"
-#: `_finish_seq` (id, reason): journal, terminal line, blocks freed
+#: `_finish_seq` (id, reason): journal, terminal line, blocks freed;
+#: read by: decode_gap_emit_ms
 SERVE_FINISH = "dml.serve.finish"
+#: the heartbeat's write, only when one is written (a request ended
+#: since the last): in a model that routes it fetches the last step's
+#: pair counts from the device; read by: decode_gap_rest_ms
+SERVE_HEARTBEAT = "dml.serve.heartbeat"
 
 # -- the trainer's loop thread (train/loop.py) ------------------------------
 #: `next(feed)` from the prefetcher, or the inline `device_put_batch`

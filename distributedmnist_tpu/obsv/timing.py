@@ -14,6 +14,10 @@ documented quirk we do not copy). Collection is async-friendly: the
 collector holds device arrays and only materializes them at report
 points, so the device pipeline is never synced per step (SURVEY §7
 "hard parts": timing capture must not cost scaling efficiency).
+
+:class:`LoopClock` is the decode replica's counterpart on the host's
+clock: cumulative seconds of its serial loop by phase, carried by every
+heartbeat.
 """
 
 from __future__ import annotations
@@ -219,6 +223,53 @@ class StepTimeCollector:
             # series stays visible when only that half is on
             out["snapshot_stall_ms"] = self.snapshot_stall_stats().to_dict()
         return out
+
+
+class LoopClock:
+    """Cumulative seconds of one serial loop by phase, always on: the
+    decode replica's batcher thread reads ``time.perf_counter()`` at the
+    boundaries its host spans already mark (``obsv/spans.py``) and adds
+    the differences up, as ``decode_steps`` counts dispatches. Counters,
+    not a store of spans: any two heartbeats that carry them give the
+    milliseconds an iteration spent in each phase over every iteration
+    between them, on the host's clock alone and with no profiler
+    session. A phase is entered together with the span of the same
+    region (the clock read inside it), so the two cannot name different
+    regions; phases do not nest. What the named phases leave of
+    :meth:`wall_s` is the loop's ``other``."""
+
+    def __init__(self, phases: tuple[str, ...]):
+        self.seconds = dict.fromkeys(phases, 0.0)
+        self._began: float | None = None   # the first phase entered
+
+    def phase(self, name: str, span=None) -> "_Phase":
+        """``with clock.phase("fetch", spans.span(...)):``; ``span`` is
+        any context manager, entered first and left last."""
+        return _Phase(self, name, span)
+
+    def wall_s(self) -> float:
+        """Seconds since the loop entered its first phase."""
+        return (0.0 if self._began is None
+                else time.perf_counter() - self._began)
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "span", "t0")
+
+    def __init__(self, clock: LoopClock, name: str, span):
+        self.clock, self.name, self.span = clock, name, span
+
+    def __enter__(self) -> None:
+        if self.span is not None:
+            self.span.__enter__()
+        self.t0 = time.perf_counter()
+        if self.clock._began is None:
+            self.clock._began = self.t0
+
+    def __exit__(self, *exc) -> None:
+        self.clock.seconds[self.name] += time.perf_counter() - self.t0
+        if self.span is not None:
+            self.span.__exit__(*exc)
 
 
 class ReplicaDeviceProbe:

@@ -97,8 +97,17 @@ from ..core.config import ConfigError
 from ..models.registry import sample_token
 from ..models.transformer import decode_attention_arm
 from ..obsv import spans
+from ..obsv.timing import LoopClock
 from .kv_cache import PagedKVCache, cache_shapes, stored_head_dim
 from .server import ServingReplica, _Pending
+
+
+#: the phases of the batcher thread's loop that `LoopClock` times, each
+#: the region of the span(s) named beside it in obsv/spans.py; what they
+#: leave of `loop_wall_s` (the swap, the deadline sweep, the heartbeat) is
+#: the loop's `other`
+LOOP_PHASES = ("idle", "admit", "prefill", "inputs", "dispatch", "fetch",
+               "emit")
 
 
 class _DecodeSeq(_Pending):
@@ -237,6 +246,10 @@ class DecodeReplica(ServingReplica):
         self.tokens_sampled_device = 0
         self.tokens_sampled_host = 0
         self.sequences_finished = 0
+        # where the batcher thread's time goes, always on: cumulative
+        # seconds by phase, read at the boundaries the spans mark and
+        # carried by the heartbeat (`loop_s`, `loop_wall_s`)
+        self._clock = LoopClock(LOOP_PHASES)
         # block-table upload cache: slot→block assignments only change
         # on admit/finish/restart, so the [slots, width] tables array a
         # decode iteration feeds the jitted step is IDENTICAL between
@@ -377,6 +390,9 @@ class DecodeReplica(ServingReplica):
                 "decode_table_blocks": self.decode_table_blocks,
                 "tokens_sampled_device": self.tokens_sampled_device,
                 "tokens_sampled_host": self.tokens_sampled_host,
+                "loop_s": {k: round(v, 6)
+                           for k, v in self._clock.seconds.items()},
+                "loop_wall_s": round(self._clock.wall_s(), 6),
                 **self._routing_fields()}
 
     def _routing_fields(self) -> dict:
@@ -389,6 +405,12 @@ class DecodeReplica(ServingReplica):
         pairs = np.asarray(self._expert_pairs)
         return {"expert_pairs_held": int(pairs.sum()),
                 "experts_touched": int(np.count_nonzero(pairs))}
+
+    def _write_heartbeat(self, n: int) -> None:
+        # under its own span: in a model that routes, the write fetches
+        # the last step's pair counts from the device
+        with spans.span(spans.SERVE_HEARTBEAT):
+            super()._write_heartbeat(n)
 
     # -- the decode loop ------------------------------------------------
 
@@ -426,7 +448,9 @@ class DecodeReplica(ServingReplica):
         than evicting a running generation."""
         idle = (not self._waiting
                 and all(s is None for s in self._slots))
-        with spans.span(spans.SERVE_IDLE if idle else spans.SERVE_ADMIT):
+        phase, name = (("idle", spans.SERVE_IDLE) if idle
+                       else ("admit", spans.SERVE_ADMIT))
+        with self._clock.phase(phase, spans.span(name)):
             try:
                 # idle: park briefly on the queue instead of spinning.
                 # _waiting is capped at the slot count — anything beyond
@@ -443,7 +467,7 @@ class DecodeReplica(ServingReplica):
         while True:
             # the prefill is a sibling of the admit span, not its child:
             # the leaves tile the loop (obsv/spans.py)
-            with spans.span(spans.SERVE_ADMIT):
+            with self._clock.phase("admit", spans.span(spans.SERVE_ADMIT)):
                 s = self._place_next()
             if s is None:
                 return
@@ -488,8 +512,9 @@ class DecodeReplica(ServingReplica):
         slot = self._slots.index(s)
         plen = int(s.inputs.size)
         bucket = self._bucket(plen, self.dcfg.max_prompt_len)
-        with spans.span(spans.SERVE_PREFILL, id=s.req_id, prompt_len=plen,
-                        bucket=bucket, queue_ms=queue_ms):
+        with self._clock.phase("prefill", spans.span(
+                spans.SERVE_PREFILL, id=s.req_id, prompt_len=plen,
+                bucket=bucket, queue_ms=queue_ms)):
             with spans.span(spans.SERVE_PREFILL_FORWARD):
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :plen] = s.inputs
@@ -593,7 +618,8 @@ class DecodeReplica(ServingReplica):
         # step per version, idle-for-this-version slots masked via the
         # null block table + zero length
         for ver in sorted({s.params_step for _, s in active}):
-            with spans.span(spans.SERVE_STEP_INPUTS):
+            with self._clock.phase("inputs",
+                                   spans.span(spans.SERVE_STEP_INPUTS)):
                 mine = [(i, s) for i, s in active if s.params_step == ver]
                 width = self._table_width(mine)
                 tokens = np.zeros((num_slots,), np.int32)
@@ -606,32 +632,37 @@ class DecodeReplica(ServingReplica):
                 tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
                 tables = self._tables_for(ver, mine, num_slots, width)
                 lengths = jnp.asarray(lengths)
-            with spans.span(spans.SERVE_STEP_DISPATCH, live=len(active),
-                            waiting=len(self._waiting), version=ver,
-                            blocks=width):
+            with self._clock.phase("dispatch", spans.span(
+                    spans.SERVE_STEP_DISPATCH, live=len(active),
+                    waiting=len(self._waiting), version=ver, blocks=width)):
                 logits, greedy, self.cache.k, self.cache.v, *pairs = (
                     self._step(width)(
                         self._params_for(ver), tokens, positions,
                         self.cache.k, self.cache.v, tables, lengths))
                 self.decode_steps += 1
                 self.decode_table_blocks = width
-            with spans.span(spans.SERVE_STEP_FETCH):
+            with self._clock.phase("fetch",
+                                   spans.span(spans.SERVE_STEP_FETCH)):
                 on_host = jax.device_get(greedy)
             if pairs:
                 self._expert_pairs = pairs[0]
             draws = sum(s.temperature > 0.0 for _, s in mine)
-            # every slot's token before any is appended or streamed: one
-            # span an iteration, never around a `dml.serve.stream`
-            with spans.span(spans.SERVE_SAMPLE, device=len(mine) - draws,
-                            host=draws):
-                picked = [int(on_host[i]) if s.temperature <= 0.0
-                          else self._sample(s, logits[i]) for i, s in mine]
-            self.tokens_sampled_device += len(mine) - draws
-            for (i, s), tok in zip(mine, picked):
-                s.length += 1  # the fed token's K/V is now cached
-                s.tokens.append(tok)
-                self._stream_token(s, tok)
-                self._maybe_finish(i, s)
+            # one pair of clock readings around the iteration's sample,
+            # stream and finish spans, not one a slot
+            with self._clock.phase("emit"):
+                # every slot's token before any is appended or streamed:
+                # one span an iteration, never around a `dml.serve.stream`
+                with spans.span(spans.SERVE_SAMPLE,
+                                device=len(mine) - draws, host=draws):
+                    picked = [int(on_host[i]) if s.temperature <= 0.0
+                              else self._sample(s, logits[i])
+                              for i, s in mine]
+                self.tokens_sampled_device += len(mine) - draws
+                for (i, s), tok in zip(mine, picked):
+                    s.length += 1  # the fed token's K/V is now cached
+                    s.tokens.append(tok)
+                    self._stream_token(s, tok)
+                    self._maybe_finish(i, s)
 
     def _sample(self, s: _DecodeSeq, logits_row: jax.Array) -> int:
         """One token from one row of logits on the device: a prefill's

@@ -289,9 +289,14 @@ class ServingReplica:
             if n == self._last_heartbeat or self._journal_closed:
                 return
             self._last_heartbeat = n
-            self._heartbeat.write({"event": "heartbeat", "step": n,
-                                   "time": time.time(),
-                                   **self._pressure_fields()})
+            self._write_heartbeat(n)
+
+    def _write_heartbeat(self, n: int) -> None:
+        """The write itself, under the journal's lock (the decode
+        replica opens a span around it)."""
+        self._heartbeat.write({"event": "heartbeat", "step": n,
+                               "time": time.time(),
+                               **self._pressure_fields()})
 
     # -- weights ------------------------------------------------------
 

@@ -22,7 +22,8 @@ SERVE_LEAVES = {spans.SERVE_IDLE, spans.SERVE_ADMIT,
                 spans.SERVE_PREFILL_FORWARD,
                 spans.SERVE_PREFILL_CACHE_WRITE, spans.SERVE_STEP_INPUTS,
                 spans.SERVE_STEP_DISPATCH, spans.SERVE_STEP_FETCH,
-                spans.SERVE_SAMPLE, spans.SERVE_STREAM, spans.SERVE_FINISH}
+                spans.SERVE_SAMPLE, spans.SERVE_STREAM, spans.SERVE_FINISH,
+                spans.SERVE_HEARTBEAT}
 
 
 # -- reading a trace -------------------------------------------------------
@@ -294,6 +295,27 @@ def test_journal_and_heartbeat_carry_the_same_facts(traced_decode):
     assert any(b["slots_live"] == 2 for b in beats)
 
 
+def test_a_heartbeat_span_when_and_only_when_one_is_written(traced_decode):
+    evs, rep = traced_decode["spans"], traced_decode["rep"]
+    beats = (rep.serve_dir / "train_log.jsonl").read_text().splitlines()
+    opened = _named(evs, spans.SERVE_HEARTBEAT)
+    # every iteration asks; one is written after the first (the count
+    # of terminals, 0, is news) and after each iteration in which a
+    # request ended (three did, in three iterations)
+    assert len(opened) == len(beats) == 4
+    assert len(_named(evs, spans.SERVE_STEP_DISPATCH)) > len(opened)
+    # each of those after a finish that no earlier heartbeat has told
+    # of; none of the four under another span
+    at = 0
+    for e in opened:
+        finished = [f for f in _named(evs, spans.SERVE_FINISH)
+                    if at <= f["start"] and f["end"] <= e["start"]]
+        assert bool(finished) == (e is not opened[0])
+        assert not any(o["start"] <= e["start"] and e["end"] <= o["end"]
+                       for o in evs if o is not e)
+        at = e["end"]
+
+
 def test_the_same_tokens_with_and_without_a_profiler_session(
         traced_decode, published, tmp_path):
     _, conns, occupancy = drive(published, tmp_path / "replica")
@@ -466,7 +488,7 @@ def test_the_paged_kernel_has_its_name():
 def test_span_names_are_unique_and_prefixed():
     names = [v for k, v in vars(spans).items()
              if k.isupper() and isinstance(v, str) and k != "PREFIX"]
-    assert len(names) == len(set(names)) == 19
+    assert len(names) == len(set(names)) == 20
     assert all(n.startswith(spans.PREFIX) for n in names)
     assert len(set(spans.SCOPES)) == len(spans.SCOPES)
     # a span with no profiler session is a no-op that still nests
