@@ -58,6 +58,10 @@ FULL_MODEL = {"name": "transformer", "model_dim": 2048, "num_layers": 4,
 # d=2048, L=4 before the first chip run)
 TRAIN_LR = 0.05
 SEED = 20260926
+# what the serve phase asks of each arm's replica, and what the kernels
+# phase decodes again on caches it can compare
+SERVE_PROMPT_LENS = (5, 12, 40, 100, 7, 33)
+SERVE_NEW_TOKENS = 16
 
 
 class SmokeFailure(AssertionError):
@@ -332,8 +336,7 @@ def phase_serve(train_dir: Path, workdir: Path, *, prompt_lens: list[int],
     # tolerance is pinned in phase_kernels), so the gate is on the mean
     # common-prefix share: a broken kernel diverges at the first decode
     # step and scores 1/max_new_tokens.
-    prefix = [next((i for i, (a, b) in enumerate(zip(d, p)) if a != b),
-                   len(d)) for d, p in zip(dense, paged)]
+    prefix = [_common_prefix(d, p) for d, p in zip(dense, paged)]
     _require(all(n >= 1 for n in prefix),
              f"first (prefill) tokens differ between arms: {dense} {paged}")
     share = sum(prefix) / (len(prefix) * max_new_tokens)
@@ -351,6 +354,11 @@ def phase_serve(train_dir: Path, workdir: Path, *, prompt_lens: list[int],
                                "(servesvc/server.py)"}
 
 
+def _common_prefix(a, b) -> int:
+    """How many leading tokens two equally long sequences share."""
+    return next((i for i, (x, y) in enumerate(zip(a, b)) if x != y), len(a))
+
+
 def _max_err(got, want) -> float:
     """Largest error relative to the oracle's largest magnitude."""
     got = np.asarray(got, np.float32)
@@ -359,7 +367,9 @@ def _max_err(got, want) -> float:
 
 
 def phase_kernels(*, model: dict, batch: int, block_size: int = 16,
-                  context: int = 100, tol: float = 2e-2) -> dict:
+                  context: int = 100, tol: float = 2e-2,
+                  prompt_lens: tuple[int, ...] = SERVE_PROMPT_LENS,
+                  steps: int = SERVE_NEW_TOKENS) -> dict:
     """Both Pallas kernels, and the decode step that uses the paged one,
     against their dense oracles at the smoke's shapes.
 
@@ -460,6 +470,8 @@ def phase_kernels(*, model: dict, batch: int, block_size: int = 16,
         int(jnp.argmax(lg["paged"][0])) == int(jnp.argmax(lg["dense"][0])))
     out["latent_routed_block"] = _latent_routed_arm(model, batch)
     out["wide_cache_rows"] = _wide_cache_arm(model, block_size, context)
+    out["rows_written_in_the_kernel"] = _rows_written_arm(
+        model, block_size, list(prompt_lens), steps, tol)
     out["latent_decode"] = _latent_decode_arm(model, block_size, tol)
     return out
 
@@ -564,6 +576,155 @@ def _wide_cache_arm(model: dict, block_size: int, context: int,
                  "head_wide": "gather",
                  "stored_wide": "paged" if on_tpu else "gather"},
              f"the cache with whole rows decodes otherwise: {out}")
+    return out
+
+
+def _rows_written_arm(model: dict, block_size: int, prompt_lens: list[int],
+                      steps: int, tol: float) -> dict:
+    """(f) the new token's keys and values, written by the paged kernel
+    itself (``paged_attention_write``: the paged arm's step) against the
+    scatter through XLA: the serve phase's prompts in one batch of slots
+    (two more idle), ``steps`` greedy steps each, at 64-wide heads in
+    128-wide rows. Three decodes of the
+    same prompts, each on a cache of its own:
+
+    * ``paged``: the step as the replica runs it on a TPU;
+    * ``scattered``: the same step with the kernel's writing form
+      replaced, here and nowhere in the program, by the scatter and
+      then the read-only form. Same rows, same read, so everything must
+      be equal to the bit: every step's logits and both cache arrays
+      whole, every layer, block and lane;
+    * ``dense``: the gather arm. Its attention is other arithmetic, so
+      a deeper layer's rows differ in a last bit here and there and a
+      near-tie can flip a greedy pick: the tokens agree as far as the
+      serve phase asks, layer 0's rows (a function of the tokens alone)
+      are equal to the bit over each slot's common prefix, and the
+      deeper layers' within ``tol``."""
+    import functools
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+    from distributedmnist_tpu.servesvc.decode import while_loops
+    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
+
+    heads = max(model["model_dim"] // 64, 1)
+    mdl = get_model(ModelConfig(**{**model, "num_heads": heads}))
+    params = mdl.init(jax.random.PRNGKey(SEED + 2))
+    layers, _, hd = mdl.decode_cache_shape
+    dtype = jnp.dtype(model["compute_dtype"])
+    live, slots = len(prompt_lens), len(prompt_lens) + 2
+    width = -(-(max(prompt_lens) + steps) // block_size)
+    nblocks = live * width + 1
+    # rows of whole lanes, as the replica's cache has them on a TPU at a
+    # served model's size (this one's few blocks would stay 64 wide, and
+    # the kernel, compiled, takes whole lanes)
+    wide = -(-hd // 128) * 128
+    rng = np.random.default_rng(SEED + 2)
+    prefill = jax.jit(mdl.decode_prefill)
+    prompts = []
+    for n in prompt_lens:
+        toks = np.zeros((1, 1 << max(n - 1, 1).bit_length()), np.int32)
+        toks[0, :n] = rng.integers(0, model["vocab_size"], n)
+        logits, ks, vs = prefill(params, jnp.asarray(toks))
+        prompts.append((ks[:, 0], vs[:, 0], int(jnp.argmax(logits[0, n - 1]))))
+
+    def scatter_then_read(q, k_new, v_new, k_pages, v_pages, tables, lengths,
+                          *, layer, scale):
+        k_pages, v_pages = (
+            ppa._scattered(pages, new, tables, lengths, layer)
+            for pages, new in ((k_pages, k_new), (v_pages, v_new)))
+        return (ppa.paged_attention(q, k_pages, v_pages, tables, lengths,
+                                    layer=layer, scale=scale),
+                k_pages, v_pages)
+
+    def decode(kernel, write=None):
+        cache = PagedKVCache(layers, nblocks, block_size, heads, wide,
+                             max_blocks_per_seq=width, dtype=dtype)
+        tables = np.zeros((slots, width), np.int32)
+        for slot, (n, (ks, vs, _)) in enumerate(zip(prompt_lens, prompts)):
+            tables[slot] = cache.alloc_sequence(n + steps)
+            cache.write_prompt(tables[slot], ks, vs, n)
+        step = jax.jit(functools.partial(mdl.decode_step,
+                                         block_size=block_size,
+                                         attention_kernel=kernel),
+                       donate_argnums=(3, 4))
+        idle = [0] * (slots - live)
+        toks, picked, rows = [first for _, _, first in prompts], [], []
+        # where a prompt's padding went; an idle slot's row goes there
+        # through the scatter, and nowhere through the kernel
+        null_before = [np.asarray(a[:, 0].astype(jnp.float32))
+                       for a in (cache.k, cache.v)]
+        with mock.patch.object(ppa, "paged_attention_write",
+                               write or ppa.paged_attention_write):
+            text = step.lower(
+                params, *(jax.ShapeDtypeStruct((slots,), jnp.int32),) * 2,
+                cache.k, cache.v, jnp.asarray(tables),
+                jax.ShapeDtypeStruct((slots,), jnp.int32)).compile().as_text()
+            for i in range(steps):
+                pos = [n + i for n in prompt_lens]
+                out, cache.k, cache.v = step(
+                    params, jnp.asarray([*toks, *idle], jnp.int32),
+                    jnp.asarray([*pos, *idle], jnp.int32), cache.k, cache.v,
+                    jnp.asarray(tables),
+                    jnp.asarray([*(p + 1 for p in pos), *idle], jnp.int32))
+                rows.append(np.asarray(out[:live].astype(jnp.float32)))
+                toks = [int(t) for t in rows[-1].argmax(-1)]
+                picked.append(toks)
+        return {"tokens": np.asarray(picked).T, "logits": np.stack(rows),
+                "k": np.asarray(cache.k.astype(jnp.float32)),
+                "v": np.asarray(cache.v.astype(jnp.float32)),
+                "tables": tables, "text": text, "null_before": null_before}
+
+    paged, scattered = decode("paged"), decode("paged", scatter_then_read)
+    dense = decode("dense")
+    same_as_scattered = {
+        "logits": bool(np.array_equal(paged["logits"], scattered["logits"])),
+        "k": bool(np.array_equal(paged["k"], scattered["k"])),
+        "v": bool(np.array_equal(paged["v"], scattered["v"]))}
+    # against the gather arm, each slot as far as the two agree on its
+    # tokens: the row at position n + i is token i's, and token 0 is the
+    # prefill's (one program for both)
+    prefix = [_common_prefix(d, p)
+              for d, p in zip(dense["tokens"], paged["tokens"])]
+    first_layer_equal, deeper = True, 0.0
+    for slot, (n, agreed) in enumerate(zip(prompt_lens, prefix)):
+        at = [(paged["tables"][slot, p // block_size], p % block_size)
+              for p in range(n + min(agreed + 1, steps))]
+        blocks, offs = (np.asarray(x) for x in zip(*at))
+        for name in ("k", "v"):
+            got = paged[name][:, blocks, offs]
+            want = dense[name][:, blocks, offs]
+            first_layer_equal &= bool(np.array_equal(got[0], want[0]))
+            deeper = max(deeper, _max_err(got[1:], want[1:]))
+    share = sum(prefix) / (live * steps)
+    whiles = {name: while_loops(run["text"])
+              for name, run in (("paged", paged), ("scattered", scattered),
+                                ("dense", dense))}
+    out = {"head_dim": hd, "stored_head_dim": wide, "steps": steps,
+           "prompt_lens": prompt_lens, "slots": slots,
+           "paged_equals_scattered_to_the_bit": same_as_scattered,
+           "rows_beside_the_head_are_zero": not (
+               paged["k"][..., hd:].any() or paged["v"][..., hd:].any()),
+           "null_block_untouched": all(
+               np.array_equal(paged[name][:, 0], was) for name, was
+               in zip(("k", "v"), paged["null_before"])),
+           "dense_common_prefix": prefix,
+           "dense_token_agreement": round(share, 4),
+           "dense_first_layer_rows_equal_to_the_bit": first_layer_equal,
+           "dense_deeper_layers_max_rel_diff": round(deeper, 5),
+           "step_while_loops": whiles,
+           "paged_step_mosaic_calls": _mosaic_calls(paged["text"])["total"]}
+    _require(all(same_as_scattered.values())
+             and out["rows_beside_the_head_are_zero"]
+             and out["null_block_untouched"]
+             and np.isfinite(paged["logits"]).all()
+             and first_layer_equal and deeper <= tol and share >= 0.75,
+             f"the kernel's row copies leave another cache than the "
+             f"scatter: {out}")
     return out
 
 
@@ -771,8 +932,8 @@ def main() -> None:
             per_device_batch=4096, steps=5)
         serve = run("serve", phase_serve,
                     train_dir=Path(train["train_dir"]), workdir=workdir,
-                    prompt_lens=[5, 12, 40, 100, 7, 33],
-                    max_new_tokens=16, max_prompt_len=128)
+                    prompt_lens=list(SERVE_PROMPT_LENS),
+                    max_new_tokens=SERVE_NEW_TOKENS, max_prompt_len=128)
         _require(len(serve["arms"]["dense"]["prefill_buckets"]) > 1,
                  "only one prefill bucket compiled")
         for kernel, arm in serve["arms"].items():

@@ -1024,33 +1024,47 @@ def decode_attention_arm(attention_kernel: str,
 def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
                  blk_ids, offs, live, *, num_heads, scale, arm):
     """One layer's attention sublayer of :func:`decode_step`: this
-    token's K/V written through the block table (scope ``cache_write``),
-    the context read back by ``arm`` (:func:`decode_attention_arm`:
-    ``cache_gather`` on the gather arm; the paged kernel walks the table
-    itself), x + wo(attn)."""
+    token's K/V written through the block table and the context read
+    back by ``arm`` (:func:`decode_attention_arm`), x + wo(attn). The
+    gather arm scatters the rows (scope ``cache_write``) and gathers
+    every table entry (``cache_gather``); on the paged arm the kernel
+    that walks the table copies the rows into their page first, and
+    ``cache_write`` holds what is left outside it: the rows cast and
+    padded to the stored width."""
     num_slots, d = x.shape
     hd = d // num_heads
     ctx = live.shape[1]
     h = _rms_norm(x, blk["ln1"])
     qkv = jnp.einsum("sd,dte->ste", h, blk["wqkv"])
     q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, d]
-    with jax.named_scope("cache_write"):
-        kh = k.reshape(num_slots, num_heads, hd)
-        vh = v.reshape(num_slots, num_heads, hd)
-        k_cache = k_cache.at[li, blk_ids, offs, :, :hd].set(
-            kh.astype(k_cache.dtype))
-        v_cache = v_cache.at[li, blk_ids, offs, :, :hd].set(
-            vh.astype(v_cache.dtype))
-    qh = q.reshape(num_slots, num_heads, hd)
     if arm == "paged":
         # the kernel walks the block table over the rows as stored, the
         # cache arrays passed whole with the layer's index: no gathered
         # copy of the context, no float32 view of one, and what a token
-        # reads is what its slot's live pages hold
-        from ..ops.pallas_paged_attention import paged_attention
-        o = paged_attention(qh, k_cache, v_cache, block_tables, lengths,
-                            layer=li, scale=scale)
+        # reads is what its slot's live pages hold. It takes the cache
+        # arrays as input and output in one buffer and puts this
+        # token's rows into them itself, a copy a live slot: nothing
+        # but zeros is ever written beside a head's values, so the
+        # padded row is what a scatter of its first hd elements leaves
+        from ..ops.pallas_paged_attention import paged_attention_write
+        with jax.named_scope("cache_write"):
+            beside = ((0, 0), (0, 0), (0, k_cache.shape[-1] - hd))
+            kh = jnp.pad(k.reshape(num_slots, num_heads, hd).astype(
+                k_cache.dtype), beside)
+            vh = jnp.pad(v.reshape(num_slots, num_heads, hd).astype(
+                v_cache.dtype), beside)
+        o, k_cache, v_cache = paged_attention_write(
+            q.reshape(num_slots, num_heads, hd), kh, vh, k_cache, v_cache,
+            block_tables, lengths, layer=li, scale=scale)
     else:
+        with jax.named_scope("cache_write"):
+            kh = k.reshape(num_slots, num_heads, hd)
+            vh = v.reshape(num_slots, num_heads, hd)
+            k_cache = k_cache.at[li, blk_ids, offs, :, :hd].set(
+                kh.astype(k_cache.dtype))
+            v_cache = v_cache.at[li, blk_ids, offs, :, :hd].set(
+                vh.astype(v_cache.dtype))
+        qh = q.reshape(num_slots, num_heads, hd)
         # gather the slot's pages into one dense context view: the
         # block table IS the indirection, so this read is identical
         # for a 3-token and a 90-token sequence, at the width of the

@@ -229,9 +229,13 @@ _declare(EventSchema(
                              # two arrays' shapes; attention_arm
                              # ("paged" | "gather") and paged_calls
                              # (Mosaic calls of the paged kernel in the
-                             # compiled step): a value a table width
+                             # compiled step) and step_while_loops
+                             # (`while` instructions in it: the loops a
+                             # scatter of the token's rows compiles to):
+                             # a value a table width
                              ("cache_row_bytes", "cache_arrays",
-                              "attention_arm", "paged_calls")),
+                              "attention_arm", "paged_calls",
+                              "step_while_loops")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of

@@ -30,6 +30,19 @@ slot of one layer:
   values is one product the same way. The operands go in as stored
   (bfloat16 on the chip) and accumulate in float32.
 
+The decode step has one more thing to do with the cache: put this
+token's keys and values into it before it attends (position
+``length - 1`` reads itself through the cache). A scatter through XLA
+of ``[slots, heads, head_dim]`` into rows stored wider compiles on a
+TPU to a loop a layer an array, an iteration a slot, whatever the slots
+hold. :func:`paged_attention_write` is the same call with the rows
+given: the cache arrays are its inputs AND its outputs in one buffer
+(``input_output_aliases``), and before the first page is fetched the
+kernel copies each live slot's ``[heads, width]`` rows, one run of a
+page, from VMEM to where the table says (a DMA an array a slot, all in
+flight together under the mask's set-up, then waited for). A slot of
+length 0 writes nothing.
+
 Numeric semantics are pinned to the gather arm (and its parity tests):
 scores and softmax in float32, scale ``1/sqrt(head_dim)``, masked
 positions get the finite ``-1e30`` (whose exp underflows to exactly 0.0
@@ -74,22 +87,64 @@ PAGES_PER_STEP = 8
 
 
 def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
-                  layer_ref,
-                  q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, bias_ref, pos_ref, m_ref, l_ref, acc_ref,
-                  *,
+                  layer_ref, q_ref, *refs,
                   scale: float, num_heads: int, block_size: int,
-                  pages: int, table_width: int):
-    """Every work item of one layer, in one loop.
+                  pages: int, table_width: int, writes: bool):
+    """Every work item of one layer, in one loop; with ``writes``, each
+    live slot's new rows put into the cache first.
 
     ``tables_ref`` .. ``layer_ref`` are the scalar-prefetch operands
     (SMEM): the block tables, the lengths, each item's slot and chunk,
     the number of items, the layer. ``k_hbm``/``v_hbm`` are the cache
     arrays where they lie, ``[layers, num_blocks, block_size · heads,
-    width]``; ``k_buf``/``v_buf`` two chunks of rows each."""
+    width]``; ``k_buf``/``v_buf`` two chunks of rows each. With
+    ``writes`` the step's new rows ``k_new``/``v_new`` ``[slots, heads,
+    width]`` come in beside the query, and the cache arrays are the
+    call's outputs too, aliased to its inputs: written and read through
+    the output's name, which off the chip (interpreted) is the one that
+    holds what was written."""
+    if writes:
+        (k_new, v_new, _, _, o_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
+         bias_ref, pos_ref, m_ref, l_ref, acc_ref, write_sem) = refs
+    else:
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+         bias_ref, pos_ref, m_ref, l_ref, acc_ref) = refs
     page_rows = block_size * num_heads
     rows = pages * page_rows
     layer, num_items = layer_ref[0], count_ref[0]
+
+    def row_copies(slot):
+        """The two DMAs of a slot's new rows: ``heads`` rows in one run,
+        at offset ``(length - 1) % block_size`` of the page that holds
+        position ``length - 1``."""
+        at = lengths_ref[slot] - 1
+        block = tables_ref[slot, at // block_size]
+        run = pl.ds(pl.multiple_of((at % block_size) * num_heads, num_heads),
+                    num_heads)
+        return (pltpu.make_async_copy(k_new.at[slot],
+                                      k_hbm.at[layer, block, run],
+                                      write_sem.at[0]),
+                pltpu.make_async_copy(v_new.at[slot],
+                                      v_hbm.at[layer, block, run],
+                                      write_sem.at[1]))
+
+    def each_written_row(act):
+        """``act`` on the DMAs of every slot that has a row to write: a
+        slot of length 0 writes nothing (its table is never looked up),
+        nor does a position beyond the table."""
+        def one(slot, _):
+            n = lengths_ref[slot]
+
+            @pl.when((n > 0) & (n <= table_width * block_size))
+            def _():
+                for dma in row_copies(slot):
+                    act(dma)
+        jax.lax.fori_loop(0, lengths_ref.shape[0], one, None)
+
+    if writes:
+        # in flight under the set-up below, landed before the first
+        # page is fetched: position length - 1 is read through the cache
+        each_written_row(lambda dma: dma.start())
 
     def live_pages(slot):
         return jnp.minimum(pl.cdiv(lengths_ref[slot], block_size),
@@ -145,6 +200,8 @@ def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
     pos_ref[...] = jax.lax.broadcasted_iota(
         jnp.int32, pos_ref.shape, 1) // num_heads
     o_ref[...] = jnp.zeros_like(o_ref)  # an idle slot's rows
+    if writes:
+        each_written_row(lambda dma: dma.wait())
 
     @pl.when(num_items > 0)
     def _():
@@ -236,9 +293,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     interpret: bool | None = None) -> jax.Array:
     """Single-query attention over a paged KV cache, one layer.
 
-    ``q``: [slots, heads, head_dim] (the current token's query, AFTER
-    its K/V were scattered into the cache — position ``length-1``
-    attends to itself through the cache, exactly like the gather arm).
+    ``q``: [slots, heads, head_dim], the current token's query. Its own
+    keys and values are in the cache already (this form reads only;
+    :func:`paged_attention_write` puts them there in the same call):
+    position ``length-1`` attends to itself through the cache, exactly
+    like the gather arm.
     ``k_pages``/``v_pages``: the cache arrays whole, [layers,
     num_blocks, block_size, heads, width] with ``layer`` the one to
     read (:class:`servesvc.kv_cache.PagedKVCache`'s, passed as they
@@ -253,6 +312,77 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     if k_pages.ndim == 4:
         k_pages, v_pages = k_pages[None], v_pages[None]
+    return _paged_call(q, None, k_pages, v_pages, block_tables, lengths,
+                       layer=layer, scale=scale,
+                       pages_per_step=pages_per_step, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_step",
+                                             "interpret"))
+def paged_attention_write(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                          k_pages: jax.Array, v_pages: jax.Array,
+                          block_tables: jax.Array, lengths: jax.Array, *,
+                          layer: jax.Array | int = 0,
+                          scale: float | None = None,
+                          pages_per_step: int = PAGES_PER_STEP,
+                          interpret: bool | None = None,
+                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`paged_attention` of a step that has yet to store its
+    token: the same kernel writes the rows, then reads.
+
+    ``k_new``/``v_new``: [slots, heads, width], the token's keys and
+    values as the cache stores them (its dtype, its row's width: the
+    head's values, zeros beside them). Slot ``s``'s rows go to position
+    ``lengths[s] - 1`` (offset ``(lengths[s] - 1) % block_size`` of
+    block ``block_tables[s, (lengths[s] - 1) // block_size]``) of
+    ``layer`` in both arrays [layers, num_blocks, block_size, heads,
+    width], which the call takes and returns as one buffer
+    (``input_output_aliases``: donate them, or XLA copies each); a slot
+    of length 0 writes nothing, where a scatter would send its row to
+    the null block.
+
+    Returns (the attention [slots, heads, head_dim] float32, the two
+    cache arrays).
+    """
+    assert k_new.shape == v_new.shape == (*q.shape[:2], k_pages.shape[-1])
+    assert k_new.dtype == k_pages.dtype and v_new.dtype == v_pages.dtype
+    if not _interpreted(interpret) and k_pages.shape[-1] % _LANE:
+        # compiled, a row has to fill whole lanes (what a cache as wide
+        # as kv_cache.stored_head_dim answers has): these rows are
+        # scattered through XLA and read by the form that pads a layer
+        k_pages, v_pages = (
+            _scattered(pages, new, block_tables, lengths, layer)
+            for pages, new in ((k_pages, k_new), (v_pages, v_new)))
+        return (paged_attention(q, k_pages, v_pages, block_tables, lengths,
+                                layer=layer, scale=scale,
+                                pages_per_step=pages_per_step,
+                                interpret=interpret), k_pages, v_pages)
+    return _paged_call(q, (k_new, v_new), k_pages, v_pages, block_tables,
+                       lengths, layer=layer, scale=scale,
+                       pages_per_step=pages_per_step, interpret=interpret)
+
+
+def _interpreted(interpret: bool | None) -> bool:
+    """``interpret`` as asked, or the interpreter off the TPU."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _scattered(pages, new, block_tables, lengths, layer):
+    """``new`` at each live slot's position ``length - 1`` through XLA:
+    what the kernel's row copies leave."""
+    block_size = pages.shape[2]
+    at = lengths - 1
+    blocks = jnp.take_along_axis(
+        block_tables, (jnp.maximum(at, 0) // block_size)[:, None], axis=1)
+    blocks = jnp.where(lengths > 0, blocks[:, 0], pages.shape[1])
+    return pages.at[layer, blocks, at % block_size].set(new, mode="drop")
+
+
+def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
+                layer, scale, pages_per_step, interpret):
+    """One call of the kernel over the cache arrays whole: the
+    attention alone, or with ``new_rows`` (the keys' and the values')
+    the attention and the two arrays those rows were written to."""
     num_slots, num_heads, hd = q.shape
     layers, num_blocks, block_size, h2, row = k_pages.shape
     assert h2 == num_heads and row >= hd, (q.shape, k_pages.shape)
@@ -261,12 +391,14 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     table_width = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _interpreted(interpret)
+    lengths = lengths.astype(jnp.int32)
     if not interpret and row % _LANE:
         # compiled, a row has to fill whole lanes: this layer's pages
         # padded, a copy a call (a cache as wide as
-        # kv_cache.stored_head_dim answers never comes here)
+        # kv_cache.stored_head_dim answers never comes here, and the
+        # writing form has turned such rows away)
+        assert new_rows is None
         lanes = ((0, 0),) * 4 + ((0, -row % _LANE),)
         k_pages, v_pages = (
             jnp.pad(jax.lax.dynamic_index_in_dim(a, layer, keepdims=True),
@@ -276,7 +408,6 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     page_rows = block_size * num_heads
     rows = pages * page_rows
 
-    lengths = lengths.astype(jnp.int32)
     slot, chunk, num_items = _work_items(lengths, block_size, table_width,
                                          pages)
     # a query zero beyond the head adds nothing to a score; a block's
@@ -284,44 +415,61 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     qp = jnp.pad(q.astype(k_pages.dtype), ((0, 0), (0, 0), (0, row - hd)))
     kp = k_pages.reshape(layers, num_blocks, page_rows, row)
     vp = v_pages.reshape(layers, num_blocks, page_rows, row)
+    scalars = (block_tables.astype(jnp.int32), lengths, slot, chunk,
+               num_items,
+               jnp.asarray(layer, jnp.int32).reshape(1))
 
+    writes = new_rows is not None
     kernel = functools.partial(
         _paged_kernel, scale=scale, num_heads=num_heads,
-        block_size=block_size, pages=pages, table_width=table_width)
-    whole = lambda *_: (0, 0, 0)  # noqa: E731
+        block_size=block_size, pages=pages, table_width=table_width,
+        writes=writes)
+    a_slots_rows = pl.BlockSpec((num_slots, num_heads, row),
+                                lambda *_: (0, 0, 0))
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    out_shape = jax.ShapeDtypeStruct((num_slots, num_heads, row),
+                                     jnp.float32)
+    scratch_shapes = [
+        pltpu.VMEM((2, rows, row), k_pages.dtype),
+        pltpu.VMEM((2, rows, row), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((num_heads, rows), jnp.float32),   # a head's rows
+        pltpu.VMEM((1, rows), jnp.int32),             # a row's position
+        pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running max
+        pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running denom
+        pltpu.VMEM((num_heads, row), jnp.float32),    # accumulator
+    ]
+    if writes:
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((2,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=len(scalars),
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec((num_slots, num_heads, row), whole),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((num_slots, num_heads, row), whole),
-        scratch_shapes=[
-            pltpu.VMEM((2, rows, row), k_pages.dtype),
-            pltpu.VMEM((2, rows, row), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((num_heads, rows), jnp.float32),   # a head's rows
-            pltpu.VMEM((1, rows), jnp.int32),             # a row's position
-            pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running max
-            pltpu.VMEM((num_heads, _LANE), jnp.float32),  # running denom
-            pltpu.VMEM((num_heads, row), jnp.float32),    # accumulator
-        ],
+        in_specs=([a_slots_rows] * (3 if writes else 1)
+                  + [where_it_lies] * 2),
+        out_specs=((a_slots_rows, where_it_lies, where_it_lies)
+                   if writes else a_slots_rows),
+        scratch_shapes=scratch_shapes,
     )
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_slots, num_heads, row),
-                                       jnp.float32),
+        out_shape=((out_shape, jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                    jax.ShapeDtypeStruct(vp.shape, vp.dtype))
+                   if writes else out_shape),
+        # the cache arrays (operands 9 and 10, the scalars counted) are
+        # outputs 1 and 2: one buffer each
+        input_output_aliases=({len(scalars) + 3: 1, len(scalars) + 4: 2}
+                              if writes else {}),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables.astype(jnp.int32), lengths, slot, chunk, num_items,
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      qp, kp, vp)
-    return out[..., :hd]
+    )
+    if not writes:
+        return call(*scalars, qp, kp, vp)[..., :hd]
+    out, kp, vp = call(*scalars, qp, *new_rows, kp, vp)
+    return (out[..., :hd], kp.reshape(k_pages.shape),
+            vp.reshape(v_pages.shape))
 
 
 def paged_attention_dense(q: jax.Array, k_pages: jax.Array,

@@ -110,6 +110,11 @@ LOOP_PHASES = ("idle", "admit", "prefill", "inputs", "dispatch", "fetch",
                "emit")
 
 
+def while_loops(compiled_text: str) -> int:
+    """The ``while`` instructions in a compiled program's text."""
+    return len(re.findall(r" while\(", compiled_text))
+
+
 class _DecodeSeq(_Pending):
     """One in-flight generation (``inputs`` holds the prompt)."""
 
@@ -563,10 +568,11 @@ class DecodeReplica(ServingReplica):
         of each of its sequences' tables (``_table_width`` chose a
         width that holds them all). Rows of slots NOT on this version
         are zero (the null block) at every width — load-bearing, not
-        padding: the step scatters the new token's K/V through row
-        ``positions[i] // block_size`` of EVERY slot, and zero routes
-        the not-mine writes into the reserved null block instead of a
-        live sequence's block 0. Cached per (version, table epoch,
+        padding: the gather arm's step scatters the new token's K/V
+        through row ``positions[i] // block_size`` of EVERY slot, and
+        zero routes the not-mine writes into the reserved null block
+        instead of a live sequence's block 0 (the paged arm's kernel
+        writes nothing for a slot of length 0). Cached per (version, table epoch,
         width): between admit/finish/restart events the array at one
         width is bit-identical every iteration, so steady-state
         decoding reuses one upload instead of paying a host rebuild +
@@ -791,8 +797,11 @@ class DecodeReplica(ServingReplica):
         does with it, for ``decode_start``: ``whole_cache_copies`` counts
         the ``copy`` instructions of the cache's shape in a compiled
         step (0 where it takes the arrays as they lie, 4 where it
-        transposes both on the way in and back on the way out), and
-        ``attention_arm`` / ``paged_calls`` how it reads them."""
+        transposes both on the way in and back on the way out),
+        ``attention_arm`` / ``paged_calls`` how it reads them, and
+        ``step_while_loops`` the ``while`` instructions left in it (a
+        scatter of the new token's rows compiles to two a layer; the
+        paged kernel writes them itself: none)."""
         at = self.cache.k.format.layout
         dims = re.escape(f"[{','.join(map(str, self.cache.k.shape))}]")
         steps = [self._steps[w] for w in self._table_widths]
@@ -822,7 +831,8 @@ class DecodeReplica(ServingReplica):
                 len(re.findall(r"%paged_decode[.\d]* = [^\n]*"
                                r"custom_call_target=\"tpu_custom_call\"",
                                text))
-                for text in texts]}
+                for text in texts],
+            "step_while_loops": [while_loops(text) for text in texts]}
 
     def start(self) -> None:
         super().start()

@@ -30,8 +30,10 @@ width]`` (:func:`cache_shapes`; its ``decode_cache_shape`` carries the
 pair of widths). The allocator, the tables and the scatter are the same.
 
 Block 0 is the **reserved null block**: idle decode slots point their
-whole table (and their writes) at it, so the fixed-shape step never
-needs a branch — garbage lands in a block no sequence owns.
+whole table (and, where the step scatters the new rows, their writes)
+at it, so the fixed-shape step never needs a branch — garbage lands in
+a block no sequence owns. (The paged arm's kernel writes a live slot's
+rows itself and none for a slot of length 0.)
 
 Invariants the allocator maintains (property-tested in
 tests/test_kv_cache.py): a block is never assigned to two live

@@ -11,9 +11,11 @@ whole cache array; the step a TPU runs reads the cache through one
 paged kernel a layer at each of the replica's four table widths, with
 no gathered copy of the context and no float32 view of one, and the
 gather arm, still there by name, keeps its sizes; which arm a step
-takes is decided by what it is handed. And on the CPU test mesh: a cache with wider rows
-decodes to the bit what one with the head's own width decodes, through
-the model's step, the prompt's scatter and a replica."""
+takes is decided by what it is handed; the step a TPU runs writes the
+new token's rows inside that kernel, so it holds no loop. And on the
+CPU test mesh: a cache with wider rows decodes to the bit what one with
+the head's own width decodes, through the model's step, the prompt's
+scatter and a replica."""
 
 import functools
 import json
@@ -195,6 +197,30 @@ def test_the_step_a_tpu_runs_reads_the_cache_where_it_lies(
             assert _count(step, dims, op) == 0, (dims, op)
     assert step.memory_analysis().temp_size_in_bytes / MB <= 64
     assert _total(step) / GB <= 7.6
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_the_step_a_tpu_runs_writes_the_new_rows_inside_the_kernel(
+        for_the_chip, width):
+    """The paged arm hands the token's rows to the kernel, which takes
+    each cache array as input and output in one buffer: the compiled
+    step holds no ``while`` (a scatter of the rows is two loops a
+    layer: what the gather arm, by name, still compiles to), no
+    ``dynamic-update-slice`` and no ``scatter`` at all, every one of its
+    24 calls aliases outputs 1 and 2 to the cache operands (the test
+    above holds that no copy is made of them and what the step needs
+    beside its arguments)."""
+    text = for_the_chip["stored_wide"][width].as_text()
+    assert decode_mod.while_loops(text) == 0
+    for op in ("dynamic-update-slice", "scatter"):
+        assert not re.findall(rf" {op}\(", text), op
+    calls = re.findall(r"%paged_decode[.\d]* = [^\n]*", text)
+    assert len(calls) == OPT_1_3B["num_layers"]
+    assert all("output_to_operand_aliasing={{1}: (9, {}), {2}: (10, {})}"
+               in call for call in calls)
+    if width in GATHER_WIDTHS:
+        gather = for_the_chip["gather"][width].as_text()
+        assert decode_mod.while_loops(gather) == 2 * OPT_1_3B["num_layers"]
 
 
 def test_a_step_takes_the_arm_its_input_decides():
@@ -413,3 +439,7 @@ def test_a_replica_decodes_what_the_full_context_forward_decodes(
     # on a CPU, whatever the rows' width: the gather, and no Mosaic call
     assert started["attention_arm"] == ["gather"] * 4
     assert started["paged_calls"] == [0] * 4
+    # whatever the gather arm's scatter compiles to here: a count a width
+    assert len(started["step_while_loops"]) == 4
+    assert all(isinstance(n, int) and n >= 0
+               for n in started["step_while_loops"])

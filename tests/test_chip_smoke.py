@@ -137,13 +137,25 @@ def test_phases_pass_tiny_on_the_cpu_mesh(tmp_path, scratch_cache):
     assert serve["identical"] and serve["token_agreement"] == 1.0  # f32
 
     kern = chip_smoke.phase_kernels(model=TINY_MODEL, batch=1, block_size=8,
-                                    context=20, tol=1e-4)
+                                    context=20, tol=1e-4,
+                                    prompt_lens=(3, 11, 6), steps=6)
     assert kern["decode_step_argmax_equal"]
     assert kern["paged_vs_dense"] <= 1e-4
     # a CPU stores every head's rows whole: the arm proves its plumbing
     wide = kern["wide_cache_rows"]
     assert wide["stored_head_dim"] == wide["head_dim"] == 64
     assert wide["logits_bit_equal"] and wide["cache_bytes_equal"]
+    # the paged arm's step writes the token's rows inside the kernel: what
+    # the scatter leaves, to the bit, and across a block's edge (3 + 6 > 8)
+    written = kern["rows_written_in_the_kernel"]
+    assert set(written["paged_equals_scattered_to_the_bit"].values()) == {
+        True}
+    assert written["null_block_untouched"]
+    assert written["dense_first_layer_rows_equal_to_the_bit"]
+    assert written["dense_token_agreement"] == 1.0  # f32
+    assert written["slots"] == 5 and written["steps"] == 6
+    assert (written["head_dim"], written["stored_head_dim"]) == (64, 128)
+    assert written["rows_beside_the_head_are_zero"]
     # the absorbed decode step against the expanded forward, float32
     latent = kern["latent_decode"]
     assert latent["steps"] == 8 and latent["absorbed_vs_expanded"] <= 1e-4

@@ -15,9 +15,12 @@ wishful thinking:
   unspecified garbage — by contract the caller ignores both,
 * a dead table entry is never looked up: the null block may hold
   anything, a value that is no number included,
-* the cache scatter is shared by both paths, so after a decode step
-  the caches agree everywhere OUTSIDE the reserved null block (an
-  idle slot's garbage row legitimately lands there, divergently).
+* the gather arm scatters the new token's rows through XLA, the paged
+  arm hands them to the kernel, which copies them into their page
+  before it reads (``paged_attention_write``): the same rows at the
+  same place, so after a decode step the caches agree everywhere
+  OUTSIDE the reserved null block (the scatter sends an idle slot's
+  garbage row there; the kernel writes nothing for a slot of length 0).
 
 CPU/GPU run the kernel in interpret mode — same index arithmetic, DMAs
 and masking as compiled TPU, so these pins hold on every backend; the
@@ -125,8 +128,10 @@ def test_paged_parity_survives_block_free_and_reuse():
 @pytest.mark.tier1
 def test_decode_step_paged_matches_dense_end_to_end():
     """Full decode_step through a real transformer: per-slot logits
-    agree between kernels for live slots, and the (shared) cache
-    scatter leaves both caches equal outside the reserved null block."""
+    agree between kernels for live slots, and the scatter and the
+    kernel's row copies leave both caches equal outside the reserved
+    null block (layer 0's rows, which no attention's output has
+    touched, to the bit; the paged arm leaves the null block alone)."""
     import jax
     import jax.numpy as jnp
 
@@ -171,10 +176,15 @@ def test_decode_step_paged_matches_dense_end_to_end():
     lp, kp, vp = out["paged"]
     ld, kd, vd = out["dense"]
     np.testing.assert_allclose(lp[:3], ld[:3], atol=1e-4, rtol=1e-4)
-    # cache parity outside the null block (idle-slot garbage rows are
-    # ROUTED to block 0 by both paths, but with path-specific bytes)
+    # cache parity outside the null block (the gather arm routes the
+    # idle slot's garbage row to block 0; the paged arm writes none)
     np.testing.assert_allclose(kp[:, 1:], kd[:, 1:], atol=1e-5)
     np.testing.assert_allclose(vp[:, 1:], vd[:, 1:], atol=1e-5)
+    np.testing.assert_array_equal(kp[0, 1:], kd[0, 1:])
+    np.testing.assert_array_equal(vp[0, 1:], vd[0, 1:])
+    assert (kp[0, 1:] != np.asarray(cache_p.k)[0, 1:]).any()
+    np.testing.assert_array_equal(kp[:, 0], np.asarray(cache_p.k)[:, 0])
+    np.testing.assert_array_equal(vp[:, 0], np.asarray(cache_p.v)[:, 0])
 
 
 @pytest.mark.tier1
@@ -293,6 +303,70 @@ def test_kernel_takes_the_cache_whole_with_the_layers_index():
     assert np.abs(np.asarray(read(2)) - np.asarray(want)).max() > 0.5
 
 
+#: the writing form's cases: (table width, pages an item, each slot's
+#: length with the new token counted, the head's width, the row's, the
+#: layer, whether it is traced); blocks of 4
+ROWS_WRITTEN = {
+    "a_token_at_a_blocks_first_offset": (3, 8, [5, 9, 1], 16, 16, 0, False),
+    "a_token_at_a_blocks_last_offset": (3, 2, [4, 12, 8], 16, 16, 1, False),
+    "a_slot_of_length_0_beside_live_ones": (6, 4, [0, 7, 0, 24], 16, 16, 2,
+                                            False),
+    "every_slot_of_length_0": (3, 8, [0, 0], 16, 16, 0, False),
+    "rows_wider_than_the_head": (6, 4, [6, 0, 21, 16], 64, 128, 1, False),
+    "a_traced_layer": (9, 4, [36, 1, 17, 0, 30], 16, 32, 2, True),
+    "a_table_narrower_than_pages_per_step": (3, 8, [10, 3, 0, 12], 64, 128,
+                                             0, False),
+}
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ROWS_WRITTEN)
+def test_the_kernel_writes_the_new_rows_the_scatter_writes(case, dtype):
+    """``paged_attention_write`` against scatter-then-read, through the
+    interpreter: every live slot's ``[heads, row]`` rows at position
+    ``length - 1`` of its table in the layer asked for, both arrays
+    equal to the bit in every layer, block and lane, and the attention
+    equal to what the read-only form reads from the scattered cache. A
+    slot of length 0 (its table all ``NULL_BLOCK``) changes no row of
+    any block, the null block's included."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_attention, paged_attention_write)
+
+    width, pages, lengths, hd, row, layer, traced = ROWS_WRITTEN[case]
+    rng = np.random.default_rng(len(case))
+    q, k, v, _, _, tables, lens = _stored(rng, lengths, width, dtype,
+                                          hd=hd, row=row)
+    k, v = (jnp.stack([a + 1.0, a, a - 1.0]).at[:, 0].set(3.0)
+            for a in (k, v))
+    k_new, v_new = (jnp.asarray(rng.standard_normal((len(lengths), 2, row)),
+                                dtype) for _ in range(2))
+    write = jax.jit(lambda li: paged_attention_write(
+        q, k_new, v_new, k, v, tables, lens, layer=li,
+        pages_per_step=pages, interpret=True))
+    got, got_k, got_v = write(jnp.asarray(layer) if traced else layer)
+
+    want_k, want_v = np.array(k), np.array(v)
+    for slot, n in enumerate(lengths):
+        if n:
+            at = (layer, int(tables[slot, (n - 1) // 4]), (n - 1) % 4)
+            assert at[1] != 0
+            want_k[at], want_v[at] = k_new[slot], v_new[slot]
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    assert any(lengths) == (want_k != np.asarray(k)).any()
+    want = paged_attention(q, jnp.asarray(want_k), jnp.asarray(want_v),
+                           tables, lens, layer=layer, pages_per_step=pages,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert got.shape == (len(lengths), 2, hd)
+    np.testing.assert_array_equal(np.asarray(got)[np.asarray(lengths) == 0],
+                                  0.0)
+
+
 @pytest.mark.tier1
 @pytest.mark.parametrize("stored", [16, 128])
 def test_both_arms_decode_the_same_greedy_tokens(stored):
@@ -342,3 +416,73 @@ def test_both_arms_decode_the_same_greedy_tokens(stored):
     assert tokens_paged == tokens_dense
     np.testing.assert_allclose(logits_paged, logits_dense, atol=1e-4,
                                rtol=1e-4)
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("stored", [16, 128])
+def test_a_paged_step_leaves_the_cache_the_scatter_leaves(stored):
+    """``decode_step`` on the paged arm, whose kernel writes the token's
+    rows, against the same step with the writing form replaced (here, by
+    the test) by a scatter through XLA and then the read-only form: five
+    greedy steps for two slots of three across a block's edge, the
+    logits and both cache arrays equal to the bit, every layer, block
+    and lane; beside a 16-wide head a 128-wide row keeps its zeros, and
+    the idle slot leaves the null block as the prompts left it."""
+    import functools
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.core.config import ModelConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
+
+    model = get_model(ModelConfig(**LM_MODEL))
+    params = model.init(jax.random.PRNGKey(3))
+    prompts = [[1, 2, 3], list(range(1, 11))]
+
+    def scatter_then_read(q, k_new, v_new, k_pages, v_pages, tables, lengths,
+                          *, layer, scale):
+        at = lengths[:2] - 1                  # the two live slots'
+        blocks = jnp.take_along_axis(tables[:2], (at // 4)[:, None],
+                                     axis=1)[:, 0]
+        k_pages = k_pages.at[layer, blocks, at % 4].set(k_new[:2])
+        v_pages = v_pages.at[layer, blocks, at % 4].set(v_new[:2])
+        return (ppa.paged_attention(q, k_pages, v_pages, tables, lengths,
+                                    layer=layer, scale=scale),
+                k_pages, v_pages)
+
+    def decode(write):
+        cache = PagedKVCache(2, 40, 4, 4, stored, max_blocks_per_seq=4)
+        tables = np.zeros((3, 4), np.int32)
+        toks, rows = [], []
+        for slot, prompt in enumerate(prompts):
+            logits, ks, vs = model.decode_prefill(
+                params, jnp.asarray([prompt], jnp.int32))
+            tables[slot] = cache.alloc_sequence(len(prompt) + 5)
+            cache.write_prompt(tables[slot], ks[:, 0], vs[:, 0], len(prompt))
+            toks.append(int(jnp.argmax(logits[0, -1])))
+        null = np.asarray(cache.k[:, 0])
+        with mock.patch.object(ppa, "paged_attention_write", write):
+            step = jax.jit(functools.partial(
+                model.decode_step, block_size=4, attention_kernel="paged"))
+            for i in range(5):
+                pos = [len(p) + i for p in prompts]
+                out, cache.k, cache.v = step(
+                    params, jnp.asarray([*toks, 0], jnp.int32),
+                    jnp.asarray([*pos, 0], jnp.int32), cache.k, cache.v,
+                    jnp.asarray(tables),
+                    jnp.asarray([pos[0] + 1, pos[1] + 1, 0], jnp.int32))
+                rows.append(np.asarray(out[:2]))
+                toks = [int(t) for t in rows[-1].argmax(-1)]
+        np.testing.assert_array_equal(np.asarray(cache.k[:, 0]), null)
+        return np.stack(rows), np.asarray(cache.k), np.asarray(cache.v)
+
+    got, got_k, got_v = decode(ppa.paged_attention_write)
+    want, want_k, want_v = decode(scatter_then_read)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got_k, want_k)
+    np.testing.assert_array_equal(got_v, want_v)
+    assert got_k[..., :16].any() and not got_k[..., 16:].any()
