@@ -95,9 +95,8 @@ READERS = ("latent_attention_ms_per_step", "moe_ms_per_step",
 def describe(tokens_per_step: int) -> None:
     """Print, for the newest traced run under ``runtime.WORK_ROOT``, the
     train step's scope table with the block's scopes kept and the six
-    readers' values. ``BENCHMARK.json`` does not list the six yet (an
-    accepted test pins its last per-layer entry: PERF.md §7), so this is
-    how PERF.md §5's table of the cell is made:
+    readers' values (``BENCHMARK.json`` lists the six since PR 42; the
+    scope table is how PERF.md §5's table of the cell is made):
 
         python3 benchmark/lib/block_scopes.py <tokens a step>
 

@@ -69,13 +69,60 @@ the choices is so not vouched for by the choices of the one that was
 asked. (Exports that always return their routing would need no second
 program, but the replica and the trainer unpack what the exports
 return today, and a benchmark PR changes no file of the program.) The
-serving check calls the step asked for its choices after the unasked
-one, over the cache that one has just written: a step given the same
-token at the same position writes the same keys and values again. It
-hands the entries of ``decode_cache_shape`` to the program's
-``PagedKVCache`` unread and scatters the prefill's ``ks[:, 0]`` and
-``vs[:, 0]`` whatever their widths, so a cache whose two arrays differ
-in width (a latent beside a rotary key) needs no edit here.
+serving check asks each call for its choices after the unasked one, over
+the state that one has just written.
+
+**A decode session** is the one line of the serving check
+(``lib/serving.py::check_decode_against_reference``) that depends on how
+a sequence's state is held. The harness keeps the seeded sequence, the
+teacher forcing, logits and never tokens, the reference's call, the
+limits and the verdict; the session is
+
+* ``prefill(prompt[int32 n], return_routing=False) -> logits[vocab]`` of
+  the prompt's last position, which leaves the prompt's state in the
+  session's one sequence (slot 0 of whatever the step is batched over);
+* ``step(token: int, position: int, return_routing=False) ->
+  logits[vocab]``, which advances that sequence by the token at that
+  position;
+* asked for its routing, either returns ``(logits, routing)`` beside,
+  ``routing`` int32 ``[routed_layers, n, k]`` from the prefill and
+  ``[routed_layers, k]`` from a step: the flag adds an output and
+  changes nothing else, the state included. The check repeats every
+  call with the flag right after the unasked one, so a step asked again
+  at the position it has just stepped leaves the state as the unasked
+  step left it (rows a token get that for nothing: the same token at
+  the same position writes the same rows; a state that is a sequence's
+  and not a token's keeps what it stepped from);
+* optionally ``said``, a dictionary the ``reference_check`` event
+  carries under ``session`` (the default one's: ``attention_arm``,
+  ``cache_arrays``, ``table_blocks``, ``step_compiled_bytes``).
+
+The default session, ``lib/serving.py::PagedSession``, is what every
+record without one of its own is driven through: the record's
+``decode_prefill`` and ``decode_step`` over a scratch ``PagedKVCache``
+built as ``DecodeReplica.__init__`` builds the replica's
+(``kv_cache.cache_shapes`` of ``decode_cache_shape``, rows as wide as
+``kv_cache.stored_head_dim`` answers, so a cache whose two arrays differ
+in width needs no edit here), the step jitted and handed the narrowest
+table width as the replica's loop does. A model whose state is not two
+paged arrays (a row a sequence, a window, any rank and count of arrays)
+brings ``decode_session(params, dcfg, cache_dtype) -> session`` on its
+model record, added to the program by the PR that adds the model: **the
+session is the code the replica itself admits and steps a sequence
+through** (not a second forward written for the check), it takes the
+replica's ``decode`` section and cache dtype, and it holds whatever
+arrays the model needs. Where the record carries the export the check
+drives it; no record does today. ``lib/decode_controls.py``'s faults in
+the weights reach either kind of session; its fault in the step wraps
+``decode_step`` and is the default session's alone.
+
+**Adding a cell, a configuration or a metric** is appending: the new
+entry goes at the END of its list in ``BENCHMARK.json`` (the driver
+compares the accepted entries by position), and a metric that an
+existing cell should report lists that cell in its own ``workloads``. No
+test under ``tests/benchmark/`` depends on how many entries a list has
+or on which is last (``test_bench_contract.py`` rehearses a seventh
+cell and a new last metric on a copy).
 """
 
 from __future__ import annotations

@@ -1,13 +1,19 @@
 #!/usr/bin/env python3
 """What a serving cell's ``correct`` can see, asked at the cell's own
 size: ``lib/serving.py::check_decode_against_reference`` (the comparison
-a run makes before its window) on the sound program and on the same
-program with one fault each, the reference's side left as it is. A
+a run makes before its window, through the decode session a run drives:
+``lib/cell.py``) on the sound program and on the same program with one
+fault each, the reference's side left as it is. A
 control that comes out ``ok`` is a fault the cell's check is blind to at
 this configuration's weights; ``PERF.md`` keeps the readings. A fault in
-the weights is data: the programs compiled are the sound ones, handed a
-tree with one leaf of zeros, and the reference reads the sound tree (the
-two trees share every other buffer). A fault written INTO a program (a
+the weights is data: the session is the sound one, handed a tree with
+one leaf of zeros, and the reference reads the sound tree
+(``reference_params``; the two trees share every other buffer), so it
+faults a record's own ``decode_session`` as it faults the default one. A
+fault in the step (``masked_newest_token``) wraps the record's
+``decode_step`` export, which only the default session calls: a record
+that brings its own session brings that control with it, and is refused
+here. A fault written INTO a program (a
 leaf times zero under the jit) lets the compiler fold the export with and
 without its routing output into two float graphs, and the flag's
 difference then reads a router's flipped near tie (0.02-0.39 on the chip,
@@ -64,32 +70,6 @@ def _patched(obj, name: str, value):
         setattr(obj, name, was)
 
 
-def _reading(model, side: str):
-    """``model`` whose decode exports read the tree under ``side``."""
-    def prefill(params, tokens, **kw):
-        return model.decode_prefill(params[side], tokens, **kw)
-
-    def step(params, *args, **kw):
-        return model.decode_step(params[side], *args, **kw)
-    return dataclasses.replace(model, decode_prefill=prefill,
-                               decode_step=step)
-
-
-class _ReferenceOn:
-    """An architecture file whose reference reads the tree under
-    ``reference``; everything else is the file's."""
-
-    def __init__(self, arch):
-        self._arch = arch
-
-    def __getattr__(self, name):
-        attr = getattr(self._arch, name)
-        if name in ("logits", "routing_slack"):
-            return lambda params, *a, **kw: attr(params["reference"], *a,
-                                                 **kw)
-        return attr
-
-
 def _with_leaf(params, at: int, path: tuple, leaf):
     """``params`` with ``blocks[at][path...]`` replaced by ``leaf``; every
     other buffer shared."""
@@ -123,6 +103,12 @@ def _drop_last_held_experts(params):
 
 
 def _mask_newest(model):
+    if getattr(model, "decode_session", None) is not None:
+        raise cell_lib.BenchmarkError(
+            "masked_newest_token wraps the record's decode_step, which a "
+            "decode_session of the record's own does not call: the PR "
+            "that brings the session brings this control")
+
     def step(params, tokens, positions, k, v, tables, lengths, **kw):
         return model.decode_step(params, tokens, positions, k, v, tables,
                                  (lengths - 1).clip(0), **kw)
@@ -176,15 +162,13 @@ def check_control(name: str, model_cfg, params, dcfg, cell, seed: int,
     patch, edit, fault = _controls()[name]
     with patch():
         model = edit(get_model(model_cfg))
-        if fault is not None:
-            model = _reading(model, "program")
-            cell = dataclasses.replace(cell, arch=_ReferenceOn(cell.arch))
-            params = {"program": fault(params), "reference": params}
+        said: dict = {}
         check = serving.check_decode_against_reference(
-            model, params, dcfg, jnp.dtype(model_cfg.compute_dtype),
-            model_cfg.vocab_size, cell, seed)
+            model, params if fault is None else fault(params), dcfg,
+            jnp.dtype(model_cfg.compute_dtype), model_cfg.vocab_size, cell,
+            seed, reference_params=params, said=said)
     return {"control": name, "seed": seed, **check,
-            "failed_by": failed_by(check)}
+            "failed_by": failed_by(check), "session": said}
 
 
 def run(workload: str, seeds: list[int], controls=CONTROLS) -> list[dict]:

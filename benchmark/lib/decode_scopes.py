@@ -16,9 +16,10 @@ a list no PR but a ``benchmark`` one edits: :func:`table` reads the trace
 with the two names added for one call, as ``lib/block_scopes.py`` does
 for the train step (one detour; PERF.md §7 says what takes both out).
 
-``BENCHMARK.json`` does not list the four (an accepted test pins its
-last per-layer entry: PERF.md §7), so this prints them, with the step's
-scope table, for the newest traced run under ``runtime.WORK_ROOT``:
+``BENCHMARK.json`` lists the four since PR 42 (a traced run's result
+line carries them); this prints them with the step's scope table, which
+no metric carries, for the newest traced run under
+``runtime.WORK_ROOT``:
 
     python3 benchmark/lib/decode_scopes.py
 

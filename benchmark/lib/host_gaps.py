@@ -3,9 +3,9 @@ which the chip runs nothing, split by what the host was doing, with the
 part that lies beneath the two calls and the trace's own clock offset.
 The eleven readers that PR 40 brought are built on this file
 (:data:`READERS`; files under ``benchmark/layer_metrics/`` that
-``BENCHMARK.json`` does not list yet, as PRs 32 and 34 left theirs:
-``PERF.md`` section 7); ``program_trace.py`` and ``trace_reduce.py`` are
-used by import.
+``BENCHMARK.json`` lists since PR 42, so a traced run's result line
+carries them); ``program_trace.py`` and ``trace_reduce.py`` are used by
+import.
 
     python3 benchmark/lib/host_gaps.py [<trace dir, .xplane.pb or .json.gz>]
 

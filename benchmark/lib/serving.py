@@ -13,7 +13,6 @@ machine's."""
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import shutil
 import subprocess
@@ -55,27 +54,195 @@ def experiment(cell, rt) -> dict:
     }
 
 
+#: what a routed model's exports, and a session, return asked for their
+#: routing (the words of a refusal)
+_PREFILL_SAID = ("decode_prefill(params, tokens, return_routing=True) -> "
+                 "(logits, ks, vs, routing)")
+_STEP_SAID = ("decode_step(..., return_routing=True) -> (logits, k, v, "
+              "routing[routed_layers, slots, k])")
+_SESSION_SAID = ("a decode session whose prefill(prompt, "
+                 "return_routing=True) and step(token, position, "
+                 "return_routing=True) return (logits row, routing)")
+
+
+class PagedSession:
+    """The default decode session (``lib/cell.py`` has the contract): one
+    sequence in slot 0 of a scratch paged cache, through the model
+    record's ``decode_prefill`` and ``decode_step``. What every record
+    without a ``decode_session`` of its own is driven through.
+
+    The cache is built as ``DecodeReplica.__init__`` builds its own:
+    ``kv_cache.cache_shapes`` of the record's ``decode_cache_shape``,
+    each array's rows as wide as ``kv_cache.stored_head_dim`` answers
+    for the device the weights are on, then ``PagedKVCache``, placed
+    where the weights are. The step is a named function jitted with the
+    cache arrays donated and compiled ahead of time a table width, the
+    narrowest of the replica's widths (the quarters of
+    ``max_blocks_per_seq``) that holds the position it writes, as
+    ``_warm_up`` and ``_table_width`` do. So on a TPU a head's rows are
+    whole lanes and ``auto`` takes the arm a served token takes;
+    ``said`` names it."""
+
+    def __init__(self, model, params, dcfg, cache_dtype):
+        import jax
+        import jax.numpy as jnp
+        from distributedmnist_tpu.models.transformer import \
+            decode_attention_arm
+        from distributedmnist_tpu.servesvc.kv_cache import (
+            PagedKVCache, cache_shapes, stored_head_dim)
+
+        self.model, self.params, self.dcfg = model, params, dcfg
+        dtype = jnp.dtype(cache_dtype)
+        at = jax.tree.leaves(params)[0].sharding
+        layers, heads, head_dim = model.decode_cache_shape
+        shapes = cache_shapes(layers, dcfg.num_blocks, dcfg.block_size,
+                              heads, head_dim)
+        if isinstance(head_dim, tuple):
+            head_dim = tuple(stored_head_dim(shape, dtype, at)
+                             for shape in shapes)
+        else:
+            head_dim = stored_head_dim(shapes[0], dtype, at)
+        self.cache = PagedKVCache(layers, dcfg.num_blocks, dcfg.block_size,
+                                  heads, head_dim, dcfg.max_blocks_per_seq(),
+                                  dtype=dtype)
+        self.cache.k, self.cache.v = jax.device_put(
+            (self.cache.k, self.cache.v), at)
+        full = self.cache.max_blocks_per_seq
+        self._widths = sorted({-(-full * k // 4) for k in (1, 2, 3, 4)})
+        how = {"block_size": dcfg.block_size,
+               "attention_kernel": dcfg.attention_kernel}
+
+        # named functions, not partials: a trace calls a partial
+        # `jit__unknown` (servesvc/decode.py)
+        def decode_step(params, tokens, positions, k_cache, v_cache,
+                        block_tables, lengths):
+            return model.decode_step(params, tokens, positions, k_cache,
+                                     v_cache, block_tables, lengths, **how)
+
+        def decode_step_asked(params, tokens, positions, k_cache, v_cache,
+                              block_tables, lengths):
+            return compare.unpack(model.decode_step(
+                params, tokens, positions, k_cache, v_cache, block_tables,
+                lengths, return_routing=True, **how), 4, _STEP_SAID)
+
+        self._prefill = jax.jit(model.decode_prefill)
+        self._prefill_asked = jax.jit(lambda p, t: compare.unpack(
+            model.decode_prefill(p, t, return_routing=True), 4,
+            _PREFILL_SAID))
+        self._step_jit = jax.jit(decode_step, donate_argnums=(3, 4))
+        self._step_asked = jax.jit(decode_step_asked, donate_argnums=(3, 4))
+        self._steps: dict = {}           # {table width: the executable}
+        self._table = None
+        self.said = {
+            "session": "paged",
+            "attention_arm": decode_attention_arm(dcfg.attention_kernel,
+                                                  self.cache.k.shape),
+            "cache_arrays": [list(self.cache.k.shape),
+                             list(self.cache.v.shape)]}
+
+    def prefill(self, prompt, return_routing: bool = False):
+        import jax.numpy as jnp
+        n = int(prompt.shape[0])
+        tokens = jnp.asarray(prompt, jnp.int32)[None]
+        if return_routing:
+            compare.require_export(self.model.decode_prefill,
+                                   "return_routing", _PREFILL_SAID)
+            compare.require_export(self.model.decode_step,
+                                   "return_routing", _STEP_SAID)
+            logits, ks, vs, routing = self._prefill_asked(self.params,
+                                                          tokens)
+        else:
+            logits, ks, vs = self._prefill(self.params, tokens)
+        if self._table is None:
+            # every block the sequence can need, as an admission does
+            self._table = self.cache.alloc_sequence(
+                n + self.dcfg.max_new_tokens)
+        self.cache.write_prompt(self._table, ks[:, 0], vs[:, 0], n)
+        row = logits[0, n - 1]
+        return (row, routing[:, 0]) if return_routing else row
+
+    def _inputs(self, token: int, position: int) -> tuple:
+        import jax.numpy as jnp
+        slots = self.dcfg.decode_slots
+        width = next(w for w in self._widths
+                     if w * self.cache.block_size >= position + 1)
+        vec = lambda v: jnp.zeros(  # noqa: E731
+            (slots,), jnp.int32).at[0].set(v)
+        tables = np.zeros((slots, width), np.int32)
+        tables[0] = self._table[:width]
+        return width, (vec(token), vec(position), self.cache.k, self.cache.v,
+                       jnp.asarray(tables), vec(position + 1))
+
+    def step(self, token: int, position: int, return_routing: bool = False):
+        width, inputs = self._inputs(token, position)
+        if return_routing:
+            # the same token at the same position writes the same rows
+            # again, so the state is as the unasked step left it
+            out, self.cache.k, self.cache.v, picked = self._step_asked(
+                self.params, *inputs)
+            return out[0], picked[:, 0]
+        if width not in self._steps:
+            self._steps[width] = self._step_jit.lower(
+                self.params, *inputs).compile()
+            self.said["table_blocks"] = width
+            self.said["step_compiled_bytes"] = _compiled_bytes(
+                self._steps[width])
+        out, self.cache.k, self.cache.v = self._steps[width](
+            self.params, *inputs)
+        return out[0]
+
+
+def _compiled_bytes(compiled) -> int | None:
+    """Arguments, outputs and temporaries of a compiled program, less
+    what it aliases; None where the backend does not say."""
+    m = compiled.memory_analysis()
+    if m is None:
+        return None
+    return int(m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def decode_session(model, params, dcfg, cache_dtype):
+    """The session one sequence is driven through: the model record's
+    own ``decode_session`` export where it carries one, else
+    :class:`PagedSession` over its paged exports."""
+    own = getattr(model, "decode_session", None)
+    if own is not None:
+        return own(params, dcfg, cache_dtype)
+    return PagedSession(model, params, dcfg, cache_dtype)
+
+
 def check_decode_against_reference(model, params, dcfg, cache_dtype,
-                                   vocab: int, cell, seed: int) -> dict:
+                                   vocab: int, cell, seed: int, *,
+                                   reference_params=None,
+                                   said: dict | None = None) -> dict:
     """Prefill of a seeded prompt and teacher-forced decode steps
-    through a scratch paged cache of the replica's own geometry, by the
-    model record's exports (the jitted step is built exactly as
-    ``DecodeReplica`` builds it), against the full forward of the
-    reference of the cell's architecture at the same positions. Logits,
-    never sampled tokens: with random weights the largest logit changes
-    on a rounding.
+    through a decode session (:func:`decode_session`: the model
+    record's own, else a scratch paged cache built and stepped as
+    ``DecodeReplica`` builds and steps its own), against the full
+    forward of the reference of the cell's architecture at the same
+    positions. Logits, never sampled tokens: with random weights the
+    largest logit changes on a rounding. How the state is held is the
+    session's; the sequence, the teacher forcing, the reference's call,
+    the limits and the verdict are here.
 
     A routed architecture's reference takes, at each position, the
     experts the program chose where it computed that position: the
     prefill's for the prompt, each decode step's for its own token. So
     a cache path that routes a decoded token otherwise than the prefill
     would have is held to the same scores (``lib/compare.py``). The
-    logits compared are those of the exports as the replica calls
-    them; the choices come from the same exports asked for them, whose
-    logits have to be the same to the bit."""
+    logits compared are those of the session as the replica drives it;
+    the choices come from the same calls asked for them, each after the
+    unasked one and over the state that one has just written, whose
+    logits have to be the same to the bit.
+
+    ``reference_params``: the tree the reference reads where it is not
+    the program's (``lib/decode_controls.py``'s faults in the weights).
+    ``said``, where given, is filled with what the session says of
+    itself (the arm, the cache arrays) for the ``reference_check``
+    event; the returned dictionary has the numbers and nothing else."""
     import jax
     import jax.numpy as jnp
-    from distributedmnist_tpu.servesvc.kv_cache import PagedKVCache
 
     arch, config = cell.arch, cell.config
     routed = compare.is_routed(arch)
@@ -84,75 +251,42 @@ def check_decode_against_reference(model, params, dcfg, cache_dtype,
     n_steps = min(CHECK_STEPS, dcfg.max_new_tokens)
     rng = np.random.default_rng([int(seed), 0xC4EC])
     seq = rng.integers(0, vocab, n_prompt + n_steps).astype(np.int32)
-    layers, n_heads, head_dim = model.decode_cache_shape
-    cache = PagedKVCache(layers, dcfg.num_blocks, dcfg.block_size, n_heads,
-                         head_dim, dcfg.max_blocks_per_seq(),
-                         dtype=cache_dtype)
-    how = {"block_size": dcfg.block_size,
-           "attention_kernel": dcfg.attention_kernel}
-    prefill = jax.jit(model.decode_prefill)
-    step = jax.jit(functools.partial(model.decode_step, **how),
-                   donate_argnums=(3, 4))
-    if routed:
-        # the same exports asked for their choices: an output more and
-        # nothing else, so their logits equal the timed programs' bit
-        # for bit (``lib/cell.py``)
-        said = ("decode_prefill(params, tokens, return_routing=True) -> "
-                "(logits, ks, vs, routing)",
-                "decode_step(..., return_routing=True) -> (logits, k, v, "
-                "routing[routed_layers, slots, k])")
-        compare.require_export(model.decode_prefill, "return_routing",
-                               said[0])
-        compare.require_export(model.decode_step, "return_routing", said[1])
-        prefill_asked = jax.jit(lambda p, t: compare.unpack(
-            model.decode_prefill(p, t, return_routing=True), 4, said[0]))
-        step_asked = jax.jit(
-            lambda *a: compare.unpack(model.decode_step(
-                *a, return_routing=True, **how), 4, said[1]),
-            donate_argnums=(3, 4))
-    prompt = jnp.asarray(seq[None, :n_prompt])
-    logits, ks, vs = prefill(params, prompt)
-    rows = [logits[0, n_prompt - 1]]
+    session = decode_session(model, params, dcfg, cache_dtype)
+
+    def asked(call, *args):
+        got = compare.unpack(call(*args, return_routing=True), 2,
+                             _SESSION_SAID)
+        return got[0], jnp.asarray(got[1])
+
+    rows = [session.prefill(seq[:n_prompt])]
     chosen, flag_diffs = [], []
     if routed:
-        asked_logits, _, _, routing = prefill_asked(params, prompt)
-        chosen.append(routing)           # [routed_layers, 1, n_prompt, k]
-        flag_diffs.append(compare.max_abs_diff((asked_logits, logits)))
-        del asked_logits
-    table = cache.alloc_sequence(n_prompt + n_steps)
-    cache.write_prompt(table, ks[:, 0], vs[:, 0], n_prompt)
-    slots = dcfg.decode_slots
-    tables = np.zeros((slots, cache.max_blocks_per_seq), np.int32)
-    tables[0] = table
-    tables = jnp.asarray(tables)
-    for i in range(n_steps):
-        pos = n_prompt + i
-        vec = lambda v: jnp.zeros(  # noqa: E731
-            (slots,), jnp.int32).at[0].set(v)
-        out, cache.k, cache.v = step(params, vec(int(seq[pos])), vec(pos),
-                                     cache.k, cache.v, tables, vec(pos + 1))
-        rows.append(out[0])
+        again, routing = asked(session.prefill, seq[:n_prompt])
+        chosen.append(routing[:, None])     # [routed_layers, 1, n_prompt, k]
+        flag_diffs.append(compare.max_abs_diff((again, rows[0])))
+    for pos in range(n_prompt, n_prompt + n_steps):
+        rows.append(session.step(int(seq[pos]), pos))
         if routed:
-            # the same step again over the cache it has just written: it
-            # writes this token's keys and values anew, the same ones
-            asked_out, cache.k, cache.v, picked = step_asked(
-                params, vec(int(seq[pos])), vec(pos), cache.k, cache.v,
-                tables, vec(pos + 1))
-            # slot 0's experts for this token: [routed_layers, 1, 1, k]
-            chosen.append(picked[:, :1, None])
-            flag_diffs.append(compare.max_abs_diff((asked_out[0], out[0])))
+            again, picked = asked(session.step, int(seq[pos]), pos)
+            chosen.append(picked[:, None, None])  # [routed_layers, 1, 1, k]
+            flag_diffs.append(compare.max_abs_diff((again, rows[-1])))
     got = jnp.stack(rows)
-    del cache, ks, vs, logits, out     # room for the float32 reference
+    if said is not None:
+        said.update(getattr(session, "said", {"session": "own"}))
+    del session, rows                  # room for the float32 reference
     toks = jnp.asarray(seq[None])
+    if reference_params is None:
+        reference_params = params
     if not routed:
         want = jax.jit(lambda p, t: arch.logits(
-            p, t, config, last=n_steps + 1))(params, toks)[0]
+            p, t, config, last=n_steps + 1))(reference_params, toks)[0]
         verdict = {}
     else:
         routing = jnp.concatenate(chosen, axis=2)
         want, slack = jax.jit(lambda p, t, r: (
             arch.logits(p, t, config, last=n_steps + 1, routing=r)[0],
-            arch.routing_slack(p, t, config, r)))(params, toks, routing)
+            arch.routing_slack(p, t, config, r)))(reference_params, toks,
+                                                  routing)
         verdict = compare.routing_verdict(
             slack, routing, compare.routed_experts(arch, config),
             float(np.max(flag_diffs)))
@@ -222,12 +356,14 @@ def boot_replica(cell, rt):
             dataclasses.replace(model, init=lambda key: params), cfg)
         jax.block_until_ready(state)
         rt.mark("weights_made")
+        said: dict = {}
         check = check_decode_against_reference(
             model, state.params, cfg.decode,
             jax.numpy.dtype(model_cfg.compute_dtype), cfg.model.vocab_size,
-            cell, rt.seed)
+            cell, rt.seed, said=said)
         del params
-    rt.say(event="reference_check", **check, tolerance=DECODE_LOGITS_TOL)
+    rt.say(event="reference_check", **check, tolerance=DECODE_LOGITS_TOL,
+           session=said)
     rt.mark("reference_checked")
 
     # The replica follows a published checkpoint by design. `launch

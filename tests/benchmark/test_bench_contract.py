@@ -529,6 +529,75 @@ def test_a_cell_is_added_by_new_files_and_entries_alone(tmp_path):
     assert cell_lib.load_cell(CELLS[0], root=root).name == CELLS[0]
 
 
+def test_a_seventh_cell_and_a_new_last_metric_are_entries_alone(tmp_path):
+    """What a ``model_config`` or ``perf_opt`` PR does to the file as it
+    stands: one more cell (here an accepted configuration under an
+    accepted traffic file, a pair no cell has), the cell appended to
+    the ``workloads`` of the end-to-end metric it reports, and one
+    per-layer entry appended AFTER the last, with its reader as a new
+    file. Nothing that was there is edited or moved, every check of
+    this file holds on the copy as on the tree, and the cell resolves."""
+    root = _copy_of_the_benchmark(tmp_path)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()
+              and p.name != "BENCHMARK.json"}
+    bench = cell_lib.load_json(root / "BENCHMARK.json")
+    was = json.loads(json.dumps(bench))
+    pairs = {(w["config"], w["traffic"]) for w in bench["workloads"]}
+    config, traffic = next(
+        (c["name"], t.stem) for c in bench["configs"]
+        for t in sorted((root / "benchmark/traffic").glob("serve_*.json"))
+        if "serve" in cell_lib.load_json(root / c["file"])
+        and (c["name"], t.stem) not in pairs)
+    name = f"{config}.{traffic}"
+    bench["workloads"].append({"name": name, "config": config,
+                               "traffic": traffic, "chips": 1,
+                               "why": "a rehearsal"})
+    gap = next(m for m in bench["end_to_end"] if m["name"] == "itl_ms_p90")
+    gap["workloads"].append(name)
+    (root / "benchmark/layer_metrics/rehearsed_steps.py").write_text(
+        "def read(trace, counters):\n"
+        "    return float(counters['decode_steps'])\n")
+    bench["per_layer"].append({
+        "name": "rehearsed_steps", "unit": "steps", "better": "higher",
+        "source": "program_counter", "layer": "decode_loop",
+        "moves": "itl_ms_p90", "workloads": [name]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+
+    check_everything(root)
+    check_everything(ROOT)
+    cell = cell_lib.load_cell(name, root=root)
+    assert (cell.config_name, cell.traffic_name, cell.chips) == (
+        config, traffic, 1)
+    assert [m["name"] for m in cell.end_to_end] == ["itl_ms_p90", "setup_s"]
+    # its own metric, and those that list no cells and so reach every one
+    listed = [m["name"] for m in cell.per_layer]
+    assert listed[-1] == "rehearsed_steps"
+    assert set(listed[:-1]) == {m["name"] for m in was["per_layer"]
+                                if "workloads" not in m}
+    assert run_mod.per_layer_metrics(
+        dataclasses.replace(cell, per_layer=cell.per_layer[-1:]), {},
+        {"decode_steps": 3}, root=root) == {
+            "rehearsed_steps": {"value": 3.0, "unit": "steps"}}
+    # appended: every list begins with what it held, entry for entry
+    # (the driver compares the accepted entries by position); of the
+    # end-to-end metrics one list of cells grew by the new cell
+    after = cell_lib.load_json(root / "BENCHMARK.json")
+    for key in ("configs", "workloads", "per_layer"):
+        assert after[key][:len(was[key])] == was[key]
+    assert (len(after["workloads"]), len(after["per_layer"])) == (
+        len(was["workloads"]) + 1, len(was["per_layer"]) + 1)
+    assert gap["workloads"].pop() == name
+    assert bench["end_to_end"] == was["end_to_end"]
+    # every cell that was there reports what it reported
+    for cell_name in _cells(was):
+        there = cell_lib.load_cell(cell_name, root=root)
+        here = cell_lib.load_cell(cell_name)
+        for mine in ("end_to_end", "per_layer"):
+            assert [m["name"] for m in getattr(there, mine)] == [
+                m["name"] for m in getattr(here, mine)]
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
 def test_a_listed_metric_without_a_reading_is_an_error_not_a_zero(tmp_path):
     cell = cell_lib.load_cell("opt-1.3b.serve_chat_open")
     with pytest.raises(cell_lib.BenchmarkError, match="found nothing"):
