@@ -192,6 +192,25 @@ class ModelConfig:
     # norm_eps: the epsilon inside the root of every RMSNorm of the model.
     sandwich_norm: bool = False
     norm_eps: float = 1e-6
+    # kv_heads > 0: an attention layer keeps that many key-value heads,
+    # a group of num_heads / kv_heads query heads reading each (0: one a
+    # query head).
+    kv_heads: int = 0
+    # ssm_state_dim > 0: every layer's first sublayer is a selective
+    # state-space mixer (ops/ssm.py: Mamba, arXiv:2312.00752 s3, with
+    # Jamba's norms on the step, B and C, arXiv:2403.19887) and not
+    # attention, but layer i with i % attn_layer_period ==
+    # attn_layer_offset (period 0: none attends). The mixer is
+    # ssm_expand x model_dim channels wide with a state of ssm_state_dim
+    # a channel, a causal convolution of ssm_conv taps and a step
+    # projected through ssm_dt_rank (0: model_dim / 16). Such a model
+    # has no positional term and its head is the embedding, tied.
+    ssm_state_dim: int = 0
+    ssm_expand: int = 2
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
 
 
 @dataclass(frozen=True)

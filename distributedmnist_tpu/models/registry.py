@@ -193,6 +193,21 @@ class Model:
     decode_step: Callable[..., tuple] | None = None
     decode_cache_shape: tuple | None = None
     decode_counts: bool = False
+    # A model with state-space layers keeps, beside the paged rows of its
+    # attention layers (``decode_cache_shape`` counts those layers and
+    # their key-value heads), arrays a SEQUENCE owns whole:
+    # decode_state_shape = (mixer layers, state size N, channels E,
+    # convolution taps before the newest K - 1), what
+    # ``servesvc.kv_cache.SlotState`` is allocated with. Its exports take
+    # them: decode_prefill(params, tokens [b, s], lengths [b]) ->
+    # (logits [b, 1, vocab] of position lengths - 1, k, v, state [Lm, b,
+    # N, E], tail [Lm, K - 1, b, E]);
+    # decode_step(params, tokens, positions, k_cache, v_cache,
+    # block_tables, lengths, state, tail, block_size=B) -> (logits,
+    # k_cache, v_cache, state, tail), ``state`` and ``tail`` an array a
+    # mixer layer, [S, N, E] and [K - 1, S, E]. Such a
+    # model's record is a :class:`SessionModel`.
+    decode_state_shape: tuple | None = None
     # When True, ``apply`` and the sharded applies accept
     # ``return_aux=True`` and return (logits, aux), ``aux`` a mapping:
     # the train step adds ``aux_weight * aux["loss"]`` (the load-balance
@@ -207,6 +222,18 @@ class Model:
     # they refuse such a model rather than silently training without
     # dropout.
     uses_dropout: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class SessionModel(Model):
+    """A model whose decode state is not two paged arrays alone, so that
+    no one can drive a sequence through it by the paged exports and a
+    scratch cache: ``decode_session(params, dcfg, cache_dtype)`` is one
+    sequence through the decode replica's own prefill, step and stores
+    (``servesvc.decode.SlotSession``; the serving check's contract is in
+    benchmark/lib/cell.py). A record without the attribute is driven
+    through its paged exports."""
+    decode_session: Callable[..., Any] | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -342,8 +369,18 @@ def _transformer(cfg: ModelConfig) -> Model:
     routed = cfg.routed_experts > 0
     held = (cfg.first_held_expert, cfg.held_experts or cfg.routed_experts)
     sizes = None
+    mixed = cfg.ssm_state_dim > 0
     if (latent or routed or cfg.ffn_dim or cfg.residual_streams > 1
-            or cfg.nextn_layers or cfg.sandwich_norm):
+            or cfg.nextn_layers or cfg.sandwich_norm or cfg.kv_heads
+            or mixed):
+        if (mixed or cfg.kv_heads) and (
+                moe or latent or routed or cfg.residual_streams > 1
+                or cfg.nextn_layers or cfg.sandwich_norm):
+            raise ValueError(
+                "state-space layers (model.ssm_state_dim) and grouped "
+                "key-value heads (model.kv_heads) are built with the "
+                "dense or gated feed-forward, the plain residual and "
+                "attention through wqkv")
         if moe and routed:
             raise ValueError("model.num_experts (capacity routing) and "
                              "model.routed_experts (per-token routing) "
@@ -365,6 +402,11 @@ def _transformer(cfg: ModelConfig) -> Model:
             expert_ffn_dim=cfg.expert_ffn_dim, dense_layers=cfg.dense_layers,
             residual_streams=cfg.residual_streams,
             nextn_layers=cfg.nextn_layers, sandwich_norm=cfg.sandwich_norm,
+            kv_heads=cfg.kv_heads, ssm_state_dim=cfg.ssm_state_dim,
+            ssm_expand=cfg.ssm_expand, ssm_conv=cfg.ssm_conv,
+            ssm_dt_rank=cfg.ssm_dt_rank,
+            attn_period=cfg.attn_layer_period,
+            attn_offset=cfg.attn_layer_offset,
             # a bias that never moves is no bias: a served model without
             # one in its source keeps the leaf at zeros
             **({} if cfg.router_bias_rate else {"router_bias_init": 0.0}))
@@ -520,7 +562,9 @@ def _transformer(cfg: ModelConfig) -> Model:
             num_heads=cfg.num_heads, attention_fn=make_seq_attn(seq_axis),
             model_axis=model_axis, feed_forward=feed_forward,
             projections=projections, residual=residual,
-            out_norm=cfg.sandwich_norm, norm_eps=cfg.norm_eps)
+            out_norm=cfg.sandwich_norm, norm_eps=cfg.norm_eps,
+            kv_heads=cfg.kv_heads,
+            mixer=sizes is not None and not sizes.attends(layer))
 
     # one device, or replicas of the whole model. Where the leading
     # layers' feed-forward differs from the others', a block a layer,
@@ -530,6 +574,13 @@ def _transformer(cfg: ModelConfig) -> Model:
         per_kind = {False: block, True: block_for(layer=cfg.dense_layers)}
         block = tuple(per_kind[i >= cfg.dense_layers]
                       for i in range(cfg.num_layers))
+    if mixed:
+        # the pattern of the model section: an attention layer where the
+        # sizes say one attends, a state-space layer elsewhere
+        attends = [sizes.attends(i) for i in range(cfg.num_layers)]
+        per_kind = {a: block_for(layer=attends.index(a))
+                    for a in set(attends)}
+        block = tuple(per_kind[a] for a in attends)
 
     def apply(params, x, *, train=False, dropout_key=None, return_aux=False):
         del dropout_key
@@ -611,11 +662,42 @@ def _transformer(cfg: ModelConfig) -> Model:
     # step's own attention (transformer._decode_attn) spells out the
     # plain block: no output norm, no other epsilon, no gated tree
     decode_prefill = decode_step_fn = decode_cache_shape = None
+    decode_state_shape = None
     blocks = ((block,) * cfg.num_layers
               if isinstance(block, transformer.Block) else block)
     first = blocks[0]
-    if not moe and (sizes is None or (first.decode_attn is not None
-                                      and cfg.residual_streams == 1)):
+    if mixed:
+        # a layer's state is a sequence's: rows a token for the layers
+        # that attend, one state and one convolution tail a slot for the
+        # others, through forwards that hand both over
+        kv_heads = cfg.kv_heads or cfg.num_heads
+
+        def decode_prefill(params, tokens, lengths):
+            return transformer.prefill_with_state(
+                params, tokens, lengths, block=blocks,
+                compute_dtype=compute_dtype)
+
+        def decode_step_fn(params, tokens, positions, k_cache, v_cache,
+                           block_tables, lengths, state, tail, *,
+                           block_size, attention_kernel="auto"):
+            return transformer.decode_step_with_state(
+                params, tokens, positions, k_cache, v_cache, block_tables,
+                lengths, state, tail, block=blocks, num_heads=cfg.num_heads,
+                kv_heads=kv_heads, block_size=block_size,
+                compute_dtype=compute_dtype,
+                attention_kernel=attention_kernel)
+
+        attending = sum(sizes.attends(i) for i in range(cfg.num_layers))
+        decode_cache_shape = (attending, kv_heads,
+                              cfg.model_dim // cfg.num_heads)
+        decode_state_shape = (cfg.num_layers - attending, cfg.ssm_state_dim,
+                              cfg.ssm_expand * cfg.model_dim,
+                              cfg.ssm_conv - 1)
+    # (grouped heads are served beside state-space layers only: the plain
+    # step's attention reads `wqkv` as three equal parts)
+    elif not moe and not cfg.kv_heads and (
+            sizes is None or (first.decode_attn is not None
+                              and cfg.residual_streams == 1)):
         def decode_prefill(params, tokens, positions=None,
                            return_routing=False):
             return transformer.prefill_with_kv(
@@ -648,7 +730,7 @@ def _transformer(cfg: ModelConfig) -> Model:
             if latent else
             (cfg.num_layers, cfg.num_heads, cfg.model_dim // cfg.num_heads))
 
-    return Model(name=cfg.name, init=init, apply=apply,
+    model = Model(name=cfg.name, init=init, apply=apply,
                  loss=transformer.loss_fn, accuracy=transformer.accuracy,
                  input_shape=(cfg.seq_len,), input_dtype=jnp.int32,
                  eval_metrics=lm_eval_metrics,
@@ -656,6 +738,7 @@ def _transformer(cfg: ModelConfig) -> Model:
                  decode_prefill=decode_prefill,
                  decode_step=decode_step_fn,
                  decode_cache_shape=decode_cache_shape,
+                 decode_state_shape=decode_state_shape,
                  decode_counts=routed and decode_step_fn is not None,
                  sharded_apply_factory=sharded_apply_factory,
                  partition_rules=(replicated_partition_rules
@@ -675,3 +758,12 @@ def _transformer(cfg: ModelConfig) -> Model:
                  pp_transform_chunked=transformer.stack_block_params_chunked,
                  pp_1f1b_grads_factory=pp_1f1b_grads_factory,
                  pp_1f1b_apply_factory=pp_1f1b_apply_factory)
+    if decode_state_shape is None:
+        return model
+
+    def decode_session(params, dcfg, cache_dtype):
+        from ..servesvc.decode import SlotSession
+        return SlotSession(model, params, dcfg, cache_dtype)
+    return SessionModel(**{f.name: getattr(model, f.name)
+                           for f in dataclasses.fields(model)},
+                        decode_session=decode_session)

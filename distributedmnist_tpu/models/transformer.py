@@ -115,11 +115,30 @@ class Sizes(NamedTuple):
     # the scale the selection bias starts at; 0 for a router without one
     # (``router_bias_rate`` 0: the leaf stays zeros and selects nothing)
     router_bias_init: float = 0.03
+    # key-value heads an attention layer keeps (0: one a query head)
+    kv_heads: int = 0
+    # state-space layers (ops/ssm.py): ``ssm_state_dim`` > 0 makes every
+    # layer one but those at ``attn_offset`` modulo ``attn_period``
+    ssm_state_dim: int = 0
+    ssm_expand: int = 2
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    attn_period: int = 0
+    attn_offset: int = 0
+
+    def attends(self, layer: int) -> bool:
+        """Whether ``layer`` is an attention layer."""
+        return (not self.ssm_state_dim
+                or (self.attn_period > 0
+                    and layer % self.attn_period == self.attn_offset))
 
 
 #: leaves that stay float32 in the forward whatever the compute dtype:
 #: a top-k and a Sinkhorn iteration amplify what a rounding changes
-_F32_LEAVES = ("router", "router_bias", "mix1", "mix2")
+_F32_LEAVES = ("router", "router_bias", "mix1", "mix2",
+               # a state-space layer's decay, step bias and skip: what a
+               # recurrence multiplies by at every token
+               "a_log", "b_dt", "d_skip")
 
 
 def _init_mix(key: jax.Array, n: int, d: int) -> Params:
@@ -152,8 +171,43 @@ def _init_gated(key: jax.Array, lead: tuple, d: int, f: int) -> Params:
 SANDWICH_C = (0.283, 0.432)
 
 
+def _init_mixer(key: jax.Array, d: int, z: Sizes) -> Params:
+    """A state-space layer's mixer (ops/ssm.py has the equations and the
+    leaves). Matrices at 0.02 as everywhere; what a scale cannot stand
+    in for as Mamba initialises it (arXiv:2312.00752 s3.6, its published
+    code): ``a_log = log(1..N)`` a channel, ``d_skip = 1``, ``b_dt`` such
+    that ``softplus(b_dt)`` is log-uniform in [1e-3, 1e-1], the
+    convolution uniform in ``+-K^-1/2``. With ``A`` near 0 a state never
+    decays, with the step near 1 it forgets in a token: either way what
+    is kept of a sequence stops mattering to its next token."""
+    e, n, taps = z.ssm_expand * d, z.ssm_state_dim, z.ssm_conv
+    rank = z.ssm_dt_rank or -(-d // 16)
+    keys = iter(jax.random.split(key, 8))
+    tn = lambda shape: truncated_normal_init(next(keys), shape, 0.02)  # noqa: E731
+    bound = taps ** -0.5
+    step = jnp.exp(jax.random.uniform(next(keys), (e,), jnp.float32)
+                   * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return {
+        "w_in": tn((d, 2, e)),
+        "conv_w": jax.random.uniform(next(keys), (taps, e), jnp.float32,
+                                     -bound, bound),
+        "conv_b": jax.random.uniform(next(keys), (e,), jnp.float32,
+                                     -bound, bound),
+        "w_x": tn((e, rank + 2 * n)),
+        "dt_norm": _norm_scale(rank), "b_norm": _norm_scale(n),
+        "c_norm": _norm_scale(n),
+        "w_dt": tn((rank, e)),
+        # the inverse of softplus at ``step``
+        "b_dt": step + jnp.log(-jnp.expm1(-step)),
+        "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, n + 1, dtype=jnp.float32))[:, None], (n, e)),
+        "d_skip": jnp.ones((e,), jnp.float32),
+        "w_out": tn((e, d))}
+
+
 def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
-                      routed: bool, depth: int = 1) -> Params:
+                      routed: bool, depth: int = 1,
+                      attends: bool = True) -> Params:
     keys = iter(jax.random.split(key, 12))
     ones = _norm_scale
     tn = lambda shape: truncated_normal_init(next(keys), shape, 0.02)  # noqa: E731
@@ -173,6 +227,13 @@ def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
             kv_norm=ones(z.kv_latent_dim),
             wkv_b=tn((z.kv_latent_dim, heads, z.qk_nope_dim + z.v_head_dim)),
             wo=tn((heads * z.v_head_dim, d)))
+    elif not attends:
+        blk.update(_init_mixer(next(keys), d, z))
+    elif z.kv_heads and z.kv_heads != heads:
+        # fewer key-value heads than query heads: one matrix, the
+        # queries' columns, then the keys', then the values'
+        blk.update(wqkv=tn((d, (heads + 2 * z.kv_heads) * (d // heads))),
+                   wo=tn((d, d)))
     else:
         blk.update(wqkv=tn((d, 3, d)), wo=tn((d, d)))
     if z.residual_streams > 1:
@@ -219,14 +280,16 @@ def _init_sized(key: jax.Array, vocab_size: int, d: int, heads: int,
         # router sends them all to the same experts from step 1
         "embed": truncated_normal_init(next(keys), (vocab_size, d), 1.0),
         "blocks": [_init_sized_block(next(keys), d, heads, z, routed_at(i),
-                                     num_layers)
+                                     num_layers, z.attends(i))
                    for i in range(num_layers)],
         "final_norm": ones(d),
     }
     if z.kv_latent_dim:
         params["head"] = truncated_normal_init(next(keys), (d, vocab_size),
                                                0.02)
-    else:
+    elif not z.ssm_state_dim:
+        # (a model with state-space layers has no positional term at
+        # all: the recurrence orders its tokens; its head is tied)
         params["pos"] = truncated_normal_init(next(keys), (max_seq_len, d),
                                               0.02)
     if z.nextn_layers:
@@ -409,6 +472,13 @@ class Block(NamedTuple):
     # ``(x, blk, li, k_cache, v_cache, block_tables, positions, blk_ids,
     # offs, live, attention_kernel=) -> (x, k_cache, v_cache)``
     decode_attn: Callable[..., Any] | None = None
+    # set where the first sublayer is a state-space mixer and not
+    # attention (``attn`` is then that mixer over sequences, takes
+    # ``lengths`` and with ``return_kv`` returns the state and the
+    # convolution's tail in place of keys and values): the same sublayer
+    # for one token a slot against what a slot keeps of its sequence,
+    # ``(x, blk, state, tail, live) -> (x, state, tail)``
+    mixer_step: Callable[..., Any] | None = None
 
 
 def _dense_ffn(h: jax.Array, blk: Params, *,
@@ -569,7 +639,8 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
                feed_forward: Callable | None = None,
                projections: Callable | None = None,
                residual: Residual = PLAIN, out_norm: bool = False,
-               norm_eps: float = 1e-6) -> Block:
+               norm_eps: float = 1e-6, kv_heads: int = 0,
+               mixer: bool = False) -> Block:
     """The one place a layer's kind is decided: which attention behind
     which projections, which feed-forward, which residual rule, under
     which mesh axes.
@@ -612,6 +683,17 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
     joins the residual (sandwich norms, arXiv:2504.07866 §2): ``x +
     norm(F(norm x))`` over the block's ``ln1_out`` and ``ln2_out``.
     ``norm_eps`` is the epsilon of every norm of the block.
+
+    ``kv_heads``: key-value heads under ``wqkv`` where they are fewer
+    than the query heads (0: one each): ``wqkv`` is then one matrix ``[d,
+    (heads + 2 kv_heads) hd]``, a group of ``heads / kv_heads`` queries
+    attends to one head's keys and values, and the rows a cache keeps are
+    that one head's.
+
+    ``mixer``: the first sublayer is the state-space mixer of
+    ``ops/ssm.py`` over the block's leaves and not attention: no
+    projections, no attention function, no positions; the block's
+    ``mixer_step`` is its form for one token.
     """
     norm = (_rms_norm if norm_eps == 1e-6
             else functools.partial(_rms_norm, eps=norm_eps))
@@ -633,7 +715,29 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
                                        d // num_heads) for i in range(3)),
                 None, None)
 
-    project = projections or wqkv_projections
+    def grouped_projections(h, blk, positions):
+        del positions  # such a model has no positional term
+        b, d = h.shape[0], h.shape[-1]
+        hd = d // num_heads
+        qkv = h @ blk["wqkv"]
+        q, k, v = jnp.split(qkv, [num_heads * hd,
+                                  (num_heads + kv_heads) * hd], axis=-1)
+        q = q.reshape(b, -1, num_heads, hd)
+        k, v = (t.reshape(b, -1, kv_heads, hd) for t in (k, v))
+        # the attention function is handed a key and a value a query
+        # head: copies in HBM, a cost and no other departure
+        # (ops/pallas_attention.py's index maps could read the one head)
+        wide = lambda t: jnp.repeat(t, num_heads // kv_heads, axis=2)  # noqa: E731
+        return q, wide(k), wide(v), None, (k, v)
+
+    grouped = bool(kv_heads) and kv_heads != num_heads
+    if grouped and (model_axis or num_heads % kv_heads):
+        raise ValueError(f"{kv_heads} key-value heads for {num_heads} "
+                         "query heads: not a whole group a head, or "
+                         "under a model axis, which no rule splits them "
+                         "over")
+    project = projections or (grouped_projections if grouped
+                              else wqkv_projections)
 
     @jax.named_scope("attention")
     def attn(x: jax.Array, blk: Params, return_kv: bool = False,
@@ -693,7 +797,34 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
     if hasattr(project, "decode"):
         decode_attn = functools.partial(project.decode, norm=norm,
                                         out_norm=out_norm)
-    return Block(attn, ffn, residual, norm, decode_attn)
+    if not mixer:
+        return Block(attn, ffn, residual, norm, decode_attn)
+
+    from ..ops import ssm
+
+    def mix(x: jax.Array, blk: Params, return_kv: bool = False,
+            positions: jax.Array | None = None,
+            lengths: jax.Array | None = None):
+        """Pre-norm mixer sublayer over sequences from an empty state:
+        the residual after mixer(ln1(read(x))); with ``return_kv`` also
+        the state after position ``lengths - 1`` and the convolution's
+        tail there (``lengths`` [batch]; None: the whole sequence)."""
+        del positions  # the recurrence orders the tokens
+        u, kept = residual.read(x, blk.get("mix1"))
+        h = norm(u, blk["ln1"]).astype(blk["w_out"].dtype)
+        out = ssm.mixer(h, blk, norm=norm, lengths=lengths,
+                        return_state=return_kv)
+        if not return_kv:
+            return residual.write(kept, out)
+        return (residual.write(kept, out[0]), *out[1:])
+
+    def mix_step(x, blk, state, tail, live):
+        h = norm(x, blk["ln1"]).astype(blk["w_out"].dtype)
+        out, state, tail = ssm.mixer_step(h, blk, state, tail, live,
+                                          norm=norm)
+        return x + out.astype(x.dtype), state, tail
+
+    return Block(mix, ffn, residual, norm, None, mix_step)
 
 
 def apply(params: Params, tokens: jax.Array, *,
@@ -993,6 +1124,98 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     return out
 
 
+def prefill_with_state(params: Params, tokens: jax.Array,
+                       lengths: jax.Array, *, block: tuple[Block, ...],
+                       compute_dtype=jnp.bfloat16) -> tuple[jax.Array, ...]:
+    """Prompt prefill of a model whose layers are attention or
+    state-space mixers, one ``block`` a layer (a mixer's has
+    ``mixer_step``): the causal forward, and what every layer keeps of
+    a sequence for a decode replica.
+
+    tokens [b, s] int32, padded to a bucket; ``lengths`` [b] the real
+    lengths. Attention ignores the padding (causal, logits read at
+    ``lengths - 1``); a recurrence does not, so each mixer hands on the
+    state after token ``lengths - 1`` and the convolution's inputs before
+    ``lengths``, whatever the bucket. Returns (logits [b, 1, vocab]
+    float32 of position ``lengths - 1`` alone (the one a first token is
+    sampled from: the head over a whole bucket is 0.5 GB of logits at
+    these widths), k and v [attention layers, b, s, kv_heads, hd] in the
+    compute dtype, state [mixer layers, b, N, E] float32, tail [mixer
+    layers, K - 1, b, E] in the compute dtype, oldest input first)."""
+    for bk in block:
+        _one_stream(bk, "prefill_with_state")
+    p = _cast(params, compute_dtype)
+    x = block[0].residual.start(_embed(p, tokens, jnp.arange(tokens.shape[1])))
+    ks, vs, states, tails = [], [], [], []
+    for bk, blk in zip(block, p["blocks"]):
+        if bk.mixer_step is None:
+            x, k, v = bk.attn(x, blk, return_kv=True)
+            ks.append(k)
+            vs.append(v)
+        else:
+            x, state, tail = bk.attn(x, blk, return_kv=True, lengths=lengths)
+            states.append(state)
+            tails.append(tail.transpose(1, 0, 2))
+        x, _ = bk.ffn(x, blk)
+    last = jnp.take_along_axis(block[-1].residual.end(x),
+                               (lengths - 1)[:, None, None], axis=1)
+    return (_head(p, last, norm=block[-1].norm),
+            jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
+            jnp.stack(tails))
+
+
+def decode_step_with_state(params: Params, tokens: jax.Array,
+                           positions: jax.Array, k_cache: jax.Array,
+                           v_cache: jax.Array, block_tables: jax.Array,
+                           lengths: jax.Array, state: tuple,
+                           tail: tuple, *, block: tuple[Block, ...],
+                           num_heads: int, kv_heads: int,
+                           block_size: int = 16,
+                           compute_dtype=jnp.bfloat16,
+                           attention_kernel: str = "auto"
+                           ) -> tuple[jax.Array, ...]:
+    """:func:`decode_step` of a model whose layers are attention or
+    state-space mixers, one ``block`` a layer. The paged cache holds the
+    attention layers only, ``[attention layers, N, B, kv_heads, hd]``,
+    read and written by :func:`_decode_attn` as the plain block's. Beside
+    it the arrays a slot owns whole, whatever its sequence's length, a
+    pair a mixer layer: ``state[l]`` [slots, N, E] float32 and
+    ``tail[l]`` [K - 1, slots, E]; a mixer layer advances its pair for
+    the live slots (``lengths > 0``) and leaves an idle slot's as it was.
+    Hand both donated: a layer's state is then read and written in one
+    elementwise pass over its own array.
+
+    Returns (logits [S, vocab] float32, k_cache, v_cache, state, tail)."""
+    arm = decode_attention_arm(attention_kernel, k_cache.shape)
+    p = _cast(params, compute_dtype)
+    x = _embed(p, tokens, positions)  # [S, d]
+    hd = x.shape[-1] // num_heads
+    ctx_pos = jnp.arange(block_tables.shape[1] * block_size)
+    blk_ids = jnp.take_along_axis(
+        block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
+    offs = positions % block_size
+    live = ctx_pos[None, :] < lengths[:, None]  # [S, ctx]
+    stepping = lengths > 0
+    state, tail = list(state), list(tail)
+    attended = mixed = 0
+    for bk, blk in zip(block, p["blocks"]):
+        if bk.mixer_step is None:
+            x, k_cache, v_cache = _decode_attn(
+                x, blk, attended, k_cache, v_cache, block_tables, lengths,
+                blk_ids, offs, live, num_heads=num_heads,
+                scale=1.0 / (hd ** 0.5), arm=arm,
+                kv_heads=None if kv_heads == num_heads else kv_heads,
+                norm=bk.norm)
+            attended += 1
+        else:
+            x, state[mixed], tail[mixed] = bk.mixer_step(
+                x, blk, state[mixed], tail[mixed], stepping)
+            mixed += 1
+        x, _ = bk.ffn(x, blk)
+    return (_head(p, x, norm=block[-1].norm), k_cache, v_cache,
+            tuple(state), tuple(tail))
+
+
 def decode_attention_arm(attention_kernel: str,
                          cache_shape: tuple[int, ...]) -> str:
     """``"paged"`` or ``"gather"``: how a decode step asked for
@@ -1022,7 +1245,8 @@ def decode_attention_arm(attention_kernel: str,
 
 @jax.named_scope("attention")
 def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
-                 blk_ids, offs, live, *, num_heads, scale, arm):
+                 blk_ids, offs, live, *, num_heads, scale, arm,
+                 kv_heads=None, norm=_rms_norm):
     """One layer's attention sublayer of :func:`decode_step`: this
     token's K/V written through the block table and the context read
     back by ``arm`` (:func:`decode_attention_arm`), x + wo(attn). The
@@ -1030,11 +1254,19 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
     every table entry (``cache_gather``); on the paged arm the kernel
     that walks the table copies the rows into their page first, and
     ``cache_write`` holds what is left outside it: the rows cast and
-    padded to the stored width."""
+    padded to the stored width. ``kv_heads`` (None: one a query head):
+    the heads the cache keeps, where a group of ``num_heads / kv_heads``
+    queries shares one (``wqkv`` one matrix: :func:`make_block`);
+    ``norm`` the block's."""
+    if kv_heads is not None:
+        return _grouped_decode_attn(
+            x, blk, li, k_cache, v_cache, block_tables, lengths, blk_ids,
+            offs, live, num_heads=num_heads, kv_heads=kv_heads, scale=scale,
+            arm=arm, norm=norm)
     num_slots, d = x.shape
     hd = d // num_heads
     ctx = live.shape[1]
-    h = _rms_norm(x, blk["ln1"])
+    h = norm(x, blk["ln1"])
     qkv = jnp.einsum("sd,dte->ste", h, blk["wqkv"])
     q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, d]
     if arm == "paged":
@@ -1081,6 +1313,52 @@ def _decode_attn(x, blk, li, k_cache, v_cache, block_tables, lengths,
         scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
         w = jax.nn.softmax(scores, axis=-1)
         o = jnp.einsum("shk,skhd->shd", w, vp.astype(jnp.float32))
+    o = o.astype(x.dtype).reshape(num_slots, d)
+    return x + o @ blk["wo"], k_cache, v_cache
+
+
+def _grouped_decode_attn(x, blk, li, k_cache, v_cache, block_tables,
+                         lengths, blk_ids, offs, live, *, num_heads,
+                         kv_heads, scale, arm, norm):
+    """:func:`_decode_attn` where ``kv_heads`` heads' rows are cached and
+    ``num_heads / kv_heads`` queries read each: the same two arms, the
+    kernel handed the queries of all heads against the rows of the few
+    (ops/pallas_paged_attention.py), the gather one product a group."""
+    num_slots, d = x.shape
+    hd = d // num_heads
+    group = num_heads // kv_heads
+    h = norm(x, blk["ln1"]).astype(blk["wo"].dtype)
+    q, k, v = jnp.split(h @ blk["wqkv"], [num_heads * hd,
+                                          (num_heads + kv_heads) * hd],
+                        axis=-1)
+    qh = q.reshape(num_slots, num_heads, hd)
+    kh, vh = (t.reshape(num_slots, kv_heads, hd) for t in (k, v))
+    if arm == "paged":
+        from ..ops.pallas_paged_attention import paged_attention_write
+        with jax.named_scope("cache_write"):
+            beside = ((0, 0), (0, 0), (0, k_cache.shape[-1] - hd))
+            kh = jnp.pad(kh.astype(k_cache.dtype), beside)
+            vh = jnp.pad(vh.astype(v_cache.dtype), beside)
+        o, k_cache, v_cache = paged_attention_write(
+            qh, kh, vh, k_cache, v_cache, block_tables, lengths, layer=li,
+            scale=scale)
+    else:
+        with jax.named_scope("cache_write"):
+            k_cache = k_cache.at[li, blk_ids, offs, :, :hd].set(
+                kh.astype(k_cache.dtype))
+            v_cache = v_cache.at[li, blk_ids, offs, :, :hd].set(
+                vh.astype(v_cache.dtype))
+        with jax.named_scope("cache_gather"):
+            kp = k_cache[li][block_tables][..., :hd].reshape(
+                num_slots, -1, kv_heads, hd)
+            vp = v_cache[li][block_tables][..., :hd].reshape(
+                num_slots, -1, kv_heads, hd)
+        qg = qh.reshape(num_slots, kv_heads, group, hd)
+        scores = jnp.einsum("sgqd,skgd->sgqk", qg.astype(jnp.float32),
+                            kp.astype(jnp.float32)) * scale
+        scores = jnp.where(live[:, None, None, :], scores, _DECODE_NEG)
+        w = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("sgqk,skgd->sgqd", w, vp.astype(jnp.float32))
     o = o.astype(x.dtype).reshape(num_slots, d)
     return x + o @ blk["wo"], k_cache, v_cache
 

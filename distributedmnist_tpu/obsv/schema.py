@@ -233,16 +233,31 @@ _declare(EventSchema(
                              # (`while` instructions in it: the loops a
                              # scatter of the token's rows compiles to):
                              # a value a table width
+                             # of a model whose state is a sequence's
+                             # (kv_cache.SlotState): state_arrays, the
+                             # shapes of a layer's two arrays ([slots, N,
+                             # E] and [K - 1, slots, E]); state_layers,
+                             # how many layers have such a pair;
+                             # state_slot_bytes, what one sequence's
+                             # state takes; state_device_bytes, both
+                             # arrays as placed; kv_heads, the heads the
+                             # paged rows hold
                              ("cache_row_bytes", "cache_arrays",
                               "attention_arm", "paged_calls",
-                              "step_while_loops")),
+                              "step_while_loops", "state_arrays",
+                              "state_layers",
+                              "state_slot_bytes", "state_device_bytes",
+                              "kv_heads")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of
-        # `_prefill`, absent on a restart's re-prefill
+        # `_prefill`, absent on a restart's re-prefill; state_write_ms:
+        # the host's part of handing the prompt's rows and the slot's
+        # state to the stores, where the model keeps a slot state
         "prefill": _act(("id", "prompt_len", "bucket", "blocks",
                          "model_step", "ttft_ms"),
-                        ("restart", "queue_ms", "prefill_ms")),
+                        ("restart", "queue_ms", "prefill_ms",
+                         "state_write_ms")),
         "decode_finish": _act(("id", "reason", "tokens_streamed",
                                "model_step", "started_step",
                                "latency_ms"),
@@ -335,6 +350,9 @@ _declare(EventSchema(
               # (token, expert) pairs on experts held here, and how many
               # of those experts took any
               "expert_pairs_held", "experts_touched",
+              # slots whose per-sequence state was zeroed so far (finish,
+              # restart, shutdown), where the model keeps one
+              "state_resets",
               # the decode loop's clock (obsv/timing.LoopClock): cumulative
               # seconds by phase, a flat object, and of the whole loop;
               # counters, so two heartbeats give ms an iteration by phase

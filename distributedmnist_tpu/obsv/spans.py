@@ -150,10 +150,21 @@ PREFETCH_PUT = "dml.prefetch.put"
 #: latent_absorb (the query through W_uk, the weighted latents through
 #: W_uv), and moe inside ffn as in training. Read by:
 #: decode_absorb_ms_per_step, decode_moe_ms_per_step (and attention
-#: whole by decode_attention_ms_per_step)
+#: whole by decode_attention_ms_per_step). A state-space layer
+#: (ops/ssm.py) opens ssm around its mixer whole, in the place of
+#: attention (read by: decode_ssm_ms_per_step), and inside it ssm_conv (the
+#: causal convolution), ssm_scan (the recurrence over a prompt, in
+#: jit_decode_prefill and a train step; read by: prefill_scan_ms_p50) and
+#: state_update (one token a slot, in jit_decode_step; read by:
+#: decode_state_update_ms_per_step, decode_state_update_roofline).
+#: state_write is servesvc/kv_cache.py's program that puts a prefill's
+#: end state into a slot (jit_write_slot_state; no reader: 9 MB a
+#: prefill). The benchmark reads these through benchmark/lib/
+#: ssm_scopes.py
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
           "ffn", "head", "loss", "aggregate", "update", "timing",
-          "residual_mix", "moe", "mtp", "latent_absorb")
+          "residual_mix", "moe", "mtp", "latent_absorb",
+          "ssm", "ssm_conv", "ssm_scan", "state_update", "state_write")
 
 #: a host span: ``with span(SERVE_STREAM, id=...):``
 span = jax.profiler.TraceAnnotation

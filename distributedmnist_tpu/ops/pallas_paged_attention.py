@@ -88,8 +88,9 @@ PAGES_PER_STEP = 8
 
 def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
                   layer_ref, q_ref, *refs,
-                  scale: float, num_heads: int, block_size: int,
-                  pages: int, table_width: int, writes: bool):
+                  scale: float, num_heads: int, kv_heads: int,
+                  block_size: int, pages: int, table_width: int,
+                  writes: bool, group: int = 1, tile_rows: int = 0):
     """Every work item of one layer, in one loop; with ``writes``, each
     live slot's new rows put into the cache first.
 
@@ -102,25 +103,45 @@ def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
     width]`` come in beside the query, and the cache arrays are the
     call's outputs too, aliased to its inputs: written and read through
     the output's name, which off the chip (interpreted) is the one that
-    holds what was written."""
-    if writes:
+    holds what was written.
+
+    ``num_heads`` query heads read ``kv_heads`` heads' rows, ``group``
+    queries in a row each their own head's (a page is ``block_size *
+    kv_heads`` rows; a query head beyond ``group * kv_heads`` owns no
+    row): with one key-value head the mask keeps every row for every
+    query and an item's scores are the plain product.
+
+    ``tile_rows`` > 0: a token's ``kv_heads`` rows are fewer than the
+    rows of one tile of the cache as the device stores it (one bfloat16
+    row is half a 32-bit sublane: no DMA can address it). Each live
+    slot's tile of ``tile_rows`` rows that holds the token's position
+    then comes into ``k_rmw``/``v_rmw`` [slots, tile_rows, width] whole,
+    takes the new rows there, and goes back whole: two rounds of copies,
+    each all in flight together. No two slots share a page."""
+    if writes and tile_rows:
+        (k_new, v_new, _, _, o_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
+         bias_ref, pos_ref, m_ref, l_ref, acc_ref, write_sem,
+         k_rmw, v_rmw) = refs
+    elif writes:
         (k_new, v_new, _, _, o_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
          bias_ref, pos_ref, m_ref, l_ref, acc_ref, write_sem) = refs
     else:
         (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
          bias_ref, pos_ref, m_ref, l_ref, acc_ref) = refs
-    page_rows = block_size * num_heads
+    page_rows = block_size * kv_heads
     rows = pages * page_rows
     layer, num_items = layer_ref[0], count_ref[0]
 
     def row_copies(slot):
-        """The two DMAs of a slot's new rows: ``heads`` rows in one run,
-        at offset ``(length - 1) % block_size`` of the page that holds
-        position ``length - 1``."""
+        """The two DMAs of a slot's new rows: ``kv_heads`` rows in one
+        run, at offset ``(length - 1) % block_size`` of the page that
+        holds position ``length - 1``."""
         at = lengths_ref[slot] - 1
         block = tables_ref[slot, at // block_size]
-        run = pl.ds(pl.multiple_of((at % block_size) * num_heads, num_heads),
-                    num_heads)
+        if tile_rows:
+            return tile_copies(slot, block, at, back=True)
+        run = pl.ds(pl.multiple_of((at % block_size) * kv_heads, kv_heads),
+                    kv_heads)
         return (pltpu.make_async_copy(k_new.at[slot],
                                       k_hbm.at[layer, block, run],
                                       write_sem.at[0]),
@@ -128,23 +149,58 @@ def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
                                       v_hbm.at[layer, block, run],
                                       write_sem.at[1]))
 
-    def each_written_row(act):
-        """``act`` on the DMAs of every slot that has a row to write: a
-        slot of length 0 writes nothing (its table is never looked up),
-        nor does a position beyond the table."""
+    def tile_of(at):
+        """The first row of the tile that holds position ``at``'s rows."""
+        first = (at % block_size) * kv_heads
+        return pl.multiple_of(first // tile_rows * tile_rows, tile_rows)
+
+    def tile_copies(slot, block, at, back: bool):
+        """The two DMAs of a slot's tile, from the cache or back to it."""
+        run = pl.ds(tile_of(at), tile_rows)
+        pairs = ((k_hbm.at[layer, block, run], k_rmw.at[slot]),
+                 (v_hbm.at[layer, block, run], v_rmw.at[slot]))
+        return tuple(pltpu.make_async_copy(*(pair[::-1] if back else pair),
+                                           write_sem.at[i])
+                     for i, pair in enumerate(pairs))
+
+    def tiles_in(slot):
+        at = lengths_ref[slot] - 1
+        return tile_copies(slot, tables_ref[slot, at // block_size], at,
+                           back=False)
+
+    def put_new_rows(slot, _):
+        """The slot's new rows into its tile, in VMEM: a select a row."""
+        at = lengths_ref[slot] - 1
+        first = (at % block_size) * kv_heads - tile_of(at)
+        row_id = jax.lax.broadcasted_iota(jnp.int32, k_rmw.shape[1:], 0)
+        for new, tile in ((k_new, k_rmw), (v_new, v_rmw)):
+            rows, held = new[slot], tile[slot]
+            for j in range(kv_heads):
+                held = jnp.where(row_id == first + j, rows[j:j + 1], held)
+            tile[slot] = held
+
+    def each_written_row(copies_of, act):
+        """``act`` on the DMAs ``copies_of(slot)`` of every slot that has
+        a row to write: a slot of length 0 writes nothing (its table is
+        never looked up), nor does a position beyond the table."""
         def one(slot, _):
             n = lengths_ref[slot]
 
             @pl.when((n > 0) & (n <= table_width * block_size))
             def _():
-                for dma in row_copies(slot):
+                for dma in copies_of(slot):
                     act(dma)
         jax.lax.fori_loop(0, lengths_ref.shape[0], one, None)
 
+    if writes and tile_rows:
+        each_written_row(tiles_in, lambda dma: dma.start())
+        each_written_row(tiles_in, lambda dma: dma.wait())
+        # (a slot with nothing to write selects into a tile nobody reads)
+        jax.lax.fori_loop(0, lengths_ref.shape[0], put_new_rows, None)
     if writes:
         # in flight under the set-up below, landed before the first
         # page is fetched: position length - 1 is read through the cache
-        each_written_row(lambda dma: dma.start())
+        each_written_row(row_copies, lambda dma: dma.start())
 
     def live_pages(slot):
         return jnp.minimum(pl.cdiv(lengths_ref[slot], block_size),
@@ -192,16 +248,18 @@ def _paged_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
                     (page_rows, v_buf.shape[-1]), v_buf.dtype)
 
     # column c of an item's scores is row c of its chunk: position
-    # c // heads of the chunk, head c % heads. A head keeps its own
-    # rows; the rest gets the mask's value through this addend
+    # c // kv_heads of the chunk, head c % kv_heads. A query head keeps
+    # its own key-value head's rows; the rest gets the mask's value
+    # through this addend
     col = jax.lax.broadcasted_iota(jnp.int32, (num_heads, rows), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (num_heads, rows), 0)
-    bias_ref[...] = jnp.where(col % num_heads == head, 0.0, _NEG_INF)
+    owner = head if group == 1 else head // group
+    bias_ref[...] = jnp.where(col % kv_heads == owner, 0.0, _NEG_INF)
     pos_ref[...] = jax.lax.broadcasted_iota(
-        jnp.int32, pos_ref.shape, 1) // num_heads
+        jnp.int32, pos_ref.shape, 1) // kv_heads
     o_ref[...] = jnp.zeros_like(o_ref)  # an idle slot's rows
     if writes:
-        each_written_row(lambda dma: dma.wait())
+        each_written_row(row_copies, lambda dma: dma.wait())
 
     @pl.when(num_items > 0)
     def _():
@@ -344,11 +402,14 @@ def paged_attention_write(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     Returns (the attention [slots, heads, head_dim] float32, the two
     cache arrays).
     """
-    assert k_new.shape == v_new.shape == (*q.shape[:2], k_pages.shape[-1])
+    assert k_new.shape == v_new.shape == (q.shape[0], *k_pages.shape[-2:])
     assert k_new.dtype == k_pages.dtype and v_new.dtype == v_pages.dtype
-    if not _interpreted(interpret) and k_pages.shape[-1] % _LANE:
+    if not _interpreted(interpret) and (
+            k_pages.shape[-1] % _LANE or _new_rows_tile(k_pages) is None):
         # compiled, a row has to fill whole lanes (what a cache as wide
-        # as kv_cache.stored_head_dim answers has): these rows are
+        # as kv_cache.stored_head_dim answers has) and a token's rows
+        # whole tiles of the cache, or a page whole tiles for them to go
+        # through one (_paged_kernel): these rows are
         # scattered through XLA and read by the form that pads a layer
         k_pages, v_pages = (
             _scattered(pages, new, block_tables, lengths, layer)
@@ -360,6 +421,20 @@ def paged_attention_write(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     return _paged_call(q, (k_new, v_new), k_pages, v_pages, block_tables,
                        lengths, layer=layer, scale=scale,
                        pages_per_step=pages_per_step, interpret=interpret)
+
+
+def _new_rows_tile(pages: jax.Array) -> int | None:
+    """How a token's ``kv_heads`` rows get into a cache array ``[layers,
+    blocks, block_size, kv_heads, width]`` as a TPU stores it (tiles of
+    eight 32-bit sublanes, two bfloat16 rows in each): 0, they fill whole
+    tiles and are copied where they lie; ``n``, they fill none and go
+    through the tile of ``n`` rows that holds them (_paged_kernel); None,
+    neither (a page is no whole tile either): not by the kernel."""
+    tile = 8 * max(1, 4 // pages.dtype.itemsize)
+    block_size, kv_heads = pages.shape[2:4]
+    if kv_heads % tile == 0:
+        return 0
+    return None if block_size * kv_heads % tile else tile
 
 
 def _interpreted(interpret: bool | None) -> bool:
@@ -384,9 +459,16 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
     attention alone, or with ``new_rows`` (the keys' and the values')
     the attention and the two arrays those rows were written to."""
     num_slots, num_heads, hd = q.shape
-    layers, num_blocks, block_size, h2, row = k_pages.shape
-    assert h2 == num_heads and row >= hd, (q.shape, k_pages.shape)
+    layers, num_blocks, block_size, kv_heads, row = k_pages.shape
+    assert num_heads % kv_heads == 0 and row >= hd, (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
+    # the query heads the kernel holds: as given where each reads rows
+    # of its own; where a group shares a head's rows, whole tiles of
+    # them (heads beyond the model's own are zeros, own no rows and are
+    # cut off the output)
+    given, group = num_heads, num_heads // kv_heads
+    if kv_heads != num_heads:
+        num_heads = -(-num_heads // 16) * 16
     assert block_tables.shape[0] == num_slots == lengths.shape[0]
     table_width = block_tables.shape[1]
     if scale is None:
@@ -405,14 +487,15 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
                     lanes) for a in (k_pages, v_pages))
         layers, layer, row = 1, 0, row + (-row % _LANE)
     pages = min(pages_per_step, table_width)
-    page_rows = block_size * num_heads
+    page_rows = block_size * kv_heads
     rows = pages * page_rows
 
     slot, chunk, num_items = _work_items(lengths, block_size, table_width,
                                          pages)
     # a query zero beyond the head adds nothing to a score; a block's
     # positions and heads are one run of rows as stored (no copy)
-    qp = jnp.pad(q.astype(k_pages.dtype), ((0, 0), (0, 0), (0, row - hd)))
+    qp = jnp.pad(q.astype(k_pages.dtype),
+                 ((0, 0), (0, num_heads - given), (0, row - hd)))
     kp = k_pages.reshape(layers, num_blocks, page_rows, row)
     vp = v_pages.reshape(layers, num_blocks, page_rows, row)
     scalars = (block_tables.astype(jnp.int32), lengths, slot, chunk,
@@ -420,12 +503,19 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
                jnp.asarray(layer, jnp.int32).reshape(1))
 
     writes = new_rows is not None
+    # a token's rows that fill no whole tile of the cache go through a
+    # tile in VMEM (interpreted, where a page is no whole tile either,
+    # they are copied as they are: the interpreter addresses any row)
+    tile_rows = (_new_rows_tile(k_pages) or 0) if writes else 0
     kernel = functools.partial(
-        _paged_kernel, scale=scale, num_heads=num_heads,
+        _paged_kernel, scale=scale, num_heads=num_heads, kv_heads=kv_heads,
         block_size=block_size, pages=pages, table_width=table_width,
-        writes=writes)
+        writes=writes, group=group, tile_rows=tile_rows)
     a_slots_rows = pl.BlockSpec((num_slots, num_heads, row),
                                 lambda *_: (0, 0, 0))
+    new_rows_spec = (a_slots_rows if kv_heads == num_heads else
+                     pl.BlockSpec((num_slots, kv_heads, row),
+                                  lambda *_: (0, 0, 0)))
     where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
     out_shape = jax.ShapeDtypeStruct((num_slots, num_heads, row),
                                      jnp.float32)
@@ -441,10 +531,13 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
     ]
     if writes:
         scratch_shapes.append(pltpu.SemaphoreType.DMA((2,)))
+    if tile_rows:
+        scratch_shapes += [pltpu.VMEM((num_slots, tile_rows, row), a.dtype)
+                           for a in (k_pages, v_pages)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(1,),
-        in_specs=([a_slots_rows] * (3 if writes else 1)
+        in_specs=([a_slots_rows] + [new_rows_spec] * (2 if writes else 0)
                   + [where_it_lies] * 2),
         out_specs=((a_slots_rows, where_it_lies, where_it_lies)
                    if writes else a_slots_rows),
@@ -466,9 +559,9 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
         name="paged_decode",
     )
     if not writes:
-        return call(*scalars, qp, kp, vp)[..., :hd]
+        return call(*scalars, qp, kp, vp)[:, :given, :hd]
     out, kp, vp = call(*scalars, qp, *new_rows, kp, vp)
-    return (out[..., :hd], kp.reshape(k_pages.shape),
+    return (out[:, :given, :hd], kp.reshape(k_pages.shape),
             vp.reshape(v_pages.shape))
 
 
@@ -483,14 +576,18 @@ def paged_attention_dense(q: jax.Array, k_pages: jax.Array,
     kernel against this for live slots; idle rows differ by design (see
     module docstring)."""
     num_slots, num_heads, hd = q.shape
-    block_size = k_pages.shape[1]
+    block_size, kv_heads = k_pages.shape[1], k_pages.shape[2]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     ctx = block_tables.shape[1] * block_size
-    kd = k_pages[block_tables][..., :hd].reshape(num_slots, ctx, num_heads,
+    kd = k_pages[block_tables][..., :hd].reshape(num_slots, ctx, kv_heads,
                                                  hd)
-    vd = v_pages[block_tables][..., :hd].reshape(num_slots, ctx, num_heads,
+    vd = v_pages[block_tables][..., :hd].reshape(num_slots, ctx, kv_heads,
                                                  hd)
+    if kv_heads != num_heads:
+        # a group of query heads reads one head's rows
+        kd, vd = (jnp.repeat(t, num_heads // kv_heads, axis=2)
+                  for t in (kd, vd))
     live = jnp.arange(ctx)[None, :] < lengths[:, None]
     scores = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32),
                         kd.astype(jnp.float32)) * scale

@@ -30,6 +30,19 @@ slot's live pages whatever the table's width:
 ``transformer.decode_attention_arm``; ``decode_start`` says which arm
 each width compiled to.)
 
+**State that is a sequence's.** A model with state-space layers
+(``model.decode_state_shape``: ops/ssm.py) keeps, beside the paged rows
+of its attention layers, one recurrent state and one convolution tail a
+layer A SLOT, whatever the sequence's length (:class:`.kv_cache.
+SlotState`, an array a layer): allocated with the slot at admission, written by the
+prefill, handed to the step donated beside the cache and taken back
+advanced (an idle slot's slice is left as it was), zeroed at finish, and
+zeroed and rebuilt by the re-prefill of a restart. A pinned version's
+step advances its own slots only. :func:`build_stores`,
+:func:`jit_step`, :func:`run_prefill` and :func:`store_prompt` are what
+the loop admits and steps a sequence through, and what
+:class:`SlotSession` (one sequence, for the serving check) drives too.
+
 **Prefill.** Prompts are admitted through the existing bounded queue
 (typed ``overloaded`` shed when full), padded to power-of-2 buckets
 (each bucket's prefill compiles once) and run through the model's
@@ -98,7 +111,8 @@ from ..models.registry import sample_token
 from ..models.transformer import decode_attention_arm
 from ..obsv import spans
 from ..obsv.timing import LoopClock
-from .kv_cache import PagedKVCache, cache_shapes, stored_head_dim
+from .kv_cache import (PagedKVCache, SlotState, cache_shapes,
+                       stored_head_dim)
 from .server import ServingReplica, _Pending
 
 
@@ -113,6 +127,106 @@ LOOP_PHASES = ("idle", "admit", "prefill", "inputs", "dispatch", "fetch",
 def while_loops(compiled_text: str) -> int:
     """The ``while`` instructions in a compiled program's text."""
     return len(re.findall(r" while\(", compiled_text))
+
+
+def table_widths(full: int) -> list[int]:
+    """The block-table widths an iteration chooses from: the quarters of
+    the full table (fewer where a toy table has under four blocks). Each
+    is a compiled program and a load at start-up."""
+    return sorted({-(-full * k // 4) for k in (1, 2, 3, 4)})
+
+
+def build_stores(model, dcfg, dtype, sharding, put
+                 ) -> tuple[PagedKVCache, SlotState | None]:
+    """The paged cache a model's ``decode_cache_shape`` asks for, its
+    rows as wide as the device stores them whole (kv_cache.py), and the
+    per-slot state its ``decode_state_shape`` asks for (None without
+    one), both through ``put``: placed where the weights are, once (no
+    copy): a fresh ``jnp.zeros`` is committed to no device, a jitted
+    call is compiled again for an argument placed differently, and every
+    later call takes the arrays a jitted call returned."""
+    layers, heads, head_dim = model.decode_cache_shape
+    # One width for keys and values a head; one each where the model
+    # keeps a pair of rows a token (a latent, a rotated key)
+    shapes = cache_shapes(layers, dcfg.num_blocks, dcfg.block_size, heads,
+                          head_dim)
+    if isinstance(head_dim, tuple):
+        head_dim = tuple(stored_head_dim(shape, dtype, sharding)
+                         for shape in shapes)
+    else:
+        head_dim = stored_head_dim(shapes[0], dtype, sharding)
+    cache = PagedKVCache(layers, dcfg.num_blocks, dcfg.block_size, heads,
+                         head_dim, dcfg.max_blocks_per_seq(), dtype=dtype)
+    cache.k, cache.v = put((cache.k, cache.v))
+    state = None
+    if model.decode_state_shape is not None:
+        state = SlotState(model.decode_state_shape[0], dcfg.decode_slots,
+                          *model.decode_state_shape[1:], dtype=dtype)
+        state.place(put)
+    return cache, state
+
+
+def jit_step(model, dcfg):
+    """The model's decode step as the replica runs it: jitted, the cache
+    arrays (and a slot state's two, which follow ``lengths``) donated,
+    every slot's greedy pick made where the logits are."""
+    model_step = model.decode_step
+    block_size = dcfg.block_size
+    attention_kernel = dcfg.attention_kernel
+    stateful = model.decode_state_shape is not None
+
+    # a model with per-token routed layers also says how many
+    # (token, expert) pairs each expert held here took of the step's
+    # tokens, [routed_layers, held]: fetched with the greedy tokens
+    counts = {"return_counts": True} if model.decode_counts else {}
+
+    # a named function, not a functools.partial: a profiler trace
+    # calls the program `jit_decode_step`, a partial `jit__unknown`
+    def decode_step(params, tokens, positions, k_cache, v_cache,
+                    block_tables, lengths, *state):
+        logits, k_cache, v_cache, *rest = model_step(
+            params, tokens, positions, k_cache, v_cache, block_tables,
+            lengths, *state, block_size=block_size,
+            attention_kernel=attention_kernel, **counts)
+        # the greedy pick of every slot, made where the logits are:
+        # 4 bytes a slot to fetch, not a [slots, vocab] float32 array
+        with jax.named_scope("head"):
+            greedy = sample_token(logits)
+        return logits, greedy, k_cache, v_cache, *rest
+
+    # the cache arrays are rebound to the step's outputs at every
+    # call site, so they are donated: the token's scatter writes in
+    # place; so is a slot state
+    return jax.jit(decode_step,
+                   donate_argnums=(3, 4, 7, 8) if stateful else (3, 4))
+
+
+def run_prefill(prefill_jit, stateful: bool, params, prompt: np.ndarray,
+                bucket: int):
+    """The prompt padded to its bucket through the model's prefill
+    export: the logits of its last real position and everything the
+    export returned. Attention ignores the padding; a state that is a
+    sequence's does not, so such a model's export is told the prompt's
+    length, hands over the state of its last real token and computes
+    that position's logits alone."""
+    toks = np.zeros((1, bucket), np.int32)
+    toks[0, :prompt.size] = prompt
+    if stateful:
+        outs = prefill_jit(params, jnp.asarray(toks),
+                           jnp.asarray([prompt.size], jnp.int32))
+        return outs[0][0, 0], outs
+    outs = prefill_jit(params, jnp.asarray(toks))
+    return outs[0][0, prompt.size - 1], outs
+
+
+def store_prompt(cache: PagedKVCache, state: SlotState | None, slot: int,
+                 table: np.ndarray, outs: tuple, plen: int) -> None:
+    """What a prefill hands over into the stores: every attending
+    layer's rows through the sequence's block table, and the slot's
+    state whole (nothing of what the slot held before is read)."""
+    cache.write_prompt(table, outs[1][:, 0], outs[2][:, 0], plen)
+    if state is not None:
+        state.write(slot, outs[3], outs[4])
 
 
 class _DecodeSeq(_Pending):
@@ -174,63 +288,19 @@ class DecodeReplica(ServingReplica):
         from ..core.config import effective_model_config
         dtype = jnp.dtype(
             effective_model_config(self.cfg, serving=True).compute_dtype)
-        layers, heads, head_dim = self.model.decode_cache_shape
-        # rows as wide as the device stores whole (kv_cache.py): the
-        # step and the prompt's scatter then take the arrays as they
-        # lie. One width for keys and values a head; one each where the
-        # model keeps a pair of rows a token (a latent, a rotated key)
-        shapes = cache_shapes(layers, self.dcfg.num_blocks,
-                              self.dcfg.block_size, heads, head_dim)
-        if isinstance(head_dim, tuple):
-            head_dim = tuple(stored_head_dim(shape, dtype,
-                                             self.topo.replicated)
-                             for shape in shapes)
-        else:
-            head_dim = stored_head_dim(shapes[0], dtype,
-                                       self.topo.replicated)
-        self.cache = PagedKVCache(
-            layers, self.dcfg.num_blocks, self.dcfg.block_size,
-            heads, head_dim, self.dcfg.max_blocks_per_seq(), dtype=dtype)
-        # placed where the weights are, once (no copy): a fresh
-        # `jnp.zeros` is committed to no device, a jitted call is
-        # compiled again for an argument placed differently, and every
-        # later call takes the arrays a jitted call returned
-        self.cache.k, self.cache.v = self.topo.device_put_replicated(
-            (self.cache.k, self.cache.v))
-        # the block-table widths an iteration chooses from: the quarters
-        # of the full table (fewer where a toy table has under four
-        # blocks). Each is a compiled program and a load at start-up
-        full = self.cache.max_blocks_per_seq
-        self._table_widths = sorted({-(-full * k // 4) for k in (1, 2, 3, 4)})
+        self._stateful = self.model.decode_state_shape is not None
+        if self._stateful and self.tp_ranks > 1:
+            raise ConfigError(
+                f"serve.tp_ranks={self.tp_ranks}: a model with state-space "
+                "layers is served whole on one chip; its per-slot state "
+                "(servesvc/kv_cache.py::SlotState) has no rule that "
+                "splits it over a model axis")
+        self.cache, self.state = build_stores(
+            self.model, self.dcfg, dtype, self.topo.replicated,
+            self.topo.device_put_replicated)
+        self._table_widths = table_widths(self.cache.max_blocks_per_seq)
         self._prefill_jit = jax.jit(self.model.decode_prefill)
-        model_step = self.model.decode_step
-        block_size = self.dcfg.block_size
-        attention_kernel = self.dcfg.attention_kernel
-
-        # a model with per-token routed layers also says how many
-        # (token, expert) pairs each expert held here took of the step's
-        # tokens, [routed_layers, held]: fetched with the greedy tokens
-        counts = ({"return_counts": True} if self.model.decode_counts
-                  else {})
-
-        # a named function, not a functools.partial: a profiler trace
-        # calls the program `jit_decode_step`, a partial `jit__unknown`
-        def decode_step(params, tokens, positions, k_cache, v_cache,
-                        block_tables, lengths):
-            logits, k_cache, v_cache, *pairs = model_step(
-                params, tokens, positions, k_cache, v_cache, block_tables,
-                lengths, block_size=block_size,
-                attention_kernel=attention_kernel, **counts)
-            # the greedy pick of every slot, made where the logits are:
-            # 4 bytes a slot to fetch, not a [slots, vocab] float32 array
-            with jax.named_scope("head"):
-                greedy = sample_token(logits)
-            return logits, greedy, k_cache, v_cache, *pairs
-
-        # the cache arrays are rebound to the step's outputs at every
-        # call site, so they are donated: the token's scatter writes in
-        # place
-        self._decode_jit = jax.jit(decode_step, donate_argnums=(3, 4))
+        self._decode_jit = jit_step(self.model, self.dcfg)
         # {table width: the executable `_warm_up` compiled from it}
         self._steps: dict[int, jax.stages.Compiled] = {}
         # decode-loop-owned state (single writer: the batcher thread)
@@ -376,6 +446,10 @@ class DecodeReplica(ServingReplica):
         # decode_finish must time the post-restart one (matching what
         # the client-side loadgen measures after its reset)
         s.first_token_at = None
+        if self.state is not None:
+            # nothing the old weights left in the slot stays readable,
+            # whatever the re-prefill then writes
+            self.state.reset(self._slots.index(s))
         self._prefill(s, restart=True)
 
     def _pressure_fields(self) -> dict:
@@ -398,6 +472,8 @@ class DecodeReplica(ServingReplica):
                 "loop_s": {k: round(v, 6)
                            for k, v in self._clock.seconds.items()},
                 "loop_wall_s": round(self._clock.wall_s(), 6),
+                **({} if self.state is None
+                   else {"state_resets": self.state.resets}),
                 **self._routing_fields()}
 
     def _routing_fields(self) -> dict:
@@ -431,7 +507,7 @@ class DecodeReplica(ServingReplica):
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._slots[i] = None
-                self.cache.free_sequence(s.block_table)
+                self._free_stores(i, s)
                 self._reject(s.conn, s.req_id, "shutting_down",
                              admitted=True)
         while self._waiting:
@@ -504,9 +580,18 @@ class DecodeReplica(ServingReplica):
             s.sample_seed = self._seq_counter
             self._seq_counter += 1
             self._slots[free] = s
+            if self.state is not None:
+                self.state.alloc(free)
             self._bump_tables_epoch()
             return s
         return None
+
+    def _free_stores(self, slot: int, s: _DecodeSeq) -> None:
+        """What a sequence held, given back: its blocks to the pool and
+        its slot's state zeroed."""
+        self.cache.free_sequence(s.block_table)
+        if self.state is not None:
+            self.state.free(slot)
 
     def _prefill(self, s: _DecodeSeq, restart: bool = False) -> None:
         """Run the prompt through the model's prefill export (the
@@ -521,17 +606,18 @@ class DecodeReplica(ServingReplica):
                 spans.SERVE_PREFILL, id=s.req_id, prompt_len=plen,
                 bucket=bucket, queue_ms=queue_ms)):
             with spans.span(spans.SERVE_PREFILL_FORWARD):
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :plen] = s.inputs
-                logits, ks, vs = self._prefill_jit(
-                    self._params_for(s.params_step), jnp.asarray(toks))
+                row, outs = run_prefill(
+                    self._prefill_jit, self._stateful,
+                    self._params_for(s.params_step), s.inputs, bucket)
             with spans.span(spans.SERVE_PREFILL_CACHE_WRITE):
-                self.cache.write_prompt(s.block_table, ks[:, 0], vs[:, 0],
-                                        plen)
+                t_write = time.time()
+                store_prompt(self.cache, self.state, slot, s.block_table,
+                             outs, plen)
+                write_ms = round((time.time() - t_write) * 1e3, 3)
             s.length = plen
             # waits for the prefill on the device
             with spans.span(spans.SERVE_SAMPLE, id=s.req_id, slot=slot):
-                tok = self._sample(s, logits[0, plen - 1])
+                tok = self._sample(s, row)
             s.tokens.append(tok)
             self._stream_token(s, tok)
             prefill_ms = round((time.time() - t0) * 1e3, 3)
@@ -545,6 +631,10 @@ class DecodeReplica(ServingReplica):
                    # the journal (benchmark/lib/serving.py) still ask
                    # for it
                    "ttft_ms": prefill_ms}
+            if self.state is not None:
+                # the host's part of both writes (rows and slot state):
+                # the dispatches, not the device's time
+                rec["state_write_ms"] = write_ms
             if restart:
                 rec["restart"] = True
             else:
@@ -644,7 +734,13 @@ class DecodeReplica(ServingReplica):
                 logits, greedy, self.cache.k, self.cache.v, *pairs = (
                     self._step(width)(
                         self._params_for(ver), tokens, positions,
-                        self.cache.k, self.cache.v, tables, lengths))
+                        self.cache.k, self.cache.v, tables, lengths,
+                        *self._state_arrays()))
+                if self.state is not None:
+                    # taken back advanced; a slot not of this version
+                    # (length 0) as it was
+                    self.state.state, self.state.tail = pairs
+                    pairs = []
                 self.decode_steps += 1
                 self.decode_table_blocks = width
             with self._clock.phase("fetch",
@@ -741,7 +837,7 @@ class DecodeReplica(ServingReplica):
             self._dedup_put(s.req_id, payload)
             self._respond(s.conn, payload)
             self._slots[i] = None
-            self.cache.free_sequence(s.block_table)
+            self._free_stores(i, s)
             self._bump_tables_epoch()
             self._release_version(s.params_step)
             self.sequences_finished += 1
@@ -786,11 +882,34 @@ class DecodeReplica(ServingReplica):
             tables = jnp.asarray(np.zeros((num_slots, width), np.int32))
             self._steps[width] = self._decode_jit.lower(
                 self._params, idle, idle, self.cache.k, self.cache.v,
-                tables, idle).compile()
-            _, greedy, self.cache.k, self.cache.v, *_ = self._steps[width](
-                self._params, idle, idle, self.cache.k, self.cache.v,
-                tables, idle)
+                tables, idle, *self._state_arrays()).compile()
+            _, greedy, self.cache.k, self.cache.v, *rest = (
+                self._steps[width](
+                    self._params, idle, idle, self.cache.k, self.cache.v,
+                    tables, idle, *self._state_arrays()))
+            if self.state is not None:
+                self.state.state, self.state.tail = rest
         jax.block_until_ready(greedy)
+
+    def _state_arrays(self) -> tuple:
+        """What the step takes beside the cache: a slot state's two
+        arrays, or nothing."""
+        return () if self.state is None else self.state.arrays
+
+    def _state_said(self) -> dict:
+        """Of a model whose state is a sequence's, for ``decode_start``:
+        the shapes of a layer's two arrays ([slots, N, E] float32 and
+        [K - 1, slots, E]: the layout) and how many layers have such a
+        pair, what one sequence's state takes, what all of them take on
+        the device, and the key-value heads the paged rows hold."""
+        if self.state is None:
+            return {}
+        return {"state_arrays": [list(a[0].shape)
+                                 for a in self.state.arrays],
+                "state_layers": len(self.state.state),
+                "state_slot_bytes": self.state.slot_bytes(),
+                "state_device_bytes": self.state.device_bytes(),
+                "kv_heads": self.model.decode_cache_shape[1]}
 
     def _cache_said(self) -> dict:
         """How the cache lies on the device and what each width's step
@@ -845,4 +964,91 @@ class DecodeReplica(ServingReplica):
                        "table_widths": self._table_widths,
                        "swap_policy": self.dcfg.swap_policy,
                        "model_step": self.model_step,
-                       **self._cache_said()})
+                       **self._cache_said(), **self._state_said()})
+
+
+class SlotSession:
+    """One sequence in slot 0 of stores built as :class:`DecodeReplica`
+    builds its own (:func:`build_stores`), admitted and stepped through
+    what its loop admits and steps a sequence through
+    (:func:`run_prefill`, :func:`store_prompt`, :func:`jit_step` at the
+    narrowest table width that holds the position): the decode session of
+    a model whose state is a sequence's (``SessionModel.decode_session``;
+    benchmark/lib/cell.py has the contract the serving check drives it
+    by). Every other slot idle, as in a replica with one request.
+
+    A state that is a sequence's is advanced by a step, not rewritten:
+    the session keeps what slot 0 held before its last step, and a step
+    asked again at that position starts from it, so that it leaves what
+    the first left."""
+
+    def __init__(self, model, params, dcfg, cache_dtype):
+        self.model, self.params, self.dcfg = model, params, dcfg
+        at = jax.tree.leaves(params)[0].sharding
+        self.cache, self.state = build_stores(
+            model, dcfg, jnp.dtype(cache_dtype), at,
+            lambda tree: jax.device_put(tree, at))
+        self._widths = table_widths(self.cache.max_blocks_per_seq)
+        self._prefill = jax.jit(model.decode_prefill)
+        self._step = jit_step(model, dcfg)
+        self._steps: dict = {}           # {table width: the executable}
+        self._table = None
+        self._stepped = None     # (position, slot 0's state before it)
+        self._slot0 = jax.jit(lambda state, tail: (
+            jnp.stack([s[:1] for s in state]),
+            jnp.stack([t[:, :1] for t in tail])))
+        self.said = {
+            "session": "slot_state",
+            "attention_arm": decode_attention_arm(dcfg.attention_kernel,
+                                                  self.cache.k.shape),
+            "cache_arrays": [list(self.cache.k.shape),
+                             list(self.cache.v.shape)],
+            "state_arrays": [list(a[0].shape) for a in self.state.arrays],
+            "state_layers": len(self.state.state)}
+
+    def prefill(self, prompt, return_routing: bool = False):
+        if return_routing:
+            raise NotImplementedError("nothing is routed in this model")
+        prompt = np.asarray(prompt, np.int32)
+        n = int(prompt.size)
+        row, outs = run_prefill(
+            self._prefill, True, self.params, prompt,
+            ServingReplica._bucket(n, self.dcfg.max_prompt_len))
+        if self._table is None:
+            # every block the sequence can need, as an admission does
+            self._table = self.cache.alloc_sequence(
+                n + self.dcfg.max_new_tokens)
+            self.state.alloc(0)
+        store_prompt(self.cache, self.state, 0, self._table, outs, n)
+        self._stepped = None
+        return row
+
+    def step(self, token: int, position: int, return_routing: bool = False):
+        if return_routing:
+            raise NotImplementedError("nothing is routed in this model")
+        if self._stepped is not None and self._stepped[0] == position:
+            self.state.write(0, *self._stepped[1])
+        else:
+            self._stepped = (position, self._slot0(*self.state.arrays))
+        slots = self.dcfg.decode_slots
+        width = next(w for w in self._widths
+                     if w * self.cache.block_size >= position + 1)
+        vec = lambda v: jnp.zeros(  # noqa: E731
+            (slots,), jnp.int32).at[0].set(v)
+        tables = np.zeros((slots, width), np.int32)
+        tables[0] = self._table[:width]
+        inputs = (vec(token), vec(position), self.cache.k, self.cache.v,
+                  jnp.asarray(tables), vec(position + 1),
+                  *self.state.arrays)
+        if width not in self._steps:
+            self._steps[width] = self._step.lower(self.params,
+                                                  *inputs).compile()
+            self.said["table_blocks"] = width
+            m = self._steps[width].memory_analysis()
+            if m is not None:
+                self.said["step_compiled_bytes"] = int(
+                    m.argument_size_in_bytes + m.output_size_in_bytes
+                    + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        (logits, _, self.cache.k, self.cache.v, self.state.state,
+         self.state.tail) = self._steps[width](self.params, *inputs)
+        return logits[0]

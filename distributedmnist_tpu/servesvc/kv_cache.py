@@ -45,6 +45,14 @@ Allocation policy: admission reserves EVERY block a sequence can need
 (prompt + max_new_tokens) up front, so an admitted sequence always
 runs to completion — block pressure defers admission (the request
 waits, bounded by its deadline), it never kills a running generation.
+
+**State that is a sequence's and not a token's** lives beside the paged
+arrays in :class:`SlotState`: a model with state-space layers
+(ops/ssm.py) keeps of a sequence, a such layer, one recurrent state and
+the last inputs of its convolution, the same bytes however long the
+sequence. No block table: a decode slot owns its slice of both arrays
+whole from admission to finish. The paged cache then holds the rows of
+the layers that attend, and those only.
 """
 
 from __future__ import annotations
@@ -240,3 +248,108 @@ class PagedKVCache:
             ks.append(k[:, b, o])
             vs.append(v[:, b, o])
         return np.stack(ks, axis=1), np.stack(vs, axis=1)
+
+
+@jax.named_scope("state_write")
+def write_slot_state(state: tuple, tail: tuple, new_state: jax.Array,
+                     new_tail: jax.Array, slot: jax.Array, *,
+                     row: int = 0) -> tuple[tuple, tuple]:
+    """One slot's slice of every layer's two arrays replaced: ``state`` a
+    layer ``[slots, N, E]``, ``tail`` a layer ``[K - 1, slots, E]``;
+    ``new_state`` [layers, batch, N, E] and ``new_tail`` [layers, K - 1,
+    batch, E] as a prefill hands them over, of which sequence ``row`` is
+    taken. Nothing of what the slot held is read."""
+    state = tuple(jax.lax.dynamic_update_slice(
+        s, new_state[i, row][None].astype(s.dtype), (slot, 0, 0))
+        for i, s in enumerate(state))
+    tail = tuple(jax.lax.dynamic_update_slice(
+        t, new_tail[i, :, row][:, None].astype(t.dtype), (0, slot, 0))
+        for i, t in enumerate(tail))
+    return state, tail
+
+
+class SlotState:
+    """The arrays a decode slot owns whole, beside the paged cache, ONE
+    PAIR A LAYER that has such a state: ``state[l]`` [slots, N, E]
+    float32, a state-space layer's recurrent state a slot, and
+    ``tail[l]`` [K - 1, slots, E] in the compute dtype, the inputs of its
+    convolution before the slot's next token (oldest first). The channels
+    are the minor dimension of both: what a step's elementwise update
+    runs along. An array a layer, not one stacked over the layers: a
+    layer's update is then one elementwise pass that writes where it
+    reads (the arrays donated), where a slice of a stacked array written
+    back costs a second pass over the state (measured: PERF.md, PR 43).
+    Float32 for the state whatever the model computes in: a recurrence
+    over thousands of steps.
+
+    A slot's life: :meth:`alloc` at admission (it holds zeros), then
+    :meth:`write` with what the prefill hands over, the step advancing it
+    in place (the arrays donated and rebound by the caller, as the cache's
+    are), :meth:`free` at finish. :meth:`reset` zeroes a slot: at free,
+    and before a restart's re-prefill. Functional arrays, single writer
+    (the decode loop thread)."""
+
+    def __init__(self, layers: int, slots: int, state_dim: int,
+                 channels: int, taps_before: int, dtype=jnp.float32,
+                 state_dtype=jnp.float32):
+        self.state = tuple(jnp.zeros((slots, state_dim, channels),
+                                     state_dtype) for _ in range(layers))
+        self.tail = tuple(jnp.zeros((taps_before, slots, channels), dtype)
+                          for _ in range(layers))
+        self._zeros = (jnp.zeros((layers, 1, state_dim, channels),
+                                 state_dtype),
+                       jnp.zeros((layers, taps_before, 1, channels), dtype))
+        self._owned: set[int] = set()
+        self.resets = 0
+        # the function itself, so that a trace calls the program
+        # `jit_write_slot_state`; the arrays donated: a slot is written
+        # where it lies
+        self._write = jax.jit(write_slot_state, donate_argnums=(0, 1),
+                              static_argnames="row")
+
+    @property
+    def arrays(self) -> tuple[tuple, tuple]:
+        return self.state, self.tail
+
+    def place(self, put) -> None:
+        """Every array (and the zeros a reset writes) through ``put``,
+        once: where the weights are, so that no later call is compiled
+        again for an argument placed differently."""
+        self.state, self.tail, self._zeros = put(
+            (self.state, self.tail, self._zeros))
+
+    def slot_bytes(self) -> int:
+        """Bytes one sequence's state takes, all layers."""
+        return sum(a.size * a.dtype.itemsize for a in self._zeros)
+
+    def device_bytes(self) -> int:
+        return sum(a.on_device_size_in_bytes()
+                   for a in (*self.state, *self.tail))
+
+    def alloc(self, slot: int) -> None:
+        if slot in self._owned:
+            raise ValueError(f"slot {slot} already owns its state")
+        self._owned.add(slot)
+
+    def write(self, slot: int, state: jax.Array, tail: jax.Array,
+              row: int = 0) -> None:
+        """Sequence ``row`` of a prefill's end state into the slot:
+        ``state`` [layers, batch, N, E], ``tail`` [layers, K - 1, batch,
+        E]."""
+        if slot not in self._owned:
+            raise ValueError(f"slot {slot} was not allocated")
+        self.state, self.tail = self._write(
+            self.state, self.tail, state, tail, jnp.asarray(slot, jnp.int32),
+            row=row)
+
+    def reset(self, slot: int) -> None:
+        self.state, self.tail = self._write(
+            self.state, self.tail, *self._zeros,
+            jnp.asarray(slot, jnp.int32))
+        self.resets += 1
+
+    def free(self, slot: int) -> None:
+        if slot not in self._owned:
+            raise ValueError(f"slot {slot} owns no state to free")
+        self.reset(slot)
+        self._owned.remove(slot)
