@@ -346,6 +346,15 @@ _declare(EventSchema(
               "decode_waiting", "slots_live", "decode_steps",
               "decode_table_blocks", "tokens_sampled_device",
               "tokens_sampled_host",
+              # the decode loop's deferred writes and inputs made ahead
+              # (servesvc/decode.py): lines queued at a fetch and written
+              # later, the flushes that wrote any by where the loop was
+              # ({"dispatch": after a step's dispatch returned, "prefill",
+              # "swap", "park", "stop": forced before it}), iterations
+              # that took the inputs built under the step before, and
+              # those that built them between two steps
+              "lines_deferred", "line_flushes", "step_inputs_ahead",
+              "step_inputs_rebuilt",
               # of the last decode step of a model that routes: the
               # (token, expert) pairs on experts held here, and how many
               # of those experts took any

@@ -81,8 +81,14 @@ SERVE_PREFILL_FORWARD = "dml.serve.prefill.forward"
 #: (`jit_write_prompt_kv`; prefill_cache_write_share_of_busy reads the
 #: program, this span says where the host waits for it)
 SERVE_PREFILL_CACHE_WRITE = "dml.serve.prefill.cache_write"
-#: `_step_active` up to the dispatch: the numpy vectors, their three
-#: uploads and `_tables_for`; read by: decode_gap_inputs_ms
+#: twice an iteration since PR 44. Before the dispatch (`_step_inputs`):
+#: taking the inputs made ahead and, where a slot drew its token, the
+#: tokens' one upload; or, after a finish, an admission, a restart or a
+#: swap, all of them built from the books (three uploads and
+#: `_tables_for`); this one lies between two steps and is what
+#: decode_gap_inputs_ms reads. After the dispatch and the flush
+#: (`_inputs_ahead`): the next step's positions, lengths and tables,
+#: uploaded while the chip runs this one; under a step, in no gap
 SERVE_STEP_INPUTS = "dml.serve.step.inputs"
 #: the call of the jitted decode step (live, waiting, version, blocks:
 #: the width of the block table it is handed): one per iteration and
@@ -97,16 +103,23 @@ SERVE_STEP_DISPATCH = "dml.serve.step.dispatch"
 #: part runs from its end to the next dispatch's start),
 #: trace_clock_offset_ms, decode_loop_host_share (a trace's fallback)
 SERVE_STEP_FETCH = "dml.serve.step.fetch"
-#: once an iteration (device, host): the pick-up of every slot's token,
-#: `_sample`'s draws for `temperature > 0` included; and once a prefill
-#: (id, slot): `_sample` on its last row, which waits for the prefill on
-#: the device; read by: decode_sample_ms_per_iter,
+#: once an iteration (device, host), after the fetch: the pick-up of
+#: every slot's token, `_sample`'s draws for `temperature > 0` included,
+#: and the books kept on it (appended, its line queued, a finish decided,
+#: its slot, blocks and state given back); nothing is written inside it.
+#: And once a prefill (id, slot): `_sample` on its last row, which waits
+#: for the prefill on the device; read by: decode_sample_ms_per_iter,
 #: serve_idle_sample_share, decode_gap_emit_ms
 SERVE_SAMPLE = "dml.serve.sample"
-#: `_stream_token` (id): one JSON line and one `sendall`; read by:
-#: decode_stream_ms_per_iter, decode_gap_emit_ms
+#: `_stream` (id): one JSON line and one `sendall`. A prefill's first
+#: token inside the prefill; every other line in `_flush`, after the NEXT
+#: step's dispatch returned (or before a prefill, a swap, a park), so
+#: between a dispatch and its fetch and in no gap between two plain
+#: steps; read by: decode_stream_ms_per_iter, decode_gap_emit_ms
 SERVE_STREAM = "dml.serve.stream"
-#: `_finish_seq` (id, reason): journal, terminal line, blocks freed;
+#: a finish's writes in `_flush` (id, reason): journal record, dedup
+#: entry, terminal line (the finish was decided, and its blocks freed,
+#: under the sample span of the fetch that brought its last token);
 #: read by: decode_gap_emit_ms
 SERVE_FINISH = "dml.serve.finish"
 #: the heartbeat's write, only when one is written (a request ended

@@ -59,6 +59,28 @@ with ``temperature <= 0`` takes its entry; one with ``temperature > 0``
 draws from its row of the logits, which stay on the device for it. The
 request decides, there is no setting.
 
+**Between a step's tokens and the next launch** the batcher does only
+what the launch needs. When the fetch returns it keeps the books, in
+order (the token picked and appended, a finish decided, its slot, blocks
+and state given back, the tables' epoch bumped): list operations, and
+what the next step and the next admission see. What is I/O (a token's
+line, a finish's journal record, dedup entry and answer) is queued in
+that order and written when the NEXT step's dispatch has returned, while
+the chip runs it; or sooner, before anything that would keep a line
+waiting: a prefill, a swap, a park on an idle queue, the drain on stop
+(``_flush``). A connection receives the bytes it always did, in the
+order it always did, a token's line up to one dispatch call later; that
+a client is gone is learnt at the write, so it finishes ``client_gone``
+one iteration later. Then, still under the running step, the next step's
+``positions``, ``lengths`` and tables are built and uploaded: they are
+this step's plus one and no token decides them. The next iteration takes
+them if the books are as they were predicted (same version, same table
+epoch, every slot's length the expected one), with this step's own
+``greedy`` array, as it lies on the device, for its tokens where every
+live slot is greedy; else it builds all of them as before
+(``_step_inputs``). The heartbeat counts both paths and the flushes by
+where they happened.
+
 **Weight swaps mid-generation.** The checkpoint follower stages
 digest-verified publishes exactly as the classification replica does;
 the flip happens at a decode-loop boundary under a declared policy
@@ -101,6 +123,7 @@ import json
 import queue
 import re
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +145,11 @@ from .server import ServingReplica, _Pending
 #: the loop's `other`
 LOOP_PHASES = ("idle", "admit", "prefill", "inputs", "dispatch", "fetch",
                "emit")
+#: where the queued lines are written: after a step's dispatch returned
+#: (while the chip runs it), or forced, before what would keep them
+#: waiting; the heartbeat's `line_flushes` counts the flushes that found
+#: something to write, by these
+FLUSH_POINTS = ("dispatch", "prefill", "swap", "park", "stop")
 
 
 def while_loops(compiled_text: str) -> int:
@@ -227,6 +255,17 @@ def store_prompt(cache: PagedKVCache, state: SlotState | None, slot: int,
     cache.write_prompt(table, outs[1][:, 0], outs[2][:, 0], plen)
     if state is not None:
         state.write(slot, outs[3], outs[4])
+
+
+class _Ahead(NamedTuple):
+    """A step's inputs built while the step before it ran, and the books
+    they hold for: (version, tables' epoch, [(slot, length)])."""
+    books: tuple
+    width: int
+    positions: jax.Array
+    tables: jax.Array
+    lengths: jax.Array
+    greedy: jax.Array      # the step before's own picks, on the device
 
 
 class _DecodeSeq(_Pending):
@@ -338,6 +377,20 @@ class DecodeReplica(ServingReplica):
         self._tables_cache: dict[tuple[int, int, int], jax.Array] = {}
         self.table_uploads = 0
         self.table_upload_reuses = 0
+        # decided and not yet written, in the order decided: (sequence,
+        # its token's line, None) or (sequence, None, its finish's
+        # journal fields); `_flush` writes them
+        self._deferred: list[tuple] = []
+        self.lines_deferred = 0
+        self.flushes = dict.fromkeys(FLUSH_POINTS, 0)
+        # the next step's inputs, built while this one runs
+        self._ahead: _Ahead | None = None
+        # whether a step takes its tokens as the step before returned
+        # them (`_warm_up` compares the two placements of a compiled
+        # step; a jitted one moves what it is given)
+        self._feed_greedy = True
+        self.step_inputs_ahead = 0
+        self.step_inputs_rebuilt = 0
 
     # -- admission ------------------------------------------------------
 
@@ -400,6 +453,7 @@ class DecodeReplica(ServingReplica):
             staged, self._staged = self._staged, None
         if staged is None:
             return
+        self._flush("swap")     # a restart's marker follows its lines
         with spans.span(spans.SERVE_SWAP):
             self._swap(*staged)
 
@@ -469,6 +523,10 @@ class DecodeReplica(ServingReplica):
                 "decode_table_blocks": self.decode_table_blocks,
                 "tokens_sampled_device": self.tokens_sampled_device,
                 "tokens_sampled_host": self.tokens_sampled_host,
+                "lines_deferred": self.lines_deferred,
+                "line_flushes": dict(self.flushes),
+                "step_inputs_ahead": self.step_inputs_ahead,
+                "step_inputs_rebuilt": self.step_inputs_rebuilt,
                 "loop_s": {k: round(v, 6)
                            for k, v in self._clock.seconds.items()},
                 "loop_wall_s": round(self._clock.wall_s(), 6),
@@ -501,9 +559,11 @@ class DecodeReplica(ServingReplica):
             self._admit_new()
             self._step_active()
             self._maybe_heartbeat()
-        # graceful drain: in-flight generations, deferred admissions
-        # and everything still queued get a TYPED terminal — a
-        # stopping replica sheds, it never silently drops
+        # graceful drain: what was decided is written first; then
+        # in-flight generations, deferred admissions and everything
+        # still queued get a TYPED terminal — a stopping replica sheds,
+        # it never silently drops
+        self._flush("stop")
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._slots[i] = None
@@ -531,6 +591,8 @@ class DecodeReplica(ServingReplica):
                 and all(s is None for s in self._slots))
         phase, name = (("idle", spans.SERVE_IDLE) if idle
                        else ("admit", spans.SERVE_ADMIT))
+        if idle:
+            self._flush("park")
         with self._clock.phase(phase, spans.span(name)):
             try:
                 # idle: park briefly on the queue instead of spinning.
@@ -597,6 +659,7 @@ class DecodeReplica(ServingReplica):
         """Run the prompt through the model's prefill export (the
         configured attention kernel), seed the paged cache, and sample
         + stream the first token."""
+        self._flush("prefill")  # no line waits behind a prefill
         t0 = time.time()
         queue_ms = round((t0 - s.admitted_at) * 1e3, 3)
         slot = self._slots.index(s)
@@ -619,7 +682,8 @@ class DecodeReplica(ServingReplica):
             with spans.span(spans.SERVE_SAMPLE, id=s.req_id, slot=slot):
                 tok = self._sample(s, row)
             s.tokens.append(tok)
-            self._stream_token(s, tok)
+            # written here and now: the first token waits for nothing
+            self._stream(s, self._token_line(s, tok))
             prefill_ms = round((time.time() - t0) * 1e3, 3)
             rec = {"action": "prefill", "id": s.req_id, "prompt_len": plen,
                    "bucket": bucket,
@@ -683,24 +747,80 @@ class DecodeReplica(ServingReplica):
         self.table_uploads += 1
         return dev
 
-    def _table_width(self, mine) -> int:
+    def _table_width(self, mine, ahead: int = 0) -> int | None:
         """The narrowest of ``_table_widths`` that holds every one of
         these sequences AND the position the step is about to write:
         ``s.length + 1``, since this token's K/V goes to position
         ``s.length`` through ``block_tables[i, s.length // block_size]``,
         which has to lie inside the table the step is given. Read from
         the current lengths, so it narrows again when a long sequence
-        finishes."""
-        need = max(s.length for _, s in mine) + 1
-        return next(w for w in self._table_widths
-                    if w * self.cache.block_size >= need)
+        finishes. ``ahead``: for the step after ``ahead`` more tokens
+        (None where no table holds that: the sequence ends first)."""
+        need = max(s.length for _, s in mine) + ahead + 1
+        return next((w for w in self._table_widths
+                     if w * self.cache.block_size >= need), None)
+
+    def _slot_vector(self, mine, value) -> jax.Array:
+        """``value(sequence)`` at each of these sequences' slots and 0 at
+        every other, as an ``int32[slots]`` array on the device: one
+        upload."""
+        vec = np.zeros((self.dcfg.decode_slots,), np.int32)
+        for i, s in mine:
+            vec[i] = value(s)
+        return jnp.asarray(vec)
+
+    def _step_inputs(self, ver: int, mine) -> tuple:
+        """(width, tokens, positions, tables, lengths) for one version's
+        step. What `_inputs_ahead` made while the step before ran, if
+        the books are as it expected them (the version, the tables'
+        epoch, every slot's length: a finish, an admission, a restart or
+        a swap changes one of them) and then the tokens alone are new:
+        the step's own greedy array where every sequence took its entry,
+        else one upload. Otherwise all of it from the books as they
+        are."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None and ahead.books == (
+                ver, self._tables_epoch, [(i, s.length) for i, s in mine]):
+            self.step_inputs_ahead += 1
+            tokens = ahead.greedy
+            if not (self._feed_greedy
+                    and all(s.temperature <= 0.0 for _, s in mine)):
+                tokens = self._slot_vector(mine, lambda s: s.tokens[-1])
+            return (ahead.width, tokens, ahead.positions, ahead.tables,
+                    ahead.lengths)
+        self.step_inputs_rebuilt += 1
+        width = self._table_width(mine)
+        tokens = self._slot_vector(mine, lambda s: s.tokens[-1])
+        positions = self._slot_vector(mine, lambda s: s.length)
+        tables = self._tables_for(ver, mine, self.dcfg.decode_slots, width)
+        lengths = self._slot_vector(mine, lambda s: s.length + 1)
+        return width, tokens, positions, tables, lengths
+
+    def _inputs_ahead(self, ver: int, mine, greedy: jax.Array) -> None:
+        """While the step just dispatched runs: what the step after it
+        takes that this one's tokens do not decide, for the books as
+        they will be if this one ends no sequence (each a token longer,
+        nobody admitted). Nothing rests on the guess: `_step_inputs`
+        compares the books."""
+        width = self._table_width(mine, ahead=1)
+        if width is None:
+            return
+        self._ahead = _Ahead(
+            (ver, self._tables_epoch, [(i, s.length + 1) for i, s in mine]),
+            width,
+            self._slot_vector(mine, lambda s: s.length + 1),
+            self._tables_for(ver, mine, self.dcfg.decode_slots, width),
+            self._slot_vector(mine, lambda s: s.length + 2),
+            greedy)
 
     def _step_active(self) -> None:
         """One decode iteration: a single compiled step per live param
         version over the fixed slot shape, at the table width its
-        longest sequence needs, its greedy tokens fetched as one array,
-        then per-slot stream / finish — a finished slot is free for the
-        NEXT iteration's refill."""
+        longest sequence needs; while it runs, the lines the iteration
+        before decided are written and the next step's inputs built;
+        then its greedy tokens fetched as one array and the books kept
+        per slot — a finished slot is free for the NEXT iteration's
+        refill."""
         now = time.time()
         for i, s in enumerate(self._slots):
             if s is not None and now >= s.deadline_at:
@@ -709,25 +829,16 @@ class DecodeReplica(ServingReplica):
                   if s is not None]
         if not active:
             return
-        num_slots = self.dcfg.decode_slots
         # pin policy: at most a handful of live versions — one compiled
         # step per version, idle-for-this-version slots masked via the
         # null block table + zero length
-        for ver in sorted({s.params_step for _, s in active}):
+        versions = sorted({s.params_step for _, s in active})
+        for ver in versions:
+            mine = [(i, s) for i, s in active if s.params_step == ver]
             with self._clock.phase("inputs",
                                    spans.span(spans.SERVE_STEP_INPUTS)):
-                mine = [(i, s) for i, s in active if s.params_step == ver]
-                width = self._table_width(mine)
-                tokens = np.zeros((num_slots,), np.int32)
-                positions = np.zeros((num_slots,), np.int32)
-                lengths = np.zeros((num_slots,), np.int32)
-                for i, s in mine:
-                    tokens[i] = s.tokens[-1]
-                    positions[i] = s.length
-                    lengths[i] = s.length + 1
-                tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
-                tables = self._tables_for(ver, mine, num_slots, width)
-                lengths = jnp.asarray(lengths)
+                width, tokens, positions, tables, lengths = (
+                    self._step_inputs(ver, mine))
             with self._clock.phase("dispatch", spans.span(
                     spans.SERVE_STEP_DISPATCH, live=len(active),
                     waiting=len(self._waiting), version=ver, blocks=width)):
@@ -743,28 +854,38 @@ class DecodeReplica(ServingReplica):
                     pairs = []
                 self.decode_steps += 1
                 self.decode_table_blocks = width
+            # the chip is busy from here to the fetch: what the fetch
+            # before decided goes out, and the next step's inputs are
+            # made (several versions a step each: the next step is not
+            # this one's successor)
+            self._flush("dispatch")
+            if len(versions) == 1:
+                with self._clock.phase("inputs",
+                                       spans.span(spans.SERVE_STEP_INPUTS)):
+                    self._inputs_ahead(ver, mine, greedy)
             with self._clock.phase("fetch",
                                    spans.span(spans.SERVE_STEP_FETCH)):
                 on_host = jax.device_get(greedy)
             if pairs:
                 self._expert_pairs = pairs[0]
             draws = sum(s.temperature > 0.0 for _, s in mine)
-            # one pair of clock readings around the iteration's sample,
-            # stream and finish spans, not one a slot
-            with self._clock.phase("emit"):
-                # every slot's token before any is appended or streamed:
-                # one span an iteration, never around a `dml.serve.stream`
-                with spans.span(spans.SERVE_SAMPLE,
-                                device=len(mine) - draws, host=draws):
-                    picked = [int(on_host[i]) if s.temperature <= 0.0
-                              else self._sample(s, logits[i])
-                              for i, s in mine]
+            # every slot's token and what it decides, on the books alone:
+            # one span an iteration, nothing written inside it
+            with self._clock.phase("emit", spans.span(
+                    spans.SERVE_SAMPLE, device=len(mine) - draws,
+                    host=draws)):
+                on_host = on_host.tolist()
+                picked = [on_host[i] if s.temperature <= 0.0
+                          else self._sample(s, logits[i])
+                          for i, s in mine]
                 self.tokens_sampled_device += len(mine) - draws
                 for (i, s), tok in zip(mine, picked):
                     s.length += 1  # the fed token's K/V is now cached
                     s.tokens.append(tok)
-                    self._stream_token(s, tok)
+                    self._deferred.append((s, self._token_line(s, tok),
+                                           None))
                     self._maybe_finish(i, s)
+                self.lines_deferred += len(mine)
 
     def _sample(self, s: _DecodeSeq, logits_row: jax.Array) -> int:
         """One token from one row of logits on the device: a prefill's
@@ -788,15 +909,48 @@ class DecodeReplica(ServingReplica):
         except OSError:
             s.conn_dead = True  # finish early at the next check
 
-    def _stream_token(self, s: _DecodeSeq, tok: int) -> None:
+    def _token_line(self, s: _DecodeSeq, tok: int) -> dict:
+        """The line of the token just appended, counted as streamed."""
+        if s.first_token_at is None:
+            s.first_token_at = time.time()
+        self.tokens_streamed += 1
+        return {"id": s.req_id, "stream": "token", "token": int(tok),
+                "index": len(s.tokens) - 1, "model_step": s.params_step}
+
+    def _stream(self, s: _DecodeSeq, line: dict) -> None:
         with spans.span(spans.SERVE_STREAM, id=s.req_id):
-            if s.first_token_at is None:
-                s.first_token_at = time.time()
-            self.tokens_streamed += 1
-            self._send_line(s, {"id": s.req_id, "stream": "token",
-                                "token": int(tok),
-                                "index": len(s.tokens) - 1,
-                                "model_step": s.params_step})
+            self._send_line(s, line)
+
+    def _flush(self, point: str) -> None:
+        """Write what was decided and not yet written, in the order
+        decided: a token's line; a finish's journal record, dedup entry
+        and answer (exactly one terminal, after the sequence's last
+        token). ``point`` (of `FLUSH_POINTS`) says where the loop is,
+        for the count."""
+        if not self._deferred:
+            return
+        queued, self._deferred = self._deferred, []
+        self.flushes[point] += 1
+        with self._clock.phase("emit"):
+            for s, line, finish in queued:
+                if finish is None:
+                    self._stream(s, line)
+                    continue
+                with spans.span(spans.SERVE_FINISH, id=s.req_id,
+                                reason=finish["reason"]):
+                    self._terminal("decode_finish", s.req_id, **finish)
+                    payload = {
+                        "id": s.req_id, "status": "ok",
+                        "tokens": [int(t) for t in s.tokens],
+                        "finish_reason": finish["reason"],
+                        "model_step": s.params_step,
+                        "started_step": s.started_step}
+                    # idempotency: a mid-stream reset that ate this
+                    # terminal makes the retry a dedup hit carrying the
+                    # SAME completed tokens — the generation never runs
+                    # twice for one request id
+                    self._dedup_put(s.req_id, payload)
+                    self._respond(s.conn, payload)
 
     def _maybe_finish(self, i: int, s: _DecodeSeq) -> None:
         eos = self.dcfg.eos_token
@@ -810,37 +964,29 @@ class DecodeReplica(ServingReplica):
             self._finish_seq(i, s, "deadline")
 
     def _finish_seq(self, i: int, s: _DecodeSeq, reason: str) -> None:
-        """Exactly-one-terminal: journal the finish, send the final
-        line, free the blocks, release the slot (refillable this very
-        iteration) and drop the param version if this was its last
-        pinned sequence."""
-        with spans.span(spans.SERVE_FINISH, id=s.req_id, reason=reason):
-            now = time.time()
-            fields = {"reason": reason, "tokens_streamed": len(s.tokens),
-                      "model_step": s.params_step,
-                      "started_step": s.started_step,
-                      "latency_ms": round((now - s.admitted_at) * 1e3, 3)}
-            if s.first_token_at is not None:
-                fields["ttft_ms"] = round(
-                    (s.first_token_at - s.admitted_at) * 1e3, 3)
-            if s.restarts:
-                fields["restarts"] = s.restarts
-            self._terminal("decode_finish", s.req_id, **fields)
-            payload = {
-                "id": s.req_id, "status": "ok",
-                "tokens": [int(t) for t in s.tokens],
-                "finish_reason": reason, "model_step": s.params_step,
-                "started_step": s.started_step}
-            # idempotency: a mid-stream reset that ate this terminal makes
-            # the retry a dedup hit carrying the SAME completed tokens —
-            # the generation never runs twice for one request id
-            self._dedup_put(s.req_id, payload)
-            self._respond(s.conn, payload)
-            self._slots[i] = None
-            self._free_stores(i, s)
-            self._bump_tables_epoch()
-            self._release_version(s.params_step)
-            self.sequences_finished += 1
+        """Exactly-one-terminal: decide the finish now (its journal
+        record's fields as of now; the blocks freed, the slot released,
+        refillable this very iteration, the param version dropped if
+        this was its last pinned sequence) and queue what is written
+        (`_flush`: the journal record, the dedup entry, the final
+        line)."""
+        fields = {"reason": reason, "tokens_streamed": len(s.tokens),
+                  "model_step": s.params_step,
+                  "started_step": s.started_step,
+                  "latency_ms": round(
+                      (time.time() - s.admitted_at) * 1e3, 3)}
+        if s.first_token_at is not None:
+            fields["ttft_ms"] = round(
+                (s.first_token_at - s.admitted_at) * 1e3, 3)
+        if s.restarts:
+            fields["restarts"] = s.restarts
+        self._deferred.append((s, None, fields))
+        self.lines_deferred += 1
+        self._slots[i] = None
+        self._free_stores(i, s)
+        self._bump_tables_epoch()
+        self._release_version(s.params_step)
+        self.sequences_finished += 1
 
     # -- metadata / lifecycle -------------------------------------------
 
@@ -890,6 +1036,13 @@ class DecodeReplica(ServingReplica):
             if self.state is not None:
                 self.state.state, self.state.tail = rest
         jax.block_until_ready(greedy)
+        # a compiled step takes an argument only as it was compiled for:
+        # its own greedy tokens where they come back placed as its
+        # tokens go in (one chip: always)
+        self._feed_greedy = all(
+            step.input_shardings[0][1].is_equivalent_to(
+                step.output_shardings[1], 1)
+            for step in self._steps.values())
 
     def _state_arrays(self) -> tuple:
         """What the step takes beside the cache: a slot state's two

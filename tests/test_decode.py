@@ -432,6 +432,7 @@ def _drive_swap(lm_published, tmp_path, policy):
     assert rep.model_step == 20
     while rep._slots[0] is not None:
         rep._step_active()
+    rep._admit_new()    # the loop parks idle: what was decided is written
     return rep, conn
 
 
@@ -454,6 +455,7 @@ def test_swap_policy_pin_finishes_on_old_weights(lm_published, tmp_path):
     rep._admit_new()
     while rep._slots[0] is not None:
         rep._step_active()
+    rep._admit_new()
     assert conn2.lines[-1]["model_step"] == 20
     # the invariant replays green over the real journal
     assert _decode_swap_violations(rep, tmp_path / "pin_trial") == []
@@ -585,6 +587,7 @@ def test_streamed_tokens_are_the_parents_rule_on_the_same_logits(
         admit(late)
     while any(s is not None for s in rep._slots) or rep._queue.qsize():
         iteration()
+    rep._admit_new()    # parks idle: the last lines are written
 
     assert versions_live == ({1, 2} if case == "two_versions" else {1})
     if case == "two_versions":
@@ -604,6 +607,415 @@ def test_streamed_tokens_are_the_parents_rule_on_the_same_logits(
     # after its first is the step's own pick
     assert rep.tokens_sampled_device == sum(
         s.max_tokens - 1 for s, _ in seqs if s.temperature <= 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# between a step's tokens and the next launch: the books are kept at the
+# fetch, the lines written under the next step, the next step's inputs
+# built ahead (plain block, latent routed block, hybrid with slot state)
+# ---------------------------------------------------------------------------
+
+KINDS = ("plain", "latent", "hybrid")
+
+
+def _kind_model(kind: str) -> dict:
+    # the other two files import this one: asked for when first needed
+    if kind == "plain":
+        return dict(LM_MODEL)
+    if kind == "latent":
+        from test_latent_decode import LATENT
+        return dict(LATENT)
+    from test_slot_state import HYBRID
+    return dict(HYBRID)
+
+
+@pytest.fixture(scope="module")
+def kind_states():
+    """Seeded weights a kind and a seed, made once: (cfg, state)."""
+    from distributedmnist_tpu.core.config import ExperimentConfig
+    from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.parallel.api import init_train_state
+    made = {}
+
+    def state(kind: str, seed: int):
+        if (kind, seed) not in made:
+            cfg = ExperimentConfig.from_dict({
+                "model": {**_kind_model(kind), "init_seed": seed},
+                "train": {"seed": seed}})
+            made[kind, seed] = (cfg, init_train_state(get_model(cfg.model),
+                                                      cfg))
+        return made[kind, seed]
+    return state
+
+
+def kind_publish(kind_states, kind, train_dir, step: int, seed: int):
+    from distributedmnist_tpu.train.checkpoint import save_checkpoint
+    cfg, state = kind_states(kind, seed)
+    save_checkpoint(train_dir, state, step, extra={"config": cfg.to_dict()})
+    return cfg, state
+
+
+class RawConn:
+    """A socket double that keeps the bytes as sent and tells a shared
+    log of every line; ``dies_after`` lines it raises as a reset peer
+    does."""
+
+    def __init__(self, log: list, dies_after: int | None = None):
+        self.log, self.sent, self.dies_after = log, b"", dies_after
+        self.closed = 0
+
+    def settimeout(self, t):
+        pass
+
+    def gettimeout(self):
+        return None
+
+    def sendall(self, b):
+        if (self.dies_after is not None
+                and self.sent.count(b"\n") >= self.dies_after):
+            raise ConnectionResetError("the client is gone")
+        self.sent += b
+        line = json.loads(b)
+        self.log.append(("line", line["id"], line.get(
+            "index", line.get("stream") or line["status"])))
+
+    def close(self):
+        self.closed += 1
+
+
+def kind_replica(kind_states, kind, tmp_path, monkeypatch, policy="pin",
+                 slots=2):
+    """A replica of this kind, weights (seed 3, step 10) loaded, driven
+    from the test's thread; ``log`` says in order every prefill, every
+    step's dispatch returned, every fetch opened, every park, swap and
+    line, and whether anything was still queued when a prefill, a park
+    or a swap began."""
+    import jax
+
+    from distributedmnist_tpu.core.config import DecodeConfig, ServeConfig
+    from distributedmnist_tpu.servesvc import decode
+    cfg, state = kind_publish(kind_states, kind, tmp_path / "publish", 10, 3)
+    rep = decode.DecodeReplica(
+        tmp_path / "publish", serve_dir=tmp_path / "replica",
+        scfg=ServeConfig(poll_secs=0.05),
+        dcfg=DecodeConfig(decode_slots=slots, block_size=8, num_blocks=32,
+                          max_prompt_len=16, max_new_tokens=10,
+                          swap_policy=policy), cfg=cfg)
+    rep._load_initial()
+    log: list = []
+    step, prefill, install, get = (rep._decode_jit, rep._prefill_jit,
+                                   rep._install, rep._queue.get)
+
+    def stepped(*args):
+        out = step(*args)
+        log.append(("dispatch", [np.asarray(a) for a in (
+            args[1], args[2], args[5], args[6])]))
+        return out
+
+    def prefilled(*args):
+        log.append(("prefill", len(rep._deferred)))
+        return prefill(*args)
+
+    def installed(*args, **kwargs):
+        log.append(("swap", len(rep._deferred)))
+        return install(*args, **kwargs)
+
+    def parked(*args, timeout=None, **kwargs):
+        if timeout is not None:
+            log.append(("park", len(rep._deferred)))
+        return get(*args, timeout=timeout, **kwargs)
+
+    def fetched(x):
+        log.append(("fetch",))
+        return device_get(x)
+
+    device_get = jax.device_get
+    monkeypatch.setattr(decode.jax, "device_get", fetched)
+    rep._decode_jit, rep._prefill_jit = stepped, prefilled
+    rep._install, rep._queue.get = installed, parked
+    return rep, log, cfg, state
+
+
+def kind_admit(rep, log, req_id, prompt, max_tokens, dies_after=None,
+               **sampling):
+    conn = RawConn(log, dies_after)
+    seq = rep._build_item({"id": req_id, "prompt": prompt,
+                           "max_tokens": max_tokens, "deadline_ms": 600000,
+                           **sampling}, conn)
+    rep._journal({"action": "admit", "id": req_id, "deadline_ms": 600000.0})
+    rep._queue.put_nowait(seq)
+    return seq, conn
+
+
+def loop_once(rep):
+    rep._maybe_swap()
+    rep._admit_new()
+    rep._step_active()
+    rep._maybe_heartbeat()
+
+
+def drive_to_idle(rep, limit=60):
+    for _ in range(limit):
+        loop_once(rep)
+        if (all(s is None for s in rep._slots) and not rep._waiting
+                and not rep._queue.qsize()):
+            loop_once(rep)       # parks: what the last fetch decided goes
+            return
+    raise AssertionError("the replica never ran dry")
+
+
+def greedy_by_full_forward(cfg, state, prompt, n: int) -> list[int]:
+    """``n`` greedy tokens by the model's plain forward over the whole
+    context each time: no cache, no replica."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.models.registry import get_model
+    model, seq, out = get_model(cfg.model), list(prompt), []
+    with jax.default_matmul_precision("highest"):
+        for _ in range(n):
+            logits = model.apply(state.params, jnp.asarray([seq]))
+            out.append(int(jnp.argmax(logits[0, -1])))
+            seq.append(out[-1])
+    return out
+
+
+def parents_bytes(req_id, tokens, reason="max_tokens", step=10) -> bytes:
+    """What the tree before PR 44 sent a greedy request's connection
+    (its `_stream_token` and `_finish_seq`): one line a token, the
+    terminal last."""
+    lines = [{"id": req_id, "stream": "token", "token": t, "index": i,
+              "model_step": step} for i, t in enumerate(tokens)]
+    lines.append({"id": req_id, "status": "ok", "tokens": tokens,
+                  "finish_reason": reason, "model_step": step,
+                  "started_step": step})
+    return "".join(json.dumps(line) + "\n" for line in lines).encode()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_connection_receives_the_bytes_it_always_did(
+        kind_states, tmp_path, monkeypatch, kind):
+    """Three greedy requests on two slots: the third is admitted into
+    the slot a ``max_tokens`` finish freed, in the very next iteration;
+    every connection's bytes are the parent's, terminal last and once."""
+    rep, log, cfg, state = kind_replica(kind_states, kind, tmp_path,
+                                        monkeypatch)
+    asked = {"a": ([1, 2, 3], 3), "b": ([4, 5, 6, 7, 8], 7),
+             "c": ([9, 10], 4)}
+    conns = {rid: kind_admit(rep, log, rid, prompt, n)[1]
+             for rid, (prompt, n) in asked.items()}
+    loop_once(rep)                  # a and b prefilled and stepped once
+    loop_once(rep)                  # a's third token: its finish decided
+    assert rep._slots[0] is None and rep.sequences_finished == 1
+    assert b'"status"' not in conns["a"].sent       # decided, not written
+    rep._maybe_swap()
+    rep._admit_new()                # the very next admission takes the slot
+    assert rep._slots[0] is not None and rep._slots[0].req_id == "c"
+    assert conns["a"].sent.endswith(parents_bytes("a", [0])[-2:])
+    rep._step_active()
+    drive_to_idle(rep)
+    for rid, (prompt, n) in asked.items():
+        tokens = greedy_by_full_forward(cfg, state, prompt, n)
+        assert conns[rid].sent == parents_bytes(rid, tokens), rid
+        assert conns[rid].closed == 1
+    assert rep._deferred == []
+    fins = [r for r in serve_records(rep)
+            if r["action"] == "decode_finish"]
+    assert sorted(r["id"] for r in fins) == ["a", "b", "c"]
+    assert rep.lines_deferred == sum(n - 1 for _, n in asked.values()) + 3
+    assert rep.tokens_streamed == sum(n for _, n in asked.values())
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_tokens_line_goes_out_under_the_next_step(
+        kind_states, tmp_path, monkeypatch, kind):
+    """One request alone: the line of the token a fetch brought is
+    written after the NEXT step's dispatch returned and before its
+    fetch opens; the last one and the terminal when the loop parks."""
+    rep, log, _, _ = kind_replica(kind_states, kind, tmp_path, monkeypatch)
+    kind_admit(rep, log, "a", [3, 1, 4], 4)
+    drive_to_idle(rep)
+    parks = [i for i, e in enumerate(log) if e[0] == "park"]
+    order = [e if e[0] == "line" else e[0] for e in log[parks[0]:]]
+    assert order == [
+        "park",                                 # the request arrives
+        "prefill", ("line", "a", 0),            # first token: at once
+        "dispatch", "fetch",                    # brings token 1
+        "dispatch", ("line", "a", 1), "fetch",
+        "dispatch", ("line", "a", 2), "fetch",  # brings 3: max_tokens
+        ("line", "a", 3), ("line", "a", "ok"),  # about to park: written
+        "park"]
+    # nothing waited when the prefill or either park began
+    assert [e[1] for e in log if e[0] in ("prefill", "park")] == [0, 0, 0]
+    assert rep.flushes == {"dispatch": 2, "prefill": 0, "swap": 0,
+                           "park": 1, "stop": 0}
+    assert rep.lines_deferred == 4
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_vanished_client_finishes_one_iteration_later_and_once(
+        kind_states, tmp_path, monkeypatch, kind):
+    rep, log, _, _ = kind_replica(kind_states, kind, tmp_path, monkeypatch)
+    # takes lines 0 and 1, resets on line 2: the parent learnt it writing
+    # token 2 inside the iteration that picked it and finished with 3
+    # tokens; now token 2's line goes under the next step, whose fetch
+    # brings token 3 before the books say the client is gone
+    seq, conn = kind_admit(rep, log, "gone", [5, 6, 7], 9, dies_after=2)
+    _, other = kind_admit(rep, log, "stays", [8, 9], 6)
+    drive_to_idle(rep)
+    fins = [r for r in serve_records(rep) if r["action"] == "decode_finish"]
+    [gone] = [r for r in fins if r["id"] == "gone"]
+    assert gone["reason"] == "client_gone" and gone["tokens_streamed"] == 4
+    assert conn.sent.count(b"\n") == 2 and conn.closed == 1
+    [stays] = [r for r in fins if r["id"] == "stays"]
+    assert stays["reason"] == "max_tokens"
+    assert other.sent.count(b"\n") == 7 and other.closed == 1
+    assert all(s is None for s in rep._slots)
+    assert not rep.cache.allocator.in_use
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_nothing_is_queued_when_a_prefill_a_swap_a_restart_or_a_park_begins(
+        kind_states, tmp_path, monkeypatch, kind):
+    """And nothing after the drain on stop. Restart policy: the swap
+    re-prefills both live sequences, so its flush is a restart's too."""
+    rep, log, _, _ = kind_replica(kind_states, kind, tmp_path, monkeypatch,
+                                  policy="restart")
+    _, a = kind_admit(rep, log, "a", [1, 2, 3], 9)
+    _, b = kind_admit(rep, log, "b", [4, 5], 9)
+    loop_once(rep)
+    loop_once(rep)
+    assert len(rep._deferred) == 2          # the second fetch's two lines
+    kind_publish(kind_states, kind, tmp_path / "publish", 20, 4)
+    rep._staged = rep.follower.poll(rep._read_weights)[1:]
+    loop_once(rep)                          # swap: flush, restart both
+    assert rep.model_step == 20 and rep.flushes["swap"] == 1
+    _, c = kind_admit(rep, log, "c", [6, 7, 8], 3)   # waits for a slot
+    for _ in range(3):
+        loop_once(rep)
+    assert len(rep._deferred) == 2 and rep._waiting
+    rep._stop.set()
+    rep._batch_loop()                       # the drain alone
+    assert rep._deferred == [] and rep.flushes["stop"] == 1
+    # whatever began, began with nothing queued
+    began = [e for e in log if e[0] in ("prefill", "swap", "park")]
+    assert {e[0] for e in began} == {"prefill", "swap", "park"}
+    assert all(e[1] == 0 for e in began), began
+    assert len([e for e in began if e[0] == "prefill"]) == 4
+    # per connection: its lines in order, the restart marker after the
+    # lines of the old weights and before the new ones', then the typed
+    # terminal of a stopping replica, last and once
+    for conn, rid in ((a, "a"), (b, "b")):
+        lines = [json.loads(l) for l in conn.sent.splitlines()]
+        kinds = [l.get("stream") or l["status"] for l in lines]
+        assert kinds == (["token"] * 3 + ["restart"] + ["token"] * 5
+                         + ["rejected"]), (rid, kinds)
+        assert [l["index"] for l in lines if l.get("stream") == "token"] \
+            == [0, 1, 2, 0, 1, 2, 3, 4]
+        assert [l["model_step"] for l in lines[:-1]] == [10] * 3 + [20] * 6
+        assert lines[-1]["reason"] == "shutting_down" and conn.closed == 1
+    assert [json.loads(l)["reason"] for l in c.sent.splitlines()] \
+        == ["shutting_down"]
+
+
+def parents_inputs(rep, ver: int):
+    """What the tree before PR 44 handed one version's step, from the
+    books as they are now (its `_step_active`, the block under
+    `dml.serve.step.inputs`, and `_tables_for`): kept as the oracle."""
+    num_slots = rep.dcfg.decode_slots
+    mine = [(i, s) for i, s in enumerate(rep._slots)
+            if s is not None and s.params_step == ver]
+    width = rep._table_width(mine)
+    tokens = np.zeros((num_slots,), np.int32)
+    positions = np.zeros((num_slots,), np.int32)
+    lengths = np.zeros((num_slots,), np.int32)
+    tables = np.zeros((num_slots, width), np.int32)
+    for i, s in mine:
+        tokens[i] = s.tokens[-1]
+        positions[i] = s.length
+        lengths[i] = s.length + 1
+        tables[i] = s.block_table[:width]
+    return [i for i, _ in mine], [tokens, positions, tables, lengths]
+
+
+@pytest.mark.parametrize("kind, policy", [
+    ("plain", "pin"), ("plain", "restart"), ("latent", "pin"),
+    ("hybrid", "restart"), ("hybrid", "pin")])
+def test_the_step_is_handed_what_the_parent_built_ahead_or_not(
+        kind_states, tmp_path, monkeypatch, kind, policy):
+    """Through an admission, a finish, a draw, a swap (a restart, or a
+    second version pinned) every step's positions, tables and lengths
+    are the parent's arrays to the element, and its tokens at every live
+    slot (an idle slot's is the step's own pick where the array never
+    left the device); the counters say which iterations took the inputs
+    built ahead."""
+    rep, log, _, _ = kind_replica(kind_states, kind, tmp_path, monkeypatch,
+                                  policy=policy, slots=3)
+    paths, fed = [], []
+
+    def iteration(expect: str):
+        rep._maybe_swap()
+        rep._admit_new()
+        versions = sorted({s.params_step for s in rep._slots
+                           if s is not None})
+        want = [parents_inputs(rep, ver) for ver in versions]
+        before = (rep.step_inputs_ahead, rep.step_inputs_rebuilt, len(log))
+        rep._step_active()
+        rep._maybe_heartbeat()
+        got = [e[1] for e in log[before[2]:] if e[0] == "dispatch"]
+        assert len(got) == len(want)
+        for (live, arrays), handed in zip(want, got):
+            for name, a, b in zip(("tokens", "positions", "tables",
+                                   "lengths"), arrays, handed):
+                if name == "tokens":
+                    a, b = a[live], b[live]
+                np.testing.assert_array_equal(a, b, err_msg=name)
+        ahead = rep.step_inputs_ahead - before[0]
+        rebuilt = rep.step_inputs_rebuilt - before[1]
+        assert ahead + rebuilt == len(versions)
+        paths.append("ahead" if ahead else "rebuilt")
+        assert paths[-1] == expect, paths
+
+    kind_admit(rep, log, "a", [1, 2, 3], 5)
+    iteration("rebuilt")            # the first step of anything
+    iteration("ahead")
+    kind_admit(rep, log, "b", [4, 5, 6, 7], 9)
+    iteration("rebuilt")            # an admission
+    iteration("ahead")              # a's fifth token: finish decided
+    iteration("rebuilt")            # a finish
+    iteration("ahead")
+    kind_admit(rep, log, "draws", [8, 9], 6, temperature=0.8, top_k=8)
+    iteration("rebuilt")
+    iteration("ahead")              # b greedy, one draws: tokens uploaded
+    kind_publish(kind_states, kind, tmp_path / "publish", 20, 4)
+    rep._staged = rep.follower.poll(rep._read_weights)[1:]
+    # restart: both re-prefilled on step 20. pin: both stay on step 10,
+    # nothing about their step changes
+    iteration("rebuilt" if policy == "restart" else "ahead")
+    iteration("ahead")
+    kind_admit(rep, log, "late", [2, 4, 6], 4)
+    # pin: a second version is live, a step each, none built ahead
+    iteration("rebuilt")
+    iteration("rebuilt" if policy == "pin" else "ahead")
+    drive_to_idle(rep)
+    beat = rep._pressure_fields()
+    assert beat["step_inputs_ahead"] == rep.step_inputs_ahead >= 6
+    assert beat["step_inputs_rebuilt"] == rep.step_inputs_rebuilt >= 5
+    assert (beat["step_inputs_ahead"] + beat["step_inputs_rebuilt"]
+            == rep.decode_steps)
+    assert beat["lines_deferred"] == rep.lines_deferred > 0
+    flushes = beat["line_flushes"]
+    assert sum(flushes.values()) <= rep.decode_steps + 5
+    # forced: one before the swap, one before each admission that found
+    # lines waiting, the parks; never one an iteration
+    assert flushes["swap"] == 1 and flushes["stop"] == 0
+    assert 1 <= flushes["prefill"] <= 4 and flushes["park"] >= 1
+    assert flushes["dispatch"] >= rep.decode_steps - 8
+    from distributedmnist_tpu.obsv.schema import validate_event
+    assert validate_event({"event": "heartbeat", "step": 1, "time": 0.0,
+                           **beat}) == []
+    assert validate_event({"event": "heartbeat", "step": 1, "time": 0.0,
+                           **beat, "lines_defered": 1}) != []
 
 
 # ---------------------------------------------------------------------------

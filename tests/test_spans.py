@@ -147,6 +147,9 @@ def drive(published, serve_dir, trace_dir=None):
             iteration()
             if all(s is None for s in rep._slots):
                 break
+        # the loop goes on: it parks on its empty queue, and before it
+        # does, writes what the last fetch decided
+        iteration()
     finally:
         if trace_dir is not None:
             spans.stop_profile()
@@ -169,10 +172,13 @@ def _named(evs, name):
 def test_decode_loop_span_names_and_the_facts_they_carry(traced_decode):
     evs = traced_decode["spans"]
     assert {e["name"] for e in evs} == SERVE_LEAVES | {spans.SERVE_PREFILL}
-    # the first arrivals found the replica parked on its queue; no later
-    # iteration did, and nothing was staged to swap
-    assert len(_named(evs, spans.SERVE_IDLE)) == 1
+    # the first arrivals found the replica parked on its queue and the
+    # last finish left it so; no iteration between did, and nothing was
+    # staged to swap
+    assert len(_named(evs, spans.SERVE_IDLE)) == 2
     assert evs[0]["name"] == spans.SERVE_IDLE
+    assert max(evs, key=lambda e: e["start"])["name"] in (
+        spans.SERVE_IDLE, spans.SERVE_HEARTBEAT)
     prefills = _named(evs, spans.SERVE_PREFILL)
     assert [p["id"] for p in prefills] == ["a", "b", "c"]
     assert [p["prompt_len"] for p in prefills] == [3, 5, 2]
@@ -242,6 +248,55 @@ def test_one_sample_span_an_iteration_whatever_its_slots_ask_for(
     for e in samples:
         assert not any(t["start"] < e["end"] and e["start"] < t["end"]
                        for t in _named(evs, spans.SERVE_STREAM))
+
+
+def test_between_a_fetch_and_the_next_dispatch_nothing_is_written(
+        traced_decode):
+    """The gap between two steps (`benchmark/lib/host_gaps.py` reads it
+    from a fetch's end to the next dispatch's start) holds the books,
+    the admission's polls, a heartbeat and the taking of the inputs; a
+    line or a finish is written there only before a prefill or a park
+    (a forced flush: that gap is the prefill's, not a plain one). Every
+    other write, and the inputs built ahead, lie between a dispatch and
+    its fetch."""
+    evs = traced_decode["spans"]
+    dispatches = _named(evs, spans.SERVE_STEP_DISPATCH)
+    fetches = _named(evs, spans.SERVE_STEP_FETCH)
+    assert len(dispatches) == len(fetches)
+    writes = (spans.SERVE_STREAM, spans.SERVE_FINISH)
+    stops = (spans.SERVE_PREFILL, spans.SERVE_IDLE)
+    under_a_step = forced = 0
+    for f, d in zip(fetches, dispatches[1:] + [None]):
+        lo, hi = f["end"], d["start"] if d else float("inf")
+        inside = [e for e in evs if lo <= e["start"] and e["end"] <= hi]
+        plain = not any(e["name"] in stops for e in inside)
+        names = {e["name"] for e in inside}
+        if plain:
+            assert names <= {spans.SERVE_SAMPLE, spans.SERVE_ADMIT,
+                             spans.SERVE_HEARTBEAT,
+                             spans.SERVE_STEP_INPUTS}, names
+            assert len(_named(inside, spans.SERVE_STEP_INPUTS)) == 1
+        else:
+            first_stop = min(e["start"] for e in inside
+                             if e["name"] in stops)
+            for w in inside:
+                if w["name"] in writes and w["start"] < first_stop:
+                    forced += 1
+        assert spans.SERVE_SAMPLE in names      # the books, every time
+    for d, f in zip(dispatches, fetches):
+        inside = [e for e in evs
+                  if d["end"] <= e["start"] and e["end"] <= f["start"]]
+        names = [e["name"] for e in inside]
+        assert set(names) <= {*writes, spans.SERVE_STEP_INPUTS}, names
+        # the inputs built ahead, after the lines: once a step where one
+        # version is live
+        assert names.count(spans.SERVE_STEP_INPUTS) == 1
+        assert names[-1] == spans.SERVE_STEP_INPUTS
+        under_a_step += len(names) - 1
+    # all 14 tokens' lines less the three prefills' own, and three
+    # terminals, were written in one of the two places
+    assert under_a_step + forced == 14 - 3 + 3
+    assert under_a_step > forced > 0
 
 
 def test_live_on_the_dispatch_span_is_the_occupancy_arranged(traced_decode):
