@@ -138,7 +138,8 @@ class ModelConfig:
     # widths qk_nope_dim + qk_rope_dim for query and key and v_head_dim
     # for the value; the qk_rope_dim columns are rotated by position
     # (YaRN, rope_* below), so the model has no learned position table,
-    # and its head is a matrix of its own (untied).
+    # and its head is a matrix of its own (untied). q_latent_dim 0
+    # projects the query at full rank.
     q_latent_dim: int = 0
     kv_latent_dim: int = 0
     qk_nope_dim: int = 0
@@ -211,6 +212,28 @@ class ModelConfig:
     ssm_dt_rank: int = 0
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
+    # kda_head_dim > 0: every layer's first sublayer is a delta-rule
+    # linear-attention mixer (ops/kda.py: Kimi Delta Attention,
+    # arXiv:2510.26692 s3) and not attention, but the layers at
+    # attn_layer_period / attn_layer_offset as above: num_heads heads of
+    # kda_head_dim key and value channels each, a state of kda_head_dim x
+    # kda_head_dim float32 a head a sequence, three causal convolutions
+    # of kda_conv taps, a log-decay a key channel bounded below by
+    # kda_lower_bound. Its attention layers are what the other sizes say
+    # (latent where kv_latent_dim > 0), its feed-forwards too.
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    # attn_head_gate: an attention layer's output is multiplied, a head,
+    # by the sigmoid of a projection of the layer's input (w_hgate [d,
+    # heads]) before its output projection.
+    attn_head_gate: bool = False
+    # router_groups > 1: per-token routing limited to router_topk_groups
+    # of router_groups equal groups of consecutive experts
+    # (arXiv:2412.19437 s2.1.2: a group scored by the sum of its two best
+    # biased scores).
+    router_groups: int = 1
+    router_topk_groups: int = 1
 
 
 @dataclass(frozen=True)

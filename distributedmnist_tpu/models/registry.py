@@ -197,16 +197,20 @@ class Model:
     # attention layers (``decode_cache_shape`` counts those layers and
     # their key-value heads), arrays a SEQUENCE owns whole:
     # decode_state_shape = (mixer layers, state size N, channels E,
-    # convolution taps before the newest K - 1), what
-    # ``servesvc.kv_cache.SlotState`` is allocated with. Its exports take
+    # convolution taps before the newest K - 1[, the tail's width where it
+    # is not E]), what ``servesvc.kv_cache.SlotState`` is allocated with:
+    # a state-space layer's (layers, N, E, K - 1), a delta-rule layer's
+    # (layers, (heads, D), D, K - 1, 3 x heads x D). Its exports take
     # them: decode_prefill(params, tokens [b, s], lengths [b]) ->
     # (logits [b, 1, vocab] of position lengths - 1, k, v, state [Lm, b,
     # N, E], tail [Lm, K - 1, b, E]);
     # decode_step(params, tokens, positions, k_cache, v_cache,
     # block_tables, lengths, state, tail, block_size=B) -> (logits,
     # k_cache, v_cache, state, tail), ``state`` and ``tail`` an array a
-    # mixer layer, [S, N, E] and [K - 1, S, E]. Such a
-    # model's record is a :class:`SessionModel`.
+    # mixer layer, [S, N, E] and [K - 1, S, width]; both take
+    # ``return_routing`` (and the step ``return_counts``) as the paged
+    # exports do, the routing and the counts last. Such a model's record
+    # is a :class:`SessionModel`.
     decode_state_shape: tuple | None = None
     # When True, ``apply`` and the sharded applies accept
     # ``return_aux=True`` and return (logits, aux), ``aux`` a mapping:
@@ -369,18 +373,33 @@ def _transformer(cfg: ModelConfig) -> Model:
     routed = cfg.routed_experts > 0
     held = (cfg.first_held_expert, cfg.held_experts or cfg.routed_experts)
     sizes = None
-    mixed = cfg.ssm_state_dim > 0
+    mixed = cfg.ssm_state_dim > 0 or cfg.kda_head_dim > 0
     if (latent or routed or cfg.ffn_dim or cfg.residual_streams > 1
             or cfg.nextn_layers or cfg.sandwich_norm or cfg.kv_heads
-            or mixed):
-        if (mixed or cfg.kv_heads) and (
+            or mixed or cfg.attn_head_gate):
+        if cfg.ssm_state_dim and cfg.kda_head_dim:
+            raise ValueError("model.ssm_state_dim and model.kda_head_dim "
+                             "name two mixers for one layer")
+        if cfg.kda_head_dim and (
+                moe or cfg.residual_streams > 1 or cfg.nextn_layers
+                or cfg.sandwich_norm):
+            raise ValueError(
+                "delta-rule layers (model.kda_head_dim) are built with one "
+                "residual stream, no norm on a sublayer's output, no "
+                "next-next-token module and no capacity routing")
+        if (cfg.ssm_state_dim or cfg.kv_heads) and (
                 moe or latent or routed or cfg.residual_streams > 1
                 or cfg.nextn_layers or cfg.sandwich_norm):
             raise ValueError(
                 "state-space layers (model.ssm_state_dim) and grouped "
                 "key-value heads (model.kv_heads) are built with the "
                 "dense or gated feed-forward, the plain residual and "
-                "attention through wqkv")
+                "attention through wqkv (delta-rule layers, "
+                "model.kda_head_dim, also beside latent attention and "
+                "per-token routing)")
+        if cfg.attn_head_gate and not latent:
+            raise ValueError("model.attn_head_gate gates a latent "
+                             "attention layer's heads (kv_latent_dim > 0)")
         if moe and routed:
             raise ValueError("model.num_experts (capacity routing) and "
                              "model.routed_experts (per-token routing) "
@@ -393,6 +412,18 @@ def _transformer(cfg: ModelConfig) -> Model:
                 f"held experts {held} of {cfg.routed_experts}, "
                 f"{cfg.experts_per_token} a token, {cfg.expert_ffn_dim} "
                 "wide: not a share of a routed layer")
+        groups = (cfg.router_groups, cfg.router_topk_groups)
+        if routed and groups != (1, 1) and not (
+                0 < groups[1] <= groups[0]
+                and cfg.routed_experts % groups[0] == 0
+                and cfg.routed_experts // groups[0] >= 2
+                and cfg.experts_per_token
+                <= groups[1] * (cfg.routed_experts // groups[0])):
+            raise ValueError(
+                f"{groups[1]} of {groups[0]} groups over "
+                f"{cfg.routed_experts} experts, {cfg.experts_per_token} a "
+                "token: not a group limit (equal groups of at least two "
+                "experts, enough experts in the groups kept)")
         sizes = transformer.Sizes(
             q_latent_dim=cfg.q_latent_dim, kv_latent_dim=cfg.kv_latent_dim,
             qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
@@ -407,6 +438,9 @@ def _transformer(cfg: ModelConfig) -> Model:
             ssm_dt_rank=cfg.ssm_dt_rank,
             attn_period=cfg.attn_layer_period,
             attn_offset=cfg.attn_layer_offset,
+            kda_head_dim=cfg.kda_head_dim, kda_conv=cfg.kda_conv,
+            kda_lower_bound=cfg.kda_lower_bound,
+            attn_head_gate=cfg.attn_head_gate,
             # a bias that never moves is no bias: a served model without
             # one in its source keeps the leaf at zeros
             **({} if cfg.router_bias_rate else {"router_bias_init": 0.0}))
@@ -472,10 +506,13 @@ def _transformer(cfg: ModelConfig) -> Model:
         if sizes is not None and (seq_axis or model_axis or expert_axis
                                   or pipeline):
             raise NotImplementedError(
-                "latent attention, per-token routing, the gated unit, "
-                "residual streams and the next-next-token module run "
-                "unsharded or data-parallel; no partition rule, sharded "
-                "apply or pipeline stage is written for them")
+                "latent attention, per-token routing (and its group "
+                "limit), the gated unit, residual streams, the "
+                "next-next-token module, sandwich norms, grouped "
+                "key-value heads, a head-wise output gate and mixer "
+                "layers (state-space, delta-rule) run unsharded or "
+                "data-parallel; no partition rule, sharded apply or "
+                "pipeline stage is written for them")
         if expert_axis is not None and not moe:
             raise ValueError("mesh has expert parallelism but the model "
                              "has no experts (model.num_experts == 0)")
@@ -538,7 +575,9 @@ def _transformer(cfg: ModelConfig) -> Model:
             feed_forward = transformer.moe_feed_forward(
                 total=cfg.routed_experts, held=held,
                 top_k=cfg.experts_per_token, scaling=cfg.routed_scaling,
-                bias_rate=cfg.router_bias_rate)
+                bias_rate=cfg.router_bias_rate,
+                n_group=cfg.router_groups,
+                topk_group=cfg.router_topk_groups)
         elif cfg.ffn_dim:
             feed_forward = transformer.gated_feed_forward
         if latent:
@@ -552,7 +591,10 @@ def _transformer(cfg: ModelConfig) -> Model:
                 rope_mscale=cfg.rope_mscale,
                 rope_mscale_all_dim=cfg.rope_mscale_all_dim,
                 norm_eps=cfg.norm_eps)
-        if cfg.sandwich_norm:
+        if cfg.sandwich_norm or (cfg.kda_head_dim and routed):
+            # (a delta-rule model that routes: twelve routers of 512
+            # read what eleven matrix-valued states wrote, and each
+            # rounding on a router's way costs it near ties)
             residual = transformer.FLOAT32
         if cfg.residual_streams > 1:
             residual = transformer.stream_residual(
@@ -564,23 +606,24 @@ def _transformer(cfg: ModelConfig) -> Model:
             projections=projections, residual=residual,
             out_norm=cfg.sandwich_norm, norm_eps=cfg.norm_eps,
             kv_heads=cfg.kv_heads,
-            mixer=sizes is not None and not sizes.attends(layer))
+            mixer=(sizes is not None and not sizes.attends(layer)
+                   and sizes.mixer),
+            kda_lower_bound=cfg.kda_lower_bound)
 
-    # one device, or replicas of the whole model. Where the leading
-    # layers' feed-forward differs from the others', a block a layer,
-    # the last also the next-next-token module's
+    # one device, or replicas of the whole model. A layer's kind is a
+    # pair: whether it attends (the pattern of the model section: a mixer
+    # elsewhere) and whether its feed-forward is routed (the leading
+    # ``dense_layers`` are not). Where the layers are of more than one
+    # kind, a block a layer, one built a kind; the last also the
+    # next-next-token module's
+    kinds = [(sizes is None or sizes.attends(i),
+              routed and i >= cfg.dense_layers)
+             for i in range(cfg.num_layers)]
     block = block_for()
-    if routed and cfg.dense_layers:
-        per_kind = {False: block, True: block_for(layer=cfg.dense_layers)}
-        block = tuple(per_kind[i >= cfg.dense_layers]
-                      for i in range(cfg.num_layers))
-    if mixed:
-        # the pattern of the model section: an attention layer where the
-        # sizes say one attends, a state-space layer elsewhere
-        attends = [sizes.attends(i) for i in range(cfg.num_layers)]
-        per_kind = {a: block_for(layer=attends.index(a))
-                    for a in set(attends)}
-        block = tuple(per_kind[a] for a in attends)
+    if len(set(kinds)) > 1:
+        per_kind = {kind: block_for(layer=kinds.index(kind))
+                    for kind in set(kinds)}
+        block = tuple(per_kind[kind] for kind in kinds)
 
     def apply(params, x, *, train=False, dropout_key=None, return_aux=False):
         del dropout_key
@@ -663,6 +706,18 @@ def _transformer(cfg: ModelConfig) -> Model:
     # plain block: no output norm, no other epsilon, no gated tree
     decode_prefill = decode_step_fn = decode_cache_shape = None
     decode_state_shape = None
+
+    def asked_of(out, stores: int, return_routing, return_counts):
+        """A step's outputs (the logits and its stores, ``stores`` values,
+        then where anything was asked the routed layers' ``aux``) with the
+        routing and the counts in ``aux``'s place, each where asked."""
+        if not (return_routing or return_counts):
+            return out
+        aux = out[stores]
+        return (*out[:stores],
+                *((aux["routing"],) if return_routing else ()),
+                *((aux["counts"],) if return_counts else ()))
+
     blocks = ((block,) * cfg.num_layers
               if isinstance(block, transformer.Block) else block)
     first = blocks[0]
@@ -672,27 +727,40 @@ def _transformer(cfg: ModelConfig) -> Model:
         # others, through forwards that hand both over
         kv_heads = cfg.kv_heads or cfg.num_heads
 
-        def decode_prefill(params, tokens, lengths):
+        def decode_prefill(params, tokens, lengths, return_routing=False):
             return transformer.prefill_with_state(
                 params, tokens, lengths, block=blocks,
-                compute_dtype=compute_dtype)
+                compute_dtype=compute_dtype, return_routing=return_routing)
 
         def decode_step_fn(params, tokens, positions, k_cache, v_cache,
                            block_tables, lengths, state, tail, *,
-                           block_size, attention_kernel="auto"):
-            return transformer.decode_step_with_state(
+                           block_size, attention_kernel="auto",
+                           return_routing=False, return_counts=False):
+            out = transformer.decode_step_with_state(
                 params, tokens, positions, k_cache, v_cache, block_tables,
                 lengths, state, tail, block=blocks, num_heads=cfg.num_heads,
                 kv_heads=kv_heads, block_size=block_size,
                 compute_dtype=compute_dtype,
-                attention_kernel=attention_kernel)
+                attention_kernel=attention_kernel,
+                return_aux=return_routing or return_counts)
+            return asked_of(out, 5, return_routing, return_counts)
 
         attending = sum(sizes.attends(i) for i in range(cfg.num_layers))
-        decode_cache_shape = (attending, kv_heads,
-                              cfg.model_dim // cfg.num_heads)
-        decode_state_shape = (cfg.num_layers - attending, cfg.ssm_state_dim,
-                              cfg.ssm_expand * cfg.model_dim,
-                              cfg.ssm_conv - 1)
+        decode_cache_shape = (
+            (attending, 1, (cfg.kv_latent_dim, cfg.qk_rope_dim)) if latent
+            else (attending, kv_heads, cfg.model_dim // cfg.num_heads))
+        if cfg.kda_head_dim:
+            # a head's matrix a sequence, the heads apart; the tail as
+            # wide as the three convolved streams
+            decode_state_shape = (
+                cfg.num_layers - attending,
+                (cfg.num_heads, cfg.kda_head_dim), cfg.kda_head_dim,
+                cfg.kda_conv - 1, 3 * cfg.num_heads * cfg.kda_head_dim)
+        else:
+            decode_state_shape = (cfg.num_layers - attending,
+                                  cfg.ssm_state_dim,
+                                  cfg.ssm_expand * cfg.model_dim,
+                                  cfg.ssm_conv - 1)
     # (grouped heads are served beside state-space layers only: the plain
     # step's attention reads `wqkv` as three equal parts)
     elif not moe and not cfg.kv_heads and (
@@ -718,12 +786,7 @@ def _transformer(cfg: ModelConfig) -> Model:
                 block_size=block_size, compute_dtype=compute_dtype,
                 attention_kernel=attention_kernel,
                 return_aux=return_routing or return_counts)
-            if not (return_routing or return_counts):
-                return out
-            aux = out[3]
-            return (*out[:3],
-                    *((aux["routing"],) if return_routing else ()),
-                    *((aux["counts"],) if return_counts else ()))
+            return asked_of(out, 3, return_routing, return_counts)
 
         decode_cache_shape = (
             (cfg.num_layers, 1, (cfg.kv_latent_dim, cfg.qk_rope_dim))

@@ -125,10 +125,27 @@ class Sizes(NamedTuple):
     ssm_dt_rank: int = 0
     attn_period: int = 0
     attn_offset: int = 0
+    # delta-rule linear-attention layers (ops/kda.py): ``kda_head_dim`` >
+    # 0 makes every layer one, the model's heads of that many key and
+    # value channels each, but those at ``attn_offset`` modulo
+    # ``attn_period``; ``kda_conv`` taps in its three convolutions, the
+    # log-decay bounded below by ``kda_lower_bound``
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    # an attention layer's output a head times ``sigmoid(w_h . x)``
+    attn_head_gate: bool = False
+
+    @property
+    def mixer(self) -> str:
+        """The kind of the layers that do not attend: ``"ssm"``,
+        ``"kda"``, or ``""`` where every layer attends."""
+        return ("ssm" if self.ssm_state_dim
+                else "kda" if self.kda_head_dim else "")
 
     def attends(self, layer: int) -> bool:
         """Whether ``layer`` is an attention layer."""
-        return (not self.ssm_state_dim
+        return (not self.mixer
                 or (self.attn_period > 0
                     and layer % self.attn_period == self.attn_offset))
 
@@ -136,9 +153,10 @@ class Sizes(NamedTuple):
 #: leaves that stay float32 in the forward whatever the compute dtype:
 #: a top-k and a Sinkhorn iteration amplify what a rounding changes
 _F32_LEAVES = ("router", "router_bias", "mix1", "mix2",
-               # a state-space layer's decay, step bias and skip: what a
-               # recurrence multiplies by at every token
-               "a_log", "b_dt", "d_skip")
+               # a state-space layer's decay, step bias and skip, a
+               # delta-rule layer's decay and its bias: what a recurrence
+               # multiplies by at every token
+               "a_log", "b_dt", "d_skip", "dt_bias")
 
 
 def _init_mix(key: jax.Array, n: int, d: int) -> Params:
@@ -205,6 +223,41 @@ def _init_mixer(key: jax.Array, d: int, z: Sizes) -> Params:
         "w_out": tn((e, d))}
 
 
+def _init_kda(key: jax.Array, d: int, heads: int, z: Sizes) -> Params:
+    """A delta-rule layer's mixer (ops/kda.py has the equations and the
+    leaves). Matrices at 0.02 as everywhere (the block's output
+    projections then depth-scaled: :func:`_depth_scaled_outputs`), the
+    convolutions uniform in
+    ``+-K^-1/2``; what a scale cannot stand in for: a channel's log-decay
+    is ``lower_bound * sigmoid(exp(a_log) (x W_f + dt_bias))``, so with
+    ``a_log = 0`` the bias is the logit of ``-g / -lower_bound`` at ``g``
+    log-uniform in ``[-0.5, -1e-3]``: a token keeps between ``e^-0.5 =
+    0.61`` and ``0.999`` of a channel before ``x W_f`` (at 0.005, a quarter
+    of a unit of logit on a normed input) moves it. With every decay
+    near 1 a state never forgets, near ``e^lower_bound`` it forgets in a
+    token: either way what is kept of a sequence stops mattering to its
+    next token."""
+    e, taps = heads * z.kda_head_dim, z.kda_conv
+    keys = iter(jax.random.split(key, 8))
+    tn = lambda shape, scale=0.02: truncated_normal_init(  # noqa: E731
+        next(keys), shape, scale)
+    bound = taps ** -0.5
+    forget = jnp.exp(jax.random.uniform(next(keys), (e,), jnp.float32)
+                     * (math.log(0.5) - math.log(1e-3)) + math.log(1e-3))
+    share = forget / -z.kda_lower_bound
+    return {
+        "w_qkv": tn((d, 3 * e)),
+        "conv_w": jax.random.uniform(next(keys), (taps, 3 * e), jnp.float32,
+                                     -bound, bound),
+        "w_f": tn((d, e), 0.005),
+        "a_log": jnp.zeros((heads,), jnp.float32),
+        "dt_bias": jnp.log(share) - jnp.log1p(-share),
+        "w_beta": tn((d, heads)),
+        "w_og": tn((d, e)),
+        "o_norm": _norm_scale(z.kda_head_dim),
+        "wo": tn((e, d))}
+
+
 def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
                       routed: bool, depth: int = 1,
                       attends: bool = True) -> Params:
@@ -218,17 +271,25 @@ def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
         for name, c in zip(("ln1_out", "ln2_out"), SANDWICH_C):
             blk[name] = {"scale": jnp.full((d,), c / math.sqrt(depth),
                                            jnp.float32)}
-    if z.kv_latent_dim:
+    if not attends:
+        blk.update(_init_kda(next(keys), d, heads, z) if z.mixer == "kda"
+                   else _init_mixer(next(keys), d, z))
+    elif z.kv_latent_dim:
         qk = z.qk_nope_dim + z.qk_rope_dim
+        if z.q_latent_dim:
+            blk.update(
+                wq_a=tn((d, z.q_latent_dim)), q_norm=ones(z.q_latent_dim),
+                wq_b=tn((z.q_latent_dim, heads, qk)))
+        else:
+            # the query projected at full rank, no latent of its own
+            blk.update(wq=tn((d, heads, qk)))
         blk.update(
-            wq_a=tn((d, z.q_latent_dim)), q_norm=ones(z.q_latent_dim),
-            wq_b=tn((z.q_latent_dim, heads, qk)),
             wkv_a=tn((d, z.kv_latent_dim + z.qk_rope_dim)),
             kv_norm=ones(z.kv_latent_dim),
             wkv_b=tn((z.kv_latent_dim, heads, z.qk_nope_dim + z.v_head_dim)),
             wo=tn((heads * z.v_head_dim, d)))
-    elif not attends:
-        blk.update(_init_mixer(next(keys), d, z))
+        if z.attn_head_gate:
+            blk["w_hgate"] = tn((d, heads))
     elif z.kv_heads and z.kv_heads != heads:
         # fewer key-value heads than query heads: one matrix, the
         # queries' columns, then the keys', then the values'
@@ -254,7 +315,27 @@ def _init_sized_block(key: jax.Array, d: int, heads: int, z: Sizes,
         blk.update(_init_gated(next(keys), (), d, z.ffn_dim))
     else:
         blk.update(w1=tn((d, 4 * d)), w2=tn((4 * d, d)))
+    if z.mixer == "kda":
+        blk = _depth_scaled_outputs(blk, depth)
     return blk
+
+
+def _depth_scaled_outputs(blk: Params, depth: int) -> Params:
+    """A block's output projections (``wo``; ``w_down`` of the dense unit,
+    of the experts and of the shared one) times ``(2 depth)^-1/2``: GPT-2's
+    rule for the matrices that write to the residual, two sublayers a
+    layer. With them at the other matrices' scale a sublayer of this tree
+    writes 0.6 of the unit embedding, the residual at thirteen layers is
+    the sublayers' and their bfloat16 rounding reaches every later router
+    whole: 9 in 100 of a top 8 of 512 under a group limit then differ
+    from the float32 reference's at a near tie (the chip and the CPU read
+    0.908-0.912 equal sets for the harness's 0.93; PERF.md, PR 45). Scaled,
+    the embedding stays most of what a layer reads, as
+    :func:`_init_sized` means it to."""
+    scale = (2 * depth) ** -0.5
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a * scale if getattr(path[-1], "key", None) in (
+            "wo", "w_down") else a, blk)
 
 
 def _init_sized(key: jax.Array, vocab_size: int, d: int, heads: int,
@@ -287,7 +368,7 @@ def _init_sized(key: jax.Array, vocab_size: int, d: int, heads: int,
     if z.kv_latent_dim:
         params["head"] = truncated_normal_init(next(keys), (d, vocab_size),
                                                0.02)
-    elif not z.ssm_state_dim:
+    elif not z.mixer:
         # (a model with state-space layers has no positional term at
         # all: the recurrence orders its tokens; its head is tied)
         params["pos"] = truncated_normal_init(next(keys), (max_seq_len, d),
@@ -588,8 +669,9 @@ def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
     """The projections of latent attention for :func:`make_block`
     (arXiv:2405.04434 §2.1): ``project(h, blk, positions) -> (q, k, v,
     scale, rows)`` in the [b, s, heads, width] layout, over a block's
-    ``wq_a``/``q_norm``/``wq_b``/``wkv_a``/``kv_norm``/``wkv_b``. Query
-    and key are ``qk_nope_dim + qk_rope_dim`` wide, the rotated part
+    ``wq_a``/``q_norm``/``wq_b``/``wkv_a``/``kv_norm``/``wkv_b`` (or, for
+    a query projected at full rank, ``wq`` [d, heads, width] in place of
+    the first three). Query and key are ``qk_nope_dim + qk_rope_dim`` wide, the rotated part
     last and the key's one row shared by all heads; the value
     ``v_head_dim``. ``scale`` is ``(qk width)^-½`` times the square of
     YaRN's ``mscale_all_dim`` factor. ``rows`` is what a decode cache
@@ -609,9 +691,11 @@ def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
         b, s, _ = h.shape
         if positions is None:
             positions = jnp.arange(s)
-        q = jnp.einsum("bsr,rhe->bshe", _rms_norm(h @ blk["wq_a"],
-                                                   blk["q_norm"], norm_eps),
-                       blk["wq_b"])
+        if "wq" in blk:
+            q = jnp.einsum("bsd,dhe->bshe", h, blk["wq"])
+        else:
+            q = jnp.einsum("bsr,rhe->bshe", _rms_norm(
+                h @ blk["wq_a"], blk["q_norm"], norm_eps), blk["wq_b"])
         kv_a = h @ blk["wkv_a"]
         latent, k_rope = kv_a[..., :-qk_rope_dim], kv_a[..., -qk_rope_dim:]
         latent = _rms_norm(latent, blk["kv_norm"], norm_eps)
@@ -634,13 +718,23 @@ def latent_projections(*, num_heads: int, qk_nope_dim: int, qk_rope_dim: int,
     return project
 
 
+def _head_gated(o: jax.Array, h: jax.Array, blk: Params) -> jax.Array:
+    """An attention output ``o`` [..., heads, width] a head times
+    ``sigmoid(w_h . h)``, where the block has ``w_hgate`` [d, heads]."""
+    if "w_hgate" not in blk:
+        return o
+    gate = jax.nn.sigmoid((h @ blk["w_hgate"]).astype(jnp.float32))
+    return (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+
+
 def make_block(*, num_heads: int, attention_fn: Callable | None = None,
                model_axis: str | None = None,
                feed_forward: Callable | None = None,
                projections: Callable | None = None,
                residual: Residual = PLAIN, out_norm: bool = False,
                norm_eps: float = 1e-6, kv_heads: int = 0,
-               mixer: bool = False) -> Block:
+               mixer: "bool | str" = False,
+               kda_lower_bound: float = -5.0) -> Block:
     """The one place a layer's kind is decided: which attention behind
     which projections, which feed-forward, which residual rule, under
     which mesh axes.
@@ -690,10 +784,12 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
     attends to one head's keys and values, and the rows a cache keeps are
     that one head's.
 
-    ``mixer``: the first sublayer is the state-space mixer of
-    ``ops/ssm.py`` over the block's leaves and not attention: no
-    projections, no attention function, no positions; the block's
-    ``mixer_step`` is its form for one token.
+    ``mixer``: the first sublayer is a mixer over the block's leaves and
+    not attention: the state-space one of ``ops/ssm.py`` (True or
+    ``"ssm"``) or the delta-rule one of ``ops/kda.py`` (``"kda"``, its
+    log-decay bounded below by ``kda_lower_bound``): no projections, no
+    attention function, no positions; the block's ``mixer_step`` is its
+    form for one token.
     """
     norm = (_rms_norm if norm_eps == 1e-6
             else functools.partial(_rms_norm, eps=norm_eps))
@@ -752,8 +848,8 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         u, kept = residual.read(x, blk.get("mix1"))
         b = u.shape[0]
         # the norm in what the rule read; the products in the weights'
-        q, k, v, scale, rows = project(
-            norm(u, blk["ln1"]).astype(blk["wo"].dtype), blk, positions)
+        h = norm(u, blk["ln1"]).astype(blk["wo"].dtype)
+        q, k, v, scale, rows = project(h, blk, positions)
         kw = {} if scale is None else {"scale": scale}
         wide = q.shape[-1] - v.shape[-1]
         vk = jnp.pad(v, ((0, 0),) * 3 + ((0, wide),)) if wide else v
@@ -772,6 +868,7 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
             o = o.transpose(0, 2, 1, 3)
         if wide:
             o = o[..., :v.shape[-1]]
+        o = _head_gated(o, h, blk)
         # row-parallel: partial sum of the full d
         proj = o.reshape(b, -1, o.shape[2] * o.shape[3]) @ blk["wo"]
         if model_axis:
@@ -800,7 +897,14 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
     if not mixer:
         return Block(attn, ffn, residual, norm, decode_attn)
 
-    from ..ops import ssm
+    # (the functions looked up in their module at each call, not bound
+    # here: what a trace runs is what the module holds then)
+    if mixer == "kda":
+        from ..ops import kda as ops
+        out_leaf, how = "wo", {"lower_bound": kda_lower_bound}
+    else:
+        from ..ops import ssm as ops
+        out_leaf, how = "w_out", {}
 
     def mix(x: jax.Array, blk: Params, return_kv: bool = False,
             positions: jax.Array | None = None,
@@ -811,17 +915,17 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         tail there (``lengths`` [batch]; None: the whole sequence)."""
         del positions  # the recurrence orders the tokens
         u, kept = residual.read(x, blk.get("mix1"))
-        h = norm(u, blk["ln1"]).astype(blk["w_out"].dtype)
-        out = ssm.mixer(h, blk, norm=norm, lengths=lengths,
-                        return_state=return_kv)
+        h = norm(u, blk["ln1"]).astype(blk[out_leaf].dtype)
+        out = ops.mixer(h, blk, norm=norm, lengths=lengths,
+                        return_state=return_kv, **how)
         if not return_kv:
             return residual.write(kept, out)
         return (residual.write(kept, out[0]), *out[1:])
 
     def mix_step(x, blk, state, tail, live):
-        h = norm(x, blk["ln1"]).astype(blk["w_out"].dtype)
-        out, state, tail = ssm.mixer_step(h, blk, state, tail, live,
-                                          norm=norm)
+        h = norm(x, blk["ln1"]).astype(blk[out_leaf].dtype)
+        out, state, tail = ops.mixer_step(h, blk, state, tail, live,
+                                          norm=norm, **how)
         return x + out.astype(x.dtype), state, tail
 
     return Block(mix, ffn, residual, norm, None, mix_step)
@@ -1126,9 +1230,11 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
 
 def prefill_with_state(params: Params, tokens: jax.Array,
                        lengths: jax.Array, *, block: tuple[Block, ...],
-                       compute_dtype=jnp.bfloat16) -> tuple[jax.Array, ...]:
-    """Prompt prefill of a model whose layers are attention or
-    state-space mixers, one ``block`` a layer (a mixer's has
+                       compute_dtype=jnp.bfloat16,
+                       return_routing: bool = False
+                       ) -> tuple[jax.Array, ...]:
+    """Prompt prefill of a model whose layers are attention or mixers
+    (state-space or delta-rule), one ``block`` a layer (a mixer's has
     ``mixer_step``): the causal forward, and what every layer keeps of
     a sequence for a decode replica.
 
@@ -1140,13 +1246,18 @@ def prefill_with_state(params: Params, tokens: jax.Array,
     float32 of position ``lengths - 1`` alone (the one a first token is
     sampled from: the head over a whole bucket is 0.5 GB of logits at
     these widths), k and v [attention layers, b, s, kv_heads, hd] in the
-    compute dtype, state [mixer layers, b, N, E] float32, tail [mixer
-    layers, K - 1, b, E] in the compute dtype, oldest input first)."""
+    compute dtype (a latent block's rows, [attention layers, b, s,
+    kv_latent] and [.., qk_rope], in their place), state [mixer layers,
+    b, N, E] float32, tail [mixer layers, K - 1, b, width] in the compute
+    dtype, oldest input first). Per-token routed layers route the prompt
+    as :func:`apply` does; ``return_routing`` adds their choices
+    [routed_layers, b, s, k] as a last output and changes nothing
+    else."""
     for bk in block:
         _one_stream(bk, "prefill_with_state")
     p = _cast(params, compute_dtype)
     x = block[0].residual.start(_embed(p, tokens, jnp.arange(tokens.shape[1])))
-    ks, vs, states, tails = [], [], [], []
+    ks, vs, states, tails, routed = [], [], [], [], []
     for bk, blk in zip(block, p["blocks"]):
         if bk.mixer_step is None:
             x, k, v = bk.attn(x, blk, return_kv=True)
@@ -1156,12 +1267,16 @@ def prefill_with_state(params: Params, tokens: jax.Array,
             x, state, tail = bk.attn(x, blk, return_kv=True, lengths=lengths)
             states.append(state)
             tails.append(tail.transpose(1, 0, 2))
-        x, _ = bk.ffn(x, blk)
+        x, aux = bk.ffn(x, blk)
+        _loss_of(aux, routed)
     last = jnp.take_along_axis(block[-1].residual.end(x),
                                (lengths - 1)[:, None, None], axis=1)
-    return (_head(p, last, norm=block[-1].norm),
-            jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
-            jnp.stack(tails))
+    out = (_head(p, last, norm=block[-1].norm),
+           jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
+           jnp.stack(tails))
+    if return_routing:
+        out += (_stacked(routed)["routing"],)
+    return out
 
 
 def decode_step_with_state(params: Params, tokens: jax.Array,
@@ -1172,23 +1287,28 @@ def decode_step_with_state(params: Params, tokens: jax.Array,
                            num_heads: int, kv_heads: int,
                            block_size: int = 16,
                            compute_dtype=jnp.bfloat16,
-                           attention_kernel: str = "auto"
+                           attention_kernel: str = "auto",
+                           return_aux: bool = False
                            ) -> tuple[jax.Array, ...]:
     """:func:`decode_step` of a model whose layers are attention or
-    state-space mixers, one ``block`` a layer. The paged cache holds the
-    attention layers only, ``[attention layers, N, B, kv_heads, hd]``,
-    read and written by :func:`_decode_attn` as the plain block's. Beside
-    it the arrays a slot owns whole, whatever its sequence's length, a
-    pair a mixer layer: ``state[l]`` [slots, N, E] float32 and
-    ``tail[l]`` [K - 1, slots, E]; a mixer layer advances its pair for
-    the live slots (``lengths > 0``) and leaves an idle slot's as it was.
-    Hand both donated: a layer's state is then read and written in one
-    elementwise pass over its own array.
+    mixers, one ``block`` a layer. The paged cache holds the attention
+    layers only, ``[attention layers, N, B, kv_heads, hd]``, read and
+    written by :func:`_decode_attn` as the plain block's, or by the
+    block's own ``decode_attn`` (a latent block's pair of rows a token,
+    ``[attention layers, N, B, width]``). Beside it the arrays a slot
+    owns whole, whatever its sequence's length, a pair a mixer layer:
+    ``state[l]`` [slots, N, E] float32 and ``tail[l]`` [K - 1, slots,
+    width]; a mixer layer advances its pair for the live slots (``lengths
+    > 0``) and leaves an idle slot's as it was. Hand both donated: a
+    layer's state is then read and written where it lies.
 
-    Returns (logits [S, vocab] float32, k_cache, v_cache, state, tail)."""
+    Returns (logits [S, vocab] float32, k_cache, v_cache, state, tail);
+    ``return_aux`` adds what the per-token routed layers said of this
+    step's tokens, as :func:`decode_step` does, and changes nothing
+    else."""
     arm = decode_attention_arm(attention_kernel, k_cache.shape)
     p = _cast(params, compute_dtype)
-    x = _embed(p, tokens, positions)  # [S, d]
+    x = block[0].residual.start(_embed(p, tokens, positions))  # [S, d]
     hd = x.shape[-1] // num_heads
     ctx_pos = jnp.arange(block_tables.shape[1] * block_size)
     blk_ids = jnp.take_along_axis(
@@ -1198,8 +1318,18 @@ def decode_step_with_state(params: Params, tokens: jax.Array,
     stepping = lengths > 0
     state, tail = list(state), list(tail)
     attended = mixed = 0
+    routed = []
     for bk, blk in zip(block, p["blocks"]):
-        if bk.mixer_step is None:
+        if bk.mixer_step is not None:
+            x, state[mixed], tail[mixed] = bk.mixer_step(
+                x, blk, state[mixed], tail[mixed], stepping)
+            mixed += 1
+        elif bk.decode_attn is not None:
+            x, k_cache, v_cache = bk.decode_attn(
+                x, blk, attended, k_cache, v_cache, block_tables, positions,
+                blk_ids, offs, live, attention_kernel=attention_kernel)
+            attended += 1
+        else:
             x, k_cache, v_cache = _decode_attn(
                 x, blk, attended, k_cache, v_cache, block_tables, lengths,
                 blk_ids, offs, live, num_heads=num_heads,
@@ -1207,13 +1337,13 @@ def decode_step_with_state(params: Params, tokens: jax.Array,
                 kv_heads=None if kv_heads == num_heads else kv_heads,
                 norm=bk.norm)
             attended += 1
-        else:
-            x, state[mixed], tail[mixed] = bk.mixer_step(
-                x, blk, state[mixed], tail[mixed], stepping)
-            mixed += 1
-        x, _ = bk.ffn(x, blk)
-    return (_head(p, x, norm=block[-1].norm), k_cache, v_cache,
-            tuple(state), tuple(tail))
+        x, aux = bk.ffn(x, blk)
+        _loss_of(aux, routed)
+    out = (_head(p, block[-1].residual.end(x), norm=block[-1].norm),
+           k_cache, v_cache, tuple(state), tuple(tail))
+    if return_aux:
+        out += (_stacked(routed),)
+    return out
 
 
 def decode_attention_arm(attention_kernel: str,
@@ -1382,7 +1512,9 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     over the rows gathered once for all heads (``cache_gather``), the
     softmax in float32, the weighted sum of latents ``o_c[h]`` goes
     through ``W_uv`` (``latent_absorb``), then ``wo``, the output's norm
-    where the block has one, and the residual."""
+    where the block has one, and the residual. A block with ``wq``
+    projects its query at full rank; one with ``w_hgate`` gates the
+    output a head before ``wo`` (:func:`_head_gated`)."""
     if attention_kernel == "paged":
         raise NotImplementedError(
             "decode.attention_kernel='paged': the paged kernel reads keys "
@@ -1392,8 +1524,11 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     rope_dim = blk["wkv_a"].shape[1] - latent_dim
     ctx = live.shape[1]
     h = norm(x, blk["ln1"]).astype(blk["wo"].dtype)
-    q = jnp.einsum("sr,rhe->she", norm(h @ blk["wq_a"], blk["q_norm"]),
-                   blk["wq_b"])
+    if "wq" in blk:
+        q = jnp.einsum("sd,dhe->she", h, blk["wq"])
+    else:
+        q = jnp.einsum("sr,rhe->she", norm(h @ blk["wq_a"], blk["q_norm"]),
+                       blk["wq_b"])
     kv_a = h @ blk["wkv_a"]
     c = norm(kv_a[:, :latent_dim], blk["kv_norm"])
     # a slot's token is its own sequence's: positions [S] turn rows [S]
@@ -1421,6 +1556,7 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     o_c = jnp.einsum("shk,skr->shr", w.astype(cs.dtype), cs)
     with jax.named_scope("latent_absorb"):
         o = jnp.einsum("shr,rhv->shv", o_c, blk["wkv_b"][..., qk_nope_dim:])
+    o = _head_gated(o, h, blk)
     proj = o.astype(blk["wo"].dtype).reshape(num_slots, -1) @ blk["wo"]
     if out_norm:
         proj = norm(proj, blk["ln1_out"])

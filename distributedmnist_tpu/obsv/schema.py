@@ -236,18 +236,23 @@ _declare(EventSchema(
                              # of a model whose state is a sequence's
                              # (kv_cache.SlotState): state_arrays, the
                              # shapes of a layer's two arrays ([slots, N,
-                             # E] and [K - 1, slots, E]); state_layers,
+                             # E] and [K - 1, slots, W]); state_layers,
                              # how many layers have such a pair;
                              # state_slot_bytes, what one sequence's
                              # state takes; state_device_bytes, both
                              # arrays as placed; kv_heads, the heads the
-                             # paged rows hold
+                             # paged rows hold (1: a row a token for all
+                             # heads); mixer_kind ("ssm" | "kda"), the
+                             # layers that keep the state;
+                             # attention_layers, how many layers the
+                             # paged cache holds rows of
                              ("cache_row_bytes", "cache_arrays",
                               "attention_arm", "paged_calls",
                               "step_while_loops", "state_arrays",
                               "state_layers",
                               "state_slot_bytes", "state_device_bytes",
-                              "kv_heads")),
+                              "kv_heads", "mixer_kind",
+                              "attention_layers")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of

@@ -173,11 +173,19 @@ PREFETCH_PUT = "dml.prefetch.put"
 #: state_write is servesvc/kv_cache.py's program that puts a prefill's
 #: end state into a slot (jit_write_slot_state; no reader: 9 MB a
 #: prefill). The benchmark reads these through benchmark/lib/
-#: ssm_scopes.py
+#: ssm_scopes.py. A delta-rule layer (ops/kda.py) opens, in the same
+#: place, kda around its mixer whole (read by: decode_kda_ms_per_step)
+#: and inside it kda_conv (the three convolutions), kda_gate (the
+#: log-decay and beta), kda_chunk (the chunked recurrence over a prompt,
+#: in jit_decode_prefill; read by: prefill_kda_chunk_ms_p50) and
+#: kda_state (one token a slot, the matrix state read and written, in
+#: jit_decode_step; read by: decode_kda_state_ms_per_step,
+#: decode_kda_state_roofline), through benchmark/lib/kda_scopes.py
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
           "ffn", "head", "loss", "aggregate", "update", "timing",
           "residual_mix", "moe", "mtp", "latent_absorb",
-          "ssm", "ssm_conv", "ssm_scan", "state_update", "state_write")
+          "ssm", "ssm_conv", "ssm_scan", "state_update", "state_write",
+          "kda", "kda_conv", "kda_gate", "kda_chunk", "kda_state")
 
 #: a host span: ``with span(SERVE_STREAM, id=...):``
 span = jax.profiler.TraceAnnotation

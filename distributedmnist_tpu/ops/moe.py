@@ -281,22 +281,45 @@ def gated_unit(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 
 def route_tokens(x: jax.Array, router_w: jax.Array, bias: jax.Array,
-                 top_k: int, scaling: float):
+                 top_k: int, scaling: float, n_group: int = 1,
+                 topk_group: int = 1):
     """Sigmoid routing with a selection bias (arXiv:2412.19437 §2.1.2,
-    ``noaux_tc`` with one group): ``x`` [t, d] → the ``top_k`` expert ids
+    ``noaux_tc``): ``x`` [t, d] → the ``top_k`` expert ids
     [t, k] with the largest ``sigmoid(x W_r) + bias`` and their gates
     [t, k] float32, the chosen scores WITHOUT the bias renormalised to
     sum to ``scaling``. Scores in float32 at full matrix precision: a
     top-k is a discontinuity. The loss's gradient does not reach the
-    bias (:func:`balance_term` moves it)."""
+    bias (:func:`balance_term` moves it).
+
+    ``n_group > 1`` limits a token to ``topk_group`` of ``n_group`` equal
+    groups of consecutive experts (device-limited routing, same section):
+    a group's score is the sum of its two best biased scores, the
+    ``topk_group`` best groups stay and the ``top_k`` are taken inside
+    them. With one group it is the function above, operation for
+    operation."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, ids = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
-                       top_k)
+    biased = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    if n_group > 1:
+        biased = _group_limited(biased, n_group, topk_group)
+    _, ids = lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
     gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return ids.astype(jnp.int32), gates * scaling
+
+
+def _group_limited(biased: jax.Array, n_group: int,
+                   topk_group: int) -> jax.Array:
+    """``biased`` [t, experts] with every expert outside a token's
+    ``topk_group`` best groups at ``-inf``; a group's score is the sum of
+    its two best."""
+    t, total = biased.shape
+    grouped = biased.reshape(t, n_group, total // n_group)
+    group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = lax.top_k(group_score, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, total)
 
 
 def balance_term(ids: jax.Array, bias: jax.Array, rate: float) -> jax.Array:
@@ -447,10 +470,12 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def routed_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                experts: dict, shared: dict | None, *, total: int,
                held: tuple[int, int], top_k: int, scaling: float,
-               bias_rate: float = 0.0):
+               bias_rate: float = 0.0, n_group: int = 1,
+               topk_group: int = 1):
     """The routed feed-forward of one chip that holds ``held = (first,
     count)`` of a layer's ``total`` experts: ``x`` [b, s, d] is routed
-    over all ``total`` (:func:`route_tokens`), the pairs that land on
+    over all ``total`` (:func:`route_tokens`, under its group limit where
+    ``n_group > 1``), the pairs that land on
     held experts are sorted by expert (:func:`_plan`) and go through one
     grouped product with **no pair dropped and no capacity**, and the
     result is that partial sum plus the shared expert, which every chip
@@ -470,7 +495,8 @@ def routed_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     flat = x.reshape(b * s, d)
     # routed on what it is handed, float32 where the block keeps its
     # norm there; the experts' products in their weights' dtype
-    ids, gates = route_tokens(flat, router_w, bias, top_k, scaling)
+    ids, gates = route_tokens(flat, router_w, bias, top_k, scaling,
+                              n_group, topk_group)
     flat = flat.astype(experts["w_gate"].dtype)
     plan = _plan(ids, held[0], held[1], tile_rows(ids.size, total))
     out = _held_experts(flat, gates,

@@ -30,8 +30,8 @@ slot's live pages whatever the table's width:
 ``transformer.decode_attention_arm``; ``decode_start`` says which arm
 each width compiled to.)
 
-**State that is a sequence's.** A model with state-space layers
-(``model.decode_state_shape``: ops/ssm.py) keeps, beside the paged rows
+**State that is a sequence's.** A model with mixer layers
+(``model.decode_state_shape``: ops/ssm.py, ops/kda.py) keeps, beside the paged rows
 of its attention layers, one recurrent state and one convolution tail a
 layer A SLOT, whatever the sequence's length (:class:`.kv_cache.
 SlotState`, an array a layer): allocated with the slot at admission, written by the
@@ -194,10 +194,13 @@ def build_stores(model, dcfg, dtype, sharding, put
     return cache, state
 
 
-def jit_step(model, dcfg):
+def jit_step(model, dcfg, return_routing: bool = False):
     """The model's decode step as the replica runs it: jitted, the cache
     arrays (and a slot state's two, which follow ``lengths``) donated,
-    every slot's greedy pick made where the logits are."""
+    every slot's greedy pick made where the logits are.
+    ``return_routing`` (a decode session's second program, never the
+    loop's): the step asked for its routed layers' choices, which come
+    next after the stores."""
     model_step = model.decode_step
     block_size = dcfg.block_size
     attention_kernel = dcfg.attention_kernel
@@ -207,6 +210,8 @@ def jit_step(model, dcfg):
     # (token, expert) pairs each expert held here took of the step's
     # tokens, [routed_layers, held]: fetched with the greedy tokens
     counts = {"return_counts": True} if model.decode_counts else {}
+    if return_routing:
+        counts["return_routing"] = True
 
     # a named function, not a functools.partial: a profiler trace
     # calls the program `jit_decode_step`, a partial `jit__unknown`
@@ -310,8 +315,10 @@ class DecodeReplica(ServingReplica):
             raise ConfigError(
                 f"model {self.cfg.model.name!r} exports no decode step: "
                 "the registry exports one for a causal LM with the plain "
-                "block or a latent one (dense, gated or per-token routed "
-                "feed-forward), and none for a classifier, for capacity "
+                "block, a latent one (dense, gated or per-token routed "
+                "feed-forward) or mixer layers (state-space; delta-rule, "
+                "also beside latent attention and routed feed-forwards), "
+                "and none for a classifier, for capacity "
                 "routing (model.num_experts), for more than one residual "
                 "stream, or for a gated or sandwich-normed block that "
                 "does not attend through a latent")
@@ -330,8 +337,9 @@ class DecodeReplica(ServingReplica):
         self._stateful = self.model.decode_state_shape is not None
         if self._stateful and self.tp_ranks > 1:
             raise ConfigError(
-                f"serve.tp_ranks={self.tp_ranks}: a model with state-space "
-                "layers is served whole on one chip; its per-slot state "
+                f"serve.tp_ranks={self.tp_ranks}: a model with mixer layers "
+                "(state-space, delta-rule) is served on one chip; its "
+                "per-slot state "
                 "(servesvc/kv_cache.py::SlotState) has no rule that "
                 "splits it over a model axis")
         self.cache, self.state = build_stores(
@@ -849,9 +857,9 @@ class DecodeReplica(ServingReplica):
                         *self._state_arrays()))
                 if self.state is not None:
                     # taken back advanced; a slot not of this version
-                    # (length 0) as it was
-                    self.state.state, self.state.tail = pairs
-                    pairs = []
+                    # (length 0) as it was. What is left is the pair
+                    # counts of a model that also routes
+                    self.state.state, self.state.tail, *pairs = pairs
                 self.decode_steps += 1
                 self.decode_table_blocks = width
             # the chip is busy from here to the fetch: what the fetch
@@ -1034,7 +1042,7 @@ class DecodeReplica(ServingReplica):
                     self._params, idle, idle, self.cache.k, self.cache.v,
                     tables, idle, *self._state_arrays()))
             if self.state is not None:
-                self.state.state, self.state.tail = rest
+                self.state.state, self.state.tail = rest[:2]
         jax.block_until_ready(greedy)
         # a compiled step takes an argument only as it was compiled for:
         # its own greedy tokens where they come back placed as its
@@ -1052,9 +1060,11 @@ class DecodeReplica(ServingReplica):
     def _state_said(self) -> dict:
         """Of a model whose state is a sequence's, for ``decode_start``:
         the shapes of a layer's two arrays ([slots, N, E] float32 and
-        [K - 1, slots, E]: the layout) and how many layers have such a
+        [K - 1, slots, W]: the layout) and how many layers have such a
         pair, what one sequence's state takes, what all of them take on
-        the device, and the key-value heads the paged rows hold."""
+        the device, the kind of mixer that keeps it, how many layers
+        attend (the paged cache's) and the key-value heads their rows
+        hold (1: one row a token for all heads)."""
         if self.state is None:
             return {}
         return {"state_arrays": [list(a[0].shape)
@@ -1062,6 +1072,9 @@ class DecodeReplica(ServingReplica):
                 "state_layers": len(self.state.state),
                 "state_slot_bytes": self.state.slot_bytes(),
                 "state_device_bytes": self.state.device_bytes(),
+                "mixer_kind": ("kda" if self.cfg.model.kda_head_dim
+                               else "ssm"),
+                "attention_layers": self.model.decode_cache_shape[0],
                 "kv_heads": self.model.decode_cache_shape[1]}
 
     def _cache_said(self) -> dict:
@@ -1150,6 +1163,11 @@ class SlotSession:
         self._slot0 = jax.jit(lambda state, tail: (
             jnp.stack([s[:1] for s in state]),
             jnp.stack([t[:, :1] for t in tail])))
+        # the same exports asked for their routing (a model that routes):
+        # another program each, after the unasked one
+        self._prefill_asked = jax.jit(lambda p, t, n: model.decode_prefill(
+            p, t, n, return_routing=True))
+        self._step_asked = jit_step(model, dcfg, return_routing=True)
         self.said = {
             "session": "slot_state",
             "attention_arm": decode_attention_arm(dcfg.attention_kernel,
@@ -1159,13 +1177,18 @@ class SlotSession:
             "state_arrays": [list(a[0].shape) for a in self.state.arrays],
             "state_layers": len(self.state.state)}
 
-    def prefill(self, prompt, return_routing: bool = False):
-        if return_routing:
+    def _routes(self, return_routing: bool) -> bool:
+        if return_routing and not self.model.decode_counts:
             raise NotImplementedError("nothing is routed in this model")
+        return return_routing
+
+    def prefill(self, prompt, return_routing: bool = False):
+        return_routing = self._routes(return_routing)
         prompt = np.asarray(prompt, np.int32)
         n = int(prompt.size)
         row, outs = run_prefill(
-            self._prefill, True, self.params, prompt,
+            self._prefill_asked if return_routing else self._prefill, True,
+            self.params, prompt,
             ServingReplica._bucket(n, self.dcfg.max_prompt_len))
         if self._table is None:
             # every block the sequence can need, as an admission does
@@ -1174,11 +1197,11 @@ class SlotSession:
             self.state.alloc(0)
         store_prompt(self.cache, self.state, 0, self._table, outs, n)
         self._stepped = None
-        return row
+        # [routed_layers, n, k]: the prompt's own positions of the bucket
+        return (row, outs[5][:, 0, :n]) if return_routing else row
 
     def step(self, token: int, position: int, return_routing: bool = False):
-        if return_routing:
-            raise NotImplementedError("nothing is routed in this model")
+        return_routing = self._routes(return_routing)
         if self._stepped is not None and self._stepped[0] == position:
             self.state.write(0, *self._stepped[1])
         else:
@@ -1193,6 +1216,11 @@ class SlotSession:
         inputs = (vec(token), vec(position), self.cache.k, self.cache.v,
                   jnp.asarray(tables), vec(position + 1),
                   *self.state.arrays)
+        if return_routing:
+            (logits, _, self.cache.k, self.cache.v, self.state.state,
+             self.state.tail, picked, *_) = self._step_asked(self.params,
+                                                             *inputs)
+            return logits[0], picked[:, 0]
         if width not in self._steps:
             self._steps[width] = self._step.lower(self.params,
                                                   *inputs).compile()
@@ -1203,5 +1231,5 @@ class SlotSession:
                     m.argument_size_in_bytes + m.output_size_in_bytes
                     + m.temp_size_in_bytes - m.alias_size_in_bytes)
         (logits, _, self.cache.k, self.cache.v, self.state.state,
-         self.state.tail) = self._steps[width](self.params, *inputs)
+         self.state.tail, *_) = self._steps[width](self.params, *inputs)
         return logits[0]

@@ -47,12 +47,13 @@ runs to completion — block pressure defers admission (the request
 waits, bounded by its deadline), it never kills a running generation.
 
 **State that is a sequence's and not a token's** lives beside the paged
-arrays in :class:`SlotState`: a model with state-space layers
-(ops/ssm.py) keeps of a sequence, a such layer, one recurrent state and
-the last inputs of its convolution, the same bytes however long the
-sequence. No block table: a decode slot owns its slice of both arrays
-whole from admission to finish. The paged cache then holds the rows of
-the layers that attend, and those only.
+arrays in :class:`SlotState`: a model with mixer layers (state-space,
+ops/ssm.py; delta-rule, ops/kda.py) keeps of a sequence, a such layer,
+one recurrent state and the last inputs of its convolution, the same
+bytes however long the sequence. No block table: a decode slot owns its
+slice of both arrays whole from admission to finish. The paged cache then
+holds the rows of the layers that attend, and those only (keys and values
+a head, or a latent model's pair of rows a token).
 """
 
 from __future__ import annotations
@@ -255,12 +256,13 @@ def write_slot_state(state: tuple, tail: tuple, new_state: jax.Array,
                      new_tail: jax.Array, slot: jax.Array, *,
                      row: int = 0) -> tuple[tuple, tuple]:
     """One slot's slice of every layer's two arrays replaced: ``state`` a
-    layer ``[slots, N, E]``, ``tail`` a layer ``[K - 1, slots, E]``;
+    layer ``[slots, N, E]``, ``tail`` a layer ``[K - 1, slots, W]``;
     ``new_state`` [layers, batch, N, E] and ``new_tail`` [layers, K - 1,
-    batch, E] as a prefill hands them over, of which sequence ``row`` is
+    batch, W] as a prefill hands them over, of which sequence ``row`` is
     taken. Nothing of what the slot held is read."""
     state = tuple(jax.lax.dynamic_update_slice(
-        s, new_state[i, row][None].astype(s.dtype), (slot, 0, 0))
+        s, new_state[i, row][None].astype(s.dtype),
+        (slot,) + (0,) * (s.ndim - 1))
         for i, s in enumerate(state))
     tail = tuple(jax.lax.dynamic_update_slice(
         t, new_tail[i, :, row][:, None].astype(t.dtype), (0, slot, 0))
@@ -271,11 +273,24 @@ def write_slot_state(state: tuple, tail: tuple, new_state: jax.Array,
 class SlotState:
     """The arrays a decode slot owns whole, beside the paged cache, ONE
     PAIR A LAYER that has such a state: ``state[l]`` [slots, N, E]
-    float32, a state-space layer's recurrent state a slot, and
-    ``tail[l]`` [K - 1, slots, E] in the compute dtype, the inputs of its
-    convolution before the slot's next token (oldest first). The channels
-    are the minor dimension of both: what a step's elementwise update
-    runs along. An array a layer, not one stacked over the layers: a
+    float32, a mixer layer's recurrent state a slot, and ``tail[l]`` [K -
+    1, slots, W] in the compute dtype, the inputs of its convolution
+    before the slot's next token (oldest first). By kind of mixer:
+
+    * state-space (ops/ssm.py): ``N`` the state's size a channel, ``E``
+      the channels, and the tail as wide as the channels (``W = E``): at
+      the published Jamba widths ``[slots, 16, 5120]`` and ``[3, slots,
+      5120]``;
+    * delta-rule (ops/kda.py): a matrix a head: ``N = (heads, D)``, a pair
+      (``state_dim`` may be a tuple: the heads apart, the rank the update
+      is one elementwise pass at), ``D`` key channels a head, ``E = D``
+      value channels, and the tail as wide as the three convolved
+      streams, ``W = 3 x heads x D`` (``tail_channels``): at 32 heads of
+      128, ``[slots, 32, 128, 128]`` (the bytes and tiles of ``[slots,
+      4096, 128]``) and ``[3, slots, 12288]``.
+
+    The minor dimension of both is what a step's elementwise update runs
+    along. An array a layer, not one stacked over the layers: a
     layer's update is then one elementwise pass that writes where it
     reads (the arrays donated), where a slice of a stacked array written
     back costs a second pass over the state (measured: PERF.md, PR 43).
@@ -289,16 +304,19 @@ class SlotState:
     and before a restart's re-prefill. Functional arrays, single writer
     (the decode loop thread)."""
 
-    def __init__(self, layers: int, slots: int, state_dim: int,
-                 channels: int, taps_before: int, dtype=jnp.float32,
-                 state_dtype=jnp.float32):
-        self.state = tuple(jnp.zeros((slots, state_dim, channels),
+    def __init__(self, layers: int, slots: int,
+                 state_dim: "int | tuple[int, ...]", channels: int,
+                 taps_before: int, tail_channels: int | None = None,
+                 dtype=jnp.float32, state_dtype=jnp.float32):
+        wide = channels if tail_channels is None else tail_channels
+        dims = state_dim if isinstance(state_dim, tuple) else (state_dim,)
+        self.state = tuple(jnp.zeros((slots, *dims, channels),
                                      state_dtype) for _ in range(layers))
-        self.tail = tuple(jnp.zeros((taps_before, slots, channels), dtype)
+        self.tail = tuple(jnp.zeros((taps_before, slots, wide), dtype)
                           for _ in range(layers))
-        self._zeros = (jnp.zeros((layers, 1, state_dim, channels),
+        self._zeros = (jnp.zeros((layers, 1, *dims, channels),
                                  state_dtype),
-                       jnp.zeros((layers, taps_before, 1, channels), dtype))
+                       jnp.zeros((layers, taps_before, 1, wide), dtype))
         self._owned: set[int] = set()
         self.resets = 0
         # the function itself, so that a trace calls the program

@@ -210,3 +210,246 @@ def test_a_hybrid_with_a_key_value_head_a_query_head_decodes_its_forward():
     for pos in range(11, 19):
         rows.append(session.step(int(seq[pos]), pos))
     assert _rel(jnp.stack(rows), full[10:]) < 1e-5
+
+
+# -- delta-rule layers beside latent attention and routed experts (PR 45) ----
+
+LING = {
+    "arch": "bailing_hybrid", "hidden_size": 64, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "head_dim": 16, "num_hidden_layers": 7,
+    "layer_group_size": 3, "vocab_size": 97, "intermediate_size": 96,
+    "first_k_dense_replace": 1, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "q_lora_rank": None,
+    "rope_theta": 6000000, "rope_scaling": None, "rms_norm_eps": 1e-6,
+    "hidden_act": "silu", "tie_word_embeddings": False,
+    "moe_intermediate_size": 32, "moe_shared_expert_intermediate_size": 32,
+    "num_experts": 4, "num_experts_per_tok": 4, "num_shared_experts": 1,
+    "n_group": 8, "topk_group": 4, "routed_scaling_factor": 2.5,
+    "score_function": "sigmoid", "topk_method": "noaux_tc",
+    "norm_topk_prob": True, "moe_router_enable_expert_bias": True,
+    "num_nextn_predict_layers": 0, "num_kv_heads_for_linear_attn": 0,
+    "group_norm_size": 1, "linear_silu": True, "use_qk_norm": True,
+    "kda_safe_gate": True, "kda_lower_bound": -5, "no_kda_lora": True,
+    "use_kda_lora": False, "short_conv_kernel_size": 4,
+    "gated_attention_proj_granularity_type": "head_wise",
+    "use_mla_nope": False, "use_bias": False, "use_qkv_bias": False,
+    "use_nGPT": False, "value_norm": False, "up_proj_norm": False,
+    "scale_router_input": False,
+    "expert_swiglu_limit_list": [0] * 7,
+    "share_expert_swiglu_limit_list": [0] * 7,
+    "published": {"num_experts": 32},
+    "assumed": {"seq_len": 64, "first_held_expert": 0,
+                "router_bias_rate": 0.001},
+    "model_assumed": {"attention_impl": "dense", "compute_dtype": "float32"}}
+
+
+@pytest.fixture(scope="module")
+def ling():
+    arch = cell_lib.load_arch(LING)
+    model = get_model(ModelConfig(**arch.model_section(LING)))
+    params = model.init(jax.random.PRNGKey(1))
+    # norm scales away from one: a norm left out shows; matrices at the
+    # inverse root of this toy's width, not the published one's
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: a + 0.1 * jax.random.normal(
+            jax.random.PRNGKey(len(path)), a.shape)
+        if getattr(path[-1], "key", None) == "scale" else a, params)
+    params["blocks"] = jax.tree_util.tree_map_with_path(
+        lambda path, a: a * 4 if a.ndim >= 2 and getattr(
+            path[-1], "key", None) != "conv_w" else a, params["blocks"])
+    return arch, model, params
+
+
+def test_the_delta_rule_pattern_is_read_from_the_model_section(ling):
+    arch, model, params = ling
+    kinds = ["w_qkv" in blk for blk in params["blocks"]]
+    assert kinds == [not arch.attends(LING, i) for i in range(7)]
+    assert kinds == [True, True, False, True, True, False, True]
+    assert ["router" in blk for blk in params["blocks"]] == [False] + [True] * 6
+    assert model.decode_cache_shape == (2, 1, (32, 8))
+    assert model.decode_state_shape == (5, (4, 16), 16, 3, 192)
+    assert "wq" in params["blocks"][2] and "wq_a" not in params["blocks"][2]
+    assert params["blocks"][2]["w_hgate"].shape == (64, 4)
+    assert "head" in params and "pos" not in params
+    assert sum(a.size for a in jax.tree.leaves(params)) == arch.param_count(
+        LING)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_the_delta_rule_models_apply_is_the_reference(ling, impl):
+    arch, _, params = ling
+    model = get_model(ModelConfig(**{**arch.model_section(LING),
+                                     "attention_impl": impl}))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 23), 0, 97)
+    with jax.default_matmul_precision("highest"):
+        got, aux = model.apply(params, tokens, return_aux=True)
+        want = arch.logits(params, tokens, LING, routing=aux["routing"])
+        slack = arch.routing_slack(params, tokens, LING, aux["routing"])
+    assert aux["routing"].shape == (6, 2, 23, 4)
+    assert _rel(got, want) < 2e-5
+    # the program's choices are the reference's own, group limit and all
+    assert float(slack.max()) == 0.0
+    free = arch.logits(params, tokens, LING)
+    assert _rel(got, free) < 2e-5
+
+
+@pytest.mark.parametrize("asked", [False, True],
+                         ids=["as_the_loop_calls_it", "asked_for_routing"])
+def test_delta_rule_prefill_then_steps_through_the_session_is_the_reference(
+        ling, asked):
+    """Prefill of 11 in a bucket of 16, then eight teacher-forced steps,
+    logits against the reference's full forward under the program's own
+    choices; asked for its routing the session returns the same logits to
+    the bit and leaves the state as the unasked step left it."""
+    arch, model, params = ling
+    session = model.decode_session(params, DECODE, jnp.float32)
+    assert session.said["state_arrays"] == [[3, 4, 16, 16], [3, 3, 192]]
+    seq = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (19,), 0, 97))
+    with jax.default_matmul_precision("highest"):
+        rows = [session.prefill(seq[:11])]
+        again, routing = session.prefill(seq[:11], return_routing=True)
+        chosen, diffs = [routing[:, None]], [jnp.abs(again - rows[0]).max()]
+        assert routing.shape == (6, 11, 4)
+        for pos in range(11, 19):
+            rows.append(session.step(int(seq[pos]), pos))
+            if asked:
+                again, picked = session.step(int(seq[pos]), pos,
+                                             return_routing=True)
+                diffs.append(jnp.abs(again - rows[-1]).max())
+            else:
+                _, picked = session.step(int(seq[pos]), pos,
+                                         return_routing=True)
+            assert picked.shape == (6, 4)
+            chosen.append(picked[:, None, None])
+        forced = jnp.concatenate(chosen, axis=2)
+        want = arch.logits(params, jnp.asarray(seq[None]), LING, last=9,
+                           routing=forced)[0]
+        slack = arch.routing_slack(params, jnp.asarray(seq[None]), LING,
+                                   forced)
+    assert _rel(jnp.stack(rows), want) < 2e-5
+    assert float(max(diffs)) == 0.0               # routing_flag_diff
+    assert float(slack.max()) == 0.0
+    # a second prompt in the same slot starts from nothing of the first
+    other = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (7,), 0, 97))
+    fresh = model.decode_session(params, DECODE, jnp.float32)
+    assert _rel(session.prefill(other), fresh.prefill(other)) < 1e-6
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer_of_the_reference():
+    """What ties the share to the model: the routed parts of the sixteen
+    chips' shares (2 experts each of 32 under the group limit) plus the
+    shared expert once are the reference's uncut layer."""
+    from distributedmnist_tpu.models import transformer
+    from distributedmnist_tpu.ops import moe
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    z = transformer.Sizes(routed_experts=32, held=(0, 32), shared_experts=1,
+                          expert_ffn_dim=32)
+    blk = transformer._init_sized_block(keys[0], 64, 4, z, routed=True)
+    blk["router"] = blk["router"] * 10     # scores wide enough apart
+    h = jax.random.normal(keys[1], (2, 48, 64))
+    uncut = {**LING, "num_experts": 32}
+    arch = cell_lib.load_arch(uncut)
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([arch._routed(seq, blk, uncut, None)[0]
+                          for seq in h])
+        shared = moe.gated_unit(h, **blk["shared"])
+        total, pairs = shared, 0
+        for first in range(0, 32, 2):
+            held = jax.tree.map(lambda w: w[first:first + 2], blk["experts"])
+            out, ids, counts, _ = moe.routed_ffn(
+                h, blk["router"], blk["router_bias"], held, blk["shared"],
+                total=32, held=(first, 2), top_k=4, scaling=2.5, n_group=8,
+                topk_group=4)
+            # the shared expert is in every share: counted once
+            total, pairs = total + (out - shared), pairs + int(counts.sum())
+            # each share is the reference's share of the same layer
+            share = jnp.stack([arch._routed(
+                seq, {**blk, "experts": held},
+                {**uncut, "num_experts": 2,
+                 "assumed": {**uncut["assumed"], "first_held_expert": first}},
+                None)[0] for seq in h])
+            assert _rel(out, share) < 2e-5
+    assert pairs == 2 * 48 * 4                      # every pair, once
+    assert _rel(total, want) < 2e-5
+    # a token's experts lie in at most four groups of four
+    assert int(jnp.max(jnp.sum(jnp.any(
+        (ids // 4)[..., None] == jnp.arange(8), axis=-2), axis=-1))) <= 4
+
+
+def test_route_tokens_with_one_group_is_todays_to_the_bit():
+    from distributedmnist_tpu.ops import moe
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    x = jax.random.normal(keys[0], (64, 48))
+    w = jax.random.normal(keys[1], (48, 32))
+    bias = jax.random.normal(keys[2], (32,)) * 0.03
+
+    def today(x, router_w, bias, top_k, scaling):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32)), top_k)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
+        gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), gates * scaling
+
+    for groups in ({}, {"n_group": 1, "topk_group": 1}):
+        ids, gates = moe.route_tokens(x, w, bias, 4, 2.5, **groups)
+        want_ids, want_gates = today(x, w, bias, 4, 2.5)
+        assert bool((ids == want_ids).all())
+        assert float(jnp.abs(gates - want_gates).max()) == 0.0
+    # and one program: the jaxprs are the same, equation for equation
+    mine = jax.make_jaxpr(lambda *a: moe.route_tokens(*a, 4, 2.5))(x, w, bias)
+    theirs = jax.make_jaxpr(lambda *a: today(*a, 4, 2.5))(x, w, bias)
+    assert str(mine) == str(theirs)
+
+
+def test_route_tokens_under_the_group_limit_is_the_references_selection():
+    from distributedmnist_tpu.ops import moe
+    arch = cell_lib.load_arch(LING)
+    keys = jax.random.split(jax.random.PRNGKey(12), 3)
+    x = jax.random.normal(keys[0], (96, 64))
+    blk = {"router": jax.random.normal(keys[1], (64, 32)) * 0.3,
+           "router_bias": jax.random.normal(keys[2], (32,)) * 0.03,
+           "experts": {k: jnp.zeros((4, *s)) for k, s in (
+               ("w_gate", (64, 8)), ("w_up", (64, 8)), ("w_down", (8, 64)))}}
+    with jax.default_matmul_precision("highest"):
+        ids, gates = moe.route_tokens(x, blk["router"], blk["router_bias"],
+                                      4, 2.5, n_group=8, topk_group=4)
+        _, own, slack = arch._routed(x, blk, LING, None)
+        _, _, forced_slack = arch._routed(x, blk, LING, ids)
+        loose, _ = moe.route_tokens(x, blk["router"], blk["router_bias"],
+                                    4, 2.5)
+        _, _, loose_slack = arch._routed(x, blk, LING, loose)
+    assert bool((jnp.sort(ids, -1) == jnp.sort(own, -1)).all())
+    assert float(slack.max()) == 0.0 and float(forced_slack.max()) == 0.0
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-5)
+    # without the limit the best 4 of all 32 leave the 4 best groups at
+    # most positions, and the slack that knows the limit says by how much
+    differs = (jnp.sort(loose, -1) != jnp.sort(own, -1)).any(-1)
+    assert 0.3 < float(differs.mean())
+    assert float(loose_slack.max()) > 0.25
+    assert bool(((loose_slack > 0) == differs).all())
+
+
+def test_what_is_still_refused_says_so():
+    with pytest.raises(ValueError, match="two mixers"):
+        get_model(ModelConfig(name="transformer", ssm_state_dim=4,
+                              kda_head_dim=8))
+    with pytest.raises(ValueError, match="delta-rule layers"):
+        get_model(ModelConfig(name="transformer", kda_head_dim=8,
+                              sandwich_norm=True))
+    with pytest.raises(ValueError, match="delta-rule layers"):
+        get_model(ModelConfig(name="transformer", kda_head_dim=8,
+                              residual_streams=4))
+    with pytest.raises(ValueError, match="attn_head_gate"):
+        get_model(ModelConfig(name="transformer", attn_head_gate=True))
+    with pytest.raises(ValueError, match="not a group limit"):
+        get_model(ModelConfig(name="transformer", routed_experts=30,
+                              held_experts=30, experts_per_token=2,
+                              expert_ffn_dim=8, router_groups=4,
+                              router_topk_groups=2))
+    model = get_model(ModelConfig(**cell_lib.load_arch(LING).model_section(
+        LING)))
+    with pytest.raises(NotImplementedError, match="delta-rule"):
+        model.sharded_apply_factory(None, "model")

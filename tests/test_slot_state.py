@@ -241,3 +241,114 @@ def test_a_swap_leaves_no_state_of_the_old_weights(tmp_path, policy):
 def test_tensor_parallel_ranks_are_refused(tmp_path):
     with pytest.raises(ConfigError, match="state-space"):
         _replica(tmp_path, tp_ranks=2)
+
+
+# -- a tail of another width, a matrix state (PR 45) ---------------------------
+
+def test_slot_state_with_a_tail_wider_than_its_channels():
+    st = SlotState(layers=2, slots=3, state_dim=4, channels=8, taps_before=3,
+                   tail_channels=24)
+    assert [a.shape for a in st.state] == [(3, 4, 8)] * 2
+    assert [a.shape for a in st.tail] == [(3, 3, 24)] * 2
+    assert st.slot_bytes() == 2 * (4 * 8 * 4 + 3 * 24 * 4)
+    st.alloc(2)
+    st.write(2, jnp.ones((2, 1, 4, 8)), jnp.full((2, 3, 1, 24), 2.0))
+    assert all(float(t[:, 2].min()) == 2.0 for t in st.tail)
+    assert _largest(t[:, :2] for t in st.tail) == 0.0
+    st.free(2)
+    assert _largest(st.tail) == 0.0 and st.resets == 1
+
+
+def test_slot_state_with_a_matrix_a_head():
+    """A delta-rule layer's: ``state_dim`` a pair, the heads apart."""
+    st = SlotState(layers=2, slots=3, state_dim=(2, 4), channels=4,
+                   taps_before=3, tail_channels=24, dtype=jnp.bfloat16)
+    assert [a.shape for a in st.state] == [(3, 2, 4, 4)] * 2
+    assert st.state[0].dtype == jnp.float32
+    assert st.tail[0].dtype == jnp.bfloat16
+    assert st.slot_bytes() == 2 * (2 * 4 * 4 * 4 + 3 * 24 * 2)
+    st.alloc(1)
+    new = jnp.arange(2 * 2 * 2 * 4 * 4, dtype=jnp.float32).reshape(
+        2, 2, 2, 4, 4)
+    st.write(1, new, jnp.ones((2, 3, 2, 24)), row=1)
+    assert all(float(jnp.abs(s[1] - new[i, 1]).max()) == 0.0
+               for i, s in enumerate(st.state))
+    assert _largest(s[0] for s in st.state) == 0.0
+    assert _largest(s[2] for s in st.state) == 0.0
+    st.reset(1)
+    assert _largest(st.state) == 0.0
+
+
+KDA_HYBRID = {"name": "transformer", "seq_len": 64, "model_dim": 64,
+              "num_heads": 4, "num_layers": 4, "vocab_size": 61,
+              "ffn_dim": 96, "kda_head_dim": 16, "attn_layer_period": 4,
+              "attn_layer_offset": 3, "kv_latent_dim": 32, "qk_nope_dim": 16,
+              "qk_rope_dim": 8, "v_head_dim": 16, "attn_head_gate": True,
+              "routed_experts": 16, "held_experts": 4,
+              "experts_per_token": 2, "shared_experts": 1,
+              "expert_ffn_dim": 32, "routed_scaling": 2.5, "dense_layers": 1,
+              "router_groups": 4, "router_topk_groups": 2,
+              "compute_dtype": "float32", "attention_impl": "dense"}
+
+
+def test_a_delta_rule_replica_says_its_three_stores_and_counts_its_pairs(
+        tmp_path, monkeypatch):
+    """Three delta-rule layers, one latent-attention layer, three routed
+    feed-forwards, served through the loop: ``decode_start`` says the
+    matrix state, the tail of another width and the latent cache's two
+    row widths; the heartbeat carries the routed layers' pair counts
+    beside ``state_resets``; sequences batched get the logits each gets
+    alone."""
+    monkeypatch.setitem(globals(), "HYBRID", KDA_HYBRID)
+    rep, cfg, params = _replica(tmp_path)
+    rep.start()
+    try:
+        start = next(r for r in serve_records(rep)
+                     if r.get("action") == "decode_start")
+        assert start["state_arrays"] == [[3, 4, 16, 16], [3, 3, 192]]
+        assert start["state_layers"] == 3 and start["mixer_kind"] == "kda"
+        assert start["attention_layers"] == 1 and start["kv_heads"] == 1
+        assert start["cache_arrays"] == [[1, 32, 8, 32], [1, 32, 8, 8]]
+        assert start["state_slot_bytes"] == 3 * (4 * 16 * 16 * 4
+                                                 + 3 * 192 * 4)
+        assert start["attention_arm"] == ["gather"] * len(
+            start["table_widths"])
+    finally:
+        rep.stop()
+
+
+def test_delta_rule_sequences_batched_get_the_logits_each_gets_alone(
+        tmp_path, monkeypatch):
+    monkeypatch.setitem(globals(), "HYBRID", KDA_HYBRID)
+    rep, cfg, params = _loaded(tmp_path)
+    prompts = {"a": [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], "b": [2, 7],
+               "c": [8, 2, 8, 1, 8, 2, 8]}
+    conns = {}
+    for rid, prompt in prompts.items():
+        _, conns[rid] = admit_direct(rep, {
+            "id": rid, "prompt": prompt, "max_tokens": 9,
+            "deadline_ms": 120000})
+    _drive(rep, 6)
+    fields = rep._pressure_fields()
+    # the last step's pairs on the four held experts of three routed layers
+    assert 0 <= fields["expert_pairs_held"] <= 3 * 3 * 2
+    assert 0 <= fields["experts_touched"] <= 3 * 4
+    assert fields["expert_pairs_held"] >= fields["experts_touched"]
+    _drive(rep, 8)
+    assert rep._pressure_fields()["state_resets"] == 3   # freed at finish
+    model = get_model(cfg.model)
+    served = jax.tree.map(jnp.asarray, rep._params)
+    for rid, prompt in prompts.items():
+        got = [ln["token"] for ln in conns[rid].lines
+               if ln.get("stream") == "token"]
+        assert len(got) == 9
+        ses = model.decode_session(served, rep.dcfg, jnp.float32)
+        row = ses.prefill(np.asarray(prompt, np.int32))
+        alone = [int(jnp.argmax(row))]
+        for i in range(8):
+            row = ses.step(alone[-1], len(prompt) + i)
+            alone.append(int(jnp.argmax(row)))
+        assert got == alone, rid
+    # nothing of the finished sequences is left in a slot
+    assert _largest(rep.state.state) == 0.0
+    assert _largest(rep.state.tail) == 0.0
