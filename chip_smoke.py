@@ -782,18 +782,28 @@ def _latent_routed_arm(model: dict, batch: int) -> dict:
 
 
 def _latent_decode_arm(model: dict, block_size: int, tol: float) -> dict:
-    """(e) the absorbed decode step against the expanded form: a block
-    with latent attention, sandwich norms and per-token routing over a
-    held share (one residual stream: the kind with a decode export), at
-    the smoke's width cut eightfold, two layers. A prompt's latents and
-    rotated keys written into a paged cache stored as the device keeps
-    its rows whole, 8 greedy steps through it, each step's logits
-    against the full forward's at that position (flash, the expanded
-    form) within the kernels' tolerance; every step's expert ids valid."""
+    """(e) the absorbed decode step against the expanded form, and the
+    latent paged kernel against the gather: a block with latent
+    attention, sandwich norms and per-token routing over a held share
+    (one residual stream: the kind with a decode export), at the smoke's
+    width cut eightfold with a latent of 128, two layers. A prompt's
+    latents and rotated keys written into a paged cache stored as the
+    device keeps its rows whole (128 | 128 on a v5e, where ``auto``
+    answers ``paged`` and the compiled step holds a Mosaic call of
+    ``paged_latent_decode`` a layer: main() requires both), 8 greedy
+    steps through it under ``paged``, each step's logits against the
+    full forward's at that position (flash, the expanded form) within
+    the kernels' tolerance; the same tokens through ``dense`` on a cache
+    of its own: logits within the tolerance, layer 0's rows of both
+    arrays to the bit (deeper layers' rows come from rounded
+    attention); every step's expert ids valid."""
+    import re
+
     import jax
     import jax.numpy as jnp
     from distributedmnist_tpu.core.config import ModelConfig
     from distributedmnist_tpu.models.registry import get_model
+    from distributedmnist_tpu.models.transformer import decode_attention_arm
     from distributedmnist_tpu.servesvc.kv_cache import (PagedKVCache,
                                                         cache_shapes,
                                                         stored_head_dim)
@@ -802,7 +812,7 @@ def _latent_decode_arm(model: dict, block_size: int, tol: float) -> dict:
     seq = 64
     mdl = get_model(ModelConfig(**{
         **model, "model_dim": d, "num_heads": heads, "num_layers": 2,
-        "seq_len": seq, "q_latent_dim": d // 2, "kv_latent_dim": d // 4,
+        "seq_len": seq, "q_latent_dim": d // 2, "kv_latent_dim": 128,
         "qk_nope_dim": 24, "qk_rope_dim": 8, "v_head_dim": 16,
         "rope_theta": 25.6e6, "ffn_dim": 2 * d, "routed_experts": 16,
         "held_experts": 8, "experts_per_token": 4, "shared_experts": 1,
@@ -815,38 +825,65 @@ def _latent_decode_arm(model: dict, block_size: int, tol: float) -> dict:
     stored = tuple(
         stored_head_dim(shape, dtype) for shape in cache_shapes(
             layers, blocks, block_size, one, widths))
-    cache = PagedKVCache(layers, blocks, block_size, one, stored,
-                         seq // block_size, dtype=dtype)
     toks = np.zeros((1, seq), np.int32)
     toks[0, :plen] = np.random.default_rng(SEED + 2).integers(
         0, model["vocab_size"], plen)
     logits, cs, krs = jax.jit(mdl.decode_prefill)(params,
                                                   jnp.asarray(toks[:, :plen]))
-    table = cache.alloc_sequence(plen + steps)
-    cache.write_prompt(table, cs[:, 0], krs[:, 0], plen)
-    step = jax.jit(lambda *a: mdl.decode_step(
-        *a, block_size=block_size, return_routing=True), donate_argnums=(3, 4))
-    tables = np.zeros((slots, seq // block_size), np.int32)
-    tables[1] = table
-    tok, rows, valid = int(jnp.argmax(logits[0, plen - 1])), [], True
-    for pos in range(plen, plen + steps):
-        toks[0, pos] = tok
-        vec = lambda v: jnp.zeros((slots,), jnp.int32).at[1].set(v)  # noqa: E731
-        out, cache.k, cache.v, ids = step(
-            params, vec(tok), vec(pos), cache.k, cache.v,
-            jnp.asarray(tables), vec(pos + 1))
-        rows.append(out[1])
-        ids = np.sort(np.asarray(ids[:, 1]), axis=-1)
-        valid = valid and bool(ids.min() >= 0 and ids.max() < 16
-                               and (ids[:, 1:] != ids[:, :-1]).all())
-        tok = int(jnp.argmax(out[1]))
+    first = int(jnp.argmax(logits[0, plen - 1]))
+    vec = lambda v: jnp.zeros((slots,), jnp.int32).at[1].set(v)  # noqa: E731
+
+    def decoded(kernel, tokens=None):
+        """``steps`` steps under ``kernel`` on a cache of its own, greedy
+        or fed ``tokens``: the logits rows, the cache, the compiled
+        step's text, whether every expert id was valid."""
+        cache = PagedKVCache(layers, blocks, block_size, one, stored,
+                             seq // block_size, dtype=dtype)
+        table = cache.alloc_sequence(plen + steps)
+        cache.write_prompt(table, cs[:, 0], krs[:, 0], plen)
+        tables = np.zeros((slots, seq // block_size), np.int32)
+        tables[1] = table
+        step = jax.jit(lambda *a: mdl.decode_step(
+            *a, block_size=block_size, attention_kernel=kernel,
+            return_routing=True), donate_argnums=(3, 4))
+        tok, rows, fed, valid, text = first, [], [], True, None
+        for i, pos in enumerate(range(plen, plen + steps)):
+            tok = tok if tokens is None else tokens[i]
+            fed.append(tok)
+            args = (params, vec(tok), vec(pos), cache.k, cache.v,
+                    jnp.asarray(tables), vec(pos + 1))
+            if text is None:
+                text = step.lower(*args).compile().as_text()
+            out, cache.k, cache.v, ids = step(*args)
+            rows.append(out[1])
+            ids = np.sort(np.asarray(ids[:, 1]), axis=-1)
+            valid = valid and bool(ids.min() >= 0 and ids.max() < 16
+                                   and (ids[:, 1:] != ids[:, :-1]).all())
+            tok = int(jnp.argmax(out[1]))
+        return jnp.stack(rows), cache, fed, valid, text
+
+    rows, cache, fed, valid, text = decoded("paged")
+    toks[0, plen:plen + steps] = fed
+    arm = decode_attention_arm("auto", cache.k.shape, cache.v.shape)
+    calls = len(re.findall(r"%paged_latent_decode[.\d]* = [^\n]*"
+                           r"custom_call_target=\"tpu_custom_call\"", text))
     want = jax.jit(mdl.apply)(params, jnp.asarray(toks[:, :plen + steps]))
-    err = _max_err(jnp.stack(rows), want[0, plen:plen + steps])
-    _require(np.isfinite(np.asarray(jnp.stack(rows))).all() and err <= tol
-             and valid,
+    err = _max_err(rows, want[0, plen:plen + steps])
+    _require(np.isfinite(np.asarray(rows)).all() and err <= tol and valid,
              f"latent decode: absorbed against expanded {err}, expert ids "
              f"valid: {valid}")
-    return {"absorbed_vs_expanded": round(err, 5), "steps": steps,
+    rows_d, cache_d, _, valid_d, text_d = decoded("dense", fed)
+    err_d = _max_err(rows, rows_d)
+    # (the gather's scatter sends an idle slot's row to the null block)
+    same = all(bool(jnp.array_equal(a[0, 1:], b[0, 1:])) for a, b in
+               ((cache.k, cache_d.k), (cache.v, cache_d.v)))
+    _require(err_d <= tol and same and valid_d
+             and "paged_latent_decode" not in text_d,
+             f"latent decode: the kernel against the gather {err_d}, layer "
+             f"0's rows equal: {same}")
+    return {"absorbed_vs_expanded": round(err, 5),
+            "paged_vs_dense": round(err_d, 5), "layer0_rows_equal": same,
+            "auto_arm": arm, "paged_calls": calls, "steps": steps,
             "cache_arrays": [list(cache.k.shape), list(cache.v.shape)],
             "cache_row_widths_stored": list(stored)}
 
@@ -949,6 +986,9 @@ def main() -> None:
                  and kern["flash_mosaic_calls"]["backward"] >= 1
                  and kern["paged_mosaic_calls"]["total"] >= 1,
                  f"a kernel ran interpreted: {kern}")
+        latent = kern["latent_decode"]
+        _require(latent["auto_arm"] == "paged" and latent["paged_calls"] == 2,
+                 f"the latent step is not on its paged kernel: {latent}")
 
     print(json.dumps({
         "phase": "summary", "versions": gate["versions"],

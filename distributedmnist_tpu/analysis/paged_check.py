@@ -13,9 +13,13 @@ bit-identical — only the cost model breaks):
   ``servesvc/`` re-materializes ``[slots, max_context]`` K/V every
   iteration.  On a TPU the step reads the cache through the paged
   kernel, which walks block tables in-kernel over the rows as stored
-  (``ops/pallas_paged_attention.py``); the oracle is for tests, and
-  the gather *arm* (a CPU, a toy head's rows, a latent block, or
-  ``decode.attention_kernel = dense``) lives in
+  (``ops/pallas_paged_attention.py``: keys and values a head through
+  ``paged_attention_write``; a latent block's one row a token for all
+  heads, two arrays of two widths, through
+  ``paged_latent_attention_write``, which takes the absorbed queries
+  and is pinned to its gather arm's weights, rounded once); the oracle
+  is for tests, and the gather *arm* (a CPU, a toy head's or a toy
+  latent's rows, or ``decode.attention_kernel = dense``) lives in
   ``models/transformer.py``, outside this lint's scope on purpose.
 * **per-iteration table rebuild** — constructing the block-table
   array (``zeros``/``asarray``/``array`` over a ``table``-named

@@ -839,9 +839,11 @@ class DecodeConfig:
       view — O(table width) per token. ``"dense"`` names the gather
       (the oracle the parity tests pin), ``"paged"`` the kernel
       (interpreted off the TPU). Numerics are pinned equal for live
-      slots (tests/test_paged_attention.py). A latent block reads its
-      one row a token through its own gather under ``"auto"`` and
-      ``"dense"``.
+      slots (tests/test_paged_attention.py). A latent block's one row
+      a token takes the same arms by the same rule, asked of both its
+      arrays and of a page (whole tiles: the latent form of the kernel
+      writes the token's row through its tile); ``"paged"`` named for
+      arrays that form does not compile for raises.
     """
 
     decode_slots: int = 4

@@ -551,7 +551,7 @@ class Block(NamedTuple):
     # the attention sublayer of :func:`decode_step`, one token against the
     # paged cache: None is the plain block's (:func:`_decode_attn`), else
     # ``(x, blk, li, k_cache, v_cache, block_tables, positions, blk_ids,
-    # offs, live, attention_kernel=) -> (x, k_cache, v_cache)``
+    # offs, live, lengths=, attention_kernel=) -> (x, k_cache, v_cache)``
     decode_attn: Callable[..., Any] | None = None
     # set where the first sublayer is a state-space mixer and not
     # attention (``attn`` is then that mixer over sequences, takes
@@ -1189,7 +1189,8 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     and the oracle); ``"dense"`` and ``"paged"`` name an arm whatever
     the input. Both share the pinned numerics below; parity across them
     is tested in tests/test_paged_attention.py. A latent block has its
-    own read (``attn``), a gather.
+    own read (``attn``), which takes the same arms by the same rule
+    through the latent form of the kernel.
 
     Returns (logits [S, vocab] float32, k_cache, v_cache) with this
     token's K/V written at its block/offset. Attention numerics match
@@ -1219,7 +1220,8 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
         else:
             x, k_cache, v_cache = attn(
                 x, blk, li, k_cache, v_cache, block_tables, positions,
-                blk_ids, offs, live, attention_kernel=attention_kernel)
+                blk_ids, offs, live, lengths=lengths,
+                attention_kernel=attention_kernel)
         x, aux = ffns[li](x, blk)
         _loss_of(aux, routed)
     out = (_head(p, residual.end(x), norm=norm), k_cache, v_cache)
@@ -1327,7 +1329,8 @@ def decode_step_with_state(params: Params, tokens: jax.Array,
         elif bk.decode_attn is not None:
             x, k_cache, v_cache = bk.decode_attn(
                 x, blk, attended, k_cache, v_cache, block_tables, positions,
-                blk_ids, offs, live, attention_kernel=attention_kernel)
+                blk_ids, offs, live, lengths=lengths,
+                attention_kernel=attention_kernel)
             attended += 1
         else:
             x, k_cache, v_cache = _decode_attn(
@@ -1347,29 +1350,35 @@ def decode_step_with_state(params: Params, tokens: jax.Array,
 
 
 def decode_attention_arm(attention_kernel: str,
-                         cache_shape: tuple[int, ...]) -> str:
+                         *cache_shapes: tuple[int, ...]) -> str:
     """``"paged"`` or ``"gather"``: how a decode step asked for
-    ``attention_kernel`` (``decode.attention_kernel``) reads a cache
-    array of ``cache_shape``. ``"dense"`` and ``"paged"`` name the arm.
-    ``"auto"`` decides by what is there: keys and values a head
-    ([L, N, B, h, width]; a latent's one row a token for all heads is
-    read by its own block's gather) stored in whole lanes (what
-    ``kv_cache.stored_head_dim`` answers on a TPU, and not on a CPU or
-    for a toy head) where the process's devices are TPUs
-    (``jax.devices()``: what its jitted step runs on) go through the
-    kernel, which takes such rows as they lie; anything else through
-    the gather."""
+    ``attention_kernel`` (``decode.attention_kernel``) reads cache
+    arrays of ``cache_shapes`` (one where both are alike). ``"dense"``
+    and ``"paged"`` name the arm.
+    ``"auto"`` decides by what is there: where the process's devices
+    are TPUs (``jax.devices()``: what its jitted step runs on), arrays
+    the kernel compiles for as they lie go through it, anything else
+    through the gather. Keys and values a head, [L, N, B, h, width]:
+    rows stored in whole lanes (what ``kv_cache.stored_head_dim``
+    answers on a TPU, and not on a CPU or for a toy head). A latent's
+    one row a token for all heads, [L, N, B, width] and another width
+    for its rotated key: every array's rows whole lanes AND a page whole
+    tiles, because the kernel writes the token's row through its tile
+    (``ops/pallas_paged_attention.py::latent_rows_as_they_lie``, the
+    question the compiled kernel itself raises on)."""
     if attention_kernel not in DECODE_ATTENTION_KERNELS:
         raise ValueError(
             f"decode.attention_kernel must be one of "
             f"{', '.join(DECODE_ATTENTION_KERNELS)}, got "
             f"{attention_kernel!r}")
-    if len(cache_shape) != 5:
-        return "gather"
     if attention_kernel == "auto":
-        whole_lanes = cache_shape[-1] % 128 == 0
+        if len(cache_shapes[0]) == 4:
+            from ..ops.pallas_paged_attention import latent_rows_as_they_lie
+            as_they_lie = latent_rows_as_they_lie(*cache_shapes)
+        else:
+            as_they_lie = all(shape[-1] % 128 == 0 for shape in cache_shapes)
         on_tpus = jax.devices()[0].platform == "tpu"
-        return "paged" if whole_lanes and on_tpus else "gather"
+        return "paged" if as_they_lie and on_tpus else "gather"
     return "paged" if attention_kernel == "paged" else "gather"
 
 
@@ -1495,8 +1504,8 @@ def _grouped_decode_attn(x, blk, li, k_cache, v_cache, block_tables,
 
 @jax.named_scope("attention")
 def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
-                             positions, blk_ids, offs, live, *, qk_nope_dim,
-                             scale, rotate, norm, out_norm,
+                             positions, blk_ids, offs, live, *, lengths,
+                             qk_nope_dim, scale, rotate, norm, out_norm,
                              attention_kernel):
     """One layer's latent attention sublayer of :func:`decode_step`
     (arXiv:2405.04434 §2.1, the absorbed form): the same function as
@@ -1509,16 +1518,24 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     With ``wkv_b`` = ``[W_uk | W_uv]`` a head: the query's unrotated part
     goes through ``W_uk`` (``q_c[h] = q_n[h] W_uk[h]ᵀ``, scope
     ``latent_absorb``), scores are ``(q_c[h]·c_t + q_r[h]·k_r,t)·scale``
-    over the rows gathered once for all heads (``cache_gather``), the
-    softmax in float32, the weighted sum of latents ``o_c[h]`` goes
-    through ``W_uv`` (``latent_absorb``), then ``wo``, the output's norm
-    where the block has one, and the residual. A block with ``wq``
+    over every live row, the softmax in float32, the weighted sum of
+    latents ``o_c[h]`` (the weights rounded to the cache's dtype once)
+    goes through ``W_uv`` (``latent_absorb``), then ``wo``, the output's
+    norm where the block has one, and the residual. A block with ``wq``
     projects its query at full rank; one with ``w_hgate`` gates the
-    output a head before ``wo`` (:func:`_head_gated`)."""
-    if attention_kernel == "paged":
-        raise NotImplementedError(
-            "decode.attention_kernel='paged': the paged kernel reads keys "
-            "and values a head; a latent cache is read by the dense gather")
+    output a head before ``wo`` (:func:`_head_gated`).
+
+    The rows are read by the arm :func:`decode_attention_arm` answers
+    for both arrays. ``"paged"``: one call of the latent kernel
+    (ops/pallas_paged_attention.py::paged_latent_attention_write) over
+    the two arrays whole, which puts the token's rows where the table
+    says and walks the live pages; ``cache_write`` holds what is left
+    outside it, the rows cast and padded to the stored widths.
+    ``"gather"`` (the oracle, a CPU, ``dense``): the rows scattered
+    (``cache_write``), the table's width gathered once for all heads
+    (``cache_gather``)."""
+    arm = decode_attention_arm(attention_kernel, k_cache.shape,
+                               v_cache.shape)
     num_slots = x.shape[0]
     latent_dim = blk["wkv_b"].shape[0]
     rope_dim = blk["wkv_a"].shape[1] - latent_dim
@@ -1534,26 +1551,50 @@ def _latent_decode_attention(x, blk, li, k_cache, v_cache, block_tables,
     # a slot's token is its own sequence's: positions [S] turn rows [S]
     q_r = rotate(q[..., qk_nope_dim:], positions)
     k_r = rotate(kv_a[:, None, latent_dim:], positions)[:, 0]
-    with jax.named_scope("cache_write"):
-        k_cache = k_cache.at[li, blk_ids, offs, :latent_dim].set(
-            c.astype(k_cache.dtype))
-        v_cache = v_cache.at[li, blk_ids, offs, :rope_dim].set(
-            k_r.astype(v_cache.dtype))
-    with jax.named_scope("latent_absorb"):
-        q_c = jnp.einsum("shn,rhn->shr", q[..., :qk_nope_dim],
-                         blk["wkv_b"][..., :qk_nope_dim])
-    with jax.named_scope("cache_gather"):
-        cs = k_cache[li][block_tables][..., :latent_dim].reshape(
-            num_slots, ctx, latent_dim)
-        krs = v_cache[li][block_tables][..., :rope_dim].reshape(
-            num_slots, ctx, rope_dim)
-    scores = (jnp.einsum("shr,skr->shk", q_c, cs,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("she,ske->shk", q_r, krs,
-                           preferred_element_type=jnp.float32)) * scale
-    scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
-    w = jax.nn.softmax(scores, axis=-1)
-    o_c = jnp.einsum("shk,skr->shr", w.astype(cs.dtype), cs)
+
+    def absorbed_query():
+        with jax.named_scope("latent_absorb"):
+            return jnp.einsum("shn,rhn->shr", q[..., :qk_nope_dim],
+                              blk["wkv_b"][..., :qk_nope_dim])
+
+    if arm == "paged":
+        # the kernel takes the two cache arrays whole, as input and
+        # output in one buffer each, with the layer's index: it puts
+        # this token's rows into them, then walks the slot's live pages;
+        # no slice of a layer, no gathered view of the table's width, no
+        # float32 scores of it. Nothing but zeros is ever written beside
+        # a row's values, so the padded row is what a scatter of its
+        # first elements leaves
+        from ..ops.pallas_paged_attention import paged_latent_attention_write
+        q_c = absorbed_query()
+        with jax.named_scope("cache_write"):
+            new_c, new_kr = (
+                jnp.pad(row.astype(cache.dtype),
+                        ((0, 0), (0, cache.shape[-1] - row.shape[-1])))
+                for row, cache in ((c, k_cache), (k_r, v_cache)))
+        o_c, k_cache, v_cache = paged_latent_attention_write(
+            q_c, q_r, new_c, new_kr, k_cache, v_cache, block_tables, lengths,
+            layer=li, scale=scale)
+        o_c = o_c.astype(q_c.dtype)
+    else:
+        with jax.named_scope("cache_write"):
+            k_cache = k_cache.at[li, blk_ids, offs, :latent_dim].set(
+                c.astype(k_cache.dtype))
+            v_cache = v_cache.at[li, blk_ids, offs, :rope_dim].set(
+                k_r.astype(v_cache.dtype))
+        q_c = absorbed_query()
+        with jax.named_scope("cache_gather"):
+            cs = k_cache[li][block_tables][..., :latent_dim].reshape(
+                num_slots, ctx, latent_dim)
+            krs = v_cache[li][block_tables][..., :rope_dim].reshape(
+                num_slots, ctx, rope_dim)
+        scores = (jnp.einsum("shr,skr->shk", q_c, cs,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("she,ske->shk", q_r, krs,
+                               preferred_element_type=jnp.float32)) * scale
+        scores = jnp.where(live[:, None, :], scores, _DECODE_NEG)
+        w = jax.nn.softmax(scores, axis=-1)
+        o_c = jnp.einsum("shk,skr->shr", w.astype(cs.dtype), cs)
     with jax.named_scope("latent_absorb"):
         o = jnp.einsum("shr,rhv->shv", o_c, blk["wkv_b"][..., qk_nope_dim:])
     o = _head_gated(o, h, blk)

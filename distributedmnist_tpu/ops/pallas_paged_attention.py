@@ -56,6 +56,31 @@ into a uniform average of cache garbage — both are
 unspecified-by-contract (the decode loop never reads idle rows), and
 the parity tests compare live slots only.
 
+**The latent form** (:func:`paged_latent_attention`,
+:func:`paged_latent_attention_write`; its own body and its own call,
+``paged_latent_decode``, sharing the work items and the tile rule): a
+latent block's cache (``models/transformer.py::
+_latent_decode_attention``) keeps ONE row a token for all heads in each
+of two arrays of different widths, ``[layers, num_blocks, block_size,
+latent]`` and ``[.., rope]``, and the value is the latent itself. It
+takes the absorbed queries ``q_c`` [slots, heads, latent] and ``q_r``
+[slots, heads, rope], both arrays whole with the layer's index, the
+tables and the lengths, and with the token's two rows writes them
+first. An item is ``TARGET_ROWS`` cached positions whatever a page
+holds; its scores are two products over one fetch of its pages, every
+head against every row with no mask of heads, and the latent page is
+fetched once for both the scores and the weighted sum. Queries and
+outputs lie in HBM and move a slot at a time (a slot's ``[heads,
+width]`` of a model with 128 heads is no whole-array block of VMEM).
+One row a token is less than a tile, so the written row always goes
+through the tile that holds it. Its numerics are pinned to ITS gather
+arm, not to the above: the weights are rounded to the cache's dtype
+ONCE before the product with the latents (``w.astype(cs.dtype)`` there),
+no three pieces. Compiled it takes rows of whole lanes in both arrays
+and, to write, pages of whole tiles (:func:`latent_rows_as_they_lie`,
+which ``decode_attention_arm`` asks before it answers ``paged``), and
+RAISES on anything else: no scatter and gather inside it.
+
 Rows are taken as stored: a query padded with zeros beyond ``head_dim``
 adds nothing to a score whatever the lanes beyond it hold, and the
 output's lanes beyond ``head_dim`` are dropped. Compiled for a TPU the
@@ -63,7 +88,8 @@ row has to fill whole lanes (``width % 128 == 0``) and a block's rows
 whole tiles (``block_size · heads % 16 == 0`` in bfloat16): what
 ``kv_cache.stored_head_dim`` makes of a real model's cache. Anything
 else runs interpreted (``interpret=None`` picks the interpreter off the
-TPU, same as the training kernel) or through the gather.
+TPU, same as the training kernel) or, the plain form, through the
+gather.
 """
 
 from __future__ import annotations
@@ -563,6 +589,494 @@ def _paged_call(q, new_rows, k_pages, v_pages, block_tables, lengths, *,
     out, kp, vp = call(*scalars, qp, *new_rows, kp, vp)
     return (out[:, :given, :hd], kp.reshape(k_pages.shape),
             vp.reshape(v_pages.shape))
+
+
+#: cached positions an item of the latent kernel holds, whatever a page
+#: holds: ``TARGET_ROWS // block_size`` table entries, so that an item is
+#: the same rows at 16 positions a page and at 128 (PERF.md has the
+#: chip's readings)
+TARGET_ROWS = 1024
+
+#: table entries whose DMAs one turn of the latent kernel's page loop
+#: holds written out (the loop has ``pages // PAGE_UNROLL`` turns, or
+#: the largest divisor of ``pages`` under it). An item of 16-row pages
+#: is 64 entries: all written out they were seconds of every step's
+#: lowering, one a turn a loop of 64 scalar bodies on the chip (PERF.md
+#: has both readings)
+PAGE_UNROLL = 8
+
+
+def _latent_kernel(tables_ref, lengths_ref, slot_ref, chunk_ref, count_ref,
+                   layer_ref, qc_hbm, qr_hbm, *refs, scale: float,
+                   block_size: int, pages: int, table_width: int,
+                   writes: bool, tile_rows: int, unroll: int):
+    """Every work item of one layer of a latent cache, in one loop; with
+    ``writes``, each live slot's new row put into both arrays first.
+
+    The scalar-prefetch operands are :func:`_paged_kernel`'s. Everything
+    else lies in HBM and is copied by hand: the queries ``qc_hbm``
+    [slots, heads, latent width] and ``qr_hbm`` [slots, heads, rope
+    width] (a slot's pair comes in while the slot before it is
+    computed), the cache arrays ``c_hbm`` [layers, blocks, block_size,
+    latent width] and ``kr_hbm`` [.., rope width], the output ``o_hbm``
+    [slots, heads, latent width] float32 (a slot's goes out while the
+    next is computed). An item is ``pages`` table entries, one DMA an
+    entry an array; a page serves every head, and the latent page both
+    products. An entry past the slot's live pages fetches the last live
+    one again (its rows lie past ``length`` and are masked), so a dead
+    entry of the table is never looked up and a buffer never holds
+    anything but cached rows.
+
+    With ``writes`` the token's rows ``c_new`` / ``kr_new`` [slots, 1,
+    width] come in beside the queries and the cache arrays are outputs
+    too, aliased to the inputs. One row is less than a tile of the cache
+    as the device stores it: ``tile_rows`` > 0, each live slot's tile of
+    that many rows comes into ``c_rmw`` / ``kr_rmw`` [slots, tile_rows,
+    width], takes the row there and goes back whole (two rounds of
+    copies, each all in flight together; no two slots share a page);
+    0, interpreted with pages that are no whole tiles, the row is
+    copied as it is."""
+    if writes:
+        c_new, kr_new, _, _, o_hbm, c_hbm, kr_hbm, *scratch = refs
+    else:
+        c_hbm, kr_hbm, o_hbm, *scratch = refs
+    (c_buf, kr_buf, sem, qc_buf, qr_buf, q_sem, m_ref, l_ref, acc_ref,
+     o_buf, o_sem, *write_scratch) = scratch
+    rows = pages * block_size
+    num_slots = lengths_ref.shape[0]
+    layer, num_items = layer_ref[0], count_ref[0]
+
+    def live_pages(slot):
+        return jnp.minimum(pl.cdiv(lengths_ref[slot], block_size),
+                           table_width)
+
+    # -- the token's row into both arrays -------------------------------
+    if writes:
+        write_sem = write_scratch[0]
+        pairs = ((c_new, c_hbm), (kr_new, kr_hbm))
+
+        def where_written(slot):
+            at = lengths_ref[slot] - 1
+            return tables_ref[slot, at // block_size], at % block_size
+
+        def each_written_row(copies_of, act):
+            """``act`` on the DMAs of every slot that has a row to
+            write: a slot of length 0 writes nothing (its table is never
+            looked up), nor does a position beyond the table."""
+            def one(slot, _):
+                n = lengths_ref[slot]
+
+                @pl.when((n > 0) & (n <= table_width * block_size))
+                def _():
+                    for dma in copies_of(slot):
+                        act(dma)
+            jax.lax.fori_loop(0, num_slots, one, None)
+
+        def tile_copies(slot, back: bool):
+            block, off = where_written(slot)
+            run = pl.ds(pl.multiple_of(off // tile_rows * tile_rows,
+                                       tile_rows), tile_rows)
+            both = tuple((hbm.at[layer, block, run], rmw.at[slot])
+                         for (_, hbm), rmw in zip(pairs, write_scratch[1:]))
+            return tuple(pltpu.make_async_copy(*(pair[::-1] if back
+                                                 else pair), write_sem.at[i])
+                         for i, pair in enumerate(both))
+
+        def row_copies(slot):
+            if tile_rows:
+                return tile_copies(slot, back=True)
+            block, off = where_written(slot)
+            return tuple(pltpu.make_async_copy(
+                new.at[slot], hbm.at[layer, block, pl.ds(off, 1)],
+                write_sem.at[i]) for i, (new, hbm) in enumerate(pairs))
+
+        def put_new_row(slot, _):
+            """The slot's row into its tile, in VMEM: one select."""
+            off = (lengths_ref[slot] - 1) % block_size % tile_rows
+            for (new, _), rmw in zip(pairs, write_scratch[1:]):
+                row_id = jax.lax.broadcasted_iota(jnp.int32, rmw.shape[1:], 0)
+                rmw[slot] = jnp.where(row_id == off, new[slot], rmw[slot])
+
+        if tile_rows:
+            tiles_in = functools.partial(tile_copies, back=False)
+            each_written_row(tiles_in, lambda dma: dma.start())
+            each_written_row(tiles_in, lambda dma: dma.wait())
+            # (a slot with nothing to write selects into a tile nobody
+            # reads)
+            jax.lax.fori_loop(0, num_slots, put_new_row, None)
+        each_written_row(row_copies, lambda dma: dma.start())
+
+    # -- an idle slot's output: zeros ----------------------------------
+    def out_copy(slot):
+        return pltpu.make_async_copy(o_buf, o_hbm.at[slot], o_sem.at[0])
+
+    def each_idle_slot(act):
+        def one(slot, _):
+            @pl.when(lengths_ref[slot] <= 0)
+            def _():
+                act(out_copy(slot))
+        jax.lax.fori_loop(0, num_slots, one, None)
+
+    o_buf[...] = jnp.zeros_like(o_buf)
+    each_idle_slot(lambda dma: dma.start())
+    each_idle_slot(lambda dma: dma.wait())
+
+    # -- the items ------------------------------------------------------
+    def query_copies(item, which):
+        slot = slot_ref[item]
+        return (pltpu.make_async_copy(qc_hbm.at[slot], qc_buf.at[which],
+                                      q_sem.at[which, 0]),
+                pltpu.make_async_copy(qr_hbm.at[slot], qr_buf.at[which],
+                                      q_sem.at[which, 1]))
+
+    def page_copies(item, half, p):
+        slot = slot_ref[item]
+        entry = jnp.minimum(chunk_ref[item] * pages + p,
+                            live_pages(slot) - 1)
+        block = tables_ref[slot, entry]
+        run = pl.ds(pl.multiple_of(p * block_size, block_size), block_size)
+        return (pltpu.make_async_copy(c_hbm.at[layer, block],
+                                      c_buf.at[half, run], sem.at[half, 0]),
+                pltpu.make_async_copy(kr_hbm.at[layer, block],
+                                      kr_buf.at[half, run], sem.at[half, 1]))
+
+    def each_page(item, half, act):
+        """``act`` on the two DMAs of each of the item's pages: a loop
+        on the chip of ``unroll`` pages a turn, not all of them in the
+        trace (64 pages of 16 rows an item, written out, were seconds of
+        every step's lowering)."""
+        def some(turn, _):
+            for p in range(unroll):
+                for dma in page_copies(item, half, turn * unroll + p):
+                    act(dma)
+        jax.lax.fori_loop(0, pages // unroll, some, None)
+
+    def start(item, half, which):
+        """The item's pages on their way, and its slot's queries where
+        it is the slot's first: into the pair of buffers the slot being
+        computed does not read."""
+        @pl.when(chunk_ref[item] == 0)
+        def _():
+            for dma in query_copies(item, which):
+                dma.start()
+        each_page(item, half, lambda dma: dma.start())
+
+    if writes:
+        # landed before the first page is fetched: position length - 1
+        # is read through the cache
+        each_written_row(row_copies, lambda dma: dma.wait())
+
+    @pl.when(num_items > 0)
+    def _():
+        start(0, 0, 0)
+
+    position = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+
+    def item_step(item, carry):
+        # ``which``: the pair of query buffers this item's slot reads;
+        # ``sent``: the slots whose output has been sent on its way
+        which, sent = carry
+        half = item % 2
+        slot, chunk = slot_ref[item], chunk_ref[item]
+        which = jnp.where(chunk == 0, 1 - which, which)
+
+        @pl.when(item + 1 < num_items)
+        def _():
+            start(item + 1, 1 - half, 1 - which)
+
+        length = lengths_ref[slot]
+        num_chunks = pl.cdiv(live_pages(slot), pages)
+
+        @pl.when(chunk == 0)
+        def _():
+            for dma in query_copies(item, which):
+                dma.wait()
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        each_page(item, half, lambda dma: dma.wait())
+        c = c_buf[half]                                   # [rows, latent]
+        exact = (jax.lax.Precision.HIGHEST
+                 if c.dtype == jnp.float32 else None)
+        across = (((1,), (1,)), ((), ()))
+        sc = (jax.lax.dot_general(qc_buf[which], c, across, precision=exact,
+                                  preferred_element_type=jnp.float32)
+              + jax.lax.dot_general(qr_buf[which], kr_buf[half], across,
+                                    precision=exact,
+                                    preferred_element_type=jnp.float32)
+              ) * scale                                   # [h, rows]
+        sc = jnp.where(position < length - chunk * rows, sc, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        w = jnp.exp(sc - m_new)                           # [h, rows] f32
+        corr = jnp.exp(m_prev - m_new)                    # [h, 1]
+        l_new = l_ref[:, :1] * corr + jnp.sum(w, axis=1, keepdims=True)
+        # the weights rounded to the cache's dtype once, as the gather
+        # arm rounds them
+        wc = jnp.dot(w.astype(c.dtype), c, precision=exact,
+                     preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + wc
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        last = chunk == num_chunks - 1
+
+        @pl.when(last)
+        def _():
+            @pl.when(sent > 0)
+            def _():
+                out_copy(slot).wait()   # the slot before's has left o_buf
+            o_buf[...] = acc_ref[...] / l_ref[:, :1]
+            out_copy(slot).start()
+
+        return which, sent + last.astype(jnp.int32)
+
+    _, sent = jax.lax.fori_loop(0, num_items, item_step,
+                                (jnp.int32(1), jnp.int32(0)))
+
+    @pl.when(sent > 0)
+    def _():
+        out_copy(0).wait()
+
+
+def _latent_rows_tile(c_pages: jax.Array) -> int | None:
+    """:func:`_new_rows_tile` of a latent array ``[layers, blocks,
+    block_size, width]``: one row a token."""
+    return _new_rows_tile(jax.ShapeDtypeStruct(
+        (*c_pages.shape[:3], 1, c_pages.shape[3]), c_pages.dtype))
+
+
+def _latent_call(q_c, q_r, new_rows, c_pages, kr_pages, block_tables,
+                 lengths, *, layer, scale, target_rows, interpret):
+    """One call of the latent kernel over the two cache arrays whole:
+    the attention alone, or with ``new_rows`` (the latent's and the
+    rotated key's) the attention and the arrays the rows were written
+    to."""
+    num_slots, num_heads, latent = q_c.shape
+    rope = q_r.shape[-1]
+    layers, num_blocks, block_size, c_row = c_pages.shape
+    kr_row = kr_pages.shape[-1]
+    assert kr_pages.shape[:3] == c_pages.shape[:3], (c_pages.shape,
+                                                     kr_pages.shape)
+    assert q_r.shape[:2] == (num_slots, num_heads)
+    assert c_row >= latent and kr_row >= rope
+    assert block_tables.shape[0] == num_slots == lengths.shape[0]
+    table_width = block_tables.shape[1]
+    interpret = _interpreted(interpret)
+    lengths = lengths.astype(jnp.int32)
+    pages = max(1, min(target_rows // block_size, table_width))
+    rows = pages * block_size
+    slot, chunk, num_items = _work_items(lengths, block_size, table_width,
+                                         pages)
+    # a query zero beyond its own width adds nothing to a score whatever
+    # the row's lanes beyond it hold; the heads whole tiles (those
+    # beyond the model's own are zeros and are cut off the output)
+    heads = -(-num_heads // 16) * 16
+    qc, qr = (jnp.pad(q.astype(pages_.dtype),
+                      ((0, 0), (0, heads - num_heads),
+                       (0, pages_.shape[-1] - q.shape[-1])))
+              for q, pages_ in ((q_c, c_pages), (q_r, kr_pages)))
+    scalars = (block_tables.astype(jnp.int32), lengths, slot, chunk,
+               num_items, jnp.asarray(layer, jnp.int32).reshape(1))
+
+    writes = new_rows is not None
+    tile_rows = (_latent_rows_tile(c_pages) or 0) if writes else 0
+    unroll = max(u for u in range(1, min(PAGE_UNROLL, pages) + 1)
+                 if pages % u == 0)
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, block_size=block_size, pages=pages,
+        table_width=table_width, writes=writes, tile_rows=tile_rows,
+        unroll=unroll)
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    a_row_a_slot = [pl.BlockSpec((num_slots, 1, row), lambda *_: (0, 0, 0))
+                    for row in (c_row, kr_row)]
+    out_shape = jax.ShapeDtypeStruct((num_slots, heads, c_row), jnp.float32)
+    scratch_shapes = [
+        pltpu.VMEM((2, rows, c_row), c_pages.dtype),
+        pltpu.VMEM((2, rows, kr_row), kr_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((2, heads, c_row), c_pages.dtype),  # a slot's queries
+        pltpu.VMEM((2, heads, kr_row), kr_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((heads, _LANE), jnp.float32),       # running max
+        pltpu.VMEM((heads, _LANE), jnp.float32),       # running denom
+        pltpu.VMEM((heads, c_row), jnp.float32),       # accumulator
+        pltpu.VMEM((heads, c_row), jnp.float32),       # a slot's output
+        pltpu.SemaphoreType.DMA((1,)),
+    ]
+    if writes:
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((2,)))
+    if tile_rows:
+        scratch_shapes += [pltpu.VMEM((num_slots, tile_rows, a.shape[-1]),
+                                      a.dtype) for a in (c_pages, kr_pages)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(1,),
+        in_specs=([where_it_lies] * 2 + (a_row_a_slot if writes else [])
+                  + [where_it_lies] * 2),
+        out_specs=((where_it_lies,) * 3 if writes else where_it_lies),
+        scratch_shapes=scratch_shapes,
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=((out_shape,
+                    jax.ShapeDtypeStruct(c_pages.shape, c_pages.dtype),
+                    jax.ShapeDtypeStruct(kr_pages.shape, kr_pages.dtype))
+                   if writes else out_shape),
+        # the cache arrays (operands 10 and 11, the scalars counted) are
+        # outputs 1 and 2: one buffer each
+        input_output_aliases=({len(scalars) + 4: 1, len(scalars) + 5: 2}
+                              if writes else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_decode",
+    )
+    if not writes:
+        return call(*scalars, qc, qr, c_pages, kr_pages)[:, :num_heads,
+                                                         :latent]
+    out, c_pages, kr_pages = call(
+        *scalars, qc, qr, *(new[:, None] for new in new_rows), c_pages,
+        kr_pages)
+    return out[:, :num_heads, :latent], c_pages, kr_pages
+
+
+def latent_rows_as_they_lie(*shapes: tuple[int, ...], writes: bool = True,
+                            itemsize: int = 2) -> bool:
+    """Whether Mosaic takes latent cache arrays of ``shapes`` ([layers,
+    blocks, block_size, width]) where they lie: every array's rows whole
+    lanes and, for the form that ``writes``, a page whole tiles (eight
+    32-bit sublanes: 16 rows of ``itemsize`` 2, the narrowest a replica
+    stores, which the wider's 8 divide), since a token's one row goes
+    through the tile that holds it. The one question the decode step's
+    arm (``models/transformer.py::decode_attention_arm``) and the
+    compiled entry points ask."""
+    tile = 8 * max(1, 4 // itemsize)
+    return all(shape[-1] % _LANE == 0 and not (writes and shape[2] % tile)
+               for shape in shapes)
+
+
+def _held_to_the_lanes(c_pages, kr_pages, interpret, writes):
+    """A compiled call of arrays Mosaic does not take raises: the gather
+    arm is the caller's to choose (``decode_attention_arm`` asks the
+    same question first), not a path inside this one."""
+    if not _interpreted(interpret) and not latent_rows_as_they_lie(
+            c_pages.shape, kr_pages.shape, writes=writes,
+            itemsize=c_pages.dtype.itemsize):
+        raise ValueError(
+            f"the latent paged kernel compiles for rows of whole lanes"
+            f"{' and pages of whole tiles' if writes else ''}, not for "
+            f"cache arrays {c_pages.shape} and {kr_pages.shape} of "
+            f"{c_pages.dtype}: decode.attention_kernel = dense (or auto) "
+            f"reads these through the gather")
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "target_rows",
+                                             "interpret"))
+def paged_latent_attention(q_c: jax.Array, q_r: jax.Array,
+                           c_pages: jax.Array, kr_pages: jax.Array,
+                           block_tables: jax.Array, lengths: jax.Array, *,
+                           layer: jax.Array | int = 0, scale: float,
+                           target_rows: int = TARGET_ROWS,
+                           interpret: bool | None = None) -> jax.Array:
+    """Single-query attention over a paged LATENT cache, one layer, in
+    the absorbed form (``models/transformer.py::
+    _latent_decode_attention``): a cached token is one row for all
+    heads in each of two arrays, and the value is the latent itself.
+
+    ``q_c``: [slots, heads, latent], the query's unrotated part through
+    ``W_uk``; ``q_r``: [slots, heads, rope], its rotated part.
+    ``c_pages``: [layers, num_blocks, block_size, latent or wider], the
+    normed latents; ``kr_pages``: [.., rope or wider], the rotated keys;
+    passed whole, as they lie, with ``layer`` the one to read. The
+    token's own row is in both already (this form reads only;
+    :func:`paged_latent_attention_write` puts it there in the same
+    call). ``block_tables``, ``lengths``: as :func:`paged_attention`'s.
+    Scores are ``(q_c · c + q_r · k_r) · scale``: two products on the
+    matrix unit over one fetch of the item's pages, every head against
+    every row (no mask of heads: a row is every head's).
+
+    Numerics are pinned to the gather arm of
+    ``_latent_decode_attention`` (:func:`paged_latent_attention_dense`):
+    operands as stored, float32 accumulation, scores and softmax in
+    float32, masked positions ``-1e30``, and the weights ROUNDED ONCE to
+    the cache's dtype before the product with the latents (the plain
+    kernel's three-piece weights are not this arm's); the softmax runs
+    online over items, so the weights are rounded before the division by
+    their sum and not after it. An idle slot returns zeros.
+
+    Compiled, both rows have to fill whole lanes (what the replica's
+    cache has on a TPU; :func:`latent_rows_as_they_lie`); anything else
+    runs interpreted, or raises: the gather arm is the caller's.
+
+    Returns ``o_c`` [slots, heads, latent] float32, the weighted sum of
+    latents a head (``W_uv`` is the caller's).
+    """
+    _held_to_the_lanes(c_pages, kr_pages, interpret, writes=False)
+    return _latent_call(q_c, q_r, None, c_pages, kr_pages, block_tables,
+                        lengths, layer=layer, scale=scale,
+                        target_rows=target_rows, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "target_rows",
+                                             "interpret"))
+def paged_latent_attention_write(
+        q_c: jax.Array, q_r: jax.Array, new_c: jax.Array, new_kr: jax.Array,
+        c_pages: jax.Array, kr_pages: jax.Array, block_tables: jax.Array,
+        lengths: jax.Array, *, layer: jax.Array | int = 0, scale: float,
+        target_rows: int = TARGET_ROWS, interpret: bool | None = None,
+        ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`paged_latent_attention` of a step that has yet to store
+    its token: the same kernel writes the row, then reads.
+
+    ``new_c`` [slots, latent width as stored] and ``new_kr`` [slots,
+    rope width as stored]: the token's latent and rotated key as the
+    cache stores them (its dtype, its row's width: zeros beside the
+    values). Slot ``s``'s rows go to position ``lengths[s] - 1`` of
+    ``layer`` in both arrays, which the call takes and returns as one
+    buffer each (``input_output_aliases``: donate them, or XLA copies
+    each); one row is less than a tile of the cache as a TPU stores it,
+    so it goes through the tile that holds it (:func:`_latent_kernel`):
+    compiled, a page has to be whole tiles besides the rows whole lanes,
+    or the call raises. A slot of length 0 writes nothing.
+
+    Returns (``o_c`` [slots, heads, latent] float32, the two arrays).
+    """
+    assert new_c.shape == (q_c.shape[0], c_pages.shape[-1]), new_c.shape
+    assert new_kr.shape == (q_c.shape[0], kr_pages.shape[-1]), new_kr.shape
+    assert new_c.dtype == c_pages.dtype and new_kr.dtype == kr_pages.dtype
+    _held_to_the_lanes(c_pages, kr_pages, interpret, writes=True)
+    return _latent_call(q_c, q_r, (new_c, new_kr), c_pages, kr_pages,
+                        block_tables, lengths, layer=layer, scale=scale,
+                        target_rows=target_rows, interpret=interpret)
+
+
+def paged_latent_attention_dense(q_c: jax.Array, q_r: jax.Array,
+                                 c_pages: jax.Array, kr_pages: jax.Array,
+                                 block_tables: jax.Array,
+                                 lengths: jax.Array, *,
+                                 scale: float) -> jax.Array:
+    """The dense-gather oracle of :func:`paged_latent_attention`: one
+    layer's pages [num_blocks, block_size, width] through the full-table
+    gather, operation for operation the gather arm of
+    ``models/transformer.py::_latent_decode_attention`` (weights rounded
+    to the cache's dtype once). Live slots only: an idle slot's row is a
+    uniform average here and zeros from the kernel."""
+    num_slots, _, latent = q_c.shape
+    rope = q_r.shape[-1]
+    ctx = block_tables.shape[1] * c_pages.shape[1]
+    cs = c_pages[block_tables][..., :latent].reshape(num_slots, ctx, latent)
+    krs = kr_pages[block_tables][..., :rope].reshape(num_slots, ctx, rope)
+    live = jnp.arange(ctx)[None, :] < lengths[:, None]
+    scores = (jnp.einsum("shr,skr->shk", q_c.astype(cs.dtype), cs,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("she,ske->shk", q_r.astype(krs.dtype), krs,
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(live[:, None, :], scores, _NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("shk,skr->shr", w.astype(cs.dtype), cs,
+                      preferred_element_type=jnp.float32)
 
 
 def paged_attention_dense(q: jax.Array, k_pages: jax.Array,

@@ -1111,9 +1111,10 @@ class DecodeReplica(ServingReplica):
             # compiled step (one a layer on a TPU; none where the kernel
             # runs interpreted, or not at all)
             "attention_arm": [decode_attention_arm(
-                self.dcfg.attention_kernel, self.cache.k.shape)] * len(steps),
+                self.dcfg.attention_kernel, self.cache.k.shape,
+                self.cache.v.shape)] * len(steps),
             "paged_calls": [
-                len(re.findall(r"%paged_decode[.\d]* = [^\n]*"
+                len(re.findall(r"%paged_(?:latent_)?decode[.\d]* = [^\n]*"
                                r"custom_call_target=\"tpu_custom_call\"",
                                text))
                 for text in texts],
@@ -1171,7 +1172,8 @@ class SlotSession:
         self.said = {
             "session": "slot_state",
             "attention_arm": decode_attention_arm(dcfg.attention_kernel,
-                                                  self.cache.k.shape),
+                                                  self.cache.k.shape,
+                                                  self.cache.v.shape),
             "cache_arrays": [list(self.cache.k.shape),
                              list(self.cache.v.shape)],
             "state_arrays": [list(a[0].shape) for a in self.state.arrays],

@@ -12,7 +12,10 @@ paged kernel a layer at each of the replica's four table widths, with
 no gathered copy of the context and no float32 view of one, and the
 gather arm, still there by name, keeps its sizes; which arm a step
 takes is decided by what it is handed; the step a TPU runs writes the
-new token's rows inside that kernel, so it holds no loop. And on the
+new token's rows inside that kernel, so it holds no loop; a latent
+block's step at the latent cell's shapes reads its two arrays through
+its own kernel, a call a layer, with no slice of a layer and no gather
+of the table's width. And on the
 CPU test mesh: a cache with wider rows decodes to the bit what one with
 the head's own width decodes, through the model's step, the prompt's
 scatter and a replica."""
@@ -45,6 +48,17 @@ CACHE_SHAPE = (24, BLOCKS, BLOCK, 32, 64)
 WIDTHS = [28, 56, 84, 112]      # DecodeReplica._table_widths of 112
 GATHER_WIDTHS = [28, 112]
 MB = 1e6
+#: the latent cell's decode state (openpangu-ultra-moe-718b as served:
+#: 5 layers' latents 512 wide and rotated keys stored 128 wide, 16,385
+#: pages of 16, 64 slots, 128 heads, the widest table) on a block
+#: narrow enough to compile in seconds: nothing here counts its matrices
+LATENT_CELL = {"name": "transformer", "model_dim": 1024, "num_heads": 128,
+               "num_layers": 5, "seq_len": 4096, "vocab_size": 1024,
+               "q_latent_dim": 256, "kv_latent_dim": 512,
+               "qk_nope_dim": 128, "qk_rope_dim": 64, "v_head_dim": 128,
+               "ffn_dim": 1024, "compute_dtype": "bfloat16"}
+LATENT_SLOTS, LATENT_TABLE = 64, 256
+LATENT_SHAPES = ((5, 16385, 16, 512), (5, 16385, 16, 128))
 
 
 def _total(compiled) -> float:
@@ -111,16 +125,24 @@ def for_the_chip():
         wide = stored_head_dim(CACHE_SHAPE, jnp.bfloat16, on_chip)
 
         def compiled(width, head_dim, **how):
+            cache = sds((*CACHE_SHAPE[:-1], head_dim), jnp.bfloat16)
+            return step_of(model, params, cache, cache, SLOTS, width, **how)
+
+        def step_of(model, params, k, v, slots, width, **how):
             step = jax.jit(functools.partial(model.decode_step,
                                              block_size=BLOCK, **how),
                            donate_argnums=(3, 4))
-            cache = sds((*CACHE_SHAPE[:-1], head_dim), jnp.bfloat16)
             with _as_on_a_tpu():
                 return step.lower(
-                    params, sds((SLOTS,), jnp.int32),
-                    sds((SLOTS,), jnp.int32), cache, cache,
-                    sds((SLOTS, width), jnp.int32),
-                    sds((SLOTS,), jnp.int32)).compile()
+                    params, sds((slots,), jnp.int32),
+                    sds((slots,), jnp.int32), k, v,
+                    sds((slots, width), jnp.int32),
+                    sds((slots,), jnp.int32)).compile()
+
+        latent = get_model(ModelConfig(**LATENT_CELL))
+        latent_params = jax.tree.map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(lambda: latent.init(jax.random.PRNGKey(0))))
 
         cache = sds((*CACHE_SHAPE[:-1], wide), jnp.bfloat16)
         prompt = sds((24, 256, 32, 64), jnp.bfloat16)
@@ -128,6 +150,10 @@ def for_the_chip():
                "stored_wide": {w: compiled(w, wide) for w in WIDTHS},
                "gather": {w: compiled(w, wide, attention_kernel="dense")
                           for w in GATHER_WIDTHS},
+               "latent": step_of(
+                   latent, latent_params,
+                   *(sds(shape, jnp.bfloat16) for shape in LATENT_SHAPES),
+                   LATENT_SLOTS, LATENT_TABLE),
                "write": jax.jit(
                    write_prompt_kv, static_argnames="block_size",
                    donate_argnums=(0, 1)).lower(
@@ -225,11 +251,13 @@ def test_the_step_a_tpu_runs_writes_the_new_rows_inside_the_kernel(
 
 def test_a_step_takes_the_arm_its_input_decides():
     """``decode.attention_kernel = auto``: rows stored in whole lanes on
-    a TPU go through the kernel; the head's own width, a CPU, and a
-    latent's one row a token for all heads through the gather. A name
-    is an arm whatever the input."""
+    a TPU go through the kernel, keys and values a head and a latent's
+    one row a token for all heads alike; the head's own width, a latent
+    row that fills no whole lane or a latent page that is no whole tile,
+    and a CPU through the gather. A name is an arm whatever the input."""
     wide, own = (24, 769, 16, 32, 128), (24, 769, 16, 32, 64)
     latent = (5, 16385, 16, 512)
+    assert decode_attention_arm("auto", latent) == "gather"    # a CPU
     assert jax.devices()[0].platform == "cpu"
     assert decode_attention_arm("auto", wide) == "gather"
     # the kernels' own question alone (what the accepted compile tests
@@ -241,20 +269,38 @@ def test_a_step_takes_the_arm_its_input_decides():
         assert decode_attention_arm("auto", (2, 40, 4, 4, 256)) == "paged"
         assert decode_attention_arm("auto", own) == "gather"
         assert decode_attention_arm("auto", (2, 40, 4, 4, 192)) == "gather"
-        assert decode_attention_arm("auto", latent) == "gather"
+        assert decode_attention_arm("auto", latent) == "paged"
+        assert decode_attention_arm("auto", (5, 16385, 16, 128)) == "paged"
+        assert decode_attention_arm("auto", (5, 16385, 16, 64)) == "gather"
+        assert decode_attention_arm("auto", (3, 16, 4, 16)) == "gather"
+        # both arrays are asked, and a page has to be whole tiles too:
+        # the kernel writes the token's row through its tile
+        assert decode_attention_arm("auto", latent, (5, 16385, 16, 128)) \
+            == "paged"
+        assert decode_attention_arm("auto", latent, (5, 16385, 16, 64)) \
+            == "gather"
+        assert decode_attention_arm("auto", (5, 32769, 8, 512),
+                                    (5, 32769, 8, 128)) == "gather"
+        assert decode_attention_arm("dense", latent) == "gather"
         assert decode_attention_arm("dense", wide) == "gather"
         assert decode_attention_arm("paged", own) == "paged"
     assert decode_attention_arm("paged", wide) == "paged"
+    assert decode_attention_arm("paged", (3, 16, 4, 16)) == "paged"
     assert decode_attention_arm("dense", own) == "gather"
     with pytest.raises(ValueError, match="attention_kernel"):
         decode_attention_arm("flash", wide)
 
 
-def test_a_latent_blocks_step_is_the_same_program_on_a_tpu():
-    """A latent block has its own read of its cache: lowered as on a TPU
-    with rows in whole lanes, its step under ``auto`` is, to the letter,
-    the step under ``dense`` (the parent's default), and holds no paged
-    kernel."""
+def test_a_latent_blocks_step_calls_its_kernel_on_a_tpu():
+    """A latent block reads its cache by the arm its arrays decide:
+    lowered for a TPU with both rows in whole lanes, its step under
+    ``auto`` calls the latent kernel once a layer (one body,
+    ``paged_latent_decode``, the layer an argument); under ``dense`` it
+    holds none and is, to the letter, the text the gather compiled to
+    before there was such a kernel; with the rotated key stored at its
+    own width ``auto`` is that text too."""
+    import hashlib
+
     from distributedmnist_tpu.core.config import ModelConfig
     from distributedmnist_tpu.models.registry import get_model
 
@@ -265,19 +311,72 @@ def test_a_latent_blocks_step_is_the_same_program_on_a_tpu():
         compute_dtype="bfloat16", attention_impl="dense"))
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     sds = jax.ShapeDtypeStruct
-    args = (params, sds((3,), jnp.int32), sds((3,), jnp.int32),
-            sds((2, 16, 4, 128), jnp.bfloat16),
-            sds((2, 16, 4, 128), jnp.bfloat16), sds((3, 8), jnp.int32),
-            sds((3,), jnp.int32))
 
-    def lowered(**how):
+    def lowered(rope_row=128, **how):
+        args = (params, sds((3,), jnp.int32), sds((3,), jnp.int32),
+                sds((2, 9, 16, 128), jnp.bfloat16),
+                sds((2, 9, 16, rope_row), jnp.bfloat16),
+                sds((3, 4), jnp.int32), sds((3,), jnp.int32))
         with _as_on_a_tpu():
             return jax.jit(functools.partial(
-                model.decode_step, block_size=4, **how)).lower(
-                    *args).as_text()
+                model.decode_step, block_size=16, **how)).trace(
+                    *args).lower(lowering_platforms=("tpu",)).as_text()
 
-    assert lowered() == lowered(attention_kernel="dense")
-    assert "paged_decode" not in lowered()
+    auto, dense = lowered(), lowered(attention_kernel="dense")
+    assert len(re.findall(r"call @paged_latent_attention_write\(",
+                          auto)) == 2
+    assert auto.count('kernel_name = "paged_latent_decode"') == 1
+    assert "paged_latent_decode" not in dense
+    assert "tpu_custom_call" not in dense
+    # (the parent commit's text of this toy, block 16, 9 blocks)
+    assert hashlib.sha256(dense.encode()).hexdigest() == (
+        "5ac4814521b907c31704efbd21038bb3ca7974e83a71a455c312356dbde0b6b7")
+    narrow = lowered(rope_row=8)
+    assert "tpu_custom_call" not in narrow
+    assert narrow == lowered(rope_row=8, attention_kernel="dense")
+
+
+def test_the_latent_step_a_tpu_runs_reads_its_cache_where_it_lies(
+        for_the_chip):
+    """The latent cell's decode state compiled for a described v5e at
+    its widest table: one Mosaic call of the latent kernel a layer, each
+    with the two cache arrays aliased in and out (operands 10 and 11),
+    no ``copy`` of either; and neither of the two things the ledger's
+    ``device_ops`` showed of the gather: no operation whose output is a
+    layer's ``[16385, 16, width]`` slice of a cache array, no gather of
+    the table's ``256 · 16`` rows a slot; no loop and no scatter (the
+    token's row is written inside the call)."""
+    step = for_the_chip["latent"]
+    text = step.as_text()
+    layers = LATENT_CELL["num_layers"]
+    calls = re.findall(r"%paged_latent_decode[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"[^\n]*",
+                       text)
+    assert len(calls) == layers
+    assert all("output_to_operand_aliasing={{1}: (10, {}), {2}: (11, {})}"
+               in call for call in calls)
+    rows = LATENT_TABLE * BLOCK
+    for shape in LATENT_SHAPES:
+        for op in ("copy", "slice", "dynamic-slice", "fusion", "bitcast"):
+            assert _count(step, shape[1:], op) == 0, (shape, op)
+        assert _count(step, shape, "copy") == 0
+        width = shape[-1]
+        assert not re.findall(
+            rf"= (?:f32|bf16)\[(?:{LATENT_SLOTS},{LATENT_TABLE},{BLOCK}"
+            rf"|{LATENT_SLOTS},{rows}|{LATENT_SLOTS * rows}"
+            rf"|{LATENT_SLOTS * LATENT_TABLE},{BLOCK}),{width}\]", text)
+    assert not re.findall(
+        rf"= f32\[{LATENT_SLOTS},{LATENT_CELL['num_heads']},{rows}\]", text)
+    assert decode_mod.while_loops(text) == 0
+    for op in ("dynamic-update-slice", "scatter"):
+        assert not re.findall(rf" {op}\(", text), op
+    # (what gathers are left are the embedding's and the work items')
+    gathered = re.findall(r"= \w+\[(\d+)[\],][^=]* gather\(", text)
+    assert all(int(n) <= LATENT_SLOTS * LATENT_TABLE for n in gathered)
+    layouts = {f.layout for f in (*step.input_formats[0][3:5],
+                                  *step.output_formats[1:])}
+    assert [tuple(at.major_to_minor) for at in layouts] == [(0, 1, 2, 3)]
+    assert step.memory_analysis().temp_size_in_bytes / MB <= 64
 
 
 def test_the_prompts_scatter_on_whole_rows_copies_no_cache_array(

@@ -84,7 +84,8 @@ def test_last_line_is_the_result_and_nothing_else(tmp_path):
         "cs.phase_serve = lambda **kw: {'token_agreement': 1.0,"
         " 'arms': {'dense': arm(z), 'paged': arm(n)}}\n"
         "cs.phase_kernels = lambda **kw: {'flash_mosaic_calls': n,"
-        " 'paged_mosaic_calls': n}\n"
+        " 'paged_mosaic_calls': n, 'latent_decode':"
+        " {'auto_arm': 'paged', 'paged_calls': 2}}\n"
         "cs.main()\n")
     out = _run_script(["-c", script], REPO, tmp_path)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -159,7 +160,11 @@ def test_phases_pass_tiny_on_the_cpu_mesh(tmp_path, scratch_cache):
     # the absorbed decode step against the expanded forward, float32
     latent = kern["latent_decode"]
     assert latent["steps"] == 8 and latent["absorbed_vs_expanded"] <= 1e-4
-    assert latent["cache_arrays"] == [[2, 33, 8, 8], [2, 33, 8, 8]]
+    assert latent["cache_arrays"] == [[2, 33, 8, 128], [2, 33, 8, 8]]
+    # the latent paged kernel, interpreted here, against the gather; on
+    # a chip ``auto`` takes it and main() counts its Mosaic calls
+    assert latent["paged_vs_dense"] <= 1e-4 and latent["layer0_rows_equal"]
+    assert (latent["auto_arm"], latent["paged_calls"]) == ("gather", 0)
 
 
 def test_phase_check_failure_raises(tmp_path, scratch_cache):

@@ -214,13 +214,75 @@ def test_a_small_tile_drops_no_pair():
     assert float(jnp.max(jnp.abs(out.reshape(-1, 64) - want))) <= 1e-5
 
 
-def test_the_paged_kernel_refuses_a_latent_cache(toy):
-    model, params, seq, _ = toy
-    z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
-    with pytest.raises(NotImplementedError, match="latent cache"):
-        model.decode_step(params, z(1), z(1), jnp.zeros((3, 4, 4, 16)),
-                          jnp.zeros((3, 4, 4, 8)), z(1, 2), z(1),
-                          block_size=4, attention_kernel="paged")
+def test_the_paged_step_is_the_dense_step_in_float32(toy):
+    """``attention_kernel="paged"`` no longer refuses a latent cache:
+    the whole step through the latent kernel (interpreted here; rows
+    written inside its call) against the same steps through the gather
+    and against the expanded forward, within the file's tolerance; the
+    two caches equal to the bit outside the null block."""
+    model, params, seq, want = toy
+    _, dense, dense_cache = _decode(model, params, seq, prompt=16,
+                                    attention_kernel="dense")
+    _, paged, paged_cache = _decode(model, params, seq, prompt=16,
+                                    attention_kernel="paged")
+    peak = float(jnp.max(jnp.abs(want)))
+    for i, (d, p) in enumerate(zip(dense, paged)):
+        assert float(jnp.max(jnp.abs(p[0][1] - d[0][1]))) / peak <= F32_TOL
+        err = float(jnp.max(jnp.abs(p[0][1] - want[0, 16 + i]))) / peak
+        assert err <= F32_TOL, (i, err)
+    for got, held in ((paged_cache.k, dense_cache.k),
+                      (paged_cache.v, dense_cache.v)):
+        np.testing.assert_allclose(np.asarray(got[:, 1:]),
+                                   np.asarray(held[:, 1:]), atol=1e-6)
+    # an idle slot's row went to the null block by the scatter alone
+    assert not np.asarray(paged_cache.k[:, 0]).any()
+
+
+@pytest.mark.parametrize("block_size, heads", [(16, 128), (128, 32)])
+def test_a_bfloat16_step_takes_either_arm(block_size, heads):
+    """One whole ``decode_step`` of a latent toy in bfloat16 at the two
+    cells' pages and heads, three slots at ragged lengths (one idle, one
+    at a page's last row): the paged arm's logits within the kernels'
+    tolerance of the gather arm's, the row it wrote the scatter's to the
+    bit, nothing else of either array touched."""
+    model = get_model(ModelConfig(**{
+        **LATENT, "num_heads": heads, "model_dim": 2 * heads,
+        "num_layers": 2, "routed_experts": 0, "held_experts": 0,
+        "experts_per_token": 0, "shared_experts": 0, "dense_layers": 0,
+        "seq_len": 4 * block_size, "compute_dtype": "bfloat16"}))
+    params = model.init(jax.random.PRNGKey(0))
+    layers, one, widths = model.decode_cache_shape
+    rng = np.random.default_rng(0)
+    shapes = kv_cache.cache_shapes(layers, 9, block_size, one, (16, 128))
+    k, v = (jnp.zeros(shape, jnp.bfloat16).at[:, 1:, :, :width].set(
+        jnp.asarray(rng.standard_normal((*shape[:3], width)) * 0.5,
+                    jnp.bfloat16)[:, 1:])
+            for shape, width in zip(shapes, widths))
+    lengths = jnp.asarray([block_size + 3, 0, 2 * block_size], jnp.int32)
+    tables = jnp.asarray([[1, 2, 0, 0], [0, 0, 0, 0], [3, 4, 0, 0]],
+                         jnp.int32)
+    tokens = jnp.asarray([5, 0, 7], jnp.int32)
+
+    def step(kernel):
+        return jax.jit(functools.partial(
+            model.decode_step, block_size=block_size,
+            attention_kernel=kernel))(
+                params, tokens, jnp.maximum(lengths - 1, 0), k, v, tables,
+                lengths)
+
+    dense, paged = step("dense"), step("paged")
+    live = np.asarray(lengths) > 0
+    want, got = (np.asarray(out[0], np.float32)[live]
+                 for out in (dense, paged))
+    assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+    for at in (1, 2):
+        held, wrote = (np.asarray(out[at][:, 1:], np.float32)
+                       for out in (dense, paged))
+        np.testing.assert_array_equal(wrote[0], held[0])   # layer 0's rows
+        # deeper rows are computed from what the layer before read
+        np.testing.assert_allclose(wrote, held, atol=3e-2)
+        before = np.asarray((k, v)[at - 1][:, 1:], np.float32)
+        assert (wrote != before).any(axis=-1).sum() == 2 * layers
 
 
 def test_cache_shapes_of_a_pair_put_the_positions_second_minor():

@@ -486,3 +486,312 @@ def test_a_paged_step_leaves_the_cache_the_scatter_leaves(stored):
     np.testing.assert_array_equal(got_k, want_k)
     np.testing.assert_array_equal(got_v, want_v)
     assert got_k[..., :16].any() and not got_k[..., 16:].any()
+
+
+# -- the latent form --------------------------------------------------------
+# (ops/pallas_paged_attention.py::paged_latent_attention[_write]: one row a
+# token for all heads in each of two arrays, the value the latent itself;
+# pinned to the gather arm of models/transformer.py::_latent_decode_attention,
+# whose weights are rounded to the cache's dtype once.)
+
+#: the two cells' pages and heads (openpangu-ultra-moe-718b: 16 positions a
+#: page, 128 heads; ling-3.0-flash: 128 and 32) at toy widths; lengths that
+#: end on a page's first row (1, B + 1) and on its last (B, 2B), an idle slot
+LATENT_GEOMETRIES = [
+    pytest.param(16, 128, [17, 0, 90, 32, 1, 16], id="block16-heads128"),
+    pytest.param(128, 32, [129, 0, 300, 256, 1, 128], id="block128-heads32"),
+]
+LATENT, ROPE, SCALE = 32, 8, 0.17
+
+
+def _latent_case(block, heads, lengths, *, dtype="bfloat16", table=None,
+                 c_row=LATENT, kr_row=16, layers=2, seed=0):
+    """Random pages, queries and new rows, and tables whose dead entries
+    all point at block 0, which holds no number at all."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths, np.int32)
+    need = -(-lengths // block)
+    table = table or int(need.max())
+    blocks = 1 + int(need.sum()) + 2
+    normal = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    c = np.zeros((layers, blocks, block, c_row), np.float32)
+    kr = np.zeros((layers, blocks, block, kr_row), np.float32)
+    c[..., :LATENT], kr[..., :ROPE] = (normal(*c.shape[:3], LATENT),
+                                       normal(*kr.shape[:3], ROPE))
+    c[:, 0], kr[:, 0] = np.nan, np.nan
+    tables = np.zeros((len(lengths), table), np.int32)
+    order = iter(rng.permutation(np.arange(1, blocks)))
+    for s, n in enumerate(need):
+        tables[s, :n] = [next(order) for _ in range(n)]
+    new_c = np.zeros((len(lengths), c_row), np.float32)
+    new_kr = np.zeros((len(lengths), kr_row), np.float32)
+    new_c[:, :LATENT] = normal(len(lengths), LATENT)
+    new_kr[:, :ROPE] = normal(len(lengths), ROPE)
+    cast = lambda a: jnp.asarray(a, dtype)  # noqa: E731
+    return dict(q_c=cast(normal(len(lengths), heads, LATENT)),
+                q_r=cast(normal(len(lengths), heads, ROPE)),
+                new_c=cast(new_c), new_kr=cast(new_kr), c=cast(c),
+                kr=cast(kr), tables=jnp.asarray(tables),
+                lengths=jnp.asarray(lengths))
+
+
+def _latent_written(case, layer=1, **how):
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_latent_attention_write)
+    return paged_latent_attention_write(
+        case["q_c"], case["q_r"], case["new_c"], case["new_kr"], case["c"],
+        case["kr"], case["tables"], case["lengths"], layer=layer,
+        scale=SCALE, **how)
+
+
+def _latent_scattered(case, layer=1):
+    """What the gather arm's two scatters leave: the live slots' rows at
+    position ``length - 1``, nothing for an idle slot."""
+    import jax.numpy as jnp
+    lengths = np.asarray(case["lengths"])
+    block = case["c"].shape[2]
+    at = lengths - 1
+    out = []
+    for pages, new, width in ((case["c"], case["new_c"], LATENT),
+                              (case["kr"], case["new_kr"], ROPE)):
+        for s in np.flatnonzero(lengths > 0):
+            pages = pages.at[layer, case["tables"][s, at[s] // block],
+                             at[s] % block, :width].set(new[s, :width])
+        out.append(pages)
+    return out
+
+
+def _latent_oracle(case, c, kr, layer=1):
+    """The gather arm over one layer (it gathers the dead entries too,
+    at weight 0: its null block holds zeros)."""
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_latent_attention_dense)
+    return paged_latent_attention_dense(
+        case["q_c"], case["q_r"], c[layer].at[0].set(0.0),
+        kr[layer].at[0].set(0.0), case["tables"], case["lengths"],
+        scale=SCALE)
+
+
+def _bits(a):
+    """An array's bytes, so that rows that hold no number compare."""
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("block, heads, lengths", LATENT_GEOMETRIES)
+def test_the_latent_kernel_reads_what_the_gather_arm_reads(block, heads,
+                                                           lengths):
+    """Ragged lengths in one call, an idle slot among them, lengths that
+    end on a page's first and on its last row: every live slot within
+    bfloat16's rounding of the gather arm (both round the weights once;
+    the kernel before the division by their sum), the idle slot exact
+    zeros. The token's own row is read through the cache."""
+    case = _latent_case(block, heads, lengths)
+    got, c, kr = _latent_written(case)
+    want = np.asarray(_latent_oracle(case, *_latent_scattered(case)))
+    got, live = np.asarray(got), np.asarray(case["lengths"]) > 0
+    assert got.shape == (len(lengths), heads, LATENT)
+    assert got.dtype == np.float32
+    assert np.abs(got[live] - want[live]).max() <= 2e-2 * np.abs(want).max()
+    np.testing.assert_array_equal(got[~live], 0.0)
+    # without its own row a slot of length 1 would have nothing to read
+    assert np.abs(got[4]).max() > 0
+
+
+@pytest.mark.parametrize("block, heads, lengths", LATENT_GEOMETRIES)
+def test_the_latent_write_leaves_every_other_row_as_it_was(block, heads,
+                                                           lengths):
+    """The writing form against the scatter, bit for bit over both
+    arrays whole (both layers, the block that holds no number, the
+    lanes beside a row's values): the written row is the scatter's,
+    every other row untouched, an idle slot writes nothing; and the
+    reading form over the written arrays answers the same to the bit."""
+    from distributedmnist_tpu.ops.pallas_paged_attention import (
+        paged_latent_attention)
+    case = _latent_case(block, heads, lengths)
+    got, c, kr = _latent_written(case)
+    want_c, want_kr = _latent_scattered(case)
+    np.testing.assert_array_equal(_bits(c), _bits(want_c))
+    np.testing.assert_array_equal(_bits(kr), _bits(want_kr))
+    assert (_bits(c) != _bits(case["c"])).any(axis=-1).sum() == 5
+    read = paged_latent_attention(case["q_c"], case["q_r"], c, kr,
+                                  case["tables"], case["lengths"], layer=1,
+                                  scale=SCALE)
+    np.testing.assert_array_equal(np.asarray(read), np.asarray(got))
+
+
+@pytest.mark.parametrize("block, heads, lengths", LATENT_GEOMETRIES)
+def test_a_latent_tables_dead_tail_is_never_looked_up(block, heads, lengths):
+    """A table three times as wide as the longest context, its dead
+    entries at the block that holds no number: the answer is the narrow
+    table's to the bit, at every size of an item."""
+    narrow = _latent_case(block, heads, lengths)
+    width = 3 * narrow["tables"].shape[1]
+    wide = _latent_case(block, heads, lengths, table=width)
+    assert wide["tables"].shape[1] == width
+    want = np.asarray(_latent_written(narrow)[0])
+    assert np.isfinite(want).all()
+    for target_rows in (block, 1024, 1 << 20):
+        got = np.asarray(_latent_written(wide, target_rows=target_rows)[0])
+        if target_rows == 1024:
+            np.testing.assert_array_equal(got, want)
+        else:       # another order of the online softmax's sums
+            np.testing.assert_allclose(got, want, atol=2e-2)
+
+
+@pytest.mark.parametrize("block, heads, lengths", LATENT_GEOMETRIES)
+def test_a_rotated_key_stored_wider_reads_the_same(block, heads, lengths):
+    """The replica stores the rotated key as wide as the device's lanes
+    (and may the latent): rows 128 and 64 wide answer what rows of the
+    values' own widths answer (to the order of a product's sums: the
+    zeros beside the values are summed too), and the lanes beside the
+    values stay zeros."""
+    own = _latent_case(block, heads, lengths, kr_row=ROPE)
+    wide = _latent_case(block, heads, lengths, kr_row=128, c_row=64)
+    want, _, _ = _latent_written(own)
+    got, c, kr = _latent_written(wide)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    assert not np.asarray(kr[:, 1:, :, ROPE:], np.float32).any()
+    assert not np.asarray(c[:, 1:, :, LATENT:], np.float32).any()
+
+
+@pytest.mark.parametrize("block", [4, 8])
+def test_the_latent_kernel_in_float32_is_the_gather_arm(block):
+    """float32 pages round no weight: the kernel is the oracle to
+    accumulation order, through both ways a row gets into its page (8
+    positions a page are whole float32 tiles, so the row goes through
+    its tile; 4 are none, and interpreted the row is copied as it is)."""
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+    case = _latent_case(block, 4, [5, 0, 24, 13, 8], dtype="float32")
+    assert ppa._latent_rows_tile(case["c"]) == (8 if block == 8 else None)
+    got, c, kr = _latent_written(case, target_rows=8)
+    want_c, want_kr = _latent_scattered(case)
+    np.testing.assert_array_equal(_bits(c), _bits(want_c))
+    np.testing.assert_array_equal(_bits(kr), _bits(want_kr))
+    want = np.asarray(_latent_oracle(case, want_c, want_kr))
+    live = np.asarray(case["lengths"]) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=1e-5,
+                               rtol=1e-5)
+
+
+AS_THEY_LIE = [
+    # shapes, writes, itemsize, whether Mosaic takes them
+    pytest.param([(5, 16385, 16, 512), (5, 16385, 16, 128)], True, 2, True,
+                 id="the-latent-cell"),
+    pytest.param([(2, 6145, 128, 512), (2, 6145, 128, 128)], True, 2, True,
+                 id="the-hybrid-cell"),
+    pytest.param([(5, 16385, 16, 512), (5, 16385, 16, 64)], True, 2, False,
+                 id="a-key-of-half-a-lane"),
+    pytest.param([(2, 65, 8, 128), (2, 65, 8, 128)], True, 2, False,
+                 id="bfloat16-pages-of-8-written"),
+    pytest.param([(2, 65, 8, 128), (2, 65, 8, 128)], False, 2, True,
+                 id="bfloat16-pages-of-8-read"),
+    pytest.param([(2, 65, 8, 128), (2, 65, 8, 128)], True, 4, True,
+                 id="float32-pages-of-8-written"),
+]
+
+
+@pytest.mark.parametrize("shapes, writes, itemsize, taken", AS_THEY_LIE)
+def test_the_compiled_latent_forms_say_what_they_take(shapes, writes,
+                                                      itemsize, taken):
+    """One question for the step's arm and for the compiled entry
+    points: every array's rows whole lanes and, to write, a page whole
+    tiles. Compiled for anything else the entry points raise (no scatter
+    and gather inside them: the gather arm is the caller's); the reading
+    form is not held to the write's tile."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+    assert ppa.latent_rows_as_they_lie(*shapes, writes=writes,
+                                       itemsize=itemsize) is taken
+    if taken:
+        return
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    c, kr = (jax.ShapeDtypeStruct((2, 9, *shape[2:]), dtype)
+             for shape in shapes)
+    q_c, q_r = (jax.ShapeDtypeStruct((3, 4, a.shape[-1]), dtype)
+                for a in (c, kr))
+    new_c, new_kr = (jax.ShapeDtypeStruct((3, a.shape[-1]), dtype)
+                     for a in (c, kr))
+    tables = jax.ShapeDtypeStruct((3, 2), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((3,), jnp.int32)
+    with pytest.raises(ValueError, match="whole lanes"):
+        jax.eval_shape(
+            lambda *a: ppa.paged_latent_attention_write(
+                *a, scale=SCALE, interpret=False),
+            q_c, q_r, new_c, new_kr, c, kr, tables, lengths)
+    if not ppa.latent_rows_as_they_lie(*shapes, writes=False):
+        with pytest.raises(ValueError, match="whole lanes"):
+            jax.eval_shape(
+                lambda *a: ppa.paged_latent_attention(
+                    *a, scale=SCALE, interpret=False),
+                q_c, q_r, c, kr, tables, lengths)
+
+
+@pytest.mark.parametrize("unroll, turns_of", [(1, 1), (8, 6), (64, 12)])
+def test_the_page_loop_turns_by_a_divisor_of_the_items_pages(unroll,
+                                                             turns_of):
+    """``PAGE_UNROLL`` pages' DMAs a turn of the loop, or the largest
+    divisor of the item's pages under it (12 entries of table: 6 a turn
+    at 8, all 12 at 64): the same DMAs in the same order, so the answer
+    and both arrays are the one-a-turn loop's to the bit."""
+    from unittest import mock
+
+    import jax
+
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+    case = _latent_case(16, 4, [17, 0, 90, 190], table=12)
+    seen = []
+    real = ppa._latent_kernel
+
+    def spy(*refs, unroll, **how):
+        seen.append(unroll)
+        return real(*refs, unroll=unroll, **how)
+
+    def written():
+        jax.clear_caches()
+        return [np.asarray(a) for a in _latent_written(case)]
+
+    with mock.patch.object(ppa, "PAGE_UNROLL", 1):
+        want = written()
+    with mock.patch.object(ppa, "PAGE_UNROLL", unroll), \
+            mock.patch.object(ppa, "_latent_kernel", spy):
+        got = written()
+    assert seen == [turns_of]
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+def test_an_items_rows_follow_the_pages_size():
+    """``TARGET_ROWS`` is rows, not pages: 16 positions a page make an
+    item of 64 entries, 128 of 8, a table narrower than that of its own
+    width, and a page larger than the target of one."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributedmnist_tpu.ops import pallas_paged_attention as ppa
+
+    def pages_of(block, table, **how):
+        seen = []
+        real = ppa._work_items
+
+        def spy(lengths, block_size, table_width, pages):
+            seen.append(pages)
+            return real(lengths, block_size, table_width, pages)
+
+        case = _latent_case(block, 4, [3, 0])
+        tables = jnp.zeros((2, table), jnp.int32).at[0, 0].set(1)
+        with mock.patch.object(ppa, "_work_items", spy):
+            jax.eval_shape(lambda: ppa.paged_latent_attention.__wrapped__(
+                case["q_c"], case["q_r"], case["c"], case["kr"], tables,
+                case["lengths"], scale=SCALE, interpret=True, **how))
+        return seen[0]
+
+    assert ppa.TARGET_ROWS == 1024
+    assert pages_of(16, 256) == 64 and pages_of(128, 48) == 8
+    assert pages_of(16, 12) == 12 and pages_of(2048, 6) == 1
+    assert pages_of(16, 256, target_rows=256) == 16
