@@ -245,14 +245,20 @@ _declare(EventSchema(
                              # heads); mixer_kind ("ssm" | "kda"), the
                              # layers that keep the state;
                              # attention_layers, how many layers the
-                             # paged cache holds rows of
+                             # paged cache holds rows of; state_arm
+                             # ("kernel" | "xla": what advances the
+                             # state in a step, ops/kda.py::state_arm)
+                             # and state_kernel_calls (Mosaic calls of
+                             # kda_state_step in the compiled step, a
+                             # value a table width)
                              ("cache_row_bytes", "cache_arrays",
                               "attention_arm", "paged_calls",
                               "step_while_loops", "state_arrays",
                               "state_layers",
                               "state_slot_bytes", "state_device_bytes",
                               "kv_heads", "mixer_kind",
-                              "attention_layers")),
+                              "attention_layers", "state_arm",
+                              "state_kernel_calls")),
         # prefill_ms: the start of `_prefill` to its streamed token;
         # ttft_ms: the same value under its first name (kept for the
         # readers that ask for it); queue_ms: admission to the start of

@@ -179,7 +179,11 @@ PREFETCH_PUT = "dml.prefetch.put"
 #: log-decay and beta), kda_chunk (the chunked recurrence over a prompt,
 #: in jit_decode_prefill; read by: prefill_kda_chunk_ms_p50) and
 #: kda_state (one token a slot, the matrix state read and written, in
-#: jit_decode_step; read by: decode_kda_state_ms_per_step,
+#: jit_decode_step: on a TPU one Mosaic call a layer, kda_state_step, the
+#: kernel of ops/kda.py::state_step that holds a head's matrix in VMEM
+#: across the update, and the small fusion that makes e^g beside it; off
+#: the TPU, or for a state Mosaic does not take, ops/kda.py::step's
+#: fusions, two reads and a write; read by: decode_kda_state_ms_per_step,
 #: decode_kda_state_roofline), through benchmark/lib/kda_scopes.py
 SCOPES = ("cast", "embed", "attention", "cache_write", "cache_gather",
           "ffn", "head", "loss", "aggregate", "update", "timing",

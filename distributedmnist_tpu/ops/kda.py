@@ -24,8 +24,8 @@ keeps of a sequence is ``S`` after its last token and the ``K - 1`` inputs
 of the three convolutions before its next one (the *tail*, ``3 H D``
 wide: q, k and v side by side).
 
-Three functions carry the recurrence, plain XLA, and the tests hold them
-to one another:
+Four functions carry the recurrence, three of them plain XLA and one a
+Pallas kernel, and the tests hold them to one another:
 
 * :func:`recurrence`: token by token, the equations as written (the
   oracle);
@@ -43,24 +43,38 @@ to one another:
   padded to a bucket hands on the state of its last real token, and a
   chunk wholly past every prompt is not computed;
 * :func:`step`: one token a slot, ``S`` advanced where it lies (the
-  caller donates it).
+  caller donates it): two reads and a write of the state, because ``u``
+  needs a reduction over a head's whole matrix before the first element
+  of the new state can be written. What a decode step runs off the TPU,
+  and the kernel's oracle;
+* :func:`state_step`: the same token in ONE read and one write: a Pallas
+  kernel that holds a block of heads' matrices in VMEM across the update
+  and writes them over the block it read (the Mosaic call
+  ``kda_state_step``). What a decode step runs on a TPU, for a float32
+  state of whole tiles: :func:`state_arm` decides, from the process's
+  devices, the shape and the dtype, and there is no option.
 
 :func:`mixer` and :func:`mixer_step` are the whole sublayer body over a
 block's leaves (``w_qkv`` [d, 3 H D], ``conv_w`` [K, 3 H D], ``w_f`` [d, H
 D], ``a_log`` [H], ``dt_bias`` [H D], ``w_beta`` [d, H], ``w_og`` [d, H D],
 ``o_norm`` (one scale of D), ``wo`` [H D, d]), under the device scopes
 ``kda`` > ``kda_conv``, ``kda_gate``, ``kda_chunk`` (a sequence) and
-``kda_state`` (a token): obsv/spans.py names their readers.
+``kda_state`` (a token: the kernel's call on a TPU, :func:`step`'s
+fusions elsewhere): obsv/spans.py names their readers.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_paged_attention import _interpreted
 from .ssm import causal_conv, conv_tail
 
 Params = dict[str, Any]
@@ -72,6 +86,14 @@ Params = dict[str, Any]
 CHUNK = 32
 
 _HIGHEST = lax.Precision.HIGHEST
+
+#: heads a grid item of :func:`state_step`: 16 matrices of 128 x 128
+#: float32 are 1 MB a buffer, in and out double-buffered (PERF.md,
+#: PR 47, has the blocks tried)
+HEAD_BLOCK = 16
+#: heads the kernel's text is written out for (a tile of sublanes)
+_GROUP = 8
+_LANE = 128
 
 
 def gate(f: jax.Array, a_log: jax.Array, dt_bias: jax.Array,
@@ -233,6 +255,157 @@ def step(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return o * q.shape[-1] ** -0.5, new.astype(s.dtype)
 
 
+def state_in_vmem(shape: tuple[int, ...], dtype) -> bool:
+    """Whether Mosaic takes a state of ``shape`` ([slots, H, D, Dv]) and
+    ``dtype`` for :func:`state_step` where it lies: float32 (the kernel
+    rounds nothing the recurrence keeps), a head's matrix whole tiles
+    (``D`` down eight sublanes, ``Dv`` along whole lanes). The one
+    question :func:`state_arm` and the compiled kernel ask."""
+    return (len(shape) == 4 and jnp.dtype(dtype) == jnp.float32
+            and shape[2] % 8 == 0 and shape[3] % _LANE == 0)
+
+
+def state_arm(shape: tuple[int, ...], dtype) -> str:
+    """``"kernel"`` or ``"xla"``: what advances a decode step's state of
+    ``shape`` and ``dtype`` in :func:`mixer_step`. The kernel where the
+    process's devices are TPUs (``jax.devices()``, what its jitted step
+    runs on, and not ``jax.default_backend()``, which a test patches: the
+    reason ``models/transformer.py::decode_attention_arm`` has) and the
+    state is one it compiles for (:func:`state_in_vmem`); anything else (a
+    CPU, a toy head, a state not float32) runs :func:`step`."""
+    on_tpus = jax.devices()[0].platform == "tpu"
+    return "kernel" if on_tpus and state_in_vmem(shape, dtype) else "xla"
+
+
+def _state_kernel(live_ref, beta_ref, q_ref, k_ref, v_ref, eg_ref, s_ref,
+                  o_ref, new_ref, *, heads: int, scale: float):
+    """A slot's block of ``hb`` heads: the state's block [1, hb, D, Dv]
+    is in VMEM once, advanced by :func:`step`'s equations and written to
+    the block it came from. ``live_ref`` [slots] int32 and ``beta_ref``
+    [slots H] float32 are scalars (SMEM); ``q_ref``, ``k_ref``, ``eg_ref``
+    [1, hb, D] and ``v_ref`` [1, hb, Dv] have the channel in the lanes.
+    The heads are walked :data:`_GROUP` at a time, by a loop on the chip
+    where a block holds several groups: one group's text whatever the
+    block."""
+    slot, hb = pl.program_id(0), s_ref.shape[1]
+    first_beta = slot * heads + pl.program_id(1) * hb
+
+    def advance(first, n: int):
+        """Heads ``first`` to ``first + n`` of the block (``n`` static)."""
+        these = pl.ds(first, n)
+        q, k = q_ref[0, these], k_ref[0, these]
+        # the key-side vectors index a head's ROWS: the three [n, D]
+        # tiles, one under the other and filled up to whole lanes, are
+        # turned once a group; column ``j n + h`` is then vector ``j``
+        # of head ``h`` down the sublanes
+        fill = -3 * n % _LANE
+        down = jnp.concatenate(
+            [eg_ref[0, these], k, q]
+            + ([jnp.zeros((fill, q.shape[-1]), jnp.float32)] if fill else []),
+            axis=0).T
+        qk = jnp.sum(q * k, axis=-1, keepdims=True)               # [n, 1]
+        outs = []
+        for h in range(n):
+            e, kc, qc = (down[:, j * n + h:j * n + h + 1]
+                         for j in range(3))                       # [D, 1]
+            decayed = e * s_ref[0, first + h]
+            # (spread over the lanes once, for the reduction and the
+            # update both)
+            kc = jnp.broadcast_to(kc, decayed.shape)
+            r_k = jnp.sum(kc * decayed, axis=0, keepdims=True)    # [1, Dv]
+            r_q = jnp.sum(qc * decayed, axis=0, keepdims=True)
+            u = beta_ref[first_beta + first + h] * (
+                v_ref[0, pl.ds(first + h, 1)] - r_k)
+            new_ref[0, first + h] = decayed + kc * u
+            outs.append((r_q + qk[h:h + 1] * u) * scale)
+        o_ref[0, these] = jnp.concatenate(outs, axis=0)
+
+    @pl.when(live_ref[slot] == 0)
+    def _idle():
+        new_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[slot] != 0)
+    def _live():
+        if hb > _GROUP and hb % _GROUP == 0:
+            lax.fori_loop(0, hb // _GROUP, lambda g, _: advance(
+                pl.multiple_of(g * _GROUP, _GROUP), _GROUP), None)
+        else:
+            advance(0, hb)
+
+
+def state_step(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+               beta: jax.Array, s: jax.Array, live: jax.Array, *,
+               head_block: int | None = None,
+               interpret: bool | None = None
+               ) -> tuple[jax.Array, jax.Array]:
+    """:func:`step` for the ``live`` slots [slots] (bool) in ONE pass over
+    the state: a Pallas kernel (a Mosaic call named ``kda_state_step``)
+    whose grid item is a slot's ``head_block`` heads (None:
+    :data:`HEAD_BLOCK` where it divides ``H``, else all of them), their
+    matrices fetched to VMEM once, advanced there (every product and both
+    reductions float32 on the vector unit, ``e^g``, ``k`` and ``q`` turned
+    down a head's rows inside the kernel) and written over the block they
+    were read from (``input_output_aliases``: donate ``s``, or XLA copies
+    it). A slot that is not live keeps its state bit for bit (the block
+    written back as read) and answers zeros.
+
+    Compiled, the state has to be one Mosaic takes
+    (:func:`state_in_vmem`), or the call raises: :func:`step` is the
+    caller's to choose (:func:`state_arm` asks the same question first).
+    ``interpret=None`` picks the interpreter off the TPU, which runs any
+    shape.
+
+    Returns ``o`` [slots, H, Dv] float32 and the state advanced."""
+    # (asked here, outside the jitted call: what is traced is then cached
+    # under the answer, not under the question)
+    interpret = _interpreted(interpret)
+    if not interpret and not state_in_vmem(s.shape, s.dtype):
+        raise ValueError(
+            f"the delta-rule state kernel compiles for a float32 state "
+            f"[slots, H, D, Dv] with D a multiple of 8 and Dv of {_LANE}, "
+            f"not for {s.shape} of {s.dtype}: ops.kda.step advances this "
+            f"one")
+    heads = s.shape[1]
+    hb = head_block or (HEAD_BLOCK if heads % HEAD_BLOCK == 0 else heads)
+    assert heads % hb == 0, (heads, hb)
+    return _state_call(q, k, v, g, beta, s, live, hb=hb, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
+def _state_call(q, k, v, g, beta, s, live, *, hb, interpret):
+    """The one call of :func:`_state_kernel` over the state whole, its
+    grid a slot by a block of ``hb`` heads."""
+    slots, heads, d, dv = s.shape
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    vec = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, hb, w), lambda i, j, *_: (i, j, 0))
+    mat = pl.BlockSpec((1, hb, d, dv), lambda i, j, *_: (i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_state_kernel, heads=heads, scale=d ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(slots, heads // hb),
+            in_specs=[vec(d), vec(d), vec(dv), vec(d), mat],
+            out_specs=(vec(dv), mat)),
+        out_shape=(jax.ShapeDtypeStruct((slots, heads, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(s.shape, s.dtype)),
+        # the state (operand 6, the two scalar arrays counted) is output 1
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # the block in and out, double-buffered, and a head's
+            # temporaries
+            vmem_limit_bytes=4 * hb * d * dv * 4 + (12 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=7 * s.size, transcendentals=0,
+            bytes_accessed=4 * (2 * s.size
+                                + slots * heads * (3 * d + 2 * dv))),
+        interpret=interpret,
+        name="kda_state_step",
+    )(live.astype(jnp.int32), f32(beta).reshape(-1), f32(q), f32(k), f32(v),
+      jnp.exp(f32(g)), s)
+
+
 def _projected(h: jax.Array, blk: Params, lower_bound: float):
     """What a layer computes of its normed input beside the three
     convolved streams: the log-decay [..., H, D] and ``beta`` [..., H],
@@ -305,6 +478,9 @@ def mixer_step(h: jax.Array, blk: Params, s: jax.Array, tail: jax.Array,
     q, k, v = _heads(qkv, heads)
     g, beta, out_gate = _projected(h, blk, lower_bound)
     with jax.named_scope("kda_state"):
-        o, new_s = step(q, k, v, g, beta, s)
-        new_s = jnp.where(live[:, None, None, None], new_s, s)
+        if state_arm(s.shape, s.dtype) == "kernel":
+            o, new_s = state_step(q, k, v, g, beta, s, live)
+        else:
+            o, new_s = step(q, k, v, g, beta, s)
+            new_s = jnp.where(live[:, None, None, None], new_s, s)
     return _output(o, out_gate, blk, norm, h.dtype), new_s, new_tail

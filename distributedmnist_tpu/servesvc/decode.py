@@ -134,6 +134,7 @@ from ..models.registry import sample_token
 from ..models.transformer import decode_attention_arm
 from ..obsv import spans
 from ..obsv.timing import LoopClock
+from ..ops import kda
 from .kv_cache import (PagedKVCache, SlotState, cache_shapes,
                        stored_head_dim)
 from .server import ServingReplica, _Pending
@@ -155,6 +156,15 @@ FLUSH_POINTS = ("dispatch", "prefill", "swap", "park", "stop")
 def while_loops(compiled_text: str) -> int:
     """The ``while`` instructions in a compiled program's text."""
     return len(re.findall(r" while\(", compiled_text))
+
+
+def mosaic_calls(compiled_text: str, kernel: str) -> int:
+    """The Mosaic calls in a compiled program's text whose name matches
+    ``kernel`` (a regular expression): none where the kernel runs
+    interpreted, or not at all."""
+    return len(re.findall(rf"%{kernel}[.\d]* = [^\n]*"
+                          r"custom_call_target=\"tpu_custom_call\"",
+                          compiled_text))
 
 
 def table_widths(full: int) -> list[int]:
@@ -1057,17 +1067,27 @@ class DecodeReplica(ServingReplica):
         arrays, or nothing."""
         return () if self.state is None else self.state.arrays
 
-    def _state_said(self) -> dict:
-        """Of a model whose state is a sequence's, for ``decode_start``:
+    def _state_said(self, texts: list[str]) -> dict:
+        """Of a model whose state is a sequence's, for ``decode_start``
+        (``texts``: each width's compiled step):
         the shapes of a layer's two arrays ([slots, N, E] float32 and
         [K - 1, slots, W]: the layout) and how many layers have such a
         pair, what one sequence's state takes, what all of them take on
         the device, the kind of mixer that keeps it, how many layers
         attend (the paged cache's) and the key-value heads their rows
-        hold (1: one row a token for all heads)."""
+        hold (1: one row a token for all heads); how a step advances the
+        state (``state_arm``: ``ops/kda.py::state_arm``, which answers
+        ``"xla"`` off a TPU and for a state-space layer's rows) and, a value a
+        table width, the Mosaic calls of the kernel that holds it in
+        VMEM in the compiled step (``state_kernel_calls``: one a
+        delta-rule layer on a TPU)."""
         if self.state is None:
             return {}
-        return {"state_arrays": [list(a[0].shape)
+        kept = self.state.state[0]
+        return {"state_arm": kda.state_arm(kept.shape, kept.dtype),
+                "state_kernel_calls": [mosaic_calls(text, "kda_state_step")
+                                       for text in texts],
+                "state_arrays": [list(a[0].shape)
                                  for a in self.state.arrays],
                 "state_layers": len(self.state.state),
                 "state_slot_bytes": self.state.slot_bytes(),
@@ -1077,9 +1097,10 @@ class DecodeReplica(ServingReplica):
                 "attention_layers": self.model.decode_cache_shape[0],
                 "kv_heads": self.model.decode_cache_shape[1]}
 
-    def _cache_said(self) -> dict:
+    def _cache_said(self, texts: list[str]) -> dict:
         """How the cache lies on the device and what each width's step
-        does with it, for ``decode_start``: ``whole_cache_copies`` counts
+        (``texts``: its compiled text) does with it, for
+        ``decode_start``: ``whole_cache_copies`` counts
         the ``copy`` instructions of the cache's shape in a compiled
         step (0 where it takes the arrays as they lie, 4 where it
         transposes both on the way in and back on the way out),
@@ -1090,7 +1111,6 @@ class DecodeReplica(ServingReplica):
         at = self.cache.k.format.layout
         dims = re.escape(f"[{','.join(map(str, self.cache.k.shape))}]")
         steps = [self._steps[w] for w in self._table_widths]
-        texts = [s.as_text() for s in steps]
         arrays = (self.cache.k, self.cache.v)
         device_bytes = sum(a.on_device_size_in_bytes() for a in arrays)
         return {
@@ -1113,15 +1133,13 @@ class DecodeReplica(ServingReplica):
             "attention_arm": [decode_attention_arm(
                 self.dcfg.attention_kernel, self.cache.k.shape,
                 self.cache.v.shape)] * len(steps),
-            "paged_calls": [
-                len(re.findall(r"%paged_(?:latent_)?decode[.\d]* = [^\n]*"
-                               r"custom_call_target=\"tpu_custom_call\"",
-                               text))
-                for text in texts],
+            "paged_calls": [mosaic_calls(text, "paged_(?:latent_)?decode")
+                            for text in texts],
             "step_while_loops": [while_loops(text) for text in texts]}
 
     def start(self) -> None:
         super().start()
+        texts = [self._steps[w].as_text() for w in self._table_widths]
         self._journal({"action": "decode_start",
                        "slots": self.dcfg.decode_slots,
                        "block_size": self.dcfg.block_size,
@@ -1131,7 +1149,7 @@ class DecodeReplica(ServingReplica):
                        "table_widths": self._table_widths,
                        "swap_policy": self.dcfg.swap_policy,
                        "model_step": self.model_step,
-                       **self._cache_said(), **self._state_said()})
+                       **self._cache_said(texts), **self._state_said(texts)})
 
 
 class SlotSession:

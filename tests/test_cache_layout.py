@@ -379,6 +379,30 @@ def test_the_latent_step_a_tpu_runs_reads_its_cache_where_it_lies(
     assert step.memory_analysis().temp_size_in_bytes / MB <= 64
 
 
+@pytest.mark.parametrize("slots, heads, d, dv", [(4, 16, 128, 128),
+                                                 (2, 3, 64, 256)])
+def test_a_delta_rule_layers_donated_state_goes_through_one_mosaic_call(
+        for_the_chip, slots, heads, d, dv):
+    """``ops/kda.py::state_step`` compiled for the described chip: Mosaic
+    takes the body at a block smaller than the heads and at three heads
+    in one, the call is counted by the name ``decode_start`` counts it
+    by, and a donated state is advanced in its own buffer."""
+    from distributedmnist_tpu.ops import kda
+    sds = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=for_the_chip["on_chip"])
+    compiled = jax.jit(lambda *a: kda.state_step(*a, interpret=False),
+                       donate_argnums=5).lower(
+        sds(slots, heads, d), sds(slots, heads, d), sds(slots, heads, dv),
+        sds(slots, heads, d), sds(slots, heads), sds(slots, heads, d, dv),
+        sds(slots, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert decode_mod.mosaic_calls(text, "kda_state_step") == 1
+    assert decode_mod.mosaic_calls(text, "paged_(?:latent_)?decode") == 0
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == slots * heads * d * dv * 4
+    assert m.temp_size_in_bytes < 1 << 20
+
+
 def test_the_prompts_scatter_on_whole_rows_copies_no_cache_array(
         for_the_chip):
     write = for_the_chip["write"]

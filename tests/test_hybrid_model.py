@@ -335,6 +335,36 @@ def test_delta_rule_prefill_then_steps_through_the_session_is_the_reference(
     assert _rel(session.prefill(other), fresh.prefill(other)) < 1e-6
 
 
+@pytest.fixture(scope="module")
+def cache_free(ling):
+    """A sequence of 27 tokens and the cache-free forward's logits at
+    its last 17 positions: what prefill of 11 then 16 steps answer."""
+    _, model, params = ling
+    seq = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (27,), 0, 97))
+    with jax.default_matmul_precision("highest"):
+        return seq, model.apply(params, jnp.asarray(seq[None]))[0, 10:]
+
+
+@pytest.mark.parametrize("arm", ["xla", "kernel"])
+def test_sixteen_delta_rule_steps_are_the_cache_free_forward_on_either_arm(
+        ling, cache_free, arm, monkeypatch):
+    """Prefill of 11, then 16 teacher-forced steps whose matrix states
+    are advanced by the XLA step, or (the asking function patched) by the
+    kernel that holds them in VMEM, interpreted here: the logits of the
+    cache-free forward, to the same tolerance."""
+    from distributedmnist_tpu.ops import kda
+    _, model, params = ling
+    seq, want = cache_free
+    asked = []
+    monkeypatch.setattr(kda, "state_arm", lambda *a: asked.append(a) or arm)
+    session = model.decode_session(params, DECODE, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        rows = [session.prefill(seq[:11])] + [
+            session.step(int(seq[pos]), pos) for pos in range(11, 27)]
+    assert ((3, 4, 16, 16), jnp.float32) in asked
+    assert _rel(jnp.stack(rows), want) < 2e-5
+
+
 def test_sixteen_shares_add_up_to_the_uncut_layer_of_the_reference():
     """What ties the share to the model: the routed parts of the sixteen
     chips' shares (2 experts each of 32 under the group limit) plus the

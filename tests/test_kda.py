@@ -4,9 +4,13 @@ the token recurrence as written agree to float32 rounding, for several
 chunk sizes, ragged lengths in one bucket and from a given state; a chunk
 past the prompt is skipped; the gate stays in its bounds; the mixer's
 prefill hands over what its steps continue from; an idle slot's state is
-untouched; and the initialisation spreads the decays."""
+untouched; the initialisation spreads the decays; and the kernel that
+holds a head's state in VMEM across a token's update (``state_step``,
+interpreted here) is the step: a token, 512 tokens against the recurrence,
+a ragged ``live`` mask, a given state, and the shapes it is not asked for."""
 
 import math
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -193,3 +197,134 @@ def test_the_initialisation_spreads_the_decays():
     assert float(jnp.std(blk["w_f"])) == pytest.approx(0.005, rel=0.2)
     assert float(jnp.abs(blk["conv_w"]).max()) <= 0.5
     assert "dt_bias" in transformer._F32_LEAVES
+
+
+# -- the state held in VMEM across a token's update (state_step) ---------------
+
+def _token(seed, slots=B, heads=H, d=D, dv=D, s_scale=0.3):
+    """One token a slot and a state to advance."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = kda._l2(jax.random.normal(ks[0], (slots, heads, d)))
+    k = kda._l2(jax.random.normal(ks[1], (slots, heads, d)))
+    v = jax.random.normal(ks[2], (slots, heads, dv))
+    g = -5 * jax.nn.sigmoid(jax.random.normal(ks[3], (slots, heads, d)) * 2
+                            - 3)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (slots, heads)))
+    s = jax.random.normal(ks[5], (slots, heads, d, dv)) * s_scale
+    return q, k, v, g, beta, s
+
+
+@pytest.mark.parametrize("heads, head_block, dv, s_scale", [
+    (4, 2, D, 0.3),          # a block smaller than the heads
+    (4, 4, D, 0.3),          # all of them in one item
+    (H, None, D, 0.0),       # from an empty state, three heads in one block
+    (32, None, 24, 1.0),     # two of the module's HEAD_BLOCK, a wider value
+    (16, 8, 128, 0.3),       # the tiles Mosaic takes, two blocks a slot
+    (24, 24, D, 0.3)])       # three groups of eight walked by the loop
+def test_a_token_through_the_kernel_is_the_step(heads, head_block, dv,
+                                                s_scale):
+    x = _token(10, slots=3, heads=heads, dv=dv, s_scale=s_scale)
+    live = jnp.ones((3,), bool)
+    want_o, want_s = kda.step(*x)
+    got_o, got_s = kda.state_step(*x, live, head_block=head_block)
+    assert got_o.dtype == got_s.dtype == jnp.float32
+    assert got_s.shape == x[-1].shape and got_o.shape == x[2].shape
+    assert float(jnp.abs(got_o - want_o).max()) < 1e-6
+    assert float(jnp.abs(got_s - want_s).max()) < 1e-6
+    assert float(jnp.abs(want_o).max()) > 0.1       # and says something
+
+
+def test_512_tokens_through_the_kernel_are_the_recurrence():
+    """What a serving check of 136 positions cannot see: the error that
+    compounds. State and outputs after 512 tokens of the kernel, from a
+    given state, against the token recurrence."""
+    t = 512
+    x = _inputs(11, t=t)
+    s0 = jax.random.normal(jax.random.PRNGKey(12), (B, H, D, D)) * 0.3
+    want_o, want_s = kda.recurrence(*x, s0=s0)
+    live = jnp.ones((B,), bool)
+
+    def one_token(s, token):
+        o, s = kda.state_step(*token, s, live)
+        return s, o
+
+    s_end, o = jax.jit(lambda s, x: jax.lax.scan(one_token, s, tuple(
+        jnp.moveaxis(a, 1, 0) for a in x)))(s0, x)
+    # float32 rounding over ten times the tokens of F32's fifty
+    assert float(jnp.abs(jnp.moveaxis(o, 0, 1) - want_o).max()) < 4 * F32
+    assert float(jnp.abs(s_end - want_s).max()) < 4 * F32
+    assert float(jnp.abs(want_s).max()) > 0.1
+
+
+def test_a_ragged_live_mask_keeps_idle_states_to_the_bit():
+    x = _token(13, slots=5, heads=4)
+    live = jnp.array([True, False, True, False, False])
+    _, want_s = kda.step(*x)
+    o, s = kda.state_step(*x, live, head_block=2)
+    assert bool((s[~live] == x[-1][~live]).all())
+    assert not bool(o[~live].any())                 # an idle slot: zeros
+    assert float(jnp.abs(s[live] - want_s[live]).max()) < 1e-6
+    assert float(jnp.abs(s[live] - x[-1][live]).max()) > 1e-3
+
+
+def _as_on_a_tpu():
+    tpu = mock.Mock(platform="tpu")
+    return mock.patch.multiple(jax, devices=lambda *a: [tpu])
+
+
+@pytest.mark.parametrize("shape, dtype, why", [
+    ((4, 3, 16, 128), jnp.float32, None),
+    ((4, 32, 128, 128), jnp.float32, None),
+    ((4, 3, 12, 128), jnp.float32, "a toy D: no whole sublanes"),
+    ((4, 3, 16, 16), jnp.float32, "a toy Dv: no whole lanes"),
+    ((4, 3, 128, 192), jnp.float32, "Dv not a multiple of 128"),
+    ((4, 3, 128, 128), jnp.bfloat16, "a state that is rounded"),
+    ((4, 16, 5120), jnp.float32, "a state-space layer's rows")])
+def test_the_arm_is_asked_of_the_devices_the_shape_and_the_dtype(shape, dtype,
+                                                                 why):
+    assert kda.state_in_vmem(shape, dtype) == (why is None)
+    # a CPU runs the step whatever the shape; a patched default_backend
+    # is not what is asked
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert kda.state_arm(shape, dtype) == "xla"
+    with _as_on_a_tpu():
+        assert kda.state_arm(shape, dtype) == (
+            "kernel" if why is None else "xla")
+
+
+@pytest.mark.parametrize("d, dv, dtype", [
+    (12, 128, jnp.float32), (16, 192, jnp.float32), (16, 128, jnp.bfloat16)])
+def test_compiled_for_a_state_mosaic_does_not_take_the_kernel_raises(
+        d, dv, dtype):
+    *x, s = _token(14, d=d, dv=dv)
+    s = s.astype(dtype)
+    live = jnp.ones((B,), bool)
+    with pytest.raises(ValueError, match="ops.kda.step advances this one"):
+        kda.state_step(*x, s, live, interpret=False)
+    # and mixer_step's own question sends it to the step, whose text it is
+    with _as_on_a_tpu():
+        assert kda.state_arm(s.shape, s.dtype) == "xla"
+
+
+def test_mixer_step_takes_the_arm_it_is_told(monkeypatch):
+    """The sublayer through the kernel (the asking function patched, the
+    kernel interpreted) is the sublayer through the step, an idle slot
+    untouched; asked of a CPU the step's program holds no kernel."""
+    blk, _ = _block(2)
+    h = jax.random.normal(jax.random.PRNGKey(15), (3, 48))
+    s = jax.random.normal(jax.random.PRNGKey(16), (3, H, D, D))
+    tail = jax.random.normal(jax.random.PRNGKey(17), (3, 3, 3 * H * D))
+    live = jnp.array([True, False, True])
+    run = lambda: kda.mixer_step(h, blk, s, tail, live,  # noqa: E731
+                                 lower_bound=-5.0, norm=_norm)
+    want = run()
+    asked = []
+    monkeypatch.setattr(kda, "state_arm",
+                        lambda *a: asked.append(a) or "kernel")
+    got = run()
+    assert asked == [((3, H, D, D), jnp.float32)]
+    assert bool((got[1][1] == s[1]).all())
+    for a, b in zip(got, want):
+        assert float(jnp.abs(a - b)[jnp.array([0, 2])].max()) < 1e-5
+    assert bool((got[2] == want[2]).all())
+

@@ -118,6 +118,9 @@ def test_decode_start_and_heartbeat_say_the_state(tmp_path):
         assert start["state_device_bytes"] >= 3 * start["state_slot_bytes"]
         assert start["kv_heads"] == 1
         assert start["cache_arrays"][0] == [1, 32, 8, 1, 16]
+        # no delta-rule layer: nothing for the state's kernel to hold
+        assert start["state_arm"] == "xla"
+        assert start["state_kernel_calls"] == [0] * len(start["table_widths"])
         assert "state_resets" in rep._pressure_fields()
     finally:
         rep.stop()
@@ -313,8 +316,64 @@ def test_a_delta_rule_replica_says_its_three_stores_and_counts_its_pairs(
                                                  + 3 * 192 * 4)
         assert start["attention_arm"] == ["gather"] * len(
             start["table_widths"])
+        # a CPU's step advances the state through ops/kda.py::step
+        assert start["state_arm"] == "xla"
+        assert start["state_kernel_calls"] == [0] * len(start["table_widths"])
     finally:
         rep.stop()
+
+
+def test_through_the_state_kernel_a_readmitted_slot_starts_from_zeros(
+        tmp_path, monkeypatch):
+    """The asking function patched to the kernel (interpreted off the
+    chip: no Mosaic call to count): ``decode_start`` says the arm, a
+    sequence is served as its session alone decodes it through the XLA
+    step, a finished sequence leaves its slot zeros, and the next
+    sequence admitted to that slot gets the tokens it gets alone."""
+    from distributedmnist_tpu.ops import kda
+    monkeypatch.setitem(globals(), "HYBRID", KDA_HYBRID)
+    asked = []
+    monkeypatch.setattr(kda, "state_arm",
+                        lambda *a: asked.append(a) or "kernel")
+    rep, cfg, params = _replica(tmp_path)
+    rep.start()
+    try:
+        start = next(r for r in serve_records(rep)
+                     if r.get("action") == "decode_start")
+        assert start["state_arm"] == "kernel"
+        assert start["state_kernel_calls"] == [0] * len(start["table_widths"])
+    finally:
+        rep.stop()
+    assert ((3, 4, 16, 16), jnp.float32) in asked       # the step asked too
+    rep, cfg, params = _loaded(tmp_path / "by_hand")
+    model = get_model(cfg.model)
+    served = jax.tree.map(jnp.asarray, rep._params)
+    slots = []
+    for rid, prompt in (("a", [3, 1, 4, 1, 5, 9, 2, 6]), ("b", [2, 7, 1])):
+        seq, conn = admit_direct(rep, {"id": rid, "prompt": prompt,
+                                       "max_tokens": 6,
+                                       "deadline_ms": 120000})
+        rep._admit_new()
+        slots.append(rep._slots.index(seq))
+        assert np.abs(_slot(rep, slots[-1])[0]).max() > 0
+        _drive(rep, 8)
+        got = [ln["token"] for ln in conn.lines
+               if ln.get("stream") == "token"]
+        assert len(got) == 6
+        # freed at finish: nothing of it is left for the next occupant
+        assert _largest(rep.state.state) == 0.0
+        assert _largest(rep.state.tail) == 0.0
+        with monkeypatch.context() as unpatched:
+            unpatched.setattr(kda, "state_arm", lambda *a: "xla")
+            ses = model.decode_session(served, rep.dcfg, jnp.float32)
+            row = ses.prefill(np.asarray(prompt, np.int32))
+            alone = [int(jnp.argmax(row))]
+            for i in range(5):
+                row = ses.step(alone[-1], len(prompt) + i)
+                alone.append(int(jnp.argmax(row)))
+        assert got == alone, rid
+    assert slots[0] == slots[1]                 # the same slot, handed out again
+    assert rep._pressure_fields()["state_resets"] == 2
 
 
 def test_delta_rule_sequences_batched_get_the_logits_each_gets_alone(
