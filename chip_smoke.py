@@ -49,7 +49,8 @@ from pathlib import Path
 
 import numpy as np
 
-# the benchmarked width (bench.py bench_transformer_flash), depth 4
+# d=2048 H=16 S=1024 V=1024 at depth 4: the width the flash kernels'
+# block table was tuned at (the CPU-era harness removed at PR 48)
 FULL_MODEL = {"name": "transformer", "model_dim": 2048, "num_layers": 4,
               "num_heads": 16, "seq_len": 1024, "vocab_size": 1024,
               "attention_impl": "flash", "compute_dtype": "bfloat16"}

@@ -16,12 +16,12 @@ on where the cache lives:
   key, so a directory derived from a temporary name, a pid or the time
   would never hit;
 * ``compile.persistent_cache=false`` turns the cache off for the
-  process (the explicit cold arm of the restart-latency bench).
+  process (an explicit cold start; tests/test_compile_cache.py).
 
 :func:`cache_stats` reports entries/bytes on disk plus this process's
 hit/miss counters (from jax's monitoring events), so compile-cache
-regressions are visible in bench artifacts and worker journals instead
-of only as mysteriously slower restarts.
+regressions are visible in worker journals (and in the benchmark's
+``setup_s``) instead of only as mysteriously slower restarts.
 """
 
 from __future__ import annotations

@@ -127,8 +127,8 @@ class ModelConfig:
     #     re-runs the attention kernel, cutting the remat recompute by
     #     the attention fraction for O(b·s·d) extra bytes per layer —
     #     the right trade once attention dominates (long
-    #     sequences): measured 1.14x tokens/sec at the S=8192
-    #     long-context bench shape on v5e.
+    #     sequences): measured 1.14x tokens/sec at S=8192 (d=1024,
+    #     L=2) on a v5e in July 2026.
     remat_policy: str = "full"
     # -- sizes of the mechanisms below; each is absent at its default, and
     # a size is all there is: no key here chooses between two paths for
@@ -684,7 +684,7 @@ class TrainConfig:
     # checkpoint/manifest payload bytes before the publishing rename,
     # "full" additionally fsyncs digest sidecars, the pointer, JSONL
     # journal appends, and the parent dir after renames (the
-    # power-cut-proof bound the checkpoint_durability bench prices).
+    # power-cut-proof bound; its cost on the chip's host: not measured).
     # Unknown values raise a typed ConfigError at trainer init.
     durability: str = "none"
     # Preemption handling: SIGTERM/SIGINT flush the AsyncCheckpointer

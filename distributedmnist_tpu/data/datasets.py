@@ -89,8 +89,9 @@ def _open_maybe_gz(path: Path):
 def _read_idx_ubyte(path: Path, expect_ndim: int) -> np.ndarray:
     """Raw idx(.gz) ubyte payload.
 
-    The numpy path is the DEFAULT decode. Repeated bench_native_loader
-    idx_decode runs on the 60k-image idx3.gz put the two readers within
+    The numpy path is the DEFAULT decode. Repeated decode runs of the
+    native-loader case of the harness removed at PR 48 (BENCH_r04/r05.json
+    in git history) on the 60k-image idx3.gz put the two readers within
     run-to-run noise of each other (native 130-157 MB/s vs numpy
     136-151 — both zlib-inflate-bound); numpy avoids the extra ctypes
     boundary copy (native_loader.read_idx's .copy()) and any dependence

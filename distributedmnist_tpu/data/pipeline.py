@@ -296,12 +296,13 @@ def make_train_iterator(data: ArrayDataset, cfg: DataConfig, seed: int,
         from ..core.log import get_logger
         if not host_can_spare_producer_thread():
             # a prefetch thread can only fight the consumer for the one
-            # core — measured as a net slowdown by bench_native_loader
-            # under BOTH consumer shapes: cpu-busy (~0.6x) AND the
-            # train loop's real device-blocked shape AT THE PRODUCTION
-            # DEPTH of prefetch_batches=2 (median 0.90x over repeated
-            # quiet-box runs). The earlier BENCH_r04 1.07x for this
-            # case was measured at depth=10 — re-measured at depth 10
+            # core — measured as a net slowdown by the native-loader
+            # case of the CPU harness removed at PR 48 (BENCH_r04/r05.json
+            # in git history) under BOTH consumer shapes: cpu-busy
+            # (~0.6x) AND the train loop's real device-blocked shape AT
+            # THE PRODUCTION DEPTH of prefetch_batches=2 (median 0.90x
+            # over repeated quiet-box runs). BENCH_r04's earlier 1.07x for
+            # this case was measured at depth=10 — re-measured at depth 10
             # it is break-even noise (0.96-1.03x across runs), and at
             # the depth this gate actually governs the native path
             # loses: the per-batch queue handoff on one core costs

@@ -110,8 +110,8 @@ def collect_signals(window: dict | None, heartbeats: list[dict],
     live serving replica — queue pressure aggregates as the MAX
     occupancy fraction (one saturated replica is a problem even if
     its peers idle), KV pressure as the MIN free fraction.
-    ``train_steps_per_s`` rides along informationally (journals,
-    bench detail); it is not a scaling trigger."""
+    ``train_steps_per_s`` rides along informationally (journals);
+    it is not a scaling trigger."""
     sig: dict[str, float] = {}
     if window is not None:
         t = window.get("time")
@@ -541,7 +541,7 @@ class ResourceBroker:
     def summary(self) -> dict[str, Any]:
         """The run's autoscale summary (decision mix, reaction-time
         percentiles, flap count) from the supervisor's own event
-        stream — what the chaos trial record and bench detail embed."""
+        stream — what the chaos trial record embeds."""
         from ..obsv.journal import summarize_autoscale
         recs = [r for r in self.sup.events
                 if r.get("event") == "autoscale"]

@@ -856,7 +856,7 @@ def make_block(*, num_heads: int, attention_fn: Callable | None = None,
         if bshd:
             # kernel reads the residual layout directly ([b, s, h, hd] is
             # a free reshape of [b, s, e]) — no head transpose on either
-            # side. At the flash bench shape the transposes a bhsd
+            # side. At d=2048 H=16 S=1024 the transposes a bhsd
             # attention forces cost ~20 ms/step, 2.5× the kernel itself.
             # (A fully fused qkv-packed kernel input was also measured:
             # the strided k/v lane reads cost MORE than the slice copies
@@ -1015,7 +1015,7 @@ def _layer(block: Block, positions, remat: bool, remat_policy: str):
         # custom-vjp residuals (q/k/v/out/lse) remain resident and
         # the backward never re-runs the attention forward. Costs
         # O(b·s·d) extra bytes per layer over full remat; at the
-        # S=8192 long-context bench it buys 1.14x tokens/sec.
+        # S=8192 (d=1024, L=2, v5e, July 2026) it bought 1.14x tokens/sec.
         ffn_ckpt = jax.checkpoint(block.ffn)
         return lambda x, blk: ffn_ckpt(
             block.attn(x, blk, positions=positions), blk)

@@ -318,7 +318,7 @@ def summarize_discipline(records: list[dict]) -> dict[str, Any]:
       quorum, retarget/restore timeout),
     * ``trace`` — the per-window discipline trajectory
       ``[(effective_step, k, timeout_ms), ...]`` from the completes:
-      the parameter-vs-step curve a bench report plots,
+      the parameter-vs-step curve a report plots,
     * ``reaction_s`` — decide→staged latency percentiles,
     * ``flaps`` — consecutive opposite-direction changes closer (in
       STEPS — the controller's clock) than twice the recorded

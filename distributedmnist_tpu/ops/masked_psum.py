@@ -60,8 +60,8 @@ def masked_mean_psum(tree: Any, flag: jax.Array, axis_name: str) -> tuple[Any, j
     """
     # One elementwise pass per leaf: pre-scale by the SCALAR flag/denom
     # so psum produces the mean directly (scaling after the psum would
-    # spend a second full-size HBM pass per leaf — measured as a real
-    # throughput tax on small step times by bench_mode_overhead).
+    # spend a second full-size HBM pass per leaf — a measured tax on small
+    # steps: the harness removed at PR 48, BENCH_r04/r05.json in history).
     scale, num = contribution_scale(flag, axis_name)
     mean = jax.tree.map(
         lambda g: lax.psum(g * scale.astype(g.dtype), axis_name), tree)

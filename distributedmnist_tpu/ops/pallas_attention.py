@@ -14,7 +14,7 @@ parity. Composes with the sequence-parallel strategies:
   model-layout entry — it reads the residual stream's natural
   [batch, seq, heads, head_dim] (one free reshape away from
   [b, s, d_model]) via a head grid axis, so NO transpose is ever
-  materialized around the kernel. Measured on v5e at the bench shape
+  materialized around the kernel. Measured on v5e at d=2048 H=16 S=1024
   this removes ~20 ms/step of pure layout copies (~14% of the step).
 * Ulysses (ops/ulysses_attention): after the all-to-all each device
   holds full sequences for a head subset in [b, h, s, d] —
@@ -129,7 +129,7 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 # earlier sweep whose chain used only dq let XLA dead-code the dkv
 # kernel and mis-ranked (512,1024) at depth): (1024,1024) wins at
 # every S ≥ 1024 — 4.66 ms vs 7.78 for the old fixed (512,512) at the
-# S=1024 bench shape, 11.0 vs 16.3 at S=8192. Blocks stay ≤1024:
+# S=1024 shape (d=2048 H=16), 11.0 vs 16.3 at S=8192. Blocks stay ≤1024:
 # 2048-wide blocks exceed the 16 MB scoped-VMEM stack limit at depth
 # (compile-time OOM in the dkv kernel). Callers can still override
 # explicitly; other chips inherit the table as a heuristic.
@@ -376,8 +376,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     true for every s ≤ 1024 under the tuned table). The split dq / dkv
     kernels each recompute the score matrix; here p and do·vᵀ are
     computed ONCE and feed all three cotangents — 7 → 5 score-sized
-    matmuls (−29% backward FLOPs), measured −2.5 ms/step on the v5e
-    flash bench. Larger grids keep the two-kernel path: a fused kernel
+    matmuls (−29% backward FLOPs), measured −2.5 ms/step on a v5e at
+    d=2048 H=16 S=1024. Larger grids keep the two-kernel path: a fused kernel
     would have to revisit dq blocks across non-adjacent iterations,
     and the resulting spill/reload traffic exceeds the recompute."""
     # the always-true pl.when is load-bearing on the interpreter path:
@@ -569,8 +569,8 @@ def flash_attention_bshd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     head_dim] — one free reshape from the residual stream's
     [b, s, d_model], so no [b,s,h,d]→[b,h,s,d] transpose is ever
     materialized (pallas operand layout constraints would force real
-    HBM copies; at the bench shape those copies cost more than twice
-    the kernel itself). The head dim rides a grid axis; tiles are
+    HBM copies; at d=2048 H=16 S=1024 those copies cost more than
+    twice the kernel itself). The head dim rides a grid axis; tiles are
     strided in HBM, which the DMA engine handles natively.
     """
     b, s, h, d = q.shape

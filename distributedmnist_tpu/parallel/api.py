@@ -1263,8 +1263,8 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
             if opt.num_slots == 0:
                 # stateless sgd: lr·0 is exact, so scaling the scalar
                 # lr by the applied flag IS the no-op — no full-size
-                # per-parameter select pass (a measured throughput tax
-                # on small steps, bench_mode_overhead)
+                # per-parameter select pass (a measured tax on small steps:
+                # the harness removed at PR 48, BENCH_r04/r05.json in history)
                 new_params, new_opt = _apply_tree_update(
                     opt, state.params, mean_grads, None,
                     lr * applied.astype(jnp.float32), t_next, pspec_tree)
@@ -1399,8 +1399,8 @@ def build_train_step(model: Model, cfg: ExperimentConfig, topo: Topology,
         exe = aot_box.get("exe")
         if exe is not None:
             # one flatten covers both guards: tracers ANYWHERE in the
-            # args (a caller jitting over step_fn — e.g. bench's scanned
-            # chunks, or a jit closing over state but tracing the batch)
+            # args (a caller jitting over step_fn — e.g. a lax.scan of
+            # steps, or a jit closing over state but tracing the batch)
             # must take the traceable jit path, and a different
             # signature (a test swapping batch shapes) simply compiles
             # through jit as before. Compared leafwise with early exit —
@@ -1555,54 +1555,3 @@ def build_eval_step(model: Model, cfg: ExperimentConfig, topo: Topology):
         in_specs=(pspec, P(axis)),
         out_specs=(P(), P(), P()))
     return jax.jit(sharded)
-
-
-def build_weight_update_step(model: Model, cfg: ExperimentConfig,
-                             topo: Topology, schedule: Schedule):
-    """Jitted ``(state, grads) -> state`` applying ONLY the gradient
-    aggregation + weight update — no forward/backward — under the
-    configured discipline (replicated, or ZeRO-1 when
-    ``parallel.shard_weight_update`` applies).
-
-    This isolates the exact region the ZeRO-1 paper optimizes so the
-    ``weight_update_sharding`` bench case (bench.py) can time it and
-    meter its per-chip optimizer-state bytes without the model compute
-    drowning the signal. ``grads`` is a params-shaped pytree placed per
-    ``params_partition_specs`` (replicated on a pure-DP mesh); its
-    values only feed the update, so a bench may pass any tree of the
-    right shapes.
-    """
-    axis = topo.replica_axis
-    from ..train import optim as optim_lib
-    opt = optim_lib.make_optimizer(cfg.optim)
-    if cfg.sync.mode == "interval":
-        raise ValueError("build_weight_update_step models the per-step "
-                         "apply disciplines; interval mode applies on a "
-                         "wall-clock window (use build_train_step)")
-    state_specs = state_partition_specs(model, cfg, topo)
-    grad_specs = params_partition_specs(model, cfg, topo)
-    z_plan = zero1_plan_for(model, cfg, topo)
-
-    def shard_fn(state: TrainState, grads: Any) -> TrainState:
-        flag = jnp.ones((), jnp.float32)
-        lr = schedule(state.updates_applied)
-        t_next = state.updates_applied.astype(jnp.float32) + 1.0
-        if z_plan is not None:
-            new_params, new_opt, _, applied = _zero1_update(
-                state.params, grads, state.momentum, flag, lr, t_next,
-                axis, z_plan, opt, grad_specs)
-        else:
-            mean_grads, num = masked_mean_psum(grads, flag, axis)
-            new_params, new_opt = _apply_tree_update(
-                opt, state.params, mean_grads, state.momentum, lr,
-                t_next, grad_specs)
-            applied = (num > 0).astype(jnp.int32)
-        return state.replace(params=new_params, momentum=new_opt,
-                             step=state.step + 1,
-                             updates_applied=state.updates_applied + applied)
-
-    sharded = mesh_lib.shard_map(
-        shard_fn, mesh=topo.mesh,
-        in_specs=(state_specs, grad_specs),
-        out_specs=state_specs)
-    return jax.jit(sharded, donate_argnums=0)

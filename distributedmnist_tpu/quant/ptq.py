@@ -142,7 +142,7 @@ def dynamic_input_fake_quant(x: jax.Array) -> jax.Array:
 
 def tier_param_bytes(tree: Any) -> int:
     """Resident weight bytes of a tier tree (the memory claim the
-    bench artifact records)."""
+    sidecar's meta records; tests/test_quant.py bounds it)."""
     return sum(np.asarray(l).nbytes for l in jax.tree.leaves(tree))
 
 
@@ -159,7 +159,7 @@ def tree_params_digest(params_sd: Any) -> str:
 
 
 # ---------------------------------------------------------------------------
-# parity: the accuracy oracle shared by calibration, tests, and bench
+# parity: the accuracy oracle shared by calibration and tests
 # ---------------------------------------------------------------------------
 
 def build_tier_predict(model, template_params: Any,

@@ -748,9 +748,9 @@ class DecodeReplica(ServingReplica):
         width): between admit/finish/restart events the array at one
         width is bit-identical every iteration, so steady-state
         decoding reuses one upload instead of paying a host rebuild +
-        transfer per token (measured in bench_decode_throughput's
-        ``table_prep`` detail); a sequence that grows past a rung gets a
-        fresh, wider one.
+        transfer per token (reuse near 0.7 under churn in the harness
+        removed at PR 48; tests/test_decode_widths.py holds the counters);
+        a sequence that grows past a rung gets a fresh, wider one.
         """
         key = (ver, self._tables_epoch, width)
         cached = self._tables_cache.get(key)

@@ -95,7 +95,7 @@ class ServeGroup:
         self._log.write({"event": "serve", "time": time.time(), **record})
 
     def _write_group_json(self) -> None:
-        """Atomic group roster (pids per rank) — what the chaos/bench
+        """Atomic group roster (pids per rank) — what the chaos
         side reads to target a specific rank."""
         path = self.serve_dir / "group.json"
         tmp = path.with_suffix(".tmp")

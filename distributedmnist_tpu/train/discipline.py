@@ -238,7 +238,7 @@ class DisciplineController:
         self.last_change_step: float | None = None
         self.changes = 0
         # epoch trace: (effective_step, k, timeout_ms) per change — the
-        # per-window discipline trace benches/summaries report
+        # per-window discipline trace the summaries report
         self.trace: list[tuple[int, int, float]] = []
 
     def params_list(self) -> list[float]:

@@ -447,9 +447,9 @@ class Trainer:
                                   "where": "sync"})
             self._last_save_time = time.time()
             return
-        # what the step loop actually paid for this save — the quantity
-        # the save_stall bench gates (async-snapshot dispatch vs the
-        # sync host fetch + canonical conversion)
+        # what the step loop actually paid for this save (async-snapshot
+        # dispatch, or the sync host fetch + canonical conversion);
+        # journaled, tests/test_async_checkpoint.py reads it
         stall_ms = (time.perf_counter() - t0) * 1e3
         self.collector.add_snapshot_stall_ms(stall_ms)
         # "at_step", deliberately NOT "step": the log-tail parsers
@@ -661,7 +661,7 @@ class Trainer:
             after = cache_stats()
             # zero new entries across a compile = every program came
             # out of the persistent cache — the warm-restart evidence
-            # the bench/CI artifacts surface
+            # the worker's compile record carries
             info["persistent_cache"] = {
                 "dir": after["dir"],
                 "entries": after["entries"],

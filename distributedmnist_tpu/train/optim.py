@@ -73,7 +73,7 @@ class Optimizer:
 
 def validate(ocfg: OptimConfig) -> None:
     """Typed validation for the optimizer section — raised at build
-    time (make_optimizer) so every consumer (Trainer, bench, tests)
+    time (make_optimizer) so every consumer (Trainer, benchmark, tests)
     fails loudly before tracing anything."""
     if ocfg.name not in OPTIMIZER_NAMES:
         raise ConfigError(

@@ -15,7 +15,8 @@ assumptions:
   latest-pointer, JSONL journal appends (:class:`core.log.JsonlSink`
   calls :func:`fsync_journal` when this module says so), and the
   parent directory after every rename — the power-cut-proof upper
-  bound the ``checkpoint_durability`` bench case prices.
+  bound (its fsync tax was priced on a CPU runner's disk by the
+  harness removed at PR 48; on the chip's host: not measured).
 
 * **Deterministic disk-fault injection** (``FaultPlan.disk_faults``):
   per-worker fault scripts — :data:`DISK_FAULT_KINDS` — armed in the

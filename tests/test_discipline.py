@@ -209,7 +209,58 @@ def test_controller_journals_licensed_pairs_and_swaps_vector():
     assert ctrl.summary()["changes"] == ctrl.changes
 
 
-def test_controller_tightens_to_floor_then_relaxes_to_static():
+def _modeled_time_to_target(times, window):
+    """The controller on a [steps, n] matrix of step times, no mesh: a
+    step's barrier costs what its k-th fastest replica took. Returns
+    (modeled ms, journal)."""
+    n = times.shape[1]
+    cfg = _cfg(adaptive_window_steps=window,
+               adaptive_cooldown_steps=window)
+    journal: list[dict] = []
+    ctrl = DisciplineController(cfg, n, journal.append,
+                                lambda k, t, i: (k, t, i))
+    cost = 0.0
+    for i in range(len(times)):
+        cost += float(np.sort(times[i])[int(ctrl.current.k) - 1])
+        stats = None
+        if i + 1 >= window:
+            tail = times[i + 1 - window:i + 1]
+            p50, p90, p99 = np.percentile(tail, (50.0, 90.0, 99.0))
+            stats = WindowStats(
+                p50_ms=float(p50), p90_ms=float(p90), p99_ms=float(p99),
+                n_samples=window,
+                fast_p50_ms=float(np.median(tail, axis=0).min()))
+        ctrl.maybe_adapt(i + 1, stats)
+    return cost, journal
+
+
+@pytest.mark.parametrize("fed", ["tail_ratios", "phased_step_times"])
+def test_controller_tightens_to_floor_then_relaxes_to_static(fed):
+    if fed == "phased_step_times":
+        # calm, then two of four replicas 8x slow, then all four 3x slow
+        # (healthy but slow: the phase that breaks a fixed deadline).
+        # What the adaptive discipline is for, on modeled time: it
+        # reaches the target sooner than any static choice an operator
+        # could have made beforehand, and a deadline tuned on the calm
+        # phase's tail never gets there.
+        n, base, rng = 4, 50.0, np.random.default_rng(0)
+        times = np.stack([
+            base * mult + rng.uniform(0.0, 1.5, n)
+            for steps, mult in ((25, np.ones(n)),
+                                (30, np.array([1.0, 1.0, 8.0, 8.0])),
+                                (25, np.full(n, 3.0)))
+            for _ in range(steps)])
+        adaptive, journal = _modeled_time_to_target(times, window=6)
+        s = summarize_discipline(journal)
+        assert s["by_direction"].get("tighten", 0) >= 1
+        assert s["by_direction"].get("relax", 0) >= 1
+        assert s["flaps"] == 0 and s["completed"] == s["changes"]
+        ordered = np.sort(times, axis=1)
+        statics = {k: float(ordered[:, k - 1].sum()) for k in (n, n - 1)}
+        assert adaptive <= 0.9 * min(statics.values()), (adaptive, statics)
+        deadline = 1.5 * float(np.percentile(times[:25], 99))
+        assert (times[-25:] > deadline).all()   # no contributor, no update
+        return
     cfg = _cfg()
     ctrl, journal, _ = _run_controller([9.0] * 40, cfg)
     assert ctrl.current.k == quorum_floor(cfg, N)
@@ -462,10 +513,14 @@ def test_trainer_adapts_quorum_under_spike_profile(tmp_train_dir,
               "straggler_spike_scale": 8.0},
         train={"max_steps": 14, "log_every_steps": 1,
                "train_dir": tmp_train_dir})
-    run_summary = Trainer(cfg, datasets=synthetic_datasets).run()
+    trainer = Trainer(cfg, datasets=synthetic_datasets)
+    run_summary = trainer.run()
     summary = run_summary["discipline"]
     assert summary["changes"] >= 1
     assert summary["current_k"] < 8  # tightened off the static quorum
+    # the change was a new operand of the one executable compiled before
+    # step 1: nothing fell through to a second compile
+    assert trainer.step_fn.jitted._cache_size() == 0
 
     log = load_jsonl(Path(tmp_train_dir) / "train_log.jsonl")
     steps = [r for r in log if r.get("event") == "step"

@@ -338,16 +338,23 @@ def test_parity_refusal_blocks_publish(tmp_path, synthetic_datasets,
         ckpt.read_quant_sidecar(d, 10)
 
 
-def test_tier_predict_parity_on_eval_split(quant_run, synthetic_datasets):
+@pytest.mark.parametrize("tier, resident", [("int8", 0.35), ("bf16", 0.55)])
+def test_tier_predict_parity_on_eval_split(quant_run, synthetic_datasets,
+                                           tier, resident):
     """The accuracy-parity oracle in unit form: the dequantize-in-graph
     predicts (the exact fns the replica serves) agree with fp32 top-1
-    on the full eval split within the published epsilon."""
+    on the full eval split within the published epsilon. And the other
+    half of the trade, from the same sidecar: the tier's resident
+    weight bytes as a share of fp32's (per-channel int8 + f32 scales +
+    f32 1-D leaves lands near 0.25; the bound catches a quantizer that
+    silently stopped quantizing)."""
     import jax
 
     from distributedmnist_tpu.core.config import effective_model_config
     from distributedmnist_tpu.models.registry import get_model
     from distributedmnist_tpu.quant.ptq import (build_tier_predict,
-                                                parity_report)
+                                                parity_report,
+                                                tier_param_bytes)
     from distributedmnist_tpu.train import checkpoint as ckpt
     cfg = quant_run["cfg"]
     model = get_model(effective_model_config(cfg))
@@ -359,14 +366,17 @@ def test_tier_predict_parity_on_eval_split(quant_run, synthetic_datasets):
     labels = synthetic_datasets.test.labels
     ref = np.asarray(jax.jit(build_tier_predict(model, template, "fp32"))(
         params_sd, x))
-    for tier in ("int8", "bf16"):
-        probs = np.asarray(
-            jax.jit(build_tier_predict(model, template, tier))(
-                payload["tiers"][tier], x))
-        rep = parity_report(ref, probs, labels)
-        eps = cfg.quant.parity_epsilon
-        assert rep["agreement"] >= 1.0 - eps, (tier, rep)
-        assert rep["top1_tier"] >= rep["top1_ref"] - eps, (tier, rep)
+    probs = np.asarray(
+        jax.jit(build_tier_predict(model, template, tier))(
+            payload["tiers"][tier], x))
+    rep = parity_report(ref, probs, labels)
+    eps = cfg.quant.parity_epsilon
+    assert rep["agreement"] >= 1.0 - eps, (tier, rep)
+    assert rep["top1_tier"] >= rep["top1_ref"] - eps, (tier, rep)
+    nbytes = payload["meta"]["param_bytes"]
+    assert nbytes[tier] == tier_param_bytes(payload["tiers"][tier])
+    assert nbytes["fp32"] == tier_param_bytes(params_sd)
+    assert nbytes[tier] <= resident * nbytes["fp32"], nbytes
 
 
 # ---------------------------------------------------------------------------

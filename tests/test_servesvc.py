@@ -297,20 +297,40 @@ def test_client_fails_over_and_deadline(published, tmp_path):
 # protocol hardening (ISSUE 19): dedup cache, deadlines, quarantine
 # ---------------------------------------------------------------------------
 
-def test_dedup_replay_answers_from_cache(published, tmp_path):
+@pytest.mark.parametrize("replayed_by", ["the_same_bytes_sent_again",
+                                         "the_clients_retry_after_a_reset"])
+def test_dedup_replay_answers_from_cache(published, tmp_path, replayed_by):
     """A replayed request id (the retry after a reset ate the
     response) is answered from the idempotency cache — journaled as a
-    ``dedup_hit`` AFTER the one respond, never a second execution."""
+    ``dedup_hit`` AFTER the one respond, never a second execution.
+    Once by hand, and once as it happens: behind a proxy that cuts the
+    first response on the wire, after the replica cached it, the
+    client's own retry is the replay."""
     rep, _ = make_replica(published, tmp_path)
     rep.start()
+    proxy = None
     try:
         make_input = sample_input(published)
-        payload = {"id": "r-7", "inputs": make_input(7)}
-        first = raw_request(rep.bound_port, payload)
-        replay = raw_request(rep.bound_port, payload)
-        assert first["status"] == "ok"
-        # byte-identical outcome: same step, same probs, same id
-        assert replay == first
+        if replayed_by == "the_same_bytes_sent_again":
+            payload = {"id": "r-7", "inputs": make_input(7)}
+            first = raw_request(rep.bound_port, payload)
+            replay = raw_request(rep.bound_port, payload)
+            assert first["status"] == "ok"
+            # byte-identical outcome: same step, same probs, same id
+            assert replay == first
+        else:
+            from distributedmnist_tpu.launch.netchaos import ChaosProxy
+            from distributedmnist_tpu.servesvc.client import ServeClient
+            # any classifier response is longer than 100 bytes: the
+            # one-shot cut lands inside it
+            proxy = ChaosProxy(("127.0.0.1", rep.bound_port),
+                               [{"kind": "reset", "after_bytes": 100}],
+                               worker=1, seed=0)
+            client = ServeClient([("127.0.0.1", proxy.start())],
+                                 deadline_s=10.0)
+            out = client.request(make_input(7), request_id="r-7")
+            assert out["status"] == "ok" and out["retried"] is True
+            assert len(out["probs"]) == 10
         assert rep.dedup_hits == 1
         recs = serve_records(rep)
         acts = [(r["action"], r.get("id")) for r in recs
@@ -320,6 +340,8 @@ def test_dedup_replay_answers_from_cache(published, tmp_path):
         i_resp = acts.index(("respond", "r-7"))
         assert ("dedup_hit", "r-7") in acts[i_resp:]
     finally:
+        if proxy is not None:
+            proxy.stop()
         rep.stop()
 
 

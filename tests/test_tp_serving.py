@@ -309,17 +309,13 @@ def test_rank_shard_digest_distinct_per_rank_and_deterministic():
 # real TP replica boot (simulated mesh)
 # ---------------------------------------------------------------------------
 
-def test_decode_replica_boots_tensor_parallel(tmp_path):
-    """tp_ranks=2 builds a replica=1 × model=2 serving mesh, and the
-    mesh-portable restore actually SHARDS the followed checkpoint —
-    at least the attention/FFN weights carry the model axis."""
-    import jax
+@pytest.fixture(scope="module")
+def lm_staging(tmp_path_factory):
+    """One published 10-step checkpoint of the toy transformer."""
+    from distributedmnist_tpu.core.config import ExperimentConfig
+    from distributedmnist_tpu.train.loop import Trainer
 
-    from distributedmnist_tpu.core.config import (DecodeConfig,
-                                                  ExperimentConfig,
-                                                  ServeConfig)
-
-    staging = tmp_path / "staging"
+    staging = tmp_path_factory.mktemp("tp_staging")
     cfg = ExperimentConfig.from_dict({
         "data": {"dataset": "synthetic_lm", "batch_size": 32,
                  "synthetic_train_size": 256, "synthetic_test_size": 64,
@@ -330,8 +326,66 @@ def test_decode_replica_boots_tensor_parallel(tmp_path):
                   "save_interval_steps": 10, "save_results_period": 0,
                   "async_checkpoint": False},
     })
-    from distributedmnist_tpu.train.loop import Trainer
     Trainer(cfg).run()
+    return staging, cfg
+
+
+@pytest.mark.parametrize("rank", [0, 1])
+def test_decode_replica_boots_tensor_parallel(lm_staging, tmp_path,
+                                              monkeypatch, rank):
+    """Rank 0: tp_ranks=2 builds a replica=1 × model=2 serving mesh, and
+    the mesh-portable restore actually SHARDS the followed checkpoint —
+    at least the attention/FFN weights carry the model axis. Rank 1, a
+    follower: it restores the same publish through the same digest
+    check and journals ``shard_verify`` with the sha256 of the bytes
+    ITS half of the model axis holds."""
+    import jax
+
+    from distributedmnist_tpu.core.config import (DecodeConfig,
+                                                  ExperimentConfig,
+                                                  ServeConfig)
+
+    staging, cfg = lm_staging
+    if rank == 1:
+        from distributedmnist_tpu.models.registry import get_model
+        from distributedmnist_tpu.obsv.report import load_jsonl
+        from distributedmnist_tpu.servesvc import tp_group
+        from distributedmnist_tpu.train import checkpoint as ckpt
+
+        # the follower runs until its supervisor kills it: here its
+        # first park ends it, and it installs no signal handler in the
+        # test's process
+        park, real_sleep = 0.123, time.sleep
+
+        class Parked(Exception):
+            pass
+
+        def sleep(secs):
+            if secs == park:
+                raise Parked
+            real_sleep(secs)
+
+        monkeypatch.setattr(tp_group.signal, "signal", lambda *a: None)
+        monkeypatch.setattr(tp_group.time, "sleep", sleep)
+        with pytest.raises(Parked):
+            tp_group.run_rank_follower(staging, tmp_path / "rank1", 1, 2,
+                                       poll_secs=park)
+        verified = [r for r in load_jsonl(tmp_path / "rank1"
+                                          / "serve_log.jsonl", "serve")
+                    if r["action"] == "shard_verify"]
+        assert [(r["rank"], r["step"]) for r in verified] == [(1, 10)]
+        params = ckpt._checkpoint_state_dict(staging, 10)[0]["params"]
+        specs = get_model(cfg.model).tp_param_specs("model")
+        assert verified[0]["digest"] == tp_group.rank_shard_digest(
+            params, specs, 1, 2)
+        assert verified[0]["digest"] != tp_group.rank_shard_digest(
+            params, specs, 0, 2)
+        assert verified[0]["source_digest"] == ckpt.artifact_digest(
+            staging, 10)
+        beats = load_jsonl(tmp_path / "rank1" / "train_log.jsonl",
+                           "heartbeat")
+        assert beats[-1]["step"] == 1 and beats[-1]["tp_rank"] == 1
+        return
 
     from distributedmnist_tpu.servesvc.decode import DecodeReplica
     rep = DecodeReplica(
